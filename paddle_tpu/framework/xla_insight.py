@@ -35,6 +35,7 @@ from __future__ import annotations
 
 import hashlib
 import json
+import logging
 import os
 import threading
 import time
@@ -52,7 +53,7 @@ __all__ = [
     "aot_call", "memory_analysis_bytes", "dump_artifacts",
     "load_dump_dir", "recent", "clear_recent", "program_footprint",
     "value_bytes", "new_footprint_row", "footprint_report",
-    "COST_SCHEMA", "FOOTPRINT_SCHEMA",
+    "failure_counts", "COST_SCHEMA", "FOOTPRINT_SCHEMA",
 ]
 
 COST_SCHEMA = "paddle_tpu.xla_cost/1"
@@ -75,6 +76,12 @@ _M_BYTES = _monitor.gauge(
 _M_CAPTURE = _monitor.counter(
     "xla_insight_captures_total",
     "compile-time insight captures by outcome", labelnames=("result",))
+_M_AOT_FALLBACK = _monitor.counter(
+    "xla_insight_aot_fallback_total",
+    "AOT executables abandoned for plain jit after a call-time "
+    "signature mismatch (each one is a second compile of that program)")
+
+_log = logging.getLogger(__name__)
 
 
 def enabled() -> bool:
@@ -155,8 +162,10 @@ def capture(jit_fn, example_args: Sequence[Any], *, key_hash: str,
     callable for exactly these avals — the caller installs it (via
     :func:`aot_call`) as the cache entry's function, so the capture costs
     no second XLA compile. On any failure returns ``(None, None)`` and
-    the caller keeps plain jit dispatch; compiler observability must
-    never take down a run that would otherwise work.
+    the caller keeps plain jit dispatch, which compiles the program a
+    second time (and raises there if the failure was the program's own);
+    the failure is logged and counted (:func:`failure_counts`) so a
+    measured run can assert it never happened.
     """
     if not enabled() or not hasattr(jit_fn, "trace"):
         return None, None
@@ -165,8 +174,11 @@ def capture(jit_fn, example_args: Sequence[Any], *, key_hash: str,
         jaxpr = traced.jaxpr
         lowered = traced.lower()
         executable = lowered.compile()
-    except Exception:
+    except Exception as e:
         _M_CAPTURE.labels(result="error").inc()
+        _log.warning("xla_insight capture of %s (%s) failed, the caller "
+                     "recompiles through plain jit: %s: %s",
+                     label or "program", key_hash, type(e).__name__, e)
         return None, None
 
     insight = ProgramInsight(
@@ -182,8 +194,6 @@ def capture(jit_fn, example_args: Sequence[Any], *, key_hash: str,
         cost = executable.cost_analysis()
     except Exception:
         pass
-    if isinstance(cost, (list, tuple)):  # older jax returns [dict]
-        cost = cost[0] if cost else None
     if isinstance(cost, dict):
         insight.cost_raw = {
             str(k): float(v) for k, v in cost.items()
@@ -246,11 +256,9 @@ def capture(jit_fn, example_args: Sequence[Any], *, key_hash: str,
 
 
 def _device_count() -> int:
-    try:
-        import jax
-        return jax.device_count()
-    except Exception:
-        return 1
+    import jax
+
+    return jax.device_count()
 
 
 def memory_analysis_bytes(executable) -> Dict[str, Optional[int]]:
@@ -300,7 +308,9 @@ def aot_call(executable, fallback):
 
     Signature-mismatch errors (an aval the cache key failed to pin) are
     raised by the executable BEFORE execution, so no donated buffer has
-    been consumed when the fallback takes over.
+    been consumed when the fallback takes over. The fallback compiles
+    the program again, so it is logged and counted
+    (:func:`failure_counts`).
     """
     use_aot = [True]
 
@@ -308,11 +318,26 @@ def aot_call(executable, fallback):
         if use_aot[0]:
             try:
                 return executable(*args)
-            except (TypeError, ValueError):
+            except (TypeError, ValueError) as e:
                 use_aot[0] = False
+                _M_AOT_FALLBACK.inc()
+                _log.warning("AOT executable rejected its arguments, "
+                             "recompiling through plain jit: %s: %s",
+                             type(e).__name__, e)
         return fallback(*args)
 
     return call
+
+
+def failure_counts() -> Dict[str, int]:
+    """How often this process lost an AOT executable: failed captures
+    and call-time fallbacks. Both mean a program compiled twice, so
+    chip_smoke.py and benchmarks assert both are zero. Counted through
+    the metrics registry (zero forever under PADDLE_TPU_METRICS=0)."""
+    return {
+        "capture_errors": int(_M_CAPTURE.labels(result="error").value),
+        "aot_fallbacks": int(_M_AOT_FALLBACK.value),
+    }
 
 
 # ---------------------------------------------------------------------------

@@ -5,7 +5,7 @@ No reference twin: goodcoder-cnn/Paddle predates fused attention (its
 the fused softmax(QK^T)V is the single hottest transformer op, so it is a
 first-class op here, with a pallas flash-attention kernel for long
 sequences (paddle_tpu/ops/pallas/flash_attention.py) and an XLA einsum path
-as fallback/reference.
+for short or untileable shapes, which is also the reference.
 """
 from __future__ import annotations
 
@@ -18,23 +18,23 @@ from ..framework.registry import register_op
 from .common import maybe
 
 
-_fallback_warned = set()
+_xla_path_warned = set()
 
 # trace-time count of fused_attention_tpu lowerings that dispatched to the
-# pallas flash kernel — bench.py asserts the long-seq config actually hits
-# the flash path instead of silently falling back to the XLA einsum
+# pallas flash kernel — chip_smoke.py and bench.py assert the long-seq
+# config actually ran the flash path
 FLASH_DISPATCH_COUNT = 0
 
 
-def _warn_fallback(reason: str) -> None:
-    """One warning per distinct reason — a silent fallback would hide a
-    missing flash path (round-1 lesson)."""
-    if reason not in _fallback_warned:
-        _fallback_warned.add(reason)
+def _warn_xla_path(reason: str) -> None:
+    """One warning per distinct reason a flash-length sequence runs the
+    XLA einsum path because its shape does not tile."""
+    if reason not in _xla_path_warned:
+        _xla_path_warned.add(reason)
         import logging
 
         logging.getLogger(__name__).warning(
-            "fused_attention_tpu: falling back to the XLA einsum path: %s", reason
+            "fused_attention_tpu: using the XLA einsum path: %s", reason
         )
 
 
@@ -121,32 +121,25 @@ def _fused_attention_tpu(ctx, ins, attrs):
         )
         if layout == "BTHD":
             out = out.transpose(0, 2, 1, 3)
-    # measured crossover on v5e (bench_flash sweeps, round 4): XLA's fused
-    # attention wins at T=512 (the flash grid overhead dominates), the
-    # pallas kernel wins from ~1k up — and at T=2048 the XLA path fails to
-    # compile outright on this toolchain, so flash is also the only path.
-    # PADDLE_TPU_FLASH_MIN_SEQ overrides for crossover re-measurement.
+    # crossover measured on v5e before PR 1 (flash sweeps, another
+    # installation): XLA's fused attention won at T=512 (the flash grid
+    # overhead dominates), the pallas kernel from ~1k up. Not re-measured
+    # on current code. PADDLE_TPU_FLASH_MIN_SEQ overrides for re-measurement.
     min_seq = int(os.environ.get("PADDLE_TPU_FLASH_MIN_SEQ", 1024))
-    if out is None and use_flash and mask is None and q.shape[seq_ax] >= min_seq and q.shape[-1] in (64, 128, 256):
+    # GSPMD cannot partition a Mosaic call, and the flash kernel has no
+    # shard_map region of its own yet: a mesh program that did not take
+    # the ring path above runs the XLA einsum, which GSPMD partitions
+    single_device = mesh is None or mesh.size == 1
+    if out is None and use_flash and single_device and mask is None and q.shape[seq_ax] >= min_seq and q.shape[-1] in (64, 128, 256):
         tq, tk = q.shape[seq_ax], k.shape[seq_ax]
-        # measured on v5e @ T=2048, full GPT train step (round 5 sweep):
-        # fwd (256, 1024) + bwd (512,512;512,512) = 171.9 ms/step vs
-        # 193.7 at the old shared (256, 512) — the wide fwd kv block
-        # halves the sequential-sweep rescale work (it needs the raised
-        # per-kernel vmem limit, see pallas/flash_attention._VMEM_LIMIT),
-        # while the backward prefers square 512 tiles. Wider-than-512
-        # dq/dkv kv blocks measured strictly worse (187-196 ms).
-        try:
-            from .pallas.flash_attention import VMEM_RAISED as _vmem_raised
-        except Exception:  # pallas unavailable: the flash try below warns
-            _vmem_raised = False
-
+        # tilings from the same pre-PR-1 sweep (T=2048, full GPT train
+        # step): fwd (256, 1024) + bwd (512,512;512,512) beat the shared
+        # (256, 512) — the wide fwd kv block halves the sequential-sweep
+        # rescale work (it needs the raised per-kernel vmem limit, see
+        # pallas/backend.VMEM_LIMIT), while the backward prefers square
+        # 512 tiles.
         if layout == "BTHD":
             cand_q, cand_k = (256, 128), (1024, 512, 256, 128)
-            if not _vmem_raised:
-                # this toolchain caps kernels at the 16MB scoped budget,
-                # which the H-wide (256, 1024) tiling exceeds
-                cand_k = (512, 256, 128)
         else:
             cand_q, cand_k = (512, 256, 128), (1024, 512, 256, 128)
         if _env_blocks:
@@ -159,15 +152,12 @@ def _fused_attention_tpu(ctx, ins, attrs):
         bq = next((b for b in cand_q if tq % b == 0), None)
         bk = next((b for b in cand_k if tk % b == 0), None)
         if bq is None or bk is None:
-            _warn_fallback(f"seq lengths ({tq},{tk}) not divisible by 128")
+            _warn_xla_path(f"seq lengths ({tq},{tk}) not divisible by 128")
         else:
-            # parse the sweep knob OUTSIDE the fallback try: a malformed
-            # value must error loudly, not silently bench the XLA path.
-            # Default backward tiling: square 512 blocks (the round-5
-            # end-to-end winner), independent of the wide fwd kv block —
-            # but only when NO sweep knob is set, so a shared-blocks
-            # sweep via PADDLE_TPU_FLASH_BLOCKS keeps its historical
-            # fwd+bwd meaning.
+            # Default backward tiling: square 512 blocks, independent of
+            # the wide fwd kv block — but only when NO sweep knob is set,
+            # so a shared-blocks sweep via PADDLE_TPU_FLASH_BLOCKS keeps
+            # its fwd+bwd meaning.
             bwd_blocks = None
             env_bwd = os.environ.get("PADDLE_TPU_FLASH_BWD_BLOCKS")
             if (layout == "BTHD" and not _env_blocks and not env_bwd
@@ -184,19 +174,18 @@ def _fused_attention_tpu(ctx, ins, attrs):
                         f"PADDLE_TPU_FLASH_BWD_BLOCKS={env_bwd!r}: expected "
                         f"'bq_dq,bk_dq;bq_dkv,bk_dkv'"
                     )
-            try:
-                from .pallas.flash_attention import flash_attention
+            # no try/except: once the shape selects the kernel, a kernel
+            # that fails to trace is an error on every backend — the XLA
+            # path is a shape-based choice, never a rescue
+            from .pallas.flash_attention import flash_attention
 
-                # both layouts are native kernel tilings — no transposes
-                out = flash_attention(
-                    q, k, v, causal=is_causal, block_q=bq, block_k=bk,
-                    layout=layout, bwd_blocks=bwd_blocks,
-                )
-                global FLASH_DISPATCH_COUNT
-                FLASH_DISPATCH_COUNT += 1
-            except Exception as e:  # pallas unavailable on this backend
-                out = None
-                _warn_fallback(f"pallas kernel failed ({type(e).__name__}: {e})")
+            # both layouts are native kernel tilings — no transposes
+            out = flash_attention(
+                q, k, v, causal=is_causal, block_q=bq, block_k=bk,
+                layout=layout, bwd_blocks=bwd_blocks,
+            )
+            global FLASH_DISPATCH_COUNT
+            FLASH_DISPATCH_COUNT += 1
     if out is None:
         out = _sdpa_xla(q, k, v, mask, is_causal, layout=layout)
     p = attrs.get("dropout_p", 0.0)

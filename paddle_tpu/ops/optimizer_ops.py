@@ -8,6 +8,8 @@ the update is pure, and the executor stores the returned arrays back.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -27,13 +29,15 @@ def register_optimizer(name, fused=None):
     has the same split: fp32 master weights in its AMP decorator,
     /root/reference/python/paddle/fluid/contrib/mixed_precision/decorator.py).
 
-    `fused` (optional) runs first on the RAW (un-upcast) inputs — a pallas
-    single-pass kernel path; returning None falls through to the jnp rule."""
+    `fused(ctx, ins, attrs)` (optional) runs first on the RAW (un-upcast)
+    inputs — a pallas single-pass kernel path; returning None selects the
+    jnp rule."""
 
     def deco(fn):
+        @functools.wraps(fn)  # __wrapped__ = the plain jnp rule
         def wrapped(ctx, ins, attrs):
             if fused is not None:
-                res = fused(ins, attrs)
+                res = fused(ctx, ins, attrs)
                 if res is not None:
                     return res
             f32_ins = {
@@ -59,7 +63,6 @@ def register_optimizer(name, fused=None):
                 res[slot] = val
             return res
 
-        wrapped.__name__ = fn.__name__
         return register_op(name, stop_gradient=True)(wrapped)
 
     return deco
@@ -87,20 +90,23 @@ def _momentum(ctx, ins, attrs):
     return {"ParamOut": p_out, "VelocityOut": v_out}
 
 
-def _adam_fused_maybe(ins, attrs, weight_decay):
+def _adam_fused_maybe(ctx, ins, attrs, weight_decay):
     """Single-pass pallas adam for tile-aligned 2-D params on TPU (the hot
-    buffers: embeddings and weight matrices). Returns None to fall through
-    to the jnp path."""
+    buffers: embeddings and weight matrices). Returns None to select the
+    jnp rule: off TPU, for odd shapes, and in mesh programs — GSPMD cannot
+    partition a Mosaic call, and this lowering does not know the
+    parameter's PartitionSpec to open a shard_map region around it, while
+    XLA partitions the elementwise jnp rule for free."""
     import os
 
     if os.environ.get("PADDLE_TPU_DISABLE_FUSED_ADAM"):
         return None
-    try:
-        if jax.default_backend() != "tpu":
-            return None
-    except Exception:
-        return None
     from .pallas import fused_adam as fa
+    from .pallas.backend import on_tpu
+
+    mesh = getattr(ctx, "mesh", None)
+    if not on_tpu() or (mesh is not None and mesh.size > 1):
+        return None
 
     p, g = ins["Param"][0], ins["Grad"][0]
     m1, m2 = ins["Moment1"][0], ins["Moment2"][0]
@@ -123,7 +129,8 @@ def _adam_fused_maybe(ins, attrs, weight_decay):
     }
 
 
-@register_optimizer("adam", fused=lambda ins, attrs: _adam_fused_maybe(ins, attrs, 0.0))
+@register_optimizer(
+    "adam", fused=lambda ctx, ins, attrs: _adam_fused_maybe(ctx, ins, attrs, 0.0))
 def _adam(ctx, ins, attrs):
     p, g = ins["Param"][0], ins["Grad"][0]
     m1, m2 = ins["Moment1"][0], ins["Moment2"][0]
@@ -145,9 +152,9 @@ def _adam(ctx, ins, attrs):
     }
 
 
-def _adamw_fused(ins, attrs):
+def _adamw_fused(ctx, ins, attrs):
     coeff = attrs.get("coeff", 0.01) if attrs.get("with_decay", True) else 0.0
-    return _adam_fused_maybe(ins, attrs, coeff)
+    return _adam_fused_maybe(ctx, ins, attrs, coeff)
 
 
 @register_optimizer("adamw", fused=_adamw_fused)

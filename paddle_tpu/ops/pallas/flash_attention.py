@@ -24,42 +24,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .backend import compiler_params, on_tpu
+
 _NEG_INF = -1e30  # finite stand-in for -inf: avoids inf-inf=nan in rescaling
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
-
-
-_VMEM_LIMIT = 64 * 1024 * 1024  # v5e has 128MB VMEM; the compiler's
-# default 16MB scoped budget rejects the fastest (256, 1024) tiling by
-# ~0.4MB when the kernel sits inside the full train program
-
-def _compiler_params(dims):
-    try:
-        return pltpu.CompilerParams(dimension_semantics=dims,
-                                    vmem_limit_bytes=_VMEM_LIMIT)
-    except (AttributeError, TypeError):
-        pass
-    try:
-        return pltpu.TPUCompilerParams(dimension_semantics=dims,
-                                       vmem_limit_bytes=_VMEM_LIMIT)
-    except (AttributeError, TypeError):
-        return pltpu.TPUCompilerParams(dimension_semantics=dims)
-
-
-def _vmem_raised() -> bool:
-    """Probe once whether this toolchain accepts vmem_limit_bytes; the
-    block-size dispatcher must not pick >16MB tilings otherwise."""
-    p = _compiler_params(("arbitrary",))
-    return getattr(p, "vmem_limit_bytes", None) == _VMEM_LIMIT
-
-
-# resolved at import so the FIRST dispatch already picks safe blocks
-VMEM_RAISED = _vmem_raised()
 
 
 # ---------------------------------------------------------------- forward
@@ -301,7 +268,7 @@ def _fwd(q, k, v, *, causal, scale, block_q, block_k, interpret, bthd=False):
             jax.ShapeDtypeStruct(lse_shape, jnp.float32),
         ],
         scratch_shapes=scratch,
-        compiler_params=_compiler_params(dims),
+        compiler_params=compiler_params(dims),
         interpret=interpret,
     )(q, k, v)
     if bthd:
@@ -606,7 +573,7 @@ def _bwd(causal, scale, block_q, block_k, interpret, bthd, bwd_blocks,
         out_specs=[qspec],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)],
         scratch_shapes=dq_scratch,
-        compiler_params=_compiler_params(dims3),
+        compiler_params=compiler_params(dims3),
         interpret=interpret,
     )(q, k, v, do, lse, delta)[0]
 
@@ -640,7 +607,7 @@ def _bwd(causal, scale, block_q, block_k, interpret, bthd, bwd_blocks,
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         scratch_shapes=dkv_scratch,
-        compiler_params=_compiler_params(dims3),
+        compiler_params=compiler_params(dims3),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
     if bthd:
@@ -692,8 +659,9 @@ def flash_attention(q, k, v, causal=False, scale=None,
 
     Differentiable (flash backward kernels). Sequence lengths must divide
     the block sizes (the dispatcher in ops/attention.py guarantees this or
-    falls back to the XLA path). On non-TPU backends runs the pallas
-    interpreter, so tests on the virtual CPU mesh exercise the same code.
+    selects the XLA path from the shape). On non-TPU backends runs the
+    pallas interpreter, so tests on the virtual CPU mesh exercise the
+    same code.
     """
     bthd = layout == "BTHD"
     B, H, T, D, Tk = _dims(q, k, bthd)
@@ -703,7 +671,7 @@ def flash_attention(q, k, v, causal=False, scale=None,
     if scale is None:
         scale = 1.0 / math.sqrt(D)
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
     if bwd_blocks is not None:
         bwd_blocks = tuple(min(int(b), (Tk if i % 2 else T))
                            for i, b in enumerate(bwd_blocks))

@@ -54,16 +54,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _compiler_params, _on_tpu
+from jax import shard_map
 
-# shard_map import shim shared with parallel/ring_attention.py (the name
-# moved namespaces across jax versions)
-try:  # pragma: no cover - version-dependent
-    from jax import shard_map as _shard_map  # jax >= 0.6-era name
-    _SHARD_MAP_KW = {"check_vma": False}
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_KW = {"check_rep": False}
+from .backend import compiler_params, on_tpu
 
 _NEG_INF = -1e30  # finite stand-in for -inf (inf-inf = nan in rescaling)
 
@@ -83,14 +76,10 @@ def _cost_kwargs(flops: int, bytes_accessed: int, transcendentals: int = 0):
     """Analytic pl.CostEstimate for the kernel: XLA's cost_analysis
     cannot see inside a custom call, so the kernel states its own FLOPs
     — what keeps achieved-MFU attribution (tools/xla_report.py) from
-    reporting the lm-head as vanished compute. Degrades to nothing on
-    toolchains without the API."""
-    try:
-        return {"cost_estimate": pl.CostEstimate(
-            flops=int(flops), transcendentals=int(transcendentals),
-            bytes_accessed=int(bytes_accessed))}
-    except (AttributeError, TypeError):  # pragma: no cover
-        return {}
+    reporting the lm-head as vanished compute."""
+    return {"cost_estimate": pl.CostEstimate(
+        flops=int(flops), transcendentals=int(transcendentals),
+        bytes_accessed=int(bytes_accessed))}
 
 
 # ---------------------------------------------------------------- forward
@@ -173,7 +162,7 @@ def _stats_call(x2d, w, lbl_row, block_n, block_v, v_total, interpret):
         out_specs=[rspec, rspec, rspec],
         out_shape=[stat, stat, stat],
         scratch_shapes=[pltpu.VMEM((bn, 128), jnp.float32)] * 3,
-        compiler_params=_compiler_params(("parallel", "arbitrary")),
+        compiler_params=compiler_params(("parallel", "arbitrary")),
         interpret=interpret,
         **_cost_kwargs(2 * n * vp * d,
                        x2d.nbytes + w.nbytes + 3 * 4 * n,
@@ -264,7 +253,7 @@ def _dx_call(x2d, w, lbl_row, g_row, lse_row, block_n, block_v, v_total,
         out_specs=[xspec],
         out_shape=[jax.ShapeDtypeStruct(x2d.shape, x2d.dtype)],
         scratch_shapes=[pltpu.VMEM((bn, d), jnp.float32)],
-        compiler_params=_compiler_params(("parallel", "arbitrary")),
+        compiler_params=compiler_params(("parallel", "arbitrary")),
         interpret=interpret,
         **_cost_kwargs(4 * n * vp * d, 2 * x2d.nbytes + w.nbytes,
                        transcendentals=n * vp),
@@ -284,7 +273,7 @@ def _dw_call(x2d, w, lbl_row, g_row, lse_row, block_n, block_v, v_total,
         out_specs=[wspec],
         out_shape=[jax.ShapeDtypeStruct(w.shape, w.dtype)],
         scratch_shapes=[pltpu.VMEM((bv, d), jnp.float32)],
-        compiler_params=_compiler_params(("parallel", "arbitrary")),
+        compiler_params=compiler_params(("parallel", "arbitrary")),
         interpret=interpret,
         **_cost_kwargs(4 * n * vp * d, x2d.nbytes + 2 * w.nbytes,
                        transcendentals=n * vp),
@@ -403,7 +392,7 @@ def lmhead_ce(x2d, w, labels, block_n: int = DEFAULT_BLOCK_N,
     x2d and w (flash-style rematerializing backward); token count and
     vocab may be arbitrary (padded up to tile multiples internally)."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
     return _ce_local(x2d, w, labels, int(block_n), int(block_v),
                      bool(interpret))
 
@@ -445,9 +434,9 @@ def _ce_sharded_fwd(x2d, w, lbl, cfg):
         return _run_fwd(xl, wl, ll, vocab_axis, block_n, block_v,
                         interpret)
 
-    nll, lse = _shard_map(
+    nll, lse = shard_map(
         inner, mesh=mesh, in_specs=(xspec, wspec, lspec),
-        out_specs=(lspec, lspec), **_SHARD_MAP_KW,
+        out_specs=(lspec, lspec), check_vma=False,
     )(x2d, w, lbl)
     return nll, (x2d, w, lbl, lse)
 
@@ -455,7 +444,7 @@ def _ce_sharded_fwd(x2d, w, lbl, cfg):
 def _ce_sharded_bwd(cfg, res, g):
     """Both shard_map regions carry EXPLICIT collectives with exact
     out_specs — nothing is left to shard_map's transpose machinery
-    (check_rep/check_vma is off for the pallas calls, under which the
+    (check_vma is off for the pallas calls, under which the
     transpose of replicated-input cotangents is not trustworthy)."""
     (mesh, batch_axes, vocab_axis, gather_axis, block_n, block_v,
      interpret) = cfg
@@ -483,9 +472,9 @@ def _ce_sharded_bwd(cfg, res, g):
                                       scatter_dimension=0, tiled=True)
         return dx, dw
 
-    dx, dw = _shard_map(
+    dx, dw = shard_map(
         inner, mesh=mesh, in_specs=(xspec, wspec, lspec, lspec, lspec),
-        out_specs=(xspec, wspec), **_SHARD_MAP_KW,
+        out_specs=(xspec, wspec), check_vma=False,
     )(x2d, w, lbl, g, lse)
     return dx, dw, None
 
@@ -515,7 +504,7 @@ def lmhead_ce_sharded(x2d, w, labels, mesh,
       the backward's reduce-scatter returns dW to the shard layout.
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
     cfg = (mesh, tuple(a for a in batch_axes if a),
            vocab_axis or None, gather_axis or None,
            int(block_n), int(block_v), bool(interpret))

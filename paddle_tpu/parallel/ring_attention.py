@@ -25,17 +25,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-# shard_map moved to the jax namespace (with check_vma) after living in
-# jax.experimental (with check_rep); support both so the ring runs on
-# either side of the rename
-try:
-    from jax import shard_map as _shard_map  # jax >= 0.6-era name
-    _SHARD_MAP_KW = {"check_vma": False}
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_KW = {"check_rep": False}
 
 
 def _block_attn(q, k, v, bias_mask, scale):
@@ -127,7 +118,7 @@ def ring_attention(
     fn = functools.partial(
         _ring_attention_local, axis_name=seq_axis, causal=causal, scale=scale
     )
-    return _shard_map(
+    return shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )(q, k, v)

@@ -233,11 +233,8 @@ class DecodeModel:
 
         if not shard_insight.verify_enabled():
             return
-        try:
-            self.sharding_mismatches = shard_insight.verify_scope(
-                _DictScope(self.params), self.mesh, self.rules)
-        except Exception:
-            pass  # verification must never break the serving bring-up
+        self.sharding_mismatches = shard_insight.verify_scope(
+            _DictScope(self.params), self.mesh, self.rules)
 
     def _pages_sharding(self):
         """KV pages placement: the head dim shards over the recipe's tp

@@ -21,10 +21,7 @@ from paddle_tpu.parallel import make_mesh  # noqa: E402
 def _run_collective(op_type, per_rank_vals, attrs):
     """Run one registered collective lowering under shard_map on an 8-way
     'dp' mesh; returns the (n, ...) stacked per-rank outputs."""
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     n = len(per_rank_vals)
     mesh = make_mesh({"dp": n}, jax.devices()[:n])

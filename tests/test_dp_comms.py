@@ -544,10 +544,7 @@ def test_static_legacy_per_param_fallback(monkeypatch):
 
 
 def _run_bucket_collective(per_rank_lists, attrs):
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from paddle_tpu.framework.registry import LoweringContext, get_op_def

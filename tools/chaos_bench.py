@@ -1,5 +1,9 @@
 """Kill-one-rank chaos benchmark: certify detection, recovery and drift.
 
+CPU control-flow check, not a benchmark cell: every worker is a child
+process pinned to forced-host CPU devices and never uses the chip, so
+nothing it records is a device metric (chip_smoke.py is the on-chip path).
+
 The MULTICHIP harness's fault leg (__graft_entry__._record_multichip_round)
 and a standalone tool. Runs the same deterministic DataParallel training
 job twice over real worker processes (rendezvoused over jax.distributed,

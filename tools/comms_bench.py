@@ -1,5 +1,9 @@
 """Interconnect microbenchmark: the MULTICHIP comms leg.
 
+CPU control-flow check, not a benchmark cell: every worker is a child
+process pinned to forced-host CPU devices and never uses the chip, so
+nothing it records is a device metric (chip_smoke.py is the on-chip path).
+
 Three legs, all feeding paddle_tpu/commswatch.py (the interconnect
 ledger) and merged into one round record:
 
@@ -102,14 +106,8 @@ def sweep_live_mesh(axes: Dict[str, int],
 
     import jax
     import jax.numpy as jnp
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
-
-    try:  # the repo's shard_map shim (the name moved namespaces)
-        from jax import shard_map as _shard_map
-        _SM_KW = {"check_vma": False}
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _shard_map
-        _SM_KW = {"check_rep": False}
 
     from paddle_tpu import commswatch
 
@@ -150,9 +148,9 @@ def sweep_live_mesh(axes: Dict[str, int],
                 x = jnp.zeros((n_elems,), jnp.float32)
                 try:
                     fn, in_spec, out_spec = _fn(kind, axis, n_ax)
-                    timed = jax.jit(_shard_map(
+                    timed = jax.jit(shard_map(
                         fn, mesh=mesh, in_specs=in_spec,
-                        out_specs=out_spec, **_SM_KW))
+                        out_specs=out_spec, check_vma=False))
                     jax.block_until_ready(timed(x))  # compile + warmup
                     best = None
                     for _ in range(iters):

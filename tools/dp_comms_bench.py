@@ -1,5 +1,9 @@
 """Multi-process DP comms benchmark: per-param vs bucketed vs int8.
 
+CPU control-flow check, not a benchmark cell: every worker is a child
+process pinned to forced-host CPU devices and never uses the chip, so
+nothing it records is a device metric (chip_smoke.py is the on-chip path).
+
 The MULTICHIP harness's comms leg (__graft_entry__._record_multichip_round)
 and a standalone tool. Spawns ``nranks`` real worker processes (one CPU
 device each, rendezvoused over jax.distributed) per mode and trains the

@@ -1,5 +1,9 @@
 """GSPMD mesh-recipe weak-scaling benchmark: the MULTICHIP pjit leg.
 
+CPU control-flow check, not a benchmark cell: every worker is a child
+process pinned to forced-host CPU devices and never uses the chip, so
+nothing it records is a device metric (chip_smoke.py is the on-chip path).
+
 The MLPerf TPU-pod playbook (Kumar et al., arXiv:1909.09756) judges a
 parallelism stack by weak scaling: grow the device count with the
 per-chip batch fixed and measure how much per-chip throughput survives.

@@ -22,6 +22,11 @@ tokens_per_sec higher-is-better, p99_latency_s/ttft_s lower-is-better —
 and, for chaos rounds, availability higher-is-better with
 error_rate/recovery_seconds lower-is-better.
 
+The multi-replica legs (--chaos, --multi, --autoscale) are CPU
+control-flow checks, not benchmark cells: every replica is a child
+process pinned to CPU and never uses the chip, so nothing they record is
+a device metric (chip_smoke.py phase 3 is the on-chip serving path).
+
 **Chaos mode (--chaos)** is the serving counterpart of
 tools/chaos_bench.py: the bench spawns >=2 REAL replica processes (each
 a `--replica` worker: DecodeModel warm-loaded from a shared params .npz,
@@ -291,11 +296,16 @@ def replica_main(args) -> int:
     journal (PADDLE_TPU_SERVE_DIR) resumes across respawns."""
     import numpy as np
 
+    from paddle_tpu import compile_cache
     from paddle_tpu import flags as _flags
     from paddle_tpu import serving
     from paddle_tpu.serving import ledger
     from paddle_tpu.serving.model import calibrate, init_params
 
+    # warm restart's compile half: the persistent cache (the outer
+    # JAX_COMPILATION_CACHE_DIR, else the checkout's fixed .jax_cache)
+    # turns a respawned replica's program builds into disk hits
+    compile_cache.enable()
     t0 = time.perf_counter()
     cfg = serving.GPTConfig(vocab_size=args.vocab, n_layer=args.n_layer,
                             n_head=args.n_head, d_model=args.d_model,
@@ -561,12 +571,6 @@ def run_chaos_round(replicas: int = 2, requests: int = 80,
         "PADDLE_TPU_CHAOS_SITES": sites,
         "PADDLE_TPU_CHAOS_SEED": str(seed),
         "PADDLE_RESTART_COUNT": "0",
-        # warm restart's compile half: the XLA persistent cache turns a
-        # respawned replica's program builds into disk hits (the first
-        # boot populates it)
-        "JAX_COMPILATION_CACHE_DIR": os.path.join(base, "xla_cache"),
-        "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
-        "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES": "0",
     })
     bench_args = {
         "--n-layer": n_layer, "--d-model": d_model, "--n-head": n_head,
@@ -967,9 +971,6 @@ def run_multi_round(replicas: int = 2, requests: int = 48,
         "PADDLE_TPU_SERVE_PARAMS": params_path,
         "PADDLE_TPU_TRACE": "1",
         "PADDLE_TPU_TRACE_DIR": trace_dir,
-        "JAX_COMPILATION_CACHE_DIR": os.path.join(base, "xla_cache"),
-        "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
-        "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES": "0",
     })
     bench_args = {
         "--n-layer": n_layer, "--d-model": d_model, "--n-head": n_head,
@@ -1396,9 +1397,6 @@ def run_autoscale_round(n_layer: int = 2, d_model: int = 64,
         "PADDLE_TPU_SERVE_PARAMS": params_path,
         "PADDLE_TPU_TRACE": "1",
         "PADDLE_TPU_TRACE_DIR": trace_dir,
-        "JAX_COMPILATION_CACHE_DIR": os.path.join(base, "xla_cache"),
-        "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
-        "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES": "0",
     })
     bench_args = {
         "--n-layer": n_layer, "--d-model": d_model, "--n-head": n_head,

@@ -209,7 +209,9 @@ def self_test(verbose: bool = True) -> Dict[str, Any]:
     # -- TPU describe: probe, never hang ------------------------------
     from paddle_tpu import flags as _flags
 
-    spec = topo.parse_topology("v4:2x2x1")
+    # v5e: one device per chip, so the described count equals the chip
+    # count (a v4 describe yields two cores per chip on this libtpu)
+    spec = topo.parse_topology("v5e:2x2")
     # the registry owns this knob (default + coercion); the self-test
     # only caps it so tier-1 never waits longer than the smoke budget
     ok, reason = topo.probe_tpu_topology(spec, timeout=min(
