@@ -26,9 +26,12 @@ no platform: it uses what JAX finds and fails when that is not a TPU.
 
 Every check is a hard failure: the first one that does not hold raises,
 the exit code is non-zero and no result line is printed. On success the
-LAST line of stdout is one JSON object (device identity, per-phase facts
-and compile seconds, ``"claim": null`` — this script measures nothing).
-Seconds it prints are set-up facts (compile time, cache state), not rates.
+last two lines of stdout are one JSON object each: first the summary
+(per-phase facts and compile seconds, ``"claim": null`` — this script
+measures nothing), then, LAST, the result line with exactly these keys,
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``,
+the device as JAX reports it. Seconds it prints are set-up facts (compile
+time, cache state), not rates.
 
 The compile cache follows paddle_tpu.compile_cache: the directory in
 JAX_COMPILATION_CACHE_DIR when set, else the checkout's ``.jax_cache``.
@@ -602,6 +605,8 @@ def main() -> int:
         os.environ.setdefault("PADDLE_TPU_XLA_DUMP_DIR", tmp)
         summary = run()
     print(json.dumps(summary))
+    # the result line: these keys and no others, last on stdout
+    print(json.dumps({"ok": True, "device": summary["device"]}), flush=True)
     return 0
 
 
