@@ -15,8 +15,8 @@ reference Python executor's program cache (executor.py:1258).
 """
 from __future__ import annotations
 
+import contextlib
 import functools
-import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -61,6 +61,15 @@ _M_PROG_RUN = _monitor.counter(
     "its per-execution HLO byte prediction by", labelnames=("program",))
 _M_RUN_T = _monitor.histogram(
     "executor_run_seconds", "steady-state Executor.run wall time")
+_M_DISPATCH_T = _monitor.histogram(
+    "executor_dispatch_seconds",
+    "steady-state runs: wall time inside the one call of the compiled "
+    "program (enqueue, plus back-pressure once the in-flight queue is "
+    "full: near the step time means the device sets the pace)")
+_M_HOST_T = _monitor.histogram(
+    "executor_host_seconds",
+    "steady-state runs: Executor.run wall time outside the compiled "
+    "call (feed placement, cache lookup, scope reads and writes, fetch)")
 _M_CACHE_SIZE = _monitor.gauge(
     "executor_cache_size", "compiled programs resident in the run cache")
 _M_NONFINITE = _monitor.counter(
@@ -82,13 +91,15 @@ def lower_block(
     env from pinning dead intermediates."""
     for i, op in enumerate(block.ops):
         if op.type not in _STRUCTURAL_OPS:
-            # per-op host spans when profiling: real per-op wall time in
+            # every HLO instruction carries its Paddle op in op_name
+            # (metadata only: the compiled program is the same); per-op
+            # host spans only when profiling: real per-op wall time in
             # interpreted (eager/host-op) mode, per-op trace time under
             # jit (the trace runs once, at compile)
-            if _profiler.tracing_active():
-                with _profiler.RecordEvent(f"op/{op.type}"):
-                    lower_op(ctx, op, env, op_idx=i)
-            else:
+            op_span = (_profiler.RecordEvent(f"op/{op.type}")
+                       if _profiler.tracing_active()
+                       else contextlib.nullcontext())
+            with jax.named_scope(op.type), op_span:
                 lower_op(ctx, op, env, op_idx=i)
             if ctx.var_constraints and ctx.mesh is not None:
                 _apply_var_constraints(ctx, op, env)
@@ -96,6 +107,17 @@ def lower_block(
             for name in gc_plan.get(i, ()):
                 env.pop(name, None)
     return env
+
+
+def _program_role(block, feed_names, fetch_names, updated_names) -> str:
+    """What a block is for, read off its structure: the name its
+    compiled program carries (``jit_startup``, ``jit_train_step``,
+    ``jit_forward``)."""
+    if any(op.type.endswith("_grad") for op in block.ops):
+        return "train_step"
+    if updated_names and not feed_names and not fetch_names:
+        return "startup"
+    return "forward"
 
 
 def _compile_constraints(program):
@@ -185,6 +207,7 @@ class _CompiledBlock:
         self.updated_names = updated_names
         # compiler-observability slots (xla_insight.py): filled on the
         # first run of a fresh entry, when example arguments exist
+        self.module_name = None  # the program's name in a profile
         self.key_hash = None
         self.jittable = False
         self.insight = None  # ProgramInsight once captured
@@ -203,6 +226,7 @@ class Executor:
         self._seed = None
         self._seed_step = None  # device-resident [seed, step] uint32
         self._last_run_compiled = False  # telemetry: last run was a compile
+        self._dispatch_s: Optional[float] = None  # last run's compiled call
         self._runs_since_sample = 0  # memwatch allocator-query cadence
 
     # -- public API ----------------------------------------------------
@@ -215,15 +239,15 @@ class Executor:
         return_numpy: bool = True,
         use_prune: bool = False,  # accepted for API parity
     ):
-        t0 = time.perf_counter()
         # step-scoped tracing: declare the step (drives trace sampling),
         # open the per-step span every other span of this run nests under
         _profiler.set_step(self._step)
-        with _profiler.span("executor/run", cat="step"):
+        with _profiler.span("executor/run", cat="step",
+                            step=self._step) as sp:
             out = self._run_impl(
                 program, feed, fetch_list, scope, return_numpy, use_prune
             )
-        dt = time.perf_counter() - t0
+        dt = sp.seconds
         _monitor.note_progress()  # hang-watchdog heartbeat
         _M_RUN.inc()
         if self._last_run_compiled:
@@ -233,6 +257,11 @@ class Executor:
             _goodput.add("compile", dt)
         else:
             _M_RUN_T.observe(dt)
+            if self._dispatch_s is not None:
+                # the same two intervals the executor/dispatch span and
+                # the rest of executor/run show in a trace
+                _M_DISPATCH_T.observe(self._dispatch_s)
+                _M_HOST_T.observe(dt - self._dispatch_s)
             # steady-state run wall time is the device-compute window of
             # the step (a driver closing the step via goodput.end_step
             # accounts anything outside it as other buckets/host_other)
@@ -251,28 +280,72 @@ class Executor:
         from .compiler import CompiledProgram
 
         self._last_run_compiled = False
-        compiled_prog = None
-        if isinstance(program, CompiledProgram):
-            # reference executor.py:855 _run_parallel path: unwrap, shard
-            compiled_prog = program
-            program = compiled_prog._program
-        program = program or default_main_program()
-        feed = feed or {}
-        fetch_list = list(fetch_list or [])
-        scope = scope or global_scope()
-        if compiled_prog is not None and compiled_prog._mesh is not None:
-            compiled_prog._prepare_scope(scope)
-            feed = compiled_prog._shard_feed(
-                {k: np.asarray(v) if not isinstance(v, jax.Array) else v
-                 for k, v in feed.items()}
-            )
+        self._dispatch_s = None
+        with _profiler.span("executor/prepare", cat="executor"):
+            compiled_prog = None
+            if isinstance(program, CompiledProgram):
+                # reference executor.py:855 _run_parallel path: unwrap, shard
+                compiled_prog = program
+                program = compiled_prog._program
+            program = program or default_main_program()
+            feed = feed or {}
+            fetch_list = list(fetch_list or [])
+            scope = scope or global_scope()
+            if compiled_prog is not None and compiled_prog._mesh is not None:
+                compiled_prog._prepare_scope(scope)
+                feed = compiled_prog._shard_feed(
+                    {k: np.asarray(v) if not isinstance(v, jax.Array) else v
+                     for k, v in feed.items()}
+                )
 
-        fetch_names = [v.name if isinstance(v, Variable) else str(v) for v in fetch_list]
-        pp_meta = getattr(program, "_pipeline_meta", None)
-        if pp_meta is not None:
+            fetch_names = [v.name if isinstance(v, Variable) else str(v) for v in fetch_list]
+            pp_meta = getattr(program, "_pipeline_meta", None)
+            if pp_meta is None:
+                compiled, call_args = self._prepare(
+                    program, feed, fetch_names, scope)
+        if pp_meta is not None:  # per-section dispatch
             return self._run_pipeline(
                 program, pp_meta, feed, fetch_names, scope, return_numpy
             )
+        with _profiler.span("executor/dispatch", cat="executor",
+                            program=compiled.module_name) as sp:
+            try:
+                fetches, new_params, self._seed_step, probes = compiled.fn(
+                    *call_args)
+            except Exception as e:
+                # XLA RESOURCE_EXHAUSTED -> typed error + post-mortem:
+                # blamed op provenance, footprint by layer, top programs
+                # by peak, last live stats, remediation hints, JSON dump
+                # next to the XLA artifacts (paddle_tpu/memwatch.py). A
+                # failed dispatch may already have consumed donated
+                # buffers — there is no retry path, only a better autopsy.
+                if _memwatch.is_oom_error(e):
+                    raise _memwatch.oom_error(
+                        e, program=program, scope=scope,
+                        insights=self.compiled_insights()) from e
+                raise
+        self._dispatch_s = sp.seconds
+        with _profiler.span("executor/commit", cat="executor"):
+            self._commit(program, compiled, probes, new_params, scope)
+        if not return_numpy:
+            return list(fetches)
+        with _profiler.span("executor/fetch", cat="executor"):
+            try:
+                return [np.asarray(f) for f in fetches]
+            except Exception as e:
+                # async dispatch: an OOM raised by the device often
+                # surfaces at the host transfer, not the dispatch call —
+                # same post-mortem treatment
+                if _memwatch.is_oom_error(e):
+                    raise _memwatch.oom_error(
+                        e, program=program, scope=scope,
+                        insights=self.compiled_insights()) from e
+                raise
+
+    def _prepare(self, program, feed, fetch_names, scope):
+        """Everything between the caller's arguments and the compiled
+        call: feed conversion and placement, the cache lookup (or
+        compile), the scope reads. Returns (compiled, call_args)."""
         feed_vals = {k: self._to_device_array(program, k, v) for k, v in feed.items()}
 
         extra = getattr(program, "_extra_feeds", None)
@@ -326,22 +399,12 @@ class Executor:
 
         if compiled.key_hash:
             _M_PROG_RUN.labels(program=compiled.key_hash).inc()
-        try:
-            fetches, new_params, self._seed_step, probes = compiled.fn(
-                feed_vals, mut, const, seed_step
-            )
-        except Exception as e:
-            # XLA RESOURCE_EXHAUSTED -> typed error + post-mortem: blamed
-            # op provenance, footprint by layer, top programs by peak,
-            # last live stats, remediation hints, JSON dump next to the
-            # XLA artifacts (paddle_tpu/memwatch.py). A failed dispatch
-            # may already have consumed donated buffers — there is no
-            # retry path, only a better autopsy.
-            if _memwatch.is_oom_error(e):
-                raise _memwatch.oom_error(
-                    e, program=program, scope=scope,
-                    insights=self.compiled_insights()) from e
-            raise
+        return compiled, (feed_vals, mut, const, seed_step)
+
+    def _commit(self, program, compiled, probes, new_params, scope) -> None:
+        """After the compiled call returned (the device may still be
+        running it): the memory sample, the nan probes, and the updated
+        parameters written back to the scope."""
         # device-memory watermark: allocator queries are host work on
         # the dispatch path (goodput host_other), so steady-state runs
         # sample on a cadence — compiles always sample, and drivers that
@@ -374,20 +437,6 @@ class Executor:
                     )
         for n in compiled.updated_names:
             scope.set(n, new_params[n])
-
-        if return_numpy:
-            try:
-                return [np.asarray(f) for f in fetches]
-            except Exception as e:
-                # async dispatch: an OOM raised by the device often
-                # surfaces at the host transfer, not the dispatch call —
-                # same post-mortem treatment
-                if _memwatch.is_oom_error(e):
-                    raise _memwatch.oom_error(
-                        e, program=program, scope=scope,
-                        insights=self.compiled_insights()) from e
-                raise
-        return list(fetches)
 
     # -- dataset-driven training (reference Trainer/DeviceWorker) ------
     def train_from_dataset(self, program=None, dataset=None, scope=None,
@@ -529,7 +578,8 @@ class Executor:
                 # float output; the host run raises on the first bad op
                 for i, op in enumerate(block.ops):
                     if op.type not in _STRUCTURAL_OPS:
-                        lower_op(ctx, op, env, op_idx=i)
+                        with jax.named_scope(op.type):
+                            lower_op(ctx, op, env, op_idx=i)
                         for name in op.output_arg_names():
                             val = env.get(name)
                             if val is not None and jnp.issubdtype(
@@ -567,7 +617,6 @@ class Executor:
         has_host = any(_any_host(b) for b in program.blocks)
 
         _M_COMPILE.inc()
-        _monitor.stat_add("executor_compile_count")
         # GSPMD-native mesh programs: the recipe states the in/out
         # shardings declaratively (batch over dp/fsdp, params/optimizer
         # state per the merged rules, fetches/seed replicated) instead of
@@ -610,11 +659,18 @@ class Executor:
                 rules=getattr(program, "_sharding_rules", None) or None,
                 updated=upd_ex)
             jit_kwargs = {"in_shardings": in_sh, "out_shardings": out_sh}
+        # the module's name in a profile and in the HLO: jit_<role>. No
+        # counter, id or hash: the name is part of the persistent compile
+        # cache's key and must be the same in every process
+        fn.__name__ = fn.__qualname__ = _program_role(
+            block, feed_names, fetch_names, updated_names)
         jit_fn = fn if has_host else jax.jit(fn, donate_argnums=(1, 3),
                                              **jit_kwargs)
         compiled = _CompiledBlock(
             jit_fn, feed_names, mutable_names, const_names, fetch_names, updated_names
         )
+        compiled.module_name = (fn.__name__ if has_host
+                                else f"jit_{fn.__name__}")
         compiled.nan_probes = nan_probes if check_nan else None
         compiled.check_numerics = check_numerics
         # the insight/dump label hashes program STRUCTURE, not the cache
@@ -726,18 +782,22 @@ class Executor:
 
             sec_constraints = _compile_constraints(program)
 
-            def make_fn(sec_ops=sec_ops, out_names=out_names, mesh=mesh):
+            def make_fn(sec=sec, sec_ops=sec_ops, out_names=out_names,
+                        mesh=mesh):
                 def fn(inputs, rng_key):
                     ctx = LoweringContext(rng_key=rng_key, mesh=mesh,
                                           var_constraints=sec_constraints)
                     ctx.program = program
                     env = dict(inputs)
                     for op in sec_ops:
-                        lower_op(ctx, op, env)
+                        with jax.named_scope(op.type):
+                            lower_op(ctx, op, env)
                         if ctx.var_constraints and ctx.mesh is not None:
                             _apply_var_constraints(ctx, op, env)
                     return {n: env[n] for n in out_names}
 
+                fn.__name__ = fn.__qualname__ = (
+                    f"pp_{sec.phase}_stage{sec.stage}")
                 return jax.jit(fn)
 
             sections.append(
@@ -767,12 +827,12 @@ class Executor:
                     grad_stage[g] = info["sec"].stage
 
         def make_reducer():
-            def reduce_fn(parts):
+            def pp_grad_mean(parts):
                 return {
                     g: sum(vs) / float(len(vs)) for g, vs in parts.items()
                 }
 
-            return jax.jit(reduce_fn)
+            return jax.jit(pp_grad_mean)
 
         reducers = {s: make_reducer() for s in set(grad_stage.values())}
 

@@ -270,6 +270,7 @@ def _fwd(q, k, v, *, causal, scale, block_q, block_k, interpret, bthd=False):
         scratch_shapes=scratch,
         compiler_params=compiler_params(dims),
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     if bthd:
         out = out.reshape(B, T, H, D)
@@ -575,6 +576,7 @@ def _bwd(causal, scale, block_q, block_k, interpret, bthd, bwd_blocks,
         scratch_shapes=dq_scratch,
         compiler_params=compiler_params(dims3),
         interpret=interpret,
+        name="flash_dq",
     )(q, k, v, do, lse, delta)[0]
 
     # kv sweep: grid walks kv blocks in parallel, q blocks sequentially
@@ -609,6 +611,7 @@ def _bwd(causal, scale, block_q, block_k, interpret, bthd, bwd_blocks,
         scratch_shapes=dkv_scratch,
         compiler_params=compiler_params(dims3),
         interpret=interpret,
+        name="flash_dkv",
     )(q, k, v, do, lse, delta)
     if bthd:
         dq = dq.reshape(B, T, H, D)

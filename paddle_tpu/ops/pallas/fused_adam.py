@@ -98,4 +98,5 @@ def fused_adam(p, g, m, v, lr, beta1_pow, beta2_pow,
         ],
         input_output_aliases={1: 0, 3: 1, 4: 2},
         interpret=interpret,
+        name="fused_adam",
     )(scalars, p, g, m, v)

@@ -164,6 +164,7 @@ def _stats_call(x2d, w, lbl_row, block_n, block_v, v_total, interpret):
         scratch_shapes=[pltpu.VMEM((bn, 128), jnp.float32)] * 3,
         compiler_params=compiler_params(("parallel", "arbitrary")),
         interpret=interpret,
+        name="lmhead_ce_stats",
         **_cost_kwargs(2 * n * vp * d,
                        x2d.nbytes + w.nbytes + 3 * 4 * n,
                        transcendentals=n * vp),
@@ -255,6 +256,7 @@ def _dx_call(x2d, w, lbl_row, g_row, lse_row, block_n, block_v, v_total,
         scratch_shapes=[pltpu.VMEM((bn, d), jnp.float32)],
         compiler_params=compiler_params(("parallel", "arbitrary")),
         interpret=interpret,
+        name="lmhead_ce_dx",
         **_cost_kwargs(4 * n * vp * d, 2 * x2d.nbytes + w.nbytes,
                        transcendentals=n * vp),
     )(x2d, w, lbl_row, g_row, lse_row)[0]
@@ -275,6 +277,7 @@ def _dw_call(x2d, w, lbl_row, g_row, lse_row, block_n, block_v, v_total,
         scratch_shapes=[pltpu.VMEM((bv, d), jnp.float32)],
         compiler_params=compiler_params(("parallel", "arbitrary")),
         interpret=interpret,
+        name="lmhead_ce_dw",
         **_cost_kwargs(4 * n * vp * d, x2d.nbytes + 2 * w.nbytes,
                        transcendentals=n * vp),
     )(x2d, w, lbl_row, g_row, lse_row)[0]

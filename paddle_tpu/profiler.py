@@ -10,6 +10,15 @@ TPU translation: device-side tracing is delegated to the JAX/XLA profiler
 host-side RecordEvent spans and the end-of-run sorted table keep the
 reference's UX.
 
+One span primitive, two sinks. ``span()`` / ``RecordEvent`` ALWAYS enters
+a ``jax.profiler.TraceAnnotation``: without a profiler session that is a
+no-op, and inside one (whoever called ``jax.profiler.start_trace``: the
+benchmark's traced slice, ``start_profiler(profile_dir=...)``) the span
+is a host event in the profiler's own trace, on the one clock the device
+planes use, so a device idle gap can be named by the program phase that
+owns it. The in-memory buffer, the chrome export and the flight recorder
+below stay behind ``tracing_active()``.
+
 Distributed tracing layer on top of the reference design:
 
 - every span carries ``step``/``rank`` plus a propagatable
@@ -43,6 +52,8 @@ import threading
 import time
 from collections import defaultdict
 from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 from . import flags as _flags
 from . import monitor as _monitor
@@ -168,24 +179,52 @@ class RecordEvent:
     RPC client injects it); when given, the span parents onto the remote
     caller instead of the local stack."""
 
+    __slots__ = ("name", "event_type", "cat", "remote", "attrs",
+                 "_annotation", "t0_ns", "t1_ns", "_pushed", "span_id",
+                 "trace_id", "parent_span_id")
+
     def __init__(self, name: str, event_type: str = "op",
-                 cat: Optional[str] = None, remote: Optional[str] = None):
+                 cat: Optional[str] = None, remote: Optional[str] = None,
+                 **attrs):
         self.name = name
         self.event_type = event_type
         self.cat = cat or event_type
         self.remote = remote
-        self._t0 = None
+        self.attrs = attrs
+        self._annotation = None
+        self.t0_ns = 0
+        self.t1_ns = 0
         self._pushed = False
         self.span_id: Optional[str] = None
         self.trace_id: Optional[str] = None
         self.parent_span_id: Optional[str] = None
+
+    @property
+    def seconds(self) -> float:
+        """Wall seconds of the closed span (perf_counter), stamped
+        whether or not anything records: the always-on counters
+        (executor_dispatch_seconds, the ledger's tick_wall_s) read the
+        SAME interval the span shows in a trace."""
+        return (self.t1_ns - self.t0_ns) / 1e9
+
+    def set(self, **attrs) -> None:
+        """Attributes known only once the work is done (how many were
+        admitted); call before the span closes."""
+        self.attrs.update(attrs)
+        if self._annotation is not None:
+            self._annotation.set_metadata(**attrs)
 
     def __enter__(self):
         self.begin()
         return self
 
     def begin(self):
-        if not tracing_active():
+        # the profiler-clock half: a host event in jax.profiler's own
+        # trace, beside the device planes; a no-op without a session
+        self._annotation = _TraceAnnotation(self.name, **self.attrs)
+        self._annotation.__enter__()
+        self.t0_ns = time.perf_counter_ns()
+        if not (_enabled and _step_sampled):  # tracing_active(), inlined
             return
         stack = getattr(_tls, "stack", None)
         if stack is None:
@@ -200,24 +239,24 @@ class RecordEvent:
         self.span_id = _new_span_id()
         stack.append((self.name, self.span_id))
         self._pushed = True
-        self._t0 = time.perf_counter_ns()
 
     def end(self):
         global _dropped
+        self.t1_ns = time.perf_counter_ns()
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
         if not self._pushed:
             return
-        t1 = time.perf_counter_ns()
         stack = _tls.stack
         full = "/".join(n for n, _ in stack)
         stack.pop()
         self._pushed = False
-        if self._t0 is None:
-            return
-        dur_us = (t1 - self._t0) / 1000.0
+        dur_us = (self.t1_ns - self.t0_ns) / 1000.0
         event = {
             "name": full,
             "cat": self.cat,
-            "ts": self._t0 / 1000.0,  # us, chrome tracing unit
+            "ts": self.t0_ns / 1000.0,  # us, chrome tracing unit
             "dur": dur_us,
             "tid": threading.get_ident() % 10**6,
             "step": _step,
@@ -226,6 +265,8 @@ class RecordEvent:
             "span_id": self.span_id,
             "parent_span_id": self.parent_span_id,
         }
+        if self.attrs:
+            event["attrs"] = dict(self.attrs)
         with _lock:
             if _enabled:
                 if len(_events) < _MAX_EVENTS:
@@ -311,10 +352,16 @@ def emit_instant(name: str, cat: str = "op",
 
 
 def span(name: str, cat: str = "op",
-         remote: Optional[str] = None) -> RecordEvent:
-    """A RecordEvent that no-ops cheaply when tracing is off — the helper
-    every instrumentation site uses."""
-    return RecordEvent(name, cat=cat, remote=remote)
+         remote: Optional[str] = None, **attrs) -> RecordEvent:
+    """THE way to add a span — the helper every instrumentation site
+    uses. Always a ``jax.profiler.TraceAnnotation(name, **attrs)``: a
+    no-op without a profiler session, and inside one a host event on
+    the clock the device planes use, found by name by whoever called
+    ``jax.profiler.start_trace`` (attributes arrive as the event's
+    stats). The in-memory buffer, the flight recorder and the chrome
+    export record it only while ``tracing_active()``. ``.seconds`` of
+    the closed span is its wall time either way."""
+    return RecordEvent(name, cat=cat, remote=remote, **attrs)
 
 
 def remote_context(sp: Optional[RecordEvent] = None) -> Optional[str]:
@@ -420,6 +467,8 @@ def _chrome_trace(events: List[dict]) -> dict:
         # request_id, tick, outcome — into the chrome args verbatim
         if e.get("meta"):
             args.update(e["meta"])
+        if e.get("attrs"):  # a RAII span's attributes (tick=, bucket=)
+            args.update(e["attrs"])
         ev = {
             "name": e["name"].rsplit("/", 1)[-1],
             "cat": e.get("cat", "host"),

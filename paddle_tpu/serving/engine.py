@@ -48,7 +48,7 @@ import threading
 import time
 from collections import OrderedDict
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional, Sequence
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -471,10 +471,12 @@ class ServingEngine:
                 # here means admission is blocked (KV/slots) with an
                 # empty batch — that wait IS queue_wait badput.
                 t0 = time.perf_counter()
-                with self._wake:
-                    if self._stop:
-                        break
-                    self._wake.wait(timeout=0.05)
+                with _profiler.span("engine/idle", cat="engine",
+                                    queued=self.queue.depth()):
+                    with self._wake:
+                        if self._stop:
+                            break
+                        self._wake.wait(timeout=0.05)
                 queued = self.queue.depth()
                 if queued:
                     wall = time.perf_counter() - t0
@@ -503,32 +505,37 @@ class ServingEngine:
         step lock. Admitted executes land in _exec_ready for whoever
         claims them (the stepping thread in step(), each request's OWN
         waiting thread in drive())."""
-        t0 = time.perf_counter()
-        self._reap_stale()
-        admitted = self._admit()
-        gen_work = False
-        for req in admitted:
-            if req.kind == "generate":
+        with _profiler.span("engine/step", cat="engine"):
+            t0 = time.perf_counter()
+            with _profiler.span("engine/admit", cat="engine") as sp:
+                self._reap_stale()
+                admitted = self._admit()
+                sp.set(admitted=len(admitted), queued=self.queue.depth())
+            gen_work = False
+            for req in admitted:
+                if req.kind == "generate":
+                    gen_work = True
+                    self._run_prefill(req)
+                else:
+                    self._exec_ready.append(req)
+            decoded = 0
+            if any(r is not None and r.status == RUNNING and
+                   r.kind == "generate" for r in self._slots):
                 gen_work = True
-                self._run_prefill(req)
-            else:
-                self._exec_ready.append(req)
-        decoded = 0
-        if any(r is not None and r.status == RUNNING and
-               r.kind == "generate" for r in self._slots):
-            gen_work = True
-            decoded = self._decode_tick()
-        active = len([r for r in self.active() if r.kind == "generate"])
-        self._retire_finished()
-        if gen_work:
-            _ledger.end_tick(
-                time.perf_counter() - t0,
-                decoded_tokens=decoded,
-                active=active,
-                max_batch=self.max_batch,
-                kv_used=self.allocator.used(),
-                kv_total=self.allocator.capacity,
-                queued=self.queue.depth())
+                decoded = self._decode_tick()
+            active = len([r for r in self.active() if r.kind == "generate"])
+            with _profiler.span("engine/retire", cat="engine"):
+                self._retire_finished()
+            if gen_work:
+                with _profiler.span("engine/ledger", cat="engine"):
+                    _ledger.end_tick(
+                        time.perf_counter() - t0,
+                        decoded_tokens=decoded,
+                        active=active,
+                        max_batch=self.max_batch,
+                        kv_used=self.allocator.used(),
+                        kv_total=self.allocator.capacity,
+                        queued=self.queue.depth())
         return gen_work or bool(admitted)
 
     def _claim_execute(self, prefer: Optional[ServeRequest] = None) -> bool:
@@ -817,37 +824,37 @@ class ServingEngine:
                          queued=self.queue.depth())
 
     def _run_prefill(self, req: ServeRequest) -> None:
-        import jax
-
-        req.t_prefill0 = time.perf_counter_ns()
-        try:
-            pages, tok = self.model.prefill(
-                self.pages, req.prompt, req.prompt_len, req.blocks)
-            jax.block_until_ready(pages)
-        except Exception as e:
-            self._slots[req.slot] = None
-            req.slot = -1
-            self.allocator.free(req.blocks)
-            req.blocks = []
-            self._fail(req, f"{type(e).__name__}: {e}")
-            return
-        self.pages = pages
-        req.t_prefill1 = time.perf_counter_ns()
-        if not req.t_first_token:  # a re-prefill after eviction is not
-            req.t_first_token = req.t_prefill1  # the user's first token
-        req.context_len = req.prompt_len
-        req.out_tokens.append(tok)
-        _ledger.add("prefill_compute",
-                    (req.t_prefill1 - req.t_prefill0) / 1e9)
-        if len(req.out_tokens) >= req.max_new_tokens:
-            req.status = DONE
+        with _profiler.span("engine/prefill", cat="engine",
+                            bucket=self.model.bucket_for(req.prompt_len),
+                            prompt_len=req.prompt_len,
+                            request_id=req.request_id):
+            req.t_prefill0 = time.perf_counter_ns()
+            try:
+                # returns with pages and token ready (tick/device_sync)
+                pages, tok = self.model.prefill(
+                    self.pages, req.prompt, req.prompt_len, req.blocks)
+            except Exception as e:
+                self._slots[req.slot] = None
+                req.slot = -1
+                self.allocator.free(req.blocks)
+                req.blocks = []
+                self._fail(req, f"{type(e).__name__}: {e}")
+                return
+            self.pages = pages
+            req.t_prefill1 = time.perf_counter_ns()
+            if not req.t_first_token:  # a re-prefill after eviction is not
+                req.t_first_token = req.t_prefill1  # the user's first token
+            req.context_len = req.prompt_len
+            req.out_tokens.append(tok)
+            _ledger.add("prefill_compute",
+                        (req.t_prefill1 - req.t_prefill0) / 1e9)
+            if len(req.out_tokens) >= req.max_new_tokens:
+                req.status = DONE
 
     def _decode_tick(self) -> int:
         """One batched decode dispatch. Returns the number of tokens
         decoded (counted HERE, before retirement clears finished
         requests from their slots)."""
-        import jax
-
         self._tick_no += 1
         # serving chaos sites, seed-deterministic (paddle_tpu/chaos.py):
         # replica_kill dies NOW with slots full of in-flight state — the
@@ -856,11 +863,57 @@ class ServingEngine:
         if _chaos.enabled():
             _chaos.replica_kill(self._tick_no)
             _chaos.delay("decode_stall", where=f"decode_tick/{self._tick_no}")
+        with _profiler.span("engine/decode_tick", cat="engine",
+                            tick=self._tick_no) as tick:
+            decoded, sync_s = self._decode_tick_phases(tick)
+        if decoded:
+            # the same two intervals the spans show: the tick, and inside
+            # it the host blocked on the device
+            _ledger.note_decode_tick(tick.seconds, sync_s)
+        return decoded
+
+    def _decode_tick_phases(self, tick) -> Tuple[int, float]:
+        """The tick inside its span. Returns (tokens decoded, seconds of
+        ``tick/device_sync``)."""
+        with _profiler.span("tick/grow_blocks", cat="engine"):
+            ready = self._grow_blocks()
+        tick.set(slots=len(ready))
+        if not ready:
+            return 0, 0.0
+        with _profiler.span("tick/build_inputs", cat="engine"):
+            B = self.max_batch
+            tables = np.zeros((B, self.model.max_blocks_per_req), np.int32)
+            lens = np.zeros((B,), np.int32)
+            toks = np.zeros((B,), np.int32)
+            for req in ready:
+                tables[req.slot, :len(req.blocks)] = req.blocks
+                lens[req.slot] = req.context_len
+                toks[req.slot] = req.out_tokens[-1]
+        # tick/put_inputs, tick/enqueue, tick/device_sync: returns with
+        # pages and tokens ready, and with those spans' stamps
+        pages, nxt, (t0, t_sync, t1) = self.model.decode(
+            self.pages, tables, lens, toks)
+        with _profiler.span("tick/bookkeeping", cat="engine"):
+            self.pages = pages
+            window = (t1 - t0) / 1e9
+            _ledger.add("decode_compute", window)
+            # the engine-side leg of the span reconciliation: slot-seconds
+            _ledger.add_slot_seconds(window * len(ready))
+            for req in ready:
+                req.out_tokens.append(int(nxt[req.slot]))
+                req.context_len += 1
+                req.tick_windows.append((t0, t1, self._tick_no))
+                if len(req.out_tokens) >= req.max_new_tokens:
+                    req.status = DONE
+        return len(ready), (t1 - t_sync) / 1e9
+
+    def _grow_blocks(self) -> List[ServeRequest]:
+        """Grow each running context into its next block where needed;
+        a request that cannot get one is preempted (self-victim =
+        failure). Returns the requests that enter this tick's batch."""
         active = [r for r in self._slots
                   if r is not None and r.status == RUNNING
                   and r.kind == "generate"]
-        # grow each context into its next block where needed; a request
-        # that cannot get one is preempted (self-victim = failure)
         ready: List[ServeRequest] = []
         for req in active:
             if req.status != RUNNING or req.slot < 0:
@@ -889,34 +942,7 @@ class ServingEngine:
         # an eviction later in the growth loop may have preempted a
         # request already collected: only still-running slot-holders
         # enter the batch (a slot of -1 would corrupt another row)
-        ready = [r for r in ready
-                 if r.status == RUNNING and r.slot >= 0]
-        if not ready:
-            return 0
-        B = self.max_batch
-        tables = np.zeros((B, self.model.max_blocks_per_req), np.int32)
-        lens = np.zeros((B,), np.int32)
-        toks = np.zeros((B,), np.int32)
-        for req in ready:
-            tables[req.slot, :len(req.blocks)] = req.blocks
-            lens[req.slot] = req.context_len
-            toks[req.slot] = req.out_tokens[-1]
-        t0 = time.perf_counter_ns()
-        pages, nxt = self.model.decode(self.pages, tables, lens, toks)
-        jax.block_until_ready(pages)
-        t1 = time.perf_counter_ns()
-        self.pages = pages
-        window = (t1 - t0) / 1e9
-        _ledger.add("decode_compute", window)
-        # the engine-side leg of the span reconciliation: slot-seconds
-        _ledger.add_slot_seconds(window * len(ready))
-        for req in ready:
-            req.out_tokens.append(int(nxt[req.slot]))
-            req.context_len += 1
-            req.tick_windows.append((t0, t1, self._tick_no))
-            if len(req.out_tokens) >= req.max_new_tokens:
-                req.status = DONE
-        return len(ready)
+        return [r for r in ready if r.status == RUNNING and r.slot >= 0]
 
     # -- retirement ----------------------------------------------------
 
@@ -977,6 +1003,13 @@ class ServingEngine:
                 req.blocks = []
             req.t_done = time.perf_counter_ns()
             span_s = sum((t1 - t0) for t0, t1, _ in req.tick_windows) / 1e9
+            if req.tick_windows:
+                # inter-token gaps from stamps the lifecycle already has:
+                # the prefill's token(s), then one token a tick
+                ends = sorted({req.t_first_token, req.t_prefill1}
+                              | {t1 for _, t1, _ in req.tick_windows})
+                _ledger.note_token_gaps(
+                    [(b - a) / 1e9 for a, b in zip(ends, ends[1:])])
             if req.status == DONE and req.t_admit:
                 # teach the admission shedder what service actually
                 # costs: EMA over completed requests' in-slot seconds

@@ -24,6 +24,17 @@ scheduling visibility):
   host_other       unattributed remainder of ticks with no runnable or
                    queued work
 
+Beside the buckets the ledger counts what a short profiler slice cannot
+give over a whole run, from the same ``perf_counter`` intervals the
+engine's spans show in a trace: ``decode_ticks`` (ticks that dispatched
+a decode program; ``ticks`` also counts prefill-only and queue-wait
+ticks), ``tick_wall_s`` (the ``engine/decode_tick`` spans, summed),
+``tick_sync_s`` (the ``tick/device_sync`` spans: the host waiting on
+the device, so wall - sync is host work a tick), and ``itl_gaps_s``, a
+bounded sample of the gaps between a request's consecutive tokens, with
+``itl_gaps_seen``, how many gaps it was drawn from (more than the sample
+holds: the oldest were dropped, and a percentile says so).
+
 Tick accounting is two-phase like goodput's: the engine ``add()``s into
 the OPEN tick, then ``end_tick(wall)`` assigns the remainder by state
 (active batch -> batch_gap, queued-only -> queue_wait, else host_other)
@@ -54,6 +65,7 @@ taxonomy, never a silent pass):
 from __future__ import annotations
 
 import atexit
+import collections
 import glob
 import json
 import os
@@ -67,7 +79,8 @@ from .. import monitor as _monitor
 __all__ = [
     "BUCKETS", "PRODUCTIVE_BUCKETS", "ATTRIBUTION_BUCKETS",
     "ServingLedger", "ledger", "reset",
-    "add", "mark", "add_slot_seconds", "end_tick", "record_request",
+    "add", "mark", "add_slot_seconds", "note_decode_tick", "note_token_gaps",
+    "end_tick", "record_request",
     "record_attribution", "attribution_summary", "reconcile_attribution",
     "totals", "summary",
     "slo_summary", "status", "configure", "disable_persistence", "flush",
@@ -112,6 +125,12 @@ RESIDUAL_BOUNDS = (0.0001, 0.0002, 0.0005, 0.001, 0.002, 0.005, 0.01,
 _ATTR_TAIL = 32
 
 _EMA_ALPHA = 0.1
+# inter-token gaps kept for the tail (the newest; a 30 s chat window
+# makes ~2,500): a sample, not a histogram, because the 99th percentile
+# of a few thousand gaps is a handful of values the latency bounds
+# would put in one bin. With its count (itl_gaps_seen) left out of the
+# journal (flush) and of merges: a sample of one process's run
+_ITL_SAMPLE = 8192
 
 # fixed log-spaced bounds so per-replica histograms merge exactly across
 # restarts and ranks (1ms .. 120s covers CPU-sim ticks through pod SLOs)
@@ -331,6 +350,13 @@ class ServingLedger:
             # per-request decode span seconds vs per-tick slot-seconds
             self.request_span_seconds = 0.0
             self.decode_slot_seconds = 0.0
+            # the decode tick split into host work and device wait
+            self.decode_ticks = 0
+            self.tick_wall_s = 0.0
+            self.tick_sync_s = 0.0
+            self.itl_gaps: "collections.deque[float]" = collections.deque(
+                maxlen=_ITL_SAMPLE)
+            self.itl_gaps_seen = 0
             # per-request latency attribution (record_attribution)
             self.attribution = _new_attribution()
             self.tokens_per_sec_ema: Optional[float] = None
@@ -359,6 +385,23 @@ class ServingLedger:
             return
         with self._lock:
             self.decode_slot_seconds += float(seconds)
+
+    def note_decode_tick(self, wall_seconds: float,
+                         sync_seconds: float) -> None:
+        """One tick that dispatched a decode program: the seconds of its
+        ``engine/decode_tick`` span and, inside it, of the blocking
+        read-back (``tick/device_sync``)."""
+        with self._lock:
+            self.decode_ticks += 1
+            self.tick_wall_s += float(wall_seconds)
+            self.tick_sync_s += float(sync_seconds)
+
+    def note_token_gaps(self, gaps: Sequence[float]) -> None:
+        """A retired request's inter-token gaps (seconds), into the
+        bounded sample."""
+        with self._lock:
+            self.itl_gaps.extend(float(g) for g in gaps)
+            self.itl_gaps_seen += len(gaps)
 
     def end_tick(self, wall_seconds: float, decoded_tokens: int = 0,
                  active: int = 0, max_batch: int = 1,
@@ -524,6 +567,11 @@ class ServingLedger:
             w_wall = self.weighted_wall
             span_s = self.request_span_seconds
             slot_s = self.decode_slot_seconds
+            decode_ticks = self.decode_ticks
+            tick_wall = self.tick_wall_s
+            tick_sync = self.tick_sync_s
+            doc["itl_gaps_s"] = list(self.itl_gaps)
+            doc["itl_gaps_seen"] = self.itl_gaps_seen
             attribution = json.loads(json.dumps(self.attribution))
             base = self.base
         if base:
@@ -542,6 +590,9 @@ class ServingLedger:
             w_wall += float(base.get("weighted_wall", 0.0))
             span_s += float(base.get("request_span_seconds", 0.0))
             slot_s += float(base.get("decode_slot_seconds", 0.0))
+            decode_ticks += int(base.get("decode_ticks", 0))
+            tick_wall += float(base.get("tick_wall_s", 0.0))
+            tick_sync += float(base.get("tick_sync_s", 0.0))
             attribution = merge_attribution(base.get("attribution"),
                                             attribution)
             doc["resumed_from_journal"] = True
@@ -566,6 +617,9 @@ class ServingLedger:
             "kv_block_utilization": (kv_w / w_wall) if w_wall > 0 else None,
             "request_span_seconds": span_s,
             "decode_slot_seconds": slot_s,
+            "decode_ticks": decode_ticks,
+            "tick_wall_s": tick_wall,
+            "tick_sync_s": tick_sync,
             "attribution": attribution,
         })
         return _finalize(doc, buckets, wall)
@@ -602,6 +656,18 @@ def add_slot_seconds(seconds: float) -> None:
     if not _monitor.enabled():
         return
     _LEDGER.add_slot_seconds(seconds)
+
+
+def note_decode_tick(wall_seconds: float, sync_seconds: float) -> None:
+    if not _monitor.enabled():
+        return
+    _LEDGER.note_decode_tick(wall_seconds, sync_seconds)
+
+
+def note_token_gaps(gaps: Sequence[float]) -> None:
+    if not _monitor.enabled():
+        return
+    _LEDGER.note_token_gaps(gaps)
 
 
 def end_tick(wall_seconds: float, **kw) -> Optional[dict]:
@@ -792,6 +858,8 @@ def flush(path: Optional[str] = None) -> Optional[str]:
             return None
         path = journal_path()
     doc = totals(include_open=False)
+    # a sample of this process's run and its count, not totals
+    del doc["itl_gaps_s"], doc["itl_gaps_seen"]
     doc["span_reconciliation"] = reconcile_spans(doc)
     doc["roofline_reconciliation"] = reconcile_roofline(doc)
     doc["attribution_reconciliation"] = reconcile_attribution(doc)
@@ -873,6 +941,8 @@ def merge_ledgers(docs: List[Dict[str, Any]]) -> Dict[str, Any]:
     latency = new_hist()
     occ_w = kv_w = w_wall = 0.0
     span_s = slot_s = 0.0
+    decode_ticks = 0
+    tick_wall = tick_sync = 0.0
     ranks: List[int] = []
     roofline = None
     max_wall = 0.0
@@ -916,6 +986,9 @@ def merge_ledgers(docs: List[Dict[str, Any]]) -> Dict[str, Any]:
         w_wall += float(d.get("weighted_wall", 0.0))
         span_s += float(d.get("request_span_seconds", 0.0))
         slot_s += float(d.get("decode_slot_seconds", 0.0))
+        decode_ticks += int(d.get("decode_ticks", 0))
+        tick_wall += float(d.get("tick_wall_s", 0.0))
+        tick_sync += float(d.get("tick_sync_s", 0.0))
         if d.get("rank") is not None:
             ranks.append(int(d["rank"]))
     # replica throughputs add over the LONGEST replica wall (concurrent
@@ -943,6 +1016,9 @@ def merge_ledgers(docs: List[Dict[str, Any]]) -> Dict[str, Any]:
         "kv_block_utilization": (kv_w / w_wall) if w_wall > 0 else None,
         "request_span_seconds": span_s,
         "decode_slot_seconds": slot_s,
+        "decode_ticks": decode_ticks,
+        "tick_wall_s": tick_wall,
+        "tick_sync_s": tick_sync,
         "attribution": attribution,
         "traffic": traffic,
         "autoscale": autoscale,

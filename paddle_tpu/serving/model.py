@@ -43,6 +43,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from .. import flags as _flags
+from .. import profiler as _profiler
 from ..models.gpt import GPTConfig
 from .kv_cache import blocks_for_tokens
 
@@ -312,32 +313,38 @@ class DecodeModel:
         H, hd = cfg.n_head, cfg.head_dim
         scale = 1.0 / math.sqrt(hd)
         pos = jnp.arange(L)
-        x = p["gpt.wte"][tokens] + p["gpt.wpe"][pos][None]  # [1,L,D]
+        with jax.named_scope("embed"):
+            x = p["gpt.wte"][tokens] + p["gpt.wpe"][pos][None]  # [1,L,D]
         causal = pos[:, None] >= pos[None, :]
         for i in range(cfg.n_layer):
             ln = f"gpt.h{i}"
-            h = self._ln_p(p, x, f"{ln}.ln1")
-            q = self._linear(p, h, f"{ln}.attn.q").reshape(1, L, H, hd)
-            k = self._linear(p, h, f"{ln}.attn.k").reshape(1, L, H, hd)
-            v = self._linear(p, h, f"{ln}.attn.v").reshape(1, L, H, hd)
-            if on_kv is not None:
-                on_kv(i, k, v)
-            s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
-            s = jnp.where(causal[None, None], s, _NEG)
-            a = jax.nn.softmax(s, axis=-1)
-            o = jnp.einsum("bhqk,bkhd->bqhd", a, v).reshape(1, L, -1)
-            x = x + self._linear(p, o, f"{ln}.attn.proj")
-            x = x + self._mlp(p, self._ln_p(p, x, f"{ln}.ln2"), ln)
+            with jax.named_scope("layer"):
+                h = self._ln_p(p, x, f"{ln}.ln1")
+                q = self._linear(p, h, f"{ln}.attn.q").reshape(1, L, H, hd)
+                k = self._linear(p, h, f"{ln}.attn.k").reshape(1, L, H, hd)
+                v = self._linear(p, h, f"{ln}.attn.v").reshape(1, L, H, hd)
+                if on_kv is not None:
+                    with jax.named_scope("attn/kv_write"):
+                        on_kv(i, k, v)
+                with jax.named_scope("attn/scores"):
+                    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+                    s = jnp.where(causal[None, None], s, _NEG)
+                    a = jax.nn.softmax(s, axis=-1)
+                    o = jnp.einsum("bhqk,bkhd->bqhd", a, v).reshape(1, L, -1)
+                x = x + self._linear(p, o, f"{ln}.attn.proj")
+                with jax.named_scope("mlp"):
+                    x = x + self._mlp(p, self._ln_p(p, x, f"{ln}.ln2"), ln)
         return self._ln_p(p, x, "gpt.lnf")
 
     def _build_prefill(self, L: int):
         """The bucket-L prefill program: causal pass over [1, L], K/V
         scattered into the request's blocks, argmax token at length-1."""
+        import jax
         import jax.numpy as jnp
 
         BS = self.block_size
 
-        def fn(p, pages, tokens, length, block_ids):
+        def prefill(p, pages, tokens, length, block_ids):
             pos = jnp.arange(L)
             blk = jnp.where(pos < length, block_ids[pos // BS], 0)
             slot = jnp.where(pos < length, pos % BS, 0)
@@ -348,11 +355,13 @@ class DecodeModel:
                 cell[0] = cell[0].at[i, 1, blk, slot].set(v[0])
 
             x = self._prompt_trunk(p, tokens, L, on_kv=scatter_kv)
-            last = jnp.take(x, length - 1, axis=1)  # [1, D]
-            logits = last @ p["gpt.wte"].T  # [1, V]
-            return cell[0], jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            with jax.named_scope("lm_head"):
+                last = jnp.take(x, length - 1, axis=1)  # [1, D]
+                logits = last @ p["gpt.wte"].T  # [1, V]
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return cell[0], nxt
 
-        return self._compile(fn, "prefill", L)
+        return self._compile(prefill, "prefill", L)
 
     # -- prompt scoring -------------------------------------------------
 
@@ -369,7 +378,7 @@ class DecodeModel:
 
         from ..ops.pallas.fused_lmhead_ce import lmhead_ce
 
-        def fn(p, tokens, length):
+        def score(p, tokens, length):
             x = self._prompt_trunk(p, tokens, L)
             # positions 0..L-2 predict tokens 1..L-1; padded tail masked
             nll = lmhead_ce(x[0, :L - 1], p["gpt.wte"], tokens[0, 1:])
@@ -377,7 +386,7 @@ class DecodeModel:
             nll = jnp.where(valid, nll, 0.0)
             return nll, jnp.sum(nll)
 
-        return self._compile(fn, "score", L)
+        return self._compile(score, "score", L)
 
     def score(self, tokens, length: Optional[int] = None):
         """Per-token NLL of a prompt (the scoring API): returns
@@ -418,34 +427,42 @@ class DecodeModel:
         scale = 1.0 / math.sqrt(hd)
         barange = jnp.arange(B)
 
-        def fn(p, pages, block_tables, context_lens, tokens):
+        def decode_tick(p, pages, block_tables, context_lens, tokens):
             pos = context_lens  # [B]: the new token's position
-            x = p["gpt.wte"][tokens] + p["gpt.wpe"][pos]  # [B, D]
+            with jax.named_scope("embed"):
+                x = p["gpt.wte"][tokens] + p["gpt.wpe"][pos]  # [B, D]
             blk = block_tables[barange, pos // BS]  # [B]
             slot = pos % BS
             valid = (jnp.arange(S)[None, :] <= pos[:, None])  # [B, S]
             for i in range(cfg.n_layer):
                 ln = f"gpt.h{i}"
-                h = self._ln_p(p, x, f"{ln}.ln1")
-                q = self._linear(p, h, f"{ln}.attn.q").reshape(B, H, hd)
-                k = self._linear(p, h, f"{ln}.attn.k").reshape(B, H, hd)
-                v = self._linear(p, h, f"{ln}.attn.v").reshape(B, H, hd)
-                pages = pages.at[i, 0, blk, slot].set(k)
-                pages = pages.at[i, 1, blk, slot].set(v)
-                # [B, MAXB, BS, H, hd] -> [B, S, H, hd]
-                kk = pages[i, 0][block_tables].reshape(B, S, H, hd)
-                vv = pages[i, 1][block_tables].reshape(B, S, H, hd)
-                s = jnp.einsum("bhd,bshd->bhs", q, kk) * scale
-                s = jnp.where(valid[:, None, :], s, _NEG)
-                a = jax.nn.softmax(s, axis=-1)
-                o = jnp.einsum("bhs,bshd->bhd", a, vv).reshape(B, -1)
-                x = x + self._linear(p, o, f"{ln}.attn.proj")
-                x = x + self._mlp(p, self._ln_p(p, x, f"{ln}.ln2"), ln)
-            x = self._ln_p(p, x, "gpt.lnf")
-            logits = x @ p["gpt.wte"].T  # [B, V]
-            return pages, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                with jax.named_scope("layer"):
+                    h = self._ln_p(p, x, f"{ln}.ln1")
+                    q = self._linear(p, h, f"{ln}.attn.q").reshape(B, H, hd)
+                    k = self._linear(p, h, f"{ln}.attn.k").reshape(B, H, hd)
+                    v = self._linear(p, h, f"{ln}.attn.v").reshape(B, H, hd)
+                    with jax.named_scope("attn/kv_write"):
+                        pages = pages.at[i, 0, blk, slot].set(k)
+                        pages = pages.at[i, 1, blk, slot].set(v)
+                    with jax.named_scope("attn/kv_gather"):
+                        # [B, MAXB, BS, H, hd] -> [B, S, H, hd]
+                        kk = pages[i, 0][block_tables].reshape(B, S, H, hd)
+                        vv = pages[i, 1][block_tables].reshape(B, S, H, hd)
+                    with jax.named_scope("attn/scores"):
+                        s = jnp.einsum("bhd,bshd->bhs", q, kk) * scale
+                        s = jnp.where(valid[:, None, :], s, _NEG)
+                        a = jax.nn.softmax(s, axis=-1)
+                        o = jnp.einsum("bhs,bshd->bhd", a, vv).reshape(B, -1)
+                    x = x + self._linear(p, o, f"{ln}.attn.proj")
+                    with jax.named_scope("mlp"):
+                        x = x + self._mlp(p, self._ln_p(p, x, f"{ln}.ln2"), ln)
+            with jax.named_scope("lm_head"):
+                x = self._ln_p(p, x, "gpt.lnf")
+                logits = x @ p["gpt.wte"].T  # [B, V]
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return pages, nxt
 
-        return self._compile(fn, "decode")
+        return self._compile(decode_tick, "decode")
 
     # -- compile + AOT insight -----------------------------------------
 
@@ -458,6 +475,10 @@ class DecodeModel:
 
         from ..framework import xla_insight
 
+        # the module's name in a profile and in the HLO: jit_decode_tick,
+        # jit_prefill_<bucket>, jit_score_<bucket>. The same in every
+        # process: it is part of the persistent compile cache's key
+        fn.__name__ = fn.__qualname__ = self.program_name(kind, bucket)
         jit_fn = self._jit_for(fn, kind)
         # example args at the real shapes (compile == serve shapes)
         pages = self.init_pages()
@@ -494,6 +515,13 @@ class DecodeModel:
             return xla_insight.aot_call(executable, jit_fn)
         return jit_fn
 
+    @staticmethod
+    def program_name(kind: str, bucket: Optional[int] = None) -> str:
+        """What a serving program is called wherever it shows: the trace's
+        module line (``jit_`` + this), the HLO, a span's attribute."""
+        base = "decode_tick" if kind == "decode" else kind
+        return base if bucket is None else f"{base}_{bucket}"
+
     def _jit_for(self, fn, kind: str):
         import jax
         from jax.sharding import NamedSharding, PartitionSpec
@@ -521,8 +549,10 @@ class DecodeModel:
     def prefill(self, pages, tokens: np.ndarray, length: int,
                 block_ids: Sequence[int]):
         """Run the prompt through the smallest bucket that holds it.
-        Returns (pages, first_token:int). Raises InvalidArgument when no
-        bucket fits (the engine fails the request, not the batch)."""
+        Returns (pages, first_token:int), both ready. Raises
+        InvalidArgument when no bucket fits (the engine fails the
+        request, not the batch)."""
+        import jax
         import jax.numpy as jnp
 
         from ..framework import errors as _errors
@@ -539,24 +569,38 @@ class DecodeModel:
         ids = np.zeros((self.max_blocks_per_req,), np.int32)
         blocks = list(block_ids)[:self.max_blocks_per_req]
         ids[:len(blocks)] = blocks
-        pages, tok = self._prefill_fns[L](
-            self.params, pages, jnp.asarray(padded),
-            jnp.int32(int(length)), jnp.asarray(ids))
-        return pages, int(tok[0])
+        with _profiler.span("tick/put_inputs", cat="engine"):
+            args = (jnp.asarray(padded), jnp.int32(int(length)),
+                    jnp.asarray(ids))
+        with _profiler.span("tick/enqueue", cat="engine"):
+            pages, tok = self._prefill_fns[L](self.params, pages, *args)
+        with _profiler.span("tick/device_sync", cat="engine"):
+            first = int(tok[0])
+            jax.block_until_ready(pages)
+        return pages, first
 
     def decode(self, pages, block_tables: np.ndarray,
                context_lens: np.ndarray, tokens: np.ndarray):
-        """One decode tick at max_batch. Returns (pages, next[B] np)."""
+        """One decode tick at max_batch. Returns (pages, next[B] np,
+        stamps): both arrays ready, and the ``perf_counter_ns`` stamps of
+        the spans below, (start, start of ``tick/device_sync``, end), so
+        the engine's windows and the ledger's ``tick_sync_s`` are the
+        intervals a trace shows."""
+        import jax
         import jax.numpy as jnp
 
         if self._decode_fn is None:
             self._decode_fn = self._build_decode()
-        pages, nxt = self._decode_fn(
-            self.params, pages,
-            jnp.asarray(np.asarray(block_tables, np.int32)),
-            jnp.asarray(np.asarray(context_lens, np.int32)),
-            jnp.asarray(np.asarray(tokens, np.int32)))
-        return pages, np.asarray(nxt)
+        with _profiler.span("tick/put_inputs", cat="engine") as put:
+            args = (jnp.asarray(np.asarray(block_tables, np.int32)),
+                    jnp.asarray(np.asarray(context_lens, np.int32)),
+                    jnp.asarray(np.asarray(tokens, np.int32)))
+        with _profiler.span("tick/enqueue", cat="engine"):
+            pages, nxt = self._decode_fn(self.params, pages, *args)
+        with _profiler.span("tick/device_sync", cat="engine") as sync:
+            nxt = np.asarray(nxt)
+            jax.block_until_ready(pages)
+        return pages, nxt, (put.t0_ns, sync.t0_ns, sync.t1_ns)
 
     def warm(self, full: bool = False) -> None:
         """Compile the decode program (and the smallest prefill bucket)

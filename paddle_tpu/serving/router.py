@@ -91,9 +91,6 @@ _M_BITMATCH = _monitor.counter(
     "serve_router_bitmatch_total",
     "re-dispatch token comparisons by verdict (match/mismatch)",
     ("verdict",))
-_M_DISPATCH = _monitor.counter(
-    "serve_router_dispatch_total", "router dispatches by outcome",
-    ("outcome",))
 
 _rid_counter = itertools.count(1)
 
@@ -971,7 +968,6 @@ class Router:
             with self._lock:
                 self.stats["dispatches"] += 1
                 self.stats["failed"] += 1
-            _M_DISPATCH.labels(outcome="failed").inc()
             err = (f"admission: class {traffic_class!r} over its "
                    f"weighted share at the router admission cap")
             attribution = {"backoff_wait": 0.0, "transport": 0.0,
@@ -1066,7 +1062,6 @@ class Router:
             _M_FAILOVER.inc()
         with self._lock:
             self.stats["ok" if ok else "failed"] += 1
-        _M_DISPATCH.labels(outcome="ok" if ok else "failed").inc()
         last_err = next((a for a in reversed(attempts)
                          if not a.get("ok")), None)
         attribution, residual = self._assemble_attribution(

@@ -69,6 +69,18 @@ def sharding_drift_guard():
         f"sharding_mismatch_total grew {before} -> {after}")
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _dygraph_between_modules():
+    """Each test file starts in dygraph mode whatever ran before it on
+    its xdist worker: test_recipes.py and test_recipe_checkpoint.py end
+    in static mode, and test_distributed.py's dygraph tests then fail
+    with "'Variable' object has no attribute 'backward'"."""
+    yield
+    import paddle_tpu
+
+    paddle_tpu.disable_static()
+
+
 def free_ports(n):
     """Reserve n distinct OS-assigned free ports (bind :0, SO_REUSEADDR).
 

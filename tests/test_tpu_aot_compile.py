@@ -80,3 +80,153 @@ def test_oversize_tile_is_refused(tpu_arg):
         _compiled_text(
             loss, tpu_arg((2048, 128), jnp.bfloat16),
             tpu_arg((8192, 128), jnp.bfloat16), tpu_arg((2048,), jnp.int32))
+
+
+# ---------------------------------------------------------------------------
+# stable names: every kernel and serving program is found by its NAME in the
+# compiled HLO (and so in a device trace), at GPT-2 small's widths
+# ---------------------------------------------------------------------------
+
+import re  # noqa: E402
+
+
+def _kernel_names(text):
+    """Names of the Mosaic custom-call instructions, numeric suffix dropped."""
+    names = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
+    return sorted(re.sub(r"\.\d+$", "", n) for n in names)
+
+
+def _own_names(names):
+    """A kernel traced under jax.vjp or its transpose is jvp_<name>_ or
+    transpose_jvp_<name>__: the kernel's own name is still in it."""
+    own = re.compile(r"flash_(?:fwd|dq|dkv)|lmhead_ce_(?:stats|dx|dw)|fused_adam")
+    return sorted(own.search(n).group(0) if own.search(n) else n for n in names)
+
+
+def _metric_pattern(metric):
+    from benchmark import manifest
+
+    return re.compile(manifest.layer_metric(metric)["args"]["pattern"])
+
+
+def test_flash_kernels_carry_their_names_at_gpt2s_widths(tpu_arg):
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, layout="BTHD", interpret=False)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    qkv = [tpu_arg((2, 1024, 12, 64), jnp.bfloat16)] * 3
+    assert _kernel_names(_compiled_text(fwd, *qkv)) == ["flash_fwd"]
+    names = _kernel_names(_compiled_text(jax.grad(loss, argnums=(0, 1, 2)), *qkv))
+    assert _own_names(names) == ["flash_dkv", "flash_dq", "flash_fwd"], names
+    rx, fwd_rx = _metric_pattern("flash_kernels_roofline"), _metric_pattern("fwd_passes_per_step")
+    assert all(rx.search(n) for n in names)
+    assert sum(bool(fwd_rx.search(n)) for n in names) == 1
+
+
+def test_lmhead_ce_kernels_carry_their_names_at_gpt2s_widths(tpu_arg):
+    def loss(x, w, labels):
+        return lmhead_ce(x, w, labels, interpret=False).sum()
+
+    args = (tpu_arg((2048, 768), jnp.bfloat16), tpu_arg((50304, 768), jnp.bfloat16),
+            tpu_arg((2048,), jnp.int32))
+    assert _kernel_names(_compiled_text(loss, *args)) == ["lmhead_ce_stats"]
+    names = _kernel_names(_compiled_text(jax.grad(loss, argnums=(0, 1)), *args))
+    assert _own_names(names) == ["lmhead_ce_dw", "lmhead_ce_dx", "lmhead_ce_stats"], names
+    rx = _metric_pattern("lmhead_ce_kernels_roofline")
+    assert all(rx.search(n) for n in names)
+    assert not rx.search("flash_fwd") and not _metric_pattern("flash_kernels_roofline").search(names[0])
+
+
+def test_fused_adam_kernel_carries_its_name_at_gpt2s_widths(tpu_arg):
+    p = tpu_arg((768, 3072), jnp.bfloat16)
+    m = tpu_arg((768, 3072), jnp.float32)
+    s = tpu_arg((), jnp.float32)
+    text = _compiled_text(functools.partial(fused_adam, interpret=False), p, p, m, m, s, s, s)
+    assert _kernel_names(text) == ["fused_adam"]
+
+
+@pytest.fixture(scope="module")
+def serving_programs(tpu_arg):
+    """The decode and one prefill program of a small DecodeModel, compiled
+    for the described chip from the functions the model builds."""
+    from paddle_tpu import serving
+
+    cfg = serving.GPTConfig(vocab_size=512, n_layer=2, n_head=4, d_model=256, max_seq_len=128,
+                            dtype="bfloat16")
+    dm = serving.DecodeModel(cfg, max_batch=4, n_blocks=24, block_size=16, prefill_buckets=[32],
+                             seed=0)
+    built = {}
+    dm._compile = lambda fn, kind, bucket=None: built.setdefault(
+        dm.program_name(kind, bucket), fn)  # keep the function, compile nothing on the CPU
+    dm._build_decode()
+    dm._build_prefill(32)
+    p = {n: tpu_arg(a.shape, a.dtype) for n, a in dm.params.items()}
+    pages = tpu_arg((cfg.n_layer, 2, dm.n_blocks, dm.block_size, cfg.n_head, cfg.head_dim), jnp.bfloat16)
+    i32 = lambda *shape: tpu_arg(shape, jnp.int32)  # noqa: E731
+    args = {"decode_tick": (p, pages, i32(4, dm.max_blocks_per_req), i32(4), i32(4)),
+            "prefill_32": (p, pages, i32(1, 32), i32(), i32(dm.max_blocks_per_req))}
+    out = {}
+    for name, fn in built.items():
+        fn.__name__ = fn.__qualname__ = name  # as DecodeModel._compile names it
+        out[name] = jax.jit(fn).lower(*args[name]).compile().as_text()
+    return out
+
+
+@pytest.mark.parametrize("name", ["decode_tick", "prefill_32"])
+def test_serving_programs_carry_their_names(serving_programs, name):
+    text = serving_programs[name]
+    assert re.search(rf"HloModule jit_{name}\b", text)
+    from benchmark import manifest
+
+    metric = "decode_program_ms" if name == "decode_tick" else "prefill_program_ms"
+    assert re.search(manifest.layer_metric(metric)["args"]["pattern"], f"jit_{name}")
+
+
+@pytest.mark.parametrize("name,scopes", [
+    ("decode_tick", ("embed", "layer/attn/kv_write", "layer/attn/kv_gather", "layer/attn/scores",
+                     "layer/mlp", "lm_head")),
+    ("prefill_32", ("embed", "layer/attn/kv_write", "layer/attn/scores", "layer/mlp", "lm_head"))])
+def test_serving_programs_carry_their_scopes_in_op_name(serving_programs, name, scopes):
+    ops = set(re.findall(r'op_name="([^"]*)"', serving_programs[name]))
+    for scope in scopes:
+        assert any(f"jit({name})/{scope}/" in o for o in ops), (scope, sorted(ops)[:20])
+
+
+def test_executor_step_carries_paddle_ops_in_op_name_and_a_role_name(tpu_arg):
+    """A tiny train step lowered for the chip: module jit_train_step, every
+    instruction under its Paddle op's scope (XLA attention at this size)."""
+    import numpy as np
+
+    import paddle_tpu as paddle
+    from paddle_tpu.framework import Executor, Scope, program_guard
+    from paddle_tpu.models.gpt import GPTConfig, build_train_program
+    from paddle_tpu.optimizer import SGD
+
+    paddle.enable_static()
+    try:
+        cfg = GPTConfig(vocab_size=64, n_layer=1, n_head=2, d_model=32, max_seq_len=16)
+        main, startup, io = build_train_program(cfg, batch=2, seq=16)
+        with program_guard(main, startup):
+            SGD(learning_rate=0.1).minimize(io["loss"])
+        scope, exe = Scope(), Executor()
+        exe.run(startup, scope=scope)
+        feeds = {"tokens": jnp.zeros((2, 16), jnp.int32), "labels": jnp.zeros((2, 16), jnp.int32)}
+        for n, fn in (getattr(main, "_extra_feeds", None) or {}).items():
+            feeds[n] = jnp.asarray(fn())
+        compiled = exe._get_compiled(main, feeds, [io["loss"].name], scope)
+        startup_names = [c.module_name for c in exe._cache.values()]
+    finally:
+        paddle.disable_static()
+    assert startup_names == ["jit_startup", "jit_train_step"]
+    spec = lambda a: tpu_arg(np.shape(a), a.dtype)  # noqa: E731
+    text = compiled.fn.lower(
+        {k: spec(v) for k, v in feeds.items()},
+        {n: spec(scope.get(n)) for n in compiled.mutable_names},
+        {n: spec(scope.get(n)) for n in compiled.const_names},
+        tpu_arg((2,), jnp.uint32)).compile().as_text()
+    assert re.search(r"HloModule jit_train_step\b", text)
+    ops = set(re.findall(r'op_name="([^"]*)"', text))
+    for paddle_op in ("layer_norm", "matmul_grad", "sgd"):
+        assert any(f"jit(train_step)/{paddle_op}/" in o for o in ops), paddle_op
