@@ -513,6 +513,8 @@ class ServingEngine:
                 sp.set(admitted=len(admitted), queued=self.queue.depth())
             gen_work = False
             for req in admitted:
+                if req.status != RUNNING:
+                    continue  # preempted again: an earlier prefill failed
                 if req.kind == "generate":
                     gen_work = True
                     self._run_prefill(req)
@@ -799,6 +801,32 @@ class ServingEngine:
         _ledger.record_request(outcome="evicted")
         self.queue.push(req)
 
+    def _drop(self, req: ServeRequest, why: str) -> None:
+        """Fail a running request and give back its slot and blocks."""
+        if req.slot >= 0:
+            self._slots[req.slot] = None
+            req.slot = -1
+        self.allocator.free(req.blocks)
+        req.blocks = []
+        self._fail(req, why)
+
+    def _restore_pages(self) -> None:
+        """After a program raised: the pool is DONATED to every prefill
+        and decode program, so one that failed after dispatch took the
+        handle with it, and every running context with the handle. Then
+        serve on from a zeroed pool, the running requests preempted for
+        re-prefill as an eviction would. A program refused before
+        dispatch (a bad argument) consumed nothing, and nothing is done."""
+        if not self.pages.is_deleted():
+            return
+        self.pages = self.model.init_pages()
+        lost = [r for r in self._slots if r is not None
+                and r.status == RUNNING and r.kind == "generate"]
+        for req in lost:
+            self._preempt(req)
+        _monitor.flight_record("serve", "kv_pool_rebuilt",
+                               preempted=len(lost))
+
     # -- work ----------------------------------------------------------
 
     def _run_execute(self, req: ServeRequest) -> None:
@@ -834,11 +862,8 @@ class ServingEngine:
                 pages, tok = self.model.prefill(
                     self.pages, req.prompt, req.prompt_len, req.blocks)
             except Exception as e:
-                self._slots[req.slot] = None
-                req.slot = -1
-                self.allocator.free(req.blocks)
-                req.blocks = []
-                self._fail(req, f"{type(e).__name__}: {e}")
+                self._drop(req, f"{type(e).__name__}: {e}")
+                self._restore_pages()
                 return
             self.pages = pages
             req.t_prefill1 = time.perf_counter_ns()
@@ -891,8 +916,15 @@ class ServingEngine:
                 toks[req.slot] = req.out_tokens[-1]
         # tick/put_inputs, tick/enqueue, tick/device_sync: returns with
         # pages and tokens ready, and with those spans' stamps
-        pages, nxt, (t0, t_sync, t1) = self.model.decode(
-            self.pages, tables, lens, toks)
+        try:
+            pages, nxt, (t0, t_sync, t1) = self.model.decode(
+                self.pages, tables, lens, toks)
+        except Exception as e:  # the engine outlives a failed program
+            for req in ready:
+                self._drop(req, f"decode program failed: "
+                           f"{type(e).__name__}: {e}")
+            self._restore_pages()
+            return 0, 0.0
         with _profiler.span("tick/bookkeeping", cat="engine"):
             self.pages = pages
             window = (t1 - t0) / 1e9
@@ -927,12 +959,7 @@ class ServingEngine:
                         grown = self.allocator.alloc(
                             need - len(req.blocks), req.request_id)
                     if grown is None:
-                        if req.slot >= 0:
-                            self._slots[req.slot] = None
-                            req.slot = -1
-                        self.allocator.free(req.blocks)
-                        req.blocks = []
-                        self._fail(req, "kv blocks exhausted")
+                        self._drop(req, "kv blocks exhausted")
                         continue
                 req.blocks.extend(grown)
             if req.context_len + 1 >= self.model.cfg.max_seq_len:

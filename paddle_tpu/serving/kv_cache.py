@@ -3,16 +3,30 @@
 The vLLM-style design adapted to the repo's functional-XLA runtime: the
 cache is ONE device array of fixed-size blocks
 
-    pages[n_layer, 2, n_blocks, block_size, n_head, head_dim]
+    pages[n_layer * n_blocks, block_size, n_head * 2 * head_dim]
 
-and a request owns an ordered *block table* — the list of block ids its
-context occupies. The decode program gathers a request's K/V through its
-table and scatters the new token's K/V into the tail slot, so the cache
-never compacts and requests of wildly different lengths share one
-allocation. Block 0 is the reserved **scratch block**: padded table
-entries and inactive batch rows direct their (masked, never-read) reads
-and writes there, which keeps every gather/scatter in the compiled
-program unconditional.
+(``DecodeModel.pool_shape``), and a request owns an ordered *block
+table* — the list of block ids its context occupies, the same ids in
+every layer: layer ``i`` keeps block ``b`` at row-block ``i * n_blocks +
+b``. A token's row holds, head by head, that head's K then its V. The
+decode program gathers a request's K/V through its table and scatters
+the new token's K/V into the tail slot, so the cache never compacts and
+requests of wildly different lengths share one allocation. Block 0 (of
+every layer) is the reserved **scratch block**: padded table entries and
+inactive batch rows direct their (masked, never-read) reads and writes
+there, which keeps every gather/scatter in the compiled program
+unconditional.
+
+The shape is the device's, not the reader's. The TPU runtime stores an
+array in the most compact tiled layout for its shape; for the former
+``[n_layer, 2, n_blocks, block_size, n_head, head_dim]`` that put the
+block id on the fastest-moving dimension, so every program that indexed
+by block id copied the whole pool into a padded row-major layout and
+back (twice 25 ms of an 89 ms decode tick of GPT-2 XL on a v5e, PERF.md
+PR 25). A row that is a whole number of 128-lane tiles (2 x 64 a head)
+over a 16-token block is stored as it is indexed, so the programs take
+the pool donated and update it in place; folding the layer (and K|V)
+into the index and the row leaves no slice to materialise either.
 
 The host-side :class:`BlockAllocator` is deliberately dumb — a free
 list with LIFO reuse (the test observes a freed block coming straight

@@ -18,10 +18,16 @@ artifacts exactly like training programs (``program_flops`` gauges,
 ``PADDLE_TPU_XLA_DUMP_DIR`` dumps, and the decode roofline the SERVE
 bench reconciles measured tokens/s against).
 
+The KV pool passes through both as ONE donated array in the layout the
+chip keeps at rest (``DecodeModel.pool_shape``: rows of whole 128-lane
+tiles, the layer folded into the block index; serving/kv_cache.py says
+why): each program consumes the pool it is given and returns the same
+buffer updated in place, so a caller holds only the newest handle.
+
 Sharding comes STRAIGHT off ``parallel/recipes.py``: a resolved recipe
 supplies the mesh and the parameter rules (``GPT_TP_RULES`` — qkv/ffn-in
 column-parallel, proj/ffn-out row-parallel, vocab-sharded embeddings),
-and the KV pages shard their head dim over the recipe's tp axis — the
+and the KV pool shards its rows' heads over the recipe's tp axis — the
 placement the column-sharded qkv weights already imply, not a
 serving-local rule. ``shard_insight.verify_scope`` checks the
 intended-vs-actual placement at compile time, the same tripwire the
@@ -50,6 +56,7 @@ from .kv_cache import blocks_for_tokens
 __all__ = ["GPTConfig", "DecodeModel", "init_params", "calibrate"]
 
 _NEG = -1e30  # finite mask value: garbage behind it stays non-NaN
+_SUBLANES = 8  # rows of one (8, 128) tile, the unit the TPU lays arrays out in
 
 
 def init_params(cfg: GPTConfig, seed: int = 0) -> Dict[str, np.ndarray]:
@@ -85,6 +92,28 @@ def init_params(cfg: GPTConfig, seed: int = 0) -> Dict[str, np.ndarray]:
             p[f"{ln}.{nrm}.scale"] = np.ones(d, cfg.dtype)
             p[f"{ln}.{nrm}.bias"] = np.zeros(d, cfg.dtype)
     return p
+
+
+def _kv_rows(k, v):
+    """K and V ``[..., H, hd]`` of some tokens as rows of the KV pool
+    ``[..., H * 2 * hd]``: per head, its K then its V."""
+    import jax.numpy as jnp
+
+    kv = jnp.stack([k, v], axis=-2)  # [..., H, 2, hd]
+    return kv.reshape(kv.shape[:-3] + (-1,))
+
+
+_LAYER = "gpt.h"  # a layer's parameters inside the traced layer body
+
+
+def _layer_params(p, i: int):
+    """Layer ``i``'s parameters under names that carry no layer number
+    (``gpt.h.attn.q.w``): every layer then gives the layer body the same
+    argument tree, so the body is traced and lowered once per program,
+    not once per layer (set-up time; the compiled program is the same)."""
+    pre = f"{_LAYER}{i}."
+    return {f"{_LAYER}.{n[len(pre):]}": a for n, a in p.items()
+            if n.startswith(pre)}
 
 
 class _DictScope:
@@ -237,31 +266,51 @@ class DecodeModel:
         self.sharding_mismatches = shard_insight.verify_scope(
             _DictScope(self.params), self.mesh, self.rules)
 
+    def pool_shape(self) -> Tuple[int, int, int]:
+        """The KV pool ``[n_layer * n_blocks, block_size, n_head * 2 *
+        head_dim]``: layer ``i``'s block ``b`` is row-block ``i * n_blocks
+        + b``, and a token's row holds, head by head, that head's K then
+        its V (``2 * head_dim`` = 128 lanes a head at GPT-2's 64).
+
+        Why this shape: the TPU runtime stores an array in the most
+        compact tiled layout FOR ITS SHAPE, and a program whose gather
+        and scatter need another one opens and closes with a copy of the
+        whole array. With a row that is a whole number of 128-lane tiles
+        over a 16-token block, the layout at rest is row-major, which is
+        what gather and scatter by block id work on: the pool is donated,
+        updated in place and never copied (tests/test_tpu_aot_compile.py
+        holds the compiled programs to that; tools/serve_compile_report.py
+        prints it for any widths). Where a model's row is not such a
+        multiple the runtime pads it, and nothing here needs to know."""
+        cfg = self.cfg
+        return (cfg.n_layer * self.n_blocks, self.block_size,
+                cfg.n_head * 2 * cfg.head_dim)
+
     def _pages_sharding(self):
-        """KV pages placement: the head dim shards over the recipe's tp
-        axis — the layout the column-sharded qkv weights already imply
-        (clean_spec degrades it away when heads do not divide)."""
+        """KV pool placement, None off a mesh: the row shards over the
+        recipe's tp axis, which gives each device whole heads (K and V
+        together) — the layout the column-sharded qkv weights already
+        imply. The degrade rule judges the HEAD count, so a row that
+        divides where the heads do not stays replicated."""
+        if self.mesh is None:
+            return None
         from jax.sharding import NamedSharding, PartitionSpec
 
         from ..parallel.mesh import clean_spec
 
-        spec = PartitionSpec(None, None, None, None,
-                             self.recipe.layout.tp_axis, None)
-        shape = (self.cfg.n_layer, 2, self.n_blocks, self.block_size,
-                 self.cfg.n_head, self.cfg.head_dim)
-        return NamedSharding(self.mesh, clean_spec(spec, shape, self.mesh))
+        rows, bs, _ = self.pool_shape()
+        spec = PartitionSpec(None, None, self.recipe.layout.tp_axis)
+        return NamedSharding(self.mesh, clean_spec(
+            spec, (rows, bs, self.cfg.n_head), self.mesh))
 
     def init_pages(self):
-        """Zeroed KV pages [L, 2, NB, BS, H, hd] (block 0 = scratch)."""
-        import jax
+        """A zeroed KV pool (:meth:`pool_shape`; block 0 of every layer
+        is scratch). The decode and prefill programs consume the array
+        they are given and return its successor: hold only the newest."""
         import jax.numpy as jnp
 
-        shape = (self.cfg.n_layer, 2, self.n_blocks, self.block_size,
-                 self.cfg.n_head, self.cfg.head_dim)
-        pages = jnp.zeros(shape, self.cfg.dtype)
-        if self.mesh is not None:
-            pages = jax.device_put(pages, self._pages_sharding())
-        return pages
+        return jnp.zeros(self.pool_shape(), self.cfg.dtype,
+                         device=self._pages_sharding())
 
     # -- shared forward pieces -----------------------------------------
 
@@ -300,41 +349,50 @@ class DecodeModel:
                 return b
         return None
 
-    def _prompt_trunk(self, p, tokens, L: int, on_kv=None):
+    def _prompt_trunk(self, p, tokens, L: int, kv_dest=None):
         """The full-prompt causal transformer forward shared by prefill
         and scoring: [1, L] tokens -> final-LN hidden states [1, L, D].
-        ``on_kv(layer, k, v)`` observes each layer's K/V ([1, L, H, hd])
-        — prefill scatters them into the request's KV blocks; scoring
-        keeps nothing."""
+        ``kv_dest = (pages, blk, slot)`` is prefill's: the pool, and per
+        position the block and slot its K/V go to; every layer scatters
+        there. Scoring keeps nothing (None). Returns (hidden, pages)."""
         import jax
         import jax.numpy as jnp
 
-        cfg = self.cfg
+        cfg, NB = self.cfg, self.n_blocks
         H, hd = cfg.n_head, cfg.head_dim
         scale = 1.0 / math.sqrt(hd)
+
+        @jax.jit  # one trace for all layers: see _layer_params
+        def layer(lp, i, x, causal, kv_dest):
+            ln = _LAYER
+            h = self._ln_p(lp, x, f"{ln}.ln1")
+            q = self._linear(lp, h, f"{ln}.attn.q").reshape(1, L, H, hd)
+            k = self._linear(lp, h, f"{ln}.attn.k").reshape(1, L, H, hd)
+            v = self._linear(lp, h, f"{ln}.attn.v").reshape(1, L, H, hd)
+            if kv_dest is not None:
+                pages, blk, slot = kv_dest
+                with jax.named_scope("attn/kv_write"):
+                    pages = pages.at[i * NB + blk, slot].set(
+                        _kv_rows(k[0], v[0]))
+                kv_dest = (pages, blk, slot)
+            with jax.named_scope("attn/scores"):
+                s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+                s = jnp.where(causal[None, None], s, _NEG)
+                a = jax.nn.softmax(s, axis=-1)
+                o = jnp.einsum("bhqk,bkhd->bqhd", a, v).reshape(1, L, -1)
+            x = x + self._linear(lp, o, f"{ln}.attn.proj")
+            with jax.named_scope("mlp"):
+                x = x + self._mlp(lp, self._ln_p(lp, x, f"{ln}.ln2"), ln)
+            return x, kv_dest
+
         pos = jnp.arange(L)
         with jax.named_scope("embed"):
             x = p["gpt.wte"][tokens] + p["gpt.wpe"][pos][None]  # [1,L,D]
         causal = pos[:, None] >= pos[None, :]
         for i in range(cfg.n_layer):
-            ln = f"gpt.h{i}"
-            with jax.named_scope("layer"):
-                h = self._ln_p(p, x, f"{ln}.ln1")
-                q = self._linear(p, h, f"{ln}.attn.q").reshape(1, L, H, hd)
-                k = self._linear(p, h, f"{ln}.attn.k").reshape(1, L, H, hd)
-                v = self._linear(p, h, f"{ln}.attn.v").reshape(1, L, H, hd)
-                if on_kv is not None:
-                    with jax.named_scope("attn/kv_write"):
-                        on_kv(i, k, v)
-                with jax.named_scope("attn/scores"):
-                    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
-                    s = jnp.where(causal[None, None], s, _NEG)
-                    a = jax.nn.softmax(s, axis=-1)
-                    o = jnp.einsum("bhqk,bkhd->bqhd", a, v).reshape(1, L, -1)
-                x = x + self._linear(p, o, f"{ln}.attn.proj")
-                with jax.named_scope("mlp"):
-                    x = x + self._mlp(p, self._ln_p(p, x, f"{ln}.ln2"), ln)
-        return self._ln_p(p, x, "gpt.lnf")
+            x, kv_dest = layer(_layer_params(p, i), i, x, causal, kv_dest)
+        return (self._ln_p(p, x, "gpt.lnf"),
+                None if kv_dest is None else kv_dest[0])
 
     def _build_prefill(self, L: int):
         """The bucket-L prefill program: causal pass over [1, L], K/V
@@ -348,18 +406,12 @@ class DecodeModel:
             pos = jnp.arange(L)
             blk = jnp.where(pos < length, block_ids[pos // BS], 0)
             slot = jnp.where(pos < length, pos % BS, 0)
-            cell = [pages]
-
-            def scatter_kv(i, k, v):
-                cell[0] = cell[0].at[i, 0, blk, slot].set(k[0])
-                cell[0] = cell[0].at[i, 1, blk, slot].set(v[0])
-
-            x = self._prompt_trunk(p, tokens, L, on_kv=scatter_kv)
+            x, pages = self._prompt_trunk(p, tokens, L, (pages, blk, slot))
             with jax.named_scope("lm_head"):
                 last = jnp.take(x, length - 1, axis=1)  # [1, D]
                 logits = last @ p["gpt.wte"].T  # [1, V]
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return cell[0], nxt
+            return pages, nxt
 
         return self._compile(prefill, "prefill", L)
 
@@ -379,7 +431,7 @@ class DecodeModel:
         from ..ops.pallas.fused_lmhead_ce import lmhead_ce
 
         def score(p, tokens, length):
-            x = self._prompt_trunk(p, tokens, L)
+            x, _ = self._prompt_trunk(p, tokens, L)
             # positions 0..L-2 predict tokens 1..L-1; padded tail masked
             nll = lmhead_ce(x[0, :L - 1], p["gpt.wte"], tokens[0, 1:])
             valid = jnp.arange(L - 1) < (length - 1)
@@ -421,11 +473,43 @@ class DecodeModel:
         import jax
         import jax.numpy as jnp
 
-        cfg, BS = self.cfg, self.block_size
+        cfg, BS, NB = self.cfg, self.block_size, self.n_blocks
         B, H, hd = self.max_batch, cfg.n_head, cfg.head_dim
         S = self.gather_len
+        T = math.gcd(S, _SUBLANES)
         scale = 1.0 / math.sqrt(hd)
         barange = jnp.arange(B)
+
+        @jax.jit  # one trace for all layers: see _layer_params
+        def layer(lp, i, x, pages, block_tables, blk, slot, valid):
+            ln = _LAYER
+            h = self._ln_p(lp, x, f"{ln}.ln1")
+            q = self._linear(lp, h, f"{ln}.attn.q").reshape(B, H, hd)
+            k = self._linear(lp, h, f"{ln}.attn.k").reshape(B, H, hd)
+            v = self._linear(lp, h, f"{ln}.attn.v").reshape(B, H, hd)
+            # the layer is part of the block index: no slice of the pool
+            # is ever materialised
+            with jax.named_scope("attn/kv_write"):
+                pages = pages.at[i * NB + blk, slot].set(_kv_rows(k, v))
+            with jax.named_scope("attn/kv_gather"):
+                # [B, MAXB, BS, H*2*hd] seen as [B, S/T, H, T, 2*hd]: T
+                # tokens of one head are one (T, 128) tile of the gathered
+                # rows as they lie in HBM, so this view moves nothing. (A
+                # [B, S, H, 2*hd] view wants H on the sublanes and relays
+                # every layer's context out: a third of the tick.)
+                ctx = pages[i * NB + block_tables].reshape(
+                    B, S // T, T, H, 2 * hd).transpose(0, 1, 3, 2, 4)
+                kk, vv = ctx[..., :hd], ctx[..., hd:]
+            with jax.named_scope("attn/scores"):
+                s = jnp.einsum("bhd,bjhtd->bhjt", q, kk).reshape(
+                    B, H, S) * scale
+                s = jnp.where(valid[:, None, :], s, _NEG)
+                a = jax.nn.softmax(s, axis=-1).reshape(B, H, S // T, T)
+                o = jnp.einsum("bhjt,bjhtd->bhd", a, vv).reshape(B, -1)
+            x = x + self._linear(lp, o, f"{ln}.attn.proj")
+            with jax.named_scope("mlp"):
+                x = x + self._mlp(lp, self._ln_p(lp, x, f"{ln}.ln2"), ln)
+            return x, pages
 
         def decode_tick(p, pages, block_tables, context_lens, tokens):
             pos = context_lens  # [B]: the new token's position
@@ -435,27 +519,8 @@ class DecodeModel:
             slot = pos % BS
             valid = (jnp.arange(S)[None, :] <= pos[:, None])  # [B, S]
             for i in range(cfg.n_layer):
-                ln = f"gpt.h{i}"
-                with jax.named_scope("layer"):
-                    h = self._ln_p(p, x, f"{ln}.ln1")
-                    q = self._linear(p, h, f"{ln}.attn.q").reshape(B, H, hd)
-                    k = self._linear(p, h, f"{ln}.attn.k").reshape(B, H, hd)
-                    v = self._linear(p, h, f"{ln}.attn.v").reshape(B, H, hd)
-                    with jax.named_scope("attn/kv_write"):
-                        pages = pages.at[i, 0, blk, slot].set(k)
-                        pages = pages.at[i, 1, blk, slot].set(v)
-                    with jax.named_scope("attn/kv_gather"):
-                        # [B, MAXB, BS, H, hd] -> [B, S, H, hd]
-                        kk = pages[i, 0][block_tables].reshape(B, S, H, hd)
-                        vv = pages[i, 1][block_tables].reshape(B, S, H, hd)
-                    with jax.named_scope("attn/scores"):
-                        s = jnp.einsum("bhd,bshd->bhs", q, kk) * scale
-                        s = jnp.where(valid[:, None, :], s, _NEG)
-                        a = jax.nn.softmax(s, axis=-1)
-                        o = jnp.einsum("bhs,bshd->bhd", a, vv).reshape(B, -1)
-                    x = x + self._linear(p, o, f"{ln}.attn.proj")
-                    with jax.named_scope("mlp"):
-                        x = x + self._mlp(p, self._ln_p(p, x, f"{ln}.ln2"), ln)
+                x, pages = layer(_layer_params(p, i), i, x, pages,
+                                 block_tables, blk, slot, valid)
             with jax.named_scope("lm_head"):
                 x = self._ln_p(p, x, "gpt.lnf")
                 logits = x @ p["gpt.wte"].T  # [B, V]
@@ -470,32 +535,9 @@ class DecodeModel:
         """jit + xla_insight AOT capture: the serving program's
         cost/memory/comms plan becomes a first-class artifact (the same
         capture path the executor uses for training programs)."""
-        import jax
-        import jax.numpy as jnp
-
         from ..framework import xla_insight
 
-        # the module's name in a profile and in the HLO: jit_decode_tick,
-        # jit_prefill_<bucket>, jit_score_<bucket>. The same in every
-        # process: it is part of the persistent compile cache's key
-        fn.__name__ = fn.__qualname__ = self.program_name(kind, bucket)
-        jit_fn = self._jit_for(fn, kind)
-        # example args at the real shapes (compile == serve shapes)
-        pages = self.init_pages()
-        if kind == "decode":
-            B = self.max_batch
-            args = (self.params, pages,
-                    jnp.zeros((B, self.max_blocks_per_req), jnp.int32),
-                    jnp.zeros((B,), jnp.int32),
-                    jnp.zeros((B,), jnp.int32))
-        elif kind == "score":
-            args = (self.params, jnp.zeros((1, bucket), jnp.int32),
-                    jnp.int32(1))
-        else:
-            args = (self.params, pages,
-                    jnp.zeros((1, bucket), jnp.int32),
-                    jnp.int32(1),
-                    jnp.zeros((self.max_blocks_per_req,), jnp.int32))
+        jit_fn, args = self._program(fn, kind, bucket)
         key = xla_insight.key_hash((
             "serve", kind, bucket, self.max_batch, self.n_blocks,
             self.block_size, self.cfg.n_layer, self.cfg.n_head,
@@ -515,6 +557,35 @@ class DecodeModel:
             return xla_insight.aot_call(executable, jit_fn)
         return jit_fn
 
+    def _program(self, fn, kind: str, bucket: Optional[int] = None):
+        """``fn`` under its name and jit wrapper, and the arguments it
+        compiles at: the real parameters and abstract stand-ins for the
+        rest (compile == serve shapes and shardings; no second pool is
+        allocated to describe the first)."""
+        import jax
+        import jax.numpy as jnp
+
+        # the module's name in a profile and in the HLO: jit_decode_tick,
+        # jit_prefill_<bucket>, jit_score_<bucket>. The same in every
+        # process: it is part of the persistent compile cache's key
+        fn.__name__ = fn.__qualname__ = self.program_name(kind, bucket)
+
+        def i32(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+        pages = jax.ShapeDtypeStruct(self.pool_shape(), self.cfg.dtype,
+                                     sharding=self._pages_sharding())
+        if kind == "decode":
+            B = self.max_batch
+            args = (self.params, pages, i32(B, self.max_blocks_per_req),
+                    i32(B), i32(B))
+        elif kind == "score":
+            args = (self.params, i32(1, bucket), i32())
+        else:
+            args = (self.params, pages, i32(1, bucket), i32(),
+                    i32(self.max_blocks_per_req))
+        return self._jit_for(fn, kind), args
+
     @staticmethod
     def program_name(kind: str, bucket: Optional[int] = None) -> str:
         """What a serving program is called wherever it shows: the trace's
@@ -523,11 +594,15 @@ class DecodeModel:
         return base if bucket is None else f"{base}_{bucket}"
 
     def _jit_for(self, fn, kind: str):
+        """The jit wrapper of a serving program. Prefill and decode
+        DONATE the pool (argument 1): the returned pool is the same
+        buffer updated in place, and the array passed in is deleted."""
         import jax
         from jax.sharding import NamedSharding, PartitionSpec
 
+        donate = () if kind == "score" else (1,)
         if self.mesh is None:
-            return jax.jit(fn)
+            return jax.jit(fn, donate_argnums=donate)
         repl = NamedSharding(self.mesh, PartitionSpec())
         param_sh = {
             name: self.recipe.param_sharding(self.mesh, name, arr,
@@ -542,7 +617,8 @@ class DecodeModel:
         n_host = 3  # (tables, lens, tokens) or (tokens, length, block_ids)
         in_sh = (param_sh, pages_sh) + (repl,) * n_host
         return jax.jit(fn, in_shardings=in_sh,
-                       out_shardings=(pages_sh, repl))
+                       out_shardings=(pages_sh, repl),
+                       donate_argnums=donate)
 
     # -- public API (host-array in, host-scalar-friendly out) ----------
 

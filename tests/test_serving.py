@@ -299,6 +299,99 @@ def test_continuous_batching_bit_match(tiny_model):
         assert toks[len(p):] == got
 
 
+def _greedy_reference(model, prompt, n):
+    """n greedy tokens from the non-paged full-context forward."""
+    toks = list(prompt)
+    for _ in range(n):
+        toks.append(int(model.full_logits(np.asarray(toks))[0, -1].argmax()))
+    return toks[len(prompt):]
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_program_consumes_the_pool_it_is_given(tiny_model, program):
+    """The pool is donated: the array that went in is deleted, the one
+    that came out is the pool (same shape and placement)."""
+    pages = tiny_model.init_pages()
+    assert pages.shape == tiny_model.pool_shape() == (2 * 16, 8, 2 * 2 * 16)
+    if program == "prefill":
+        out, _ = tiny_model.prefill(pages, np.asarray([3, 4, 5]), 3, [1])
+    else:
+        tables = np.zeros((4, tiny_model.max_blocks_per_req), np.int32)
+        out, _, _ = tiny_model.decode(pages, tables, np.zeros(4, np.int32),
+                                      np.zeros(4, np.int32))
+    assert pages.is_deleted() and not out.is_deleted()
+    assert out.shape == pages.shape and out.sharding == pages.sharding
+
+
+def test_engine_holds_only_the_newest_pool(tiny_model):
+    eng = _engine(tiny_model)
+    seen = [eng.pages]
+    h = eng.submit([3, 4, 5], max_new_tokens=4)
+    while eng.step():
+        seen.append(eng.pages)
+    assert h.result(timeout=5) == _greedy_reference(tiny_model, [3, 4, 5], 4)
+    assert len(seen) >= 3 and seen[-1] is eng.pages
+    assert all(p.is_deleted() for p in seen[:-1])
+    assert not eng.pages.is_deleted()
+
+
+def _fail_once_after_dispatch(real):
+    """`real`, except that its first call raises AFTER the program ran:
+    the donated pool is already consumed, as when a device error
+    surfaces at the read-back."""
+    calls = []
+
+    def flaky(*args):
+        out = real(*args)
+        calls.append(1)
+        if len(calls) == 1:
+            raise RuntimeError("injected: failed after dispatch")
+        return out
+
+    return flaky
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_engine_survives_a_program_failing_after_dispatch(
+        tiny_model, monkeypatch, program):
+    """A program that dies holding the donated pool leaves the engine
+    serving: a fresh pool, the implicated requests failed with a reason,
+    the innocent ones re-prefilled as after an eviction, the allocator
+    consistent, and the next answer bit-equal to the reference."""
+    tiny_model.warm()
+    eng = _engine(tiny_model)
+    a = eng.submit([3, 4, 5, 6], max_new_tokens=5)
+    eng.step()  # a: prefilled and one tick decoded
+    lost = eng.pages
+    if program == "decode":
+        monkeypatch.setattr(tiny_model, "_decode_fn",
+                            _fail_once_after_dispatch(tiny_model._decode_fn))
+        failed, reason = a, "decode program failed: RuntimeError: injected"
+    else:
+        monkeypatch.setitem(tiny_model._prefill_fns, 16,
+                            _fail_once_after_dispatch(
+                                tiny_model._prefill_fns[16]))
+        failed = eng.submit([9, 8, 7], max_new_tokens=3)
+        reason = "RuntimeError: injected"
+    eng.run_until_idle()
+    assert lost.is_deleted() and not eng.pages.is_deleted()
+    assert failed.done and reason in failed._req.error
+    with pytest.raises(Exception, match="injected"):
+        failed.result(timeout=1)
+    if program == "prefill":
+        # the running request lost its context with the pool: evicted,
+        # re-prefilled, and still the reference's tokens
+        assert a._req.evictions == 1
+        assert a.result(timeout=5) == _greedy_reference(
+            tiny_model, [3, 4, 5, 6], 5)
+    assert eng.allocator.used() == 0 and not eng.active()
+    assert eng.queue.depth() == 0
+    nxt = eng.submit([11, 12, 13], max_new_tokens=4)
+    eng.run_until_idle()
+    assert nxt.result(timeout=5) == _greedy_reference(
+        tiny_model, [11, 12, 13], 4)
+
+
 def test_kv_eviction_under_pressure(tiny_model):
     """Under KV exhaustion a tight-SLO arrival preempts the loosest
     running request: the victim's blocks free and are REUSED by the
@@ -360,6 +453,26 @@ def test_decode_tp_sharding_from_recipes(tiny_model):
     h1 = eng1.submit([5, 9, 3, 44, 17], max_new_tokens=5)
     eng1.run_until_idle()
     assert tp_tokens == h1.result(timeout=5)
+
+
+@pytest.mark.parametrize("n_head,spec", [(2, (None, None, "tp")),
+                                         (3, (None, None, None))])
+def test_pool_shards_whole_heads_or_not_at_all(n_head, spec):
+    """The pool's row shards over tp only where each device then holds
+    whole heads (K and V together): 3 heads over tp=2 stay replicated
+    although the 96-lane row itself would divide."""
+    from paddle_tpu.parallel.recipes import resolve_recipe
+
+    cfg = serving.GPTConfig(vocab_size=64, n_layer=1, n_head=n_head,
+                            d_model=16 * n_head, max_seq_len=32)
+    m = serving.DecodeModel(cfg, max_batch=2, n_blocks=4, block_size=8,
+                            prefill_buckets=[16],
+                            recipe=resolve_recipe("tp", 2), seed=1)
+    pages = m.init_pages()
+    assert pages.shape == (4, 8, n_head * 2 * 16)
+    assert tuple(pages.sharding.spec) == spec
+    share = pages.addressable_shards[0].data.nbytes / pages.nbytes
+    assert share == (0.5 if spec[2] else 1.0)
 
 
 def test_never_fitting_request_fails_fast(tiny_model):

@@ -8,6 +8,7 @@ so this is what keeps a Mosaic refusal (unsupported op, layout, VMEM
 overflow) visible to tier-1. Nothing here executes.
 """
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -20,8 +21,8 @@ from paddle_tpu.ops.pallas.fused_lmhead_ce import lmhead_ce
 
 
 @pytest.fixture(scope="module")
-def tpu_arg():
-    """(shape, dtype) -> ShapeDtypeStruct placed on an abstract v5e device."""
+def tpu_device():
+    """An abstract v5e device: described, not attached."""
     from jax.experimental import topologies
 
     try:
@@ -29,7 +30,13 @@ def tpu_arg():
                                             topology_name="v5e:2x2")
     except Exception as e:  # no libtpu / no compile-only support here
         pytest.skip(f"no compile-only TPU target: {type(e).__name__}: {e}")
-    sharding = SingleDeviceSharding(topo.devices[0])
+    return topo.devices[0]
+
+
+@pytest.fixture(scope="module")
+def tpu_arg(tpu_device):
+    """(shape, dtype) -> ShapeDtypeStruct placed on the abstract device."""
+    sharding = SingleDeviceSharding(tpu_device)
     return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
                                                      sharding=sharding)
 
@@ -148,35 +155,40 @@ def test_fused_adam_kernel_carries_its_name_at_gpt2s_widths(tpu_arg):
 
 
 @pytest.fixture(scope="module")
-def serving_programs(tpu_arg):
-    """The decode and one prefill program of a small DecodeModel, compiled
-    for the described chip from the functions the model builds."""
+def serving_programs(tpu_device):
+    """The decode and one prefill program of a DecodeModel at the serving
+    cells' widths (GPT-2 XL's heads, slots and blocks; 2 layers and a
+    small vocabulary), compiled for the described chip through the model's
+    own jit wrapper and pool description. ``.text`` and ``.facts`` by
+    program name (tools/serve_compile_report.py reads the facts)."""
+    import os
+    import sys
+    import types
+
     from paddle_tpu import serving
 
-    cfg = serving.GPTConfig(vocab_size=512, n_layer=2, n_head=4, d_model=256, max_seq_len=128,
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools"))
+    import serve_compile_report as report
+
+    cfg = serving.GPTConfig(vocab_size=1024, n_layer=2, n_head=25, d_model=1600, max_seq_len=1024,
                             dtype="bfloat16")
-    dm = serving.DecodeModel(cfg, max_batch=4, n_blocks=24, block_size=16, prefill_buckets=[32],
+    dm = serving.DecodeModel(cfg, max_batch=12, n_blocks=432, block_size=16, prefill_buckets=[256],
                              seed=0)
-    built = {}
-    dm._compile = lambda fn, kind, bucket=None: built.setdefault(
-        dm.program_name(kind, bucket), fn)  # keep the function, compile nothing on the CPU
-    dm._build_decode()
-    dm._build_prefill(32)
-    p = {n: tpu_arg(a.shape, a.dtype) for n, a in dm.params.items()}
-    pages = tpu_arg((cfg.n_layer, 2, dm.n_blocks, dm.block_size, cfg.n_head, cfg.head_dim), jnp.bfloat16)
-    i32 = lambda *shape: tpu_arg(shape, jnp.int32)  # noqa: E731
-    args = {"decode_tick": (p, pages, i32(4, dm.max_blocks_per_req), i32(4), i32(4)),
-            "prefill_32": (p, pages, i32(1, 32), i32(), i32(dm.max_blocks_per_req))}
-    out = {}
-    for name, fn in built.items():
-        fn.__name__ = fn.__qualname__ = name  # as DecodeModel._compile names it
-        out[name] = jax.jit(fn).lower(*args[name]).compile().as_text()
+    out = types.SimpleNamespace(dm=dm, text={}, facts={})
+    for name, (jit_fn, args) in report.serving_programs(dm).items():
+        compiled = report.compile_on(jit_fn, args, tpu_device)
+        out.text[name] = compiled.as_text()
+        out.facts[name] = report.describe(compiled, dm.pool_shape())
     return out
 
 
-@pytest.mark.parametrize("name", ["decode_tick", "prefill_32"])
+_SERVING_PROGRAMS = ["decode_tick", "prefill_256"]
+
+
+@pytest.mark.parametrize("name", _SERVING_PROGRAMS)
 def test_serving_programs_carry_their_names(serving_programs, name):
-    text = serving_programs[name]
+    text = serving_programs.text[name]
     assert re.search(rf"HloModule jit_{name}\b", text)
     from benchmark import manifest
 
@@ -185,13 +197,63 @@ def test_serving_programs_carry_their_names(serving_programs, name):
 
 
 @pytest.mark.parametrize("name,scopes", [
-    ("decode_tick", ("embed", "layer/attn/kv_write", "layer/attn/kv_gather", "layer/attn/scores",
-                     "layer/mlp", "lm_head")),
-    ("prefill_32", ("embed", "layer/attn/kv_write", "layer/attn/scores", "layer/mlp", "lm_head"))])
+    ("decode_tick", ("embed", "jit(layer)/attn/kv_write", "jit(layer)/attn/kv_gather",
+                     "jit(layer)/attn/scores", "jit(layer)/mlp", "lm_head")),
+    ("prefill_256", ("embed", "jit(layer)/attn/kv_write", "jit(layer)/attn/scores", "jit(layer)/mlp",
+                     "lm_head"))])
 def test_serving_programs_carry_their_scopes_in_op_name(serving_programs, name, scopes):
-    ops = set(re.findall(r'op_name="([^"]*)"', serving_programs[name]))
+    """The layer body is an inner jit named ``layer`` (traced once for all
+    layers), so its scopes read ``jit(<program>)/jit(layer)/attn/...``."""
+    ops = set(re.findall(r'op_name="([^"]*)"', serving_programs.text[name]))
     for scope in scopes:
         assert any(f"jit({name})/{scope}/" in o for o in ops), (scope, sorted(ops)[:20])
+
+
+# The KV pool stays where it is (PERF.md, PR 25): XLA:TPU stores an array in
+# the most compact tiled layout for its SHAPE, and a program that gathers
+# and scatters in another layout copies the whole pool in and out.
+
+
+@pytest.mark.parametrize("name", _SERVING_PROGRAMS)
+def test_serving_program_updates_the_pool_in_place(serving_programs, name):
+    pool = serving_programs.facts[name]["pool"]
+    assert pool["parameter"] is not None and pool["aliased_to_output"], pool
+
+
+@pytest.mark.parametrize("name", _SERVING_PROGRAMS)
+def test_pool_rests_in_the_layout_its_scatter_works_on(serving_programs, name):
+    pool = serving_programs.facts[name]["pool"]
+    assert pool["layouts_in_program"] == [pool["layout"]], pool
+    assert pool["layout"].startswith("2,1,0:T(8,128)"), pool  # row-major, 128-lane rows
+
+
+@pytest.mark.parametrize("name", _SERVING_PROGRAMS)
+def test_serving_program_copies_neither_pool_nor_gathered_context(serving_programs, name):
+    dm = serving_programs.dm
+    limit = min(math.prod(dm.pool_shape()), dm.max_batch * dm.gather_len * dm.cfg.d_model)
+    big = [c for c in serving_programs.facts[name]["top_level_copies"] if c["elements"] >= limit]
+    assert not big, big
+
+
+@pytest.mark.parametrize("name", _SERVING_PROGRAMS)
+def test_serving_program_holds_no_second_pool(serving_programs, name):
+    """Temporaries stay under the pool plus the one gathered context a
+    layer works on (2 layers here: the pool alone is smaller than that)."""
+    dm = serving_programs.dm
+    pool_bytes = 2 * math.prod(dm.pool_shape())
+    context_bytes = 2 * dm.max_batch * dm.gather_len * dm.pool_shape()[-1]
+    temp = serving_programs.facts[name]["memory"]["temp_size_in_bytes"]
+    assert temp < pool_bytes + context_bytes, (temp, pool_bytes, context_bytes)
+
+
+@pytest.mark.parametrize("name", _SERVING_PROGRAMS)
+def test_pool_at_rest_is_not_padded(serving_programs, name):
+    """The pool is the only aliased argument, so the aliased bytes are the
+    pool as stored: L x NB x BS tokens of K and V, d_model wide, bf16."""
+    dm, facts = serving_programs.dm, serving_programs.facts[name]
+    assert facts["aliased_parameters"] == [facts["pool"]["parameter"]]
+    need = dm.cfg.n_layer * dm.n_blocks * dm.block_size * 2 * dm.cfg.d_model * 2
+    assert abs(facts["memory"]["alias_size_in_bytes"] - need) <= 0.01 * need
 
 
 def test_executor_step_carries_paddle_ops_in_op_name_and_a_role_name(tpu_arg):

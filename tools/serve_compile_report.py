@@ -1,0 +1,163 @@
+"""Compile-only report of the serving programs for a described TPU.
+
+XLA:TPU ships in libtpu and compiles for a chip that is described, not
+attached, so the questions that decide a serving change's memory traffic
+are answered here in seconds and at no chip time: which layout the KV
+pool has at the program's edge, whether the output aliases it, which
+whole-array ``copy`` (and unfused ``reshape`` / ``transpose``: a relayout)
+instructions the compiler put into the program, and ``memory_analysis()``. Sizes and instruction names only: nothing runs, so
+no time comes out of this tool.
+
+The programs are the ones ``DecodeModel`` serves with: its own functions
+under its own jit wrapper (donation, shardings) at its own pool
+description, with every argument re-placed on the described device.
+
+Usage (JAX_PLATFORMS=cpu; 2 layers of GPT-2 XL's widths in ~15 s, the
+full 48 in a few minutes):
+  python tools/serve_compile_report.py                  # the serving cells' widths
+  python tools/serve_compile_report.py --n-layer 48 --vocab 50304
+  python tools/serve_compile_report.py --n-head 12 --d-model 768 \\
+      --max-batch 8 --n-blocks 256 --buckets 128,512    # chip_smoke's GPT-2 small
+  python tools/serve_compile_report.py --hlo-dir /root/scratch/hlo   # keep the HLO text
+"""
+from __future__ import annotations
+
+import argparse
+import collections
+import json
+import math
+import os
+import re
+import sys
+from typing import Any, Dict, List, Tuple
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+# `%name = dtype[dims]{layout} opcode(` of one HLO instruction
+_INSTR = re.compile(
+    r"^\s*(?:ROOT )?%(?P<name>[\w.\-]+) = (?P<dtype>\w+)\[(?P<dims>[\d,]*)\]"
+    r"(?:\{(?P<layout>[^}]*)\})? (?P<op>[\w\-]+)\((?P<rest>.*)$")
+
+
+def described_device(topology: str = "v5e:2x2"):
+    """Device 0 of a TPU topology that is described, not attached."""
+    from jax.experimental import topologies
+
+    return topologies.get_topology_desc(
+        platform="tpu", topology_name=topology).devices[0]
+
+
+def serving_programs(dm) -> Dict[str, Tuple[Any, tuple]]:
+    """``{program name: (jit wrapper, example arguments)}`` of a
+    DecodeModel's decode tick and prefill buckets, as the model itself
+    would compile them (``DecodeModel._program``); nothing is compiled."""
+    built: Dict[str, Tuple[Any, tuple]] = {}
+    dm._compile = lambda fn, kind, bucket=None: built.setdefault(
+        dm.program_name(kind, bucket), dm._program(fn, kind, bucket))
+    dm._build_decode()
+    for b in dm.prefill_buckets:
+        dm._build_prefill(b)
+    del dm._compile  # the class's own again
+    return built
+
+
+def compile_on(jit_fn, args, device):
+    """Compile ``jit_fn`` at ``args`` with every leaf placed on ``device``."""
+    import jax
+    from jax.sharding import SingleDeviceSharding
+
+    sh = SingleDeviceSharding(device)
+    placed = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), args)
+    return jit_fn.lower(*placed).compile()
+
+
+def entry_instructions(hlo_text: str) -> List[Dict[str, Any]]:
+    """The instructions of the ENTRY computation, in order."""
+    out, inside = [], False
+    for line in hlo_text.splitlines():
+        if line.startswith("ENTRY "):
+            inside = True
+            continue
+        if inside and line.startswith("}"):
+            break
+        m = _INSTR.match(line) if inside else None
+        if m:
+            d = m.groupdict()
+            d["dims"] = tuple(int(x) for x in d["dims"].split(",") if x)
+            d["elements"] = math.prod(d["dims"])
+            out.append(d)
+    return out
+
+
+def describe(compiled, pool_shape: Tuple[int, ...]) -> Dict[str, Any]:
+    """What the compiled program does with a pool of ``pool_shape``."""
+    text = compiled.as_text()
+    instrs = entry_instructions(text)
+    pool = tuple(int(d) for d in pool_shape)
+    param = next((i for i in instrs if i["op"] == "parameter" and i["dims"] == pool), None)
+    param_no = int(re.match(r"(\d+)", param["rest"]).group(1)) if param else None
+    # the module line's `input_output_alias={ {0}: (772, {}, may-alias) }`
+    aliased = sorted(int(n) for n in re.findall(r"\((\d+), \{\}, \w+-alias\)",
+                                                text.split("\n", 1)[0]))
+    # a reshape or transpose that is free is a `bitcast` by now: one still
+    # standing at the top level moves its whole operand, like a copy
+    copies = collections.Counter(
+        (i["op"], f"{i['dtype']}[{','.join(map(str, i['dims']))}]", i["elements"])
+        for i in instrs if i["op"] in ("copy", "reshape", "transpose"))
+    mem = compiled.memory_analysis()
+    return {
+        "module": re.match(r"HloModule (\S+?),", text).group(1),
+        "pool": {"shape": list(pool), "parameter": param_no,
+                 "layout": param["layout"] if param else None,
+                 "aliased_to_output": param_no in aliased,
+                 # every top-level result of the pool's shape (the scatters
+                 # and what carries them) and the layout it is in
+                 "layouts_in_program": sorted({i["layout"] or "" for i in instrs
+                                               if i["dims"] == pool and i["op"] != "parameter"})},
+        "aliased_parameters": aliased,
+        "top_level_copies": [{"op": op, "shape": shape, "count": n, "elements": elements}
+                             for (op, shape, elements), n in sorted(copies.items())],
+        "memory": {k: int(getattr(mem, k)) for k in
+                   ("argument_size_in_bytes", "output_size_in_bytes", "alias_size_in_bytes",
+                    "temp_size_in_bytes", "generated_code_size_in_bytes")},
+        "instructions": len(instrs),
+    }
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--n-layer", type=int, default=2)
+    ap.add_argument("--n-head", type=int, default=25)
+    ap.add_argument("--d-model", type=int, default=1600)
+    ap.add_argument("--vocab", type=int, default=1024)
+    ap.add_argument("--max-seq-len", type=int, default=1024)
+    ap.add_argument("--max-batch", type=int, default=12)
+    ap.add_argument("--n-blocks", type=int, default=432)
+    ap.add_argument("--block-size", type=int, default=16)
+    ap.add_argument("--buckets", default="256")
+    ap.add_argument("--topology", default="v5e:2x2")
+    ap.add_argument("--hlo-dir", default=None, help="write each program's HLO text here")
+    a = ap.parse_args(argv)
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+    from paddle_tpu import serving
+
+    cfg = serving.GPTConfig(vocab_size=a.vocab, n_layer=a.n_layer, n_head=a.n_head,
+                            d_model=a.d_model, max_seq_len=a.max_seq_len, dtype="bfloat16")
+    dm = serving.DecodeModel(cfg, max_batch=a.max_batch, n_blocks=a.n_blocks,
+                             block_size=a.block_size,
+                             prefill_buckets=[int(b) for b in a.buckets.split(",")])
+    device = described_device(a.topology)
+    for name, (jit_fn, args) in serving_programs(dm).items():
+        compiled = compile_on(jit_fn, args, device)
+        if a.hlo_dir:
+            os.makedirs(a.hlo_dir, exist_ok=True)
+            with open(os.path.join(a.hlo_dir, f"{name}.hlo"), "w") as f:
+                f.write(compiled.as_text())
+        print(json.dumps({name: describe(compiled, dm.pool_shape())}, indent=1))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
