@@ -58,6 +58,21 @@ class GPTConfig:
     # logits + softmax_with_cross_entropy). Booleans keep their
     # historical meaning: True = chunked, False = off.
     fused_lm_head: Optional[object] = None
+    # -- the block's description (defaults: GPT-2). The serving plane's
+    # one layer body (serving/model.py) reads these; the training graph
+    # below builds the GPT-2 block only and refuses anything else.
+    # norm_eps and rope_theta mirror the published keys a configuration
+    # file carries (layer_norm_epsilon / rms_norm_eps, rope_theta): GPT-2
+    # and OLMoE publish the same two values, the next ones do not.
+    norm: str = "layernorm"       # or "rmsnorm": no mean, no bias
+    norm_eps: float = 1e-5
+    position: str = "learned"     # or "rope": rotate-half on q and k
+    rope_theta: float = 10000.0
+    qk_norm: bool = False         # the norm over q and k, all heads' lanes
+    bias: bool = True             # biases on the projections
+    mlp: str = "gelu"             # or "moe": router + SwiGLU experts of d_ff
+    n_experts: int = 0
+    experts_per_token: int = 0            # their softmax weights as they are
 
     @property
     def head_dim(self) -> int:
@@ -66,6 +81,12 @@ class GPTConfig:
     @property
     def ffn_dim(self) -> int:
         return self.d_ff or 4 * self.d_model
+
+    def block(self) -> tuple:
+        """The block's description as one hashable value."""
+        return (self.norm, self.norm_eps, self.position, self.rope_theta,
+                self.qk_norm, self.bias, self.mlp, self.n_experts,
+                self.experts_per_token, self.tie_embeddings)
 
 
 def _param(helper: LayerHelper, name: str, shape, dtype, std: float = 0.02, zeros=False):
@@ -153,6 +174,11 @@ def build_forward(cfg: GPTConfig, tokens, batch: int, seq: int,
     append_backward_with_checkpoints)."""
     from ..framework import device_guard
 
+    if cfg.block()[:-1] != GPTConfig().block()[:-1]:
+        raise NotImplementedError(
+            f"the training graph builds the GPT-2 block only; this "
+            f"configuration describes {cfg.block()[:-1]} (served by "
+            f"serving/model.py, not yet trained)")
     helper = LayerHelper("gpt")
     d = cfg.d_model
     pp = max(1, cfg.pp_stages)

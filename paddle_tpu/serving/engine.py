@@ -931,6 +931,8 @@ class ServingEngine:
             _ledger.add("decode_compute", window)
             # the engine-side leg of the span reconciliation: slot-seconds
             _ledger.add_slot_seconds(window * len(ready))
+            if self.model.last_routing is not None:
+                _ledger.note_routing(*self.model.last_routing)
             for req in ready:
                 req.out_tokens.append(int(nxt[req.slot]))
                 req.context_len += 1

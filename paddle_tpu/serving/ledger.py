@@ -30,7 +30,11 @@ engine's spans show in a trace: ``decode_ticks`` (ticks that dispatched
 a decode program; ``ticks`` also counts prefill-only and queue-wait
 ticks), ``tick_wall_s`` (the ``engine/decode_tick`` spans, summed),
 ``tick_sync_s`` (the ``tick/device_sync`` spans: the host waiting on
-the device, so wall - sync is host work a tick), and ``itl_gaps_s``, a
+the device, so wall - sync is host work a tick), for a model with
+experts the routing its decode ticks read back, summed over ticks and
+layers (``moe_assignments``: (live slot, expert) pairs, all computed;
+``moe_experts_hit``: distinct experts with at least one; ``moe_max_load``:
+the busiest expert's pairs), and ``itl_gaps_s``, a
 bounded sample of the gaps between a request's consecutive tokens, with
 ``itl_gaps_seen``, how many gaps it was drawn from (more than the sample
 holds: the oldest were dropped, and a percentile says so).
@@ -131,6 +135,9 @@ _EMA_ALPHA = 0.1
 # would put in one bin. With its count (itl_gaps_seen) left out of the
 # journal (flush) and of merges: a sample of one process's run
 _ITL_SAMPLE = 8192
+
+# routing of a model with experts: serving/model.py::DecodeModel.decode
+MOE_COUNTERS = ("moe_assignments", "moe_experts_hit", "moe_max_load")
 
 # fixed log-spaced bounds so per-replica histograms merge exactly across
 # restarts and ranks (1ms .. 120s covers CPU-sim ticks through pod SLOs)
@@ -354,6 +361,7 @@ class ServingLedger:
             self.decode_ticks = 0
             self.tick_wall_s = 0.0
             self.tick_sync_s = 0.0
+            self.moe = dict.fromkeys(MOE_COUNTERS, 0)
             self.itl_gaps: "collections.deque[float]" = collections.deque(
                 maxlen=_ITL_SAMPLE)
             self.itl_gaps_seen = 0
@@ -395,6 +403,14 @@ class ServingLedger:
             self.decode_ticks += 1
             self.tick_wall_s += float(wall_seconds)
             self.tick_sync_s += float(sync_seconds)
+
+    def note_routing(self, assignments: int, experts_hit: int,
+                     max_load: int) -> None:
+        """One decode tick's routing counts, each summed over layers."""
+        with self._lock:
+            for k, v in zip(MOE_COUNTERS, (assignments, experts_hit,
+                                           max_load)):
+                self.moe[k] += int(v)
 
     def note_token_gaps(self, gaps: Sequence[float]) -> None:
         """A retired request's inter-token gaps (seconds), into the
@@ -570,6 +586,7 @@ class ServingLedger:
             decode_ticks = self.decode_ticks
             tick_wall = self.tick_wall_s
             tick_sync = self.tick_sync_s
+            moe = dict(self.moe)
             doc["itl_gaps_s"] = list(self.itl_gaps)
             doc["itl_gaps_seen"] = self.itl_gaps_seen
             attribution = json.loads(json.dumps(self.attribution))
@@ -593,6 +610,8 @@ class ServingLedger:
             decode_ticks += int(base.get("decode_ticks", 0))
             tick_wall += float(base.get("tick_wall_s", 0.0))
             tick_sync += float(base.get("tick_sync_s", 0.0))
+            for k in MOE_COUNTERS:
+                moe[k] += int(base.get(k, 0))
             attribution = merge_attribution(base.get("attribution"),
                                             attribution)
             doc["resumed_from_journal"] = True
@@ -620,6 +639,7 @@ class ServingLedger:
             "decode_ticks": decode_ticks,
             "tick_wall_s": tick_wall,
             "tick_sync_s": tick_sync,
+            **moe,
             "attribution": attribution,
         })
         return _finalize(doc, buckets, wall)
@@ -662,6 +682,12 @@ def note_decode_tick(wall_seconds: float, sync_seconds: float) -> None:
     if not _monitor.enabled():
         return
     _LEDGER.note_decode_tick(wall_seconds, sync_seconds)
+
+
+def note_routing(assignments: int, experts_hit: int, max_load: int) -> None:
+    if not _monitor.enabled():
+        return
+    _LEDGER.note_routing(assignments, experts_hit, max_load)
 
 
 def note_token_gaps(gaps: Sequence[float]) -> None:
@@ -943,6 +969,7 @@ def merge_ledgers(docs: List[Dict[str, Any]]) -> Dict[str, Any]:
     span_s = slot_s = 0.0
     decode_ticks = 0
     tick_wall = tick_sync = 0.0
+    moe = dict.fromkeys(MOE_COUNTERS, 0)
     ranks: List[int] = []
     roofline = None
     max_wall = 0.0
@@ -989,6 +1016,8 @@ def merge_ledgers(docs: List[Dict[str, Any]]) -> Dict[str, Any]:
         decode_ticks += int(d.get("decode_ticks", 0))
         tick_wall += float(d.get("tick_wall_s", 0.0))
         tick_sync += float(d.get("tick_sync_s", 0.0))
+        for k in MOE_COUNTERS:
+            moe[k] += int(d.get(k, 0))
         if d.get("rank") is not None:
             ranks.append(int(d["rank"]))
     # replica throughputs add over the LONGEST replica wall (concurrent
@@ -1019,6 +1048,7 @@ def merge_ledgers(docs: List[Dict[str, Any]]) -> Dict[str, Any]:
         "decode_ticks": decode_ticks,
         "tick_wall_s": tick_wall,
         "tick_sync_s": tick_sync,
+        **moe,
         "attribution": attribution,
         "traffic": traffic,
         "autoscale": autoscale,

@@ -1,8 +1,14 @@
 """Serving-side decoder LM: prefill/decode split over a paged KV cache.
 
 The serving twin of ``models/gpt.py``: the SAME parameter names
-(``gpt.h<i>.attn.q.w`` ...), the same tied-embedding lm head, expressed
-as two pure-JAX programs instead of one training ProgramDesc —
+(``gpt.h<i>.attn.q.w`` ...), expressed as two pure-JAX programs instead
+of one training ProgramDesc. WHICH block the one layer body computes is
+the configuration's description of it (``GPTConfig.norm``, ``position``,
+``qk_norm``, ``bias``, ``mlp``, ``tie_embeddings``): GPT-2's by default
+(LayerNorm, learned positions, erf GELU, biases, tied head), OLMoE's
+with RMSNorm, rotate-half RoPE, a norm over q and k, bias-free
+projections, a router + expert layer (``ops/moe.py``) and an untied
+head. Pool, donation, programs, names and insight are one path —
 
 - **prefill**: the whole (bucket-padded) prompt in one causal pass,
   writing every position's K/V into the request's cache blocks and
@@ -59,39 +65,93 @@ _NEG = -1e30  # finite mask value: garbage behind it stays non-NaN
 _SUBLANES = 8  # rows of one (8, 128) tile, the unit the TPU lays arrays out in
 
 
-def init_params(cfg: GPTConfig, seed: int = 0) -> Dict[str, np.ndarray]:
-    """Random GPT parameters under the models/gpt.py naming scheme (the
-    names the recipes.py tp rules match). Serving benches and tests use
-    this; real deployments load a checkpoint with the same names."""
-    r = np.random.RandomState(seed)
-    d, v, t = cfg.d_model, cfg.vocab_size, cfg.max_seq_len
-    dff = cfg.ffn_dim
-
-    def norm(*shape, std=0.02):
-        return (r.randn(*shape) * std).astype(cfg.dtype)
-
-    p: Dict[str, np.ndarray] = {
-        "gpt.wte": norm(v, d),
-        "gpt.wpe": norm(t, d),
-        "gpt.lnf.scale": np.ones(d, cfg.dtype),
-        "gpt.lnf.bias": np.zeros(d, cfg.dtype),
-    }
+def param_table(cfg: GPTConfig) -> Dict[str, Tuple[tuple, float]]:
+    """``{name: (shape, std)}`` of every parameter the serving programs
+    read, as the block ``cfg`` describes it, under the models/gpt.py
+    naming scheme (the names the recipes.py tp rules match). ``std`` 0
+    stands for zeros (biases) and -1 for ones (norm gains). Expert
+    weights are stacked per layer: ``moe.gate.w`` and ``moe.up.w``
+    ``[E, D, F]``, ``moe.down.w`` ``[E, F, D]``; an untied head is
+    ``gpt.lm_head.w`` ``[D, V]`` (the training graph's name and shape)."""
+    d, v, dff = cfg.d_model, cfg.vocab_size, cfg.ffn_dim
     res_std = 0.02 / math.sqrt(2 * cfg.n_layer)
+    t: Dict[str, Tuple[tuple, float]] = {"gpt.wte": ((v, d), 0.02)}
+
+    def norm(name, shape=(d,)):
+        t[f"{name}.scale"] = (shape, -1.0)
+        if cfg.norm == "layernorm":
+            t[f"{name}.bias"] = (shape, 0.0)
+
+    def linear(name, d_in, d_out, std=0.02):
+        t[f"{name}.w"] = ((d_in, d_out), std)
+        if cfg.bias:
+            t[f"{name}.b"] = ((d_out,), 0.0)
+
+    if cfg.position == "learned":
+        t["gpt.wpe"] = ((cfg.max_seq_len, d), 0.02)
+    norm("gpt.lnf")
+    if not cfg.tie_embeddings:
+        t["gpt.lm_head.w"] = ((d, v), 0.02)
     for i in range(cfg.n_layer):
         ln = f"gpt.h{i}"
         for part in ("q", "k", "v"):
-            p[f"{ln}.attn.{part}.w"] = norm(d, d)
-            p[f"{ln}.attn.{part}.b"] = np.zeros(d, cfg.dtype)
-        p[f"{ln}.attn.proj.w"] = norm(d, d, std=res_std)
-        p[f"{ln}.attn.proj.b"] = np.zeros(d, cfg.dtype)
-        p[f"{ln}.mlp.fc_in.w"] = norm(d, dff)
-        p[f"{ln}.mlp.fc_in.b"] = np.zeros(dff, cfg.dtype)
-        p[f"{ln}.mlp.fc_out.w"] = norm(dff, d, std=res_std)
-        p[f"{ln}.mlp.fc_out.b"] = np.zeros(d, cfg.dtype)
-        for nrm in ("ln1", "ln2"):
-            p[f"{ln}.{nrm}.scale"] = np.ones(d, cfg.dtype)
-            p[f"{ln}.{nrm}.bias"] = np.zeros(d, cfg.dtype)
+            linear(f"{ln}.attn.{part}", d, d)
+        linear(f"{ln}.attn.proj", d, d, res_std)
+        if cfg.qk_norm:
+            norm(f"{ln}.attn.q_norm")
+            norm(f"{ln}.attn.k_norm")
+        if cfg.mlp == "moe":
+            e = cfg.n_experts
+            t[f"{ln}.moe.router.w"] = ((d, e), 0.02)
+            t[f"{ln}.moe.gate.w"] = ((e, d, dff), 0.02)
+            t[f"{ln}.moe.up.w"] = ((e, d, dff), 0.02)
+            t[f"{ln}.moe.down.w"] = ((e, dff, d), res_std)
+        else:
+            linear(f"{ln}.mlp.fc_in", d, dff)
+            linear(f"{ln}.mlp.fc_out", dff, d, res_std)
+        norm(f"{ln}.ln1")
+        norm(f"{ln}.ln2")
+    return t
+
+
+def init_params(cfg: GPTConfig, seed: int = 0) -> Dict[str, np.ndarray]:
+    """Random parameters of :func:`param_table` (N(0, std), residual
+    projections scaled by 1/sqrt(2L), gains 1, biases 0). Serving benches
+    and tests use this; real deployments load a checkpoint with the same
+    names."""
+    r = np.random.RandomState(seed)
+    p: Dict[str, np.ndarray] = {}
+    for name, (shape, std) in param_table(cfg).items():
+        if std > 0:
+            p[name] = (r.randn(*shape) * std).astype(cfg.dtype)
+        else:
+            p[name] = np.full(shape, 1.0 if std < 0 else 0.0, cfg.dtype)
     return p
+
+
+def _rms(x, gain, eps: float):
+    """RMSNorm over the last axis, computed in float32 (as the published
+    implementations do) and returned in x's dtype."""
+    import jax
+    import jax.numpy as jnp
+
+    xf = x.astype(jnp.float32)
+    xf = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+                            + eps)
+    return xf.astype(x.dtype) * gain
+
+
+def _rope(x, rot):
+    """Rotate-half RoPE of heads ``x`` [..., H, hd] by ``rot`` = (cos,
+    sin), each [..., 1, hd/2] float32: the two halves of a head are the
+    real and imaginary parts."""
+    import jax.numpy as jnp
+
+    cos, sin = rot
+    half = x.shape[-1] // 2
+    a, b = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
 
 
 def _kv_rows(k, v):
@@ -212,6 +272,13 @@ class DecodeModel:
         if self.recipe is not None and self.recipe.n_devices > 1:
             import jax
 
+            if cfg.mlp == "moe":
+                raise NotImplementedError(
+                    f"recipe {self.recipe.name!r} places the model on "
+                    f"{self.recipe.n_devices} devices, and a model with "
+                    f"experts is served on one: parallel/recipes.py has no "
+                    f"placement for the moe.* weights (the `ep` axis) yet")
+
             # a recipe smaller than the host's device pool runs on the
             # leading devices (the CPU-sim tests resolve tp=2 on the
             # 8-device conftest mesh)
@@ -234,6 +301,7 @@ class DecodeModel:
         self._decode_fn = None
         self._prefill_fns: Dict[int, Any] = {}
         self._score_fns: Dict[int, Any] = {}
+        self.last_routing: Optional[np.ndarray] = None  # see decode()
 
     # -- placement ------------------------------------------------------
 
@@ -314,17 +382,20 @@ class DecodeModel:
 
     # -- shared forward pieces -----------------------------------------
 
-    def _ln(self, x, name):
+    def _linear(self, p, x, name):
+        y = x @ p[f"{name}.w"]
+        return y + p[f"{name}.b"] if self.cfg.bias else y
+
+    def _ln_p(self, p, x, name):
+        """The block's norm: LayerNorm, or RMSNorm (no mean, no bias)."""
         import jax.numpy as jnp
 
-        scale = self.params[f"{name}.scale"]
-        bias = self.params[f"{name}.bias"]
+        if self.cfg.norm == "rmsnorm":
+            return _rms(x, p[f"{name}.scale"], self.cfg.norm_eps)
         mu = jnp.mean(x, axis=-1, keepdims=True)
         var = jnp.mean(jnp.square(x - mu), axis=-1, keepdims=True)
-        return (x - mu) / jnp.sqrt(var + 1e-5) * scale + bias
-
-    def _linear(self, p, x, name):
-        return x @ p[f"{name}.w"] + p[f"{name}.b"]
+        return ((x - mu) / jnp.sqrt(var + self.cfg.norm_eps)
+                * p[f"{name}.scale"] + p[f"{name}.bias"])
 
     def _mlp(self, p, x, ln):
         import jax
@@ -333,13 +404,74 @@ class DecodeModel:
                         approximate=False)
         return self._linear(p, h, f"{ln}.mlp.fc_out")
 
-    def _ln_p(self, p, x, name):
+    def _embed(self, p, tokens, pos):
+        """Token rows, plus the learned position rows where the block has
+        them (``pos`` broadcasts against ``tokens``)."""
+        x = p["gpt.wte"][tokens]
+        return x + p["gpt.wpe"][pos] if self.cfg.position == "learned" else x
+
+    def _rot(self, pos):
+        """What :func:`_rope` turns heads at positions ``pos`` [...] by:
+        (cos, sin) [..., 1, hd/2], or None where positions are learned."""
         import jax.numpy as jnp
 
-        mu = jnp.mean(x, axis=-1, keepdims=True)
-        var = jnp.mean(jnp.square(x - mu), axis=-1, keepdims=True)
-        return ((x - mu) / jnp.sqrt(var + 1e-5) * p[f"{name}.scale"]
-                + p[f"{name}.bias"])
+        if self.cfg.position != "rope":
+            return None
+        half = self.cfg.head_dim // 2
+        inv = self.cfg.rope_theta ** (-jnp.arange(half, dtype=jnp.float32)
+                                      / half)
+        ang = pos.astype(jnp.float32)[..., None, None] * inv
+        return jnp.cos(ang), jnp.sin(ang)
+
+    def _qkv(self, lp, h, rot, lead: tuple):
+        """q, k, v ``[*lead, H, hd]`` of normed hidden ``h`` ``[*lead,
+        D]``: the projections, the block's norm over all of q's and k's
+        lanes where it has one, the split into heads, RoPE by ``rot``.
+        The K that leaves here is the K the pool keeps."""
+        import jax
+
+        cfg, ln = self.cfg, _LAYER
+        q = self._linear(lp, h, f"{ln}.attn.q")
+        k = self._linear(lp, h, f"{ln}.attn.k")
+        v = self._linear(lp, h, f"{ln}.attn.v")
+        if cfg.qk_norm:
+            with jax.named_scope("attn/qk_norm"):
+                q = _rms(q, lp[f"{ln}.attn.q_norm.scale"], cfg.norm_eps)
+                k = _rms(k, lp[f"{ln}.attn.k_norm.scale"], cfg.norm_eps)
+        q, k, v = (a.reshape(*lead, cfg.n_head, cfg.head_dim)
+                   for a in (q, k, v))
+        if rot is not None:
+            with jax.named_scope("attn/rope"):
+                q, k = _rope(q, rot), _rope(k, rot)
+        return q, k, v
+
+    def _ffn(self, lp, x):
+        """The block's second half on the residual stream ``x``: norm,
+        MLP or experts, residual add. Returns (x, each row's top-k expert
+        ids ``[rows, k]``, or None where the block has no experts)."""
+        import jax
+
+        cfg, ln = self.cfg, _LAYER
+        if cfg.mlp != "moe":
+            with jax.named_scope("mlp"):
+                return x + self._mlp(
+                    lp, self._ln_p(lp, x, f"{ln}.ln2"), ln), None
+        from ..ops import moe
+
+        h = self._ln_p(lp, x, f"{ln}.ln2").reshape(-1, cfg.d_model)
+        with jax.named_scope("moe/route"):
+            dense, idx = moe.route(h, lp[f"{ln}.moe.router.w"],
+                                   cfg.experts_per_token)
+        with jax.named_scope("moe/experts"):
+            y = moe.experts(h, dense, lp[f"{ln}.moe.gate.w"],
+                            lp[f"{ln}.moe.up.w"], lp[f"{ln}.moe.down.w"])
+        return x + y.reshape(x.shape), idx
+
+    def _logits(self, p, x):
+        """Vocabulary logits of final-normed hidden ``x`` [..., D]."""
+        if self.cfg.tie_embeddings:
+            return x @ p["gpt.wte"].T
+        return x @ p["gpt.lm_head.w"]
 
     # -- prefill --------------------------------------------------------
 
@@ -359,16 +491,13 @@ class DecodeModel:
         import jax.numpy as jnp
 
         cfg, NB = self.cfg, self.n_blocks
-        H, hd = cfg.n_head, cfg.head_dim
-        scale = 1.0 / math.sqrt(hd)
+        scale = 1.0 / math.sqrt(cfg.head_dim)
 
         @jax.jit  # one trace for all layers: see _layer_params
-        def layer(lp, i, x, causal, kv_dest):
+        def layer(lp, i, x, causal, kv_dest, rot):
             ln = _LAYER
             h = self._ln_p(lp, x, f"{ln}.ln1")
-            q = self._linear(lp, h, f"{ln}.attn.q").reshape(1, L, H, hd)
-            k = self._linear(lp, h, f"{ln}.attn.k").reshape(1, L, H, hd)
-            v = self._linear(lp, h, f"{ln}.attn.v").reshape(1, L, H, hd)
+            q, k, v = self._qkv(lp, h, rot, (1, L))
             if kv_dest is not None:
                 pages, blk, slot = kv_dest
                 with jax.named_scope("attn/kv_write"):
@@ -381,16 +510,16 @@ class DecodeModel:
                 a = jax.nn.softmax(s, axis=-1)
                 o = jnp.einsum("bhqk,bkhd->bqhd", a, v).reshape(1, L, -1)
             x = x + self._linear(lp, o, f"{ln}.attn.proj")
-            with jax.named_scope("mlp"):
-                x = x + self._mlp(lp, self._ln_p(lp, x, f"{ln}.ln2"), ln)
-            return x, kv_dest
+            return self._ffn(lp, x)[0], kv_dest
 
         pos = jnp.arange(L)
         with jax.named_scope("embed"):
-            x = p["gpt.wte"][tokens] + p["gpt.wpe"][pos][None]  # [1,L,D]
+            x = self._embed(p, tokens, pos)  # [1, L, D]
         causal = pos[:, None] >= pos[None, :]
+        rot = self._rot(pos[None])
         for i in range(cfg.n_layer):
-            x, kv_dest = layer(_layer_params(p, i), i, x, causal, kv_dest)
+            x, kv_dest = layer(_layer_params(p, i), i, x, causal, kv_dest,
+                               rot)
         return (self._ln_p(p, x, "gpt.lnf"),
                 None if kv_dest is None else kv_dest[0])
 
@@ -409,7 +538,7 @@ class DecodeModel:
             x, pages = self._prompt_trunk(p, tokens, L, (pages, blk, slot))
             with jax.named_scope("lm_head"):
                 last = jnp.take(x, length - 1, axis=1)  # [1, D]
-                logits = last @ p["gpt.wte"].T  # [1, V]
+                logits = self._logits(p, last)  # [1, V]
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return pages, nxt
 
@@ -433,7 +562,9 @@ class DecodeModel:
         def score(p, tokens, length):
             x, _ = self._prompt_trunk(p, tokens, L)
             # positions 0..L-2 predict tokens 1..L-1; padded tail masked
-            nll = lmhead_ce(x[0, :L - 1], p["gpt.wte"], tokens[0, 1:])
+            head = (p["gpt.wte"] if self.cfg.tie_embeddings
+                    else p["gpt.lm_head.w"].T)
+            nll = lmhead_ce(x[0, :L - 1], head, tokens[0, 1:])
             valid = jnp.arange(L - 1) < (length - 1)
             nll = jnp.where(valid, nll, 0.0)
             return nll, jnp.sum(nll)
@@ -473,6 +604,8 @@ class DecodeModel:
         import jax
         import jax.numpy as jnp
 
+        from ..ops import moe
+
         cfg, BS, NB = self.cfg, self.block_size, self.n_blocks
         B, H, hd = self.max_batch, cfg.n_head, cfg.head_dim
         S = self.gather_len
@@ -481,12 +614,11 @@ class DecodeModel:
         barange = jnp.arange(B)
 
         @jax.jit  # one trace for all layers: see _layer_params
-        def layer(lp, i, x, pages, block_tables, blk, slot, valid):
+        def layer(lp, i, x, pages, block_tables, blk, slot, valid, rot,
+                  live):
             ln = _LAYER
             h = self._ln_p(lp, x, f"{ln}.ln1")
-            q = self._linear(lp, h, f"{ln}.attn.q").reshape(B, H, hd)
-            k = self._linear(lp, h, f"{ln}.attn.k").reshape(B, H, hd)
-            v = self._linear(lp, h, f"{ln}.attn.v").reshape(B, H, hd)
+            q, k, v = self._qkv(lp, h, rot, (B,))
             # the layer is part of the block index: no slice of the pool
             # is ever materialised
             with jax.named_scope("attn/kv_write"):
@@ -507,24 +639,33 @@ class DecodeModel:
                 a = jax.nn.softmax(s, axis=-1).reshape(B, H, S // T, T)
                 o = jnp.einsum("bhjt,bjhtd->bhd", a, vv).reshape(B, -1)
             x = x + self._linear(lp, o, f"{ln}.attn.proj")
-            with jax.named_scope("mlp"):
-                x = x + self._mlp(lp, self._ln_p(lp, x, f"{ln}.ln2"), ln)
-            return x, pages
+            x, idx = self._ffn(lp, x)
+            return x, pages, (None if idx is None else
+                              moe.routing_counts(idx, live, cfg.n_experts))
 
         def decode_tick(p, pages, block_tables, context_lens, tokens):
             pos = context_lens  # [B]: the new token's position
             with jax.named_scope("embed"):
-                x = p["gpt.wte"][tokens] + p["gpt.wpe"][pos]  # [B, D]
+                x = self._embed(p, tokens, pos)  # [B, D]
             blk = block_tables[barange, pos // BS]  # [B]
             slot = pos % BS
             valid = (jnp.arange(S)[None, :] <= pos[:, None])  # [B, S]
+            rot = self._rot(pos)
+            # a slot in use has a prompt behind it; an empty one is at 0
+            live = pos > 0 if cfg.mlp == "moe" else None
+            routing = []
             for i in range(cfg.n_layer):
-                x, pages = layer(_layer_params(p, i), i, x, pages,
-                                 block_tables, blk, slot, valid)
+                x, pages, counts = layer(_layer_params(p, i), i, x, pages,
+                                         block_tables, blk, slot, valid,
+                                         rot, live)
+                routing.append(counts)
             with jax.named_scope("lm_head"):
                 x = self._ln_p(p, x, "gpt.lnf")
-                logits = x @ p["gpt.wte"].T  # [B, V]
+                logits = self._logits(p, x)  # [B, V]
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            if live is not None:
+                # the routing counts ride behind the tokens: one read-back
+                nxt = jnp.concatenate([nxt, sum(routing)])
             return pages, nxt
 
         return self._compile(decode_tick, "decode")
@@ -542,6 +683,7 @@ class DecodeModel:
             "serve", kind, bucket, self.max_batch, self.n_blocks,
             self.block_size, self.cfg.n_layer, self.cfg.n_head,
             self.cfg.d_model, self.cfg.vocab_size, self.cfg.max_seq_len,
+            self.cfg.ffn_dim, self.cfg.block(),
             tuple(sorted(self.recipe.axes.items()))
             if self.recipe is not None else None,
         ))
@@ -661,7 +803,10 @@ class DecodeModel:
         stamps): both arrays ready, and the ``perf_counter_ns`` stamps of
         the spans below, (start, start of ``tick/device_sync``, end), so
         the engine's windows and the ledger's ``tick_sync_s`` are the
-        intervals a trace shows."""
+        intervals a trace shows. A model with experts leaves the tick's
+        routing counts in ``last_routing`` (assignments of live slots,
+        distinct experts hit, the largest expert's load, each summed over
+        the layers): they come back behind the tokens, in the one read."""
         import jax
         import jax.numpy as jnp
 
@@ -676,6 +821,8 @@ class DecodeModel:
         with _profiler.span("tick/device_sync", cat="engine") as sync:
             nxt = np.asarray(nxt)
             jax.block_until_ready(pages)
+        if self.cfg.mlp == "moe":
+            nxt, self.last_routing = np.split(nxt, [self.max_batch])
         return pages, nxt, (put.t0_ns, sync.t0_ns, sync.t1_ns)
 
     def warm(self, full: bool = False) -> None:
@@ -695,33 +842,38 @@ class DecodeModel:
 
     # -- reference path (tests) ----------------------------------------
 
-    def full_logits(self, tokens: np.ndarray) -> np.ndarray:
+    def full_logits(self, tokens: np.ndarray, with_routing: bool = False):
         """Non-paged reference forward over [1, T] — the ground truth
-        the engine's batched output is checked against."""
+        the engine's batched output is checked against. ``with_routing``
+        adds every position's top-k expert ids in every layer, ``[T,
+        n_layer, k]`` (a model with experts)."""
         import jax
         import jax.numpy as jnp
 
         cfg = self.cfg
-        H, hd = cfg.n_head, cfg.head_dim
         t = np.asarray(tokens, np.int32).reshape(1, -1)
         T = t.shape[1]
         p = self.params
-        x = p["gpt.wte"][jnp.asarray(t)] + p["gpt.wpe"][jnp.arange(T)][None]
-        causal = jnp.arange(T)[:, None] >= jnp.arange(T)[None, :]
+        pos = jnp.arange(T)
+        x = self._embed(p, jnp.asarray(t), pos)
+        causal = pos[:, None] >= pos[None, :]
+        rot = self._rot(pos[None])
+        routing = []
         for i in range(cfg.n_layer):
-            ln = f"gpt.h{i}"
-            h = self._ln_p(p, x, f"{ln}.ln1")
-            q = self._linear(p, h, f"{ln}.attn.q").reshape(1, T, H, hd)
-            k = self._linear(p, h, f"{ln}.attn.k").reshape(1, T, H, hd)
-            v = self._linear(p, h, f"{ln}.attn.v").reshape(1, T, H, hd)
-            s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(hd)
+            lp, ln = _layer_params(p, i), _LAYER
+            h = self._ln_p(lp, x, f"{ln}.ln1")
+            q, k, v = self._qkv(lp, h, rot, (1, T))
+            s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(cfg.head_dim)
             s = jnp.where(causal[None, None], s, _NEG)
             a = jax.nn.softmax(s, axis=-1)
             o = jnp.einsum("bhqk,bkhd->bqhd", a, v).reshape(1, T, -1)
-            x = x + self._linear(p, o, f"{ln}.attn.proj")
-            x = x + self._mlp(p, self._ln_p(p, x, f"{ln}.ln2"), ln)
-        x = self._ln_p(p, x, "gpt.lnf")
-        return np.asarray(x @ p["gpt.wte"].T)
+            x = x + self._linear(lp, o, f"{ln}.attn.proj")
+            x, idx = self._ffn(lp, x)
+            routing.append(idx)
+        logits = np.asarray(self._logits(p, self._ln_p(p, x, "gpt.lnf")))
+        if with_routing:
+            return logits, np.stack([np.asarray(r) for r in routing], axis=1)
+        return logits
 
     # -- roofline -------------------------------------------------------
 

@@ -42,7 +42,9 @@ def test_manifest_has_no_problems_and_the_new_metrics():
     man = manifest.load()
     assert manifest.problems(man) == []
     by_name = {m["name"]: m for m in man["per_layer"]}
-    assert [m["name"] for m in man["per_layer"]][-len(NEW):] == list(NEW)  # appended, in order
+    names = [m["name"] for m in man["per_layer"]]
+    at = names.index(NEW[0])
+    assert names[at:at + len(NEW)] == list(NEW)  # present, contiguous, in order
     for name in NEW:
         spec = manifest.layer_metric(name)
         assert spec["workloads"] == by_name[name]["workloads"]

@@ -292,3 +292,93 @@ def test_executor_step_carries_paddle_ops_in_op_name_and_a_role_name(tpu_arg):
     ops = set(re.findall(r'op_name="([^"]*)"', text))
     for paddle_op in ("layer_norm", "matmul_grad", "sgd"):
         assert any(f"jit(train_step)/{paddle_op}/" in o for o in ops), paddle_op
+
+
+# The same programs for a block of another kind: OLMoE's (RMSNorm, RoPE, q/k
+# norm, 64 experts of which a token takes 8, untied head) at the widths of
+# the cell olmoe-serve-batch, 2 of its 12 layers, no weight allocated.
+
+
+@pytest.fixture(scope="module")
+def olmoe_programs(tpu_device):
+    import os
+    import sys
+    import types
+
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools"))
+    import serve_compile_report as report
+
+    dm = report.cell_model("olmoe-serve-batch", n_layer=2)
+    out = types.SimpleNamespace(dm=dm, text={}, facts={})
+    for name, (jit_fn, args) in report.serving_programs(dm).items():
+        if name == "prefill_128":
+            continue
+        compiled = report.compile_on(jit_fn, args, tpu_device)
+        out.text[name] = compiled.as_text()
+        out.facts[name] = report.describe(compiled, dm.pool_shape())
+    return out
+
+
+@pytest.mark.parametrize("name,scopes", [
+    ("decode_tick", ("embed", "jit(layer)/attn/qk_norm", "jit(layer)/attn/rope", "jit(layer)/attn/kv_write",
+                     "jit(layer)/attn/kv_gather", "jit(layer)/attn/scores", "jit(layer)/moe/route",
+                     "jit(layer)/moe/experts", "lm_head")),
+    ("prefill_256", ("embed", "jit(layer)/attn/qk_norm", "jit(layer)/attn/rope", "jit(layer)/attn/kv_write",
+                     "jit(layer)/attn/scores", "jit(layer)/moe/route", "jit(layer)/moe/experts", "lm_head"))])
+def test_olmoe_programs_carry_their_names_and_scopes(olmoe_programs, name, scopes):
+    from benchmark import manifest
+
+    text = olmoe_programs.text[name]
+    assert re.search(rf"HloModule jit_{name}\b", text)
+    metric = "moe_decode_program_ms" if name == "decode_tick" else "moe_prefill_program_ms"
+    assert re.search(manifest.layer_metric(metric)["args"]["pattern"], f"jit_{name}")
+    ops = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in scopes:
+        assert any(f"jit({name})/{scope}/" in o for o in ops), (scope, sorted(ops)[:20])
+    assert not any("/mlp/" in o for o in ops)
+
+
+@pytest.mark.parametrize("name", _SERVING_PROGRAMS)
+def test_olmoe_pool_stays_in_place_unpadded_at_a_4096_lane_row(olmoe_programs, name):
+    dm, facts = olmoe_programs.dm, olmoe_programs.facts[name]
+    pool = facts["pool"]
+    assert dm.pool_shape() == (2 * 960, 16, 4096)
+    assert pool["parameter"] is not None and pool["aliased_to_output"], pool
+    assert pool["layouts_in_program"] == [pool["layout"]], pool
+    assert pool["layout"].startswith("2,1,0:T(8,128)"), pool
+    assert facts["aliased_parameters"] == [pool["parameter"]]
+    need = 2 * 960 * 16 * 4096 * 2
+    assert abs(facts["memory"]["alias_size_in_bytes"] - need) <= 0.01 * need
+
+
+@pytest.mark.parametrize("name", _SERVING_PROGRAMS)
+def test_olmoe_program_moves_neither_context_nor_expert_weights(olmoe_programs, name):
+    """No copy, reshape or transpose at the top level of anything as large
+    as a layer's gathered context or one stacked expert weight."""
+    dm = olmoe_programs.dm
+    cfg = dm.cfg
+    limit = min(dm.max_batch * dm.gather_len * cfg.d_model, cfg.n_experts * cfg.d_model * cfg.ffn_dim)
+    big = [c for c in olmoe_programs.facts[name]["top_level_copies"] if c["elements"] >= limit]
+    assert not big, big
+
+
+@pytest.mark.parametrize("name", _SERVING_PROGRAMS)
+def test_olmoe_temporaries_are_bounded_and_twelve_layers_fit_the_chip(olmoe_programs, name):
+    """Temporaries: under one gathered context plus one layer's expert
+    activations. The whole cell: 12 layers of weights, their pool, these
+    temporaries (a layer's are reused by the next) and the code, under the
+    chip's 16 GB with a tenth to spare."""
+    from benchmark import arch, manifest
+
+    dm, mem = olmoe_programs.dm, olmoe_programs.facts[name]["memory"]
+    cfg = dm.cfg
+    context = 2 * dm.max_batch * dm.gather_len * dm.pool_shape()[-1]
+    rows = dm.max_batch if name == "decode_tick" else 256
+    activations = cfg.n_experts * rows * (2 * cfg.ffn_dim * 2 + cfg.d_model * (2 + 4))
+    assert mem["temp_size_in_bytes"] < context + activations, (mem, context, activations)
+    conf = manifest.cell(manifest.load(), "olmoe-serve-batch")["config"]
+    weights = 2 * arch.of(conf).n_params(conf)
+    pool = 12 * 960 * 16 * 4096 * 2
+    total = weights + pool + mem["temp_size_in_bytes"] + mem["generated_code_size_in_bytes"] * 6
+    assert total < 0.9 * 16e9, total

@@ -19,6 +19,9 @@ full 48 in a few minutes):
   python tools/serve_compile_report.py --n-head 12 --d-model 768 \\
       --max-batch 8 --n-blocks 256 --buckets 128,512    # chip_smoke's GPT-2 small
   python tools/serve_compile_report.py --hlo-dir /root/scratch/hlo   # keep the HLO text
+  python tools/serve_compile_report.py --cell olmoe-serve-batch      # a benchmark cell whose
+      # configuration has a module under benchmark/arch/: its block, widths and engine
+      # settings, all its layers (--n-layer 2 for a quick look); no weight is allocated
 """
 from __future__ import annotations
 
@@ -45,6 +48,34 @@ def described_device(topology: str = "v5e:2x2"):
 
     return topologies.get_topology_desc(
         platform="tpu", topology_name=topology).devices[0]
+
+
+def abstract_model(cfg, **engine):
+    """A DecodeModel of ``cfg`` whose parameters are shapes only
+    (``serving.model.param_table``): nothing is drawn or allocated, so a
+    model that fills a chip is described on any host."""
+    import jax
+
+    from paddle_tpu.serving import DecodeModel
+    from paddle_tpu.serving.model import param_table
+
+    dm = DecodeModel(cfg, params={}, **engine)
+    dm.params = {name: jax.ShapeDtypeStruct(shape, cfg.dtype)
+                 for name, (shape, _std) in param_table(cfg).items()}
+    return dm
+
+
+def cell_model(cell: str, n_layer=None):
+    """The DecodeModel (abstract) a benchmark cell serves with."""
+    from benchmark import arch, manifest
+    from paddle_tpu import serving
+
+    c = manifest.cell(manifest.load(), cell)
+    conf, e = dict(c["config"]), c["traffic"]["engine"]
+    if n_layer:
+        conf["n_layer"] = n_layer
+    cfg = serving.GPTConfig(**arch.of(conf).gpt_config(conf, e))
+    return abstract_model(cfg, **arch.engine_args(e))
 
 
 def serving_programs(dm) -> Dict[str, Tuple[Any, tuple]]:
@@ -127,7 +158,10 @@ def describe(compiled, pool_shape: Tuple[int, ...]) -> Dict[str, Any]:
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--n-layer", type=int, default=2)
+    ap.add_argument("--cell", default=None,
+                    help="a cell of BENCHMARK.json (its configuration needs a module "
+                         "under benchmark/arch/); the width arguments are then unused")
+    ap.add_argument("--n-layer", type=int, default=None, help="default 2; a cell's own depth")
     ap.add_argument("--n-head", type=int, default=25)
     ap.add_argument("--d-model", type=int, default=1600)
     ap.add_argument("--vocab", type=int, default=1024)
@@ -143,11 +177,14 @@ def main(argv=None) -> int:
 
     from paddle_tpu import serving
 
-    cfg = serving.GPTConfig(vocab_size=a.vocab, n_layer=a.n_layer, n_head=a.n_head,
-                            d_model=a.d_model, max_seq_len=a.max_seq_len, dtype="bfloat16")
-    dm = serving.DecodeModel(cfg, max_batch=a.max_batch, n_blocks=a.n_blocks,
-                             block_size=a.block_size,
-                             prefill_buckets=[int(b) for b in a.buckets.split(",")])
+    if a.cell:
+        dm = cell_model(a.cell, a.n_layer)
+    else:
+        cfg = serving.GPTConfig(vocab_size=a.vocab, n_layer=a.n_layer or 2, n_head=a.n_head,
+                                d_model=a.d_model, max_seq_len=a.max_seq_len, dtype="bfloat16")
+        dm = abstract_model(cfg, max_batch=a.max_batch, n_blocks=a.n_blocks,
+                            block_size=a.block_size,
+                            prefill_buckets=[int(b) for b in a.buckets.split(",")])
     device = described_device(a.topology)
     for name, (jit_fn, args) in serving_programs(dm).items():
         compiled = compile_on(jit_fn, args, device)
