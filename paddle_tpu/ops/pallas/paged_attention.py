@@ -1,0 +1,220 @@
+"""Decode attention over the paged KV pool as it lies: a pallas TPU kernel.
+
+One query token per batch slot against that slot's own context, read
+straight out of the serving pool ``[n_layer * n_blocks, block_size,
+n_head * 2 * head_dim]`` (serving/kv_cache.py: per token and head, K then
+V). The XLA formulation it replaces gathers every slot's whole window
+(``max_seq_len`` positions) into a temporary and scores all of it; this
+kernel walks only the pages a slot's context occupies, ``0 ..
+context_len // block_size``, so a tick moves the live K and V once and
+nothing else.
+
+Schedule: the grid is the batch, walked in order. A step copies its
+slot's pages HBM -> VMEM itself (the pool has no BlockSpec: nothing is
+staged or relaid), ``pages_per_step`` pages at a time into one of two
+buffers, and starts the next copy (the slot's next pages, or the NEXT
+slot's first ones) before it computes on the current buffer. Running
+maximum, sum and weighted rows per head live in VMEM scratch (online
+softmax, float32).
+
+All heads at once, no lane slicing: a head is ``2 * head_dim`` lanes of
+the row (a multiple of 128), its K half then its V half. q arrives
+zero-padded over the V halves and becomes a block-diagonal ``[heads,
+row]`` matrix, so ONE matmul against the whole row scores every head
+(the V lanes meet zeros), and ``p @ rows`` weighs every head's lanes in
+ONE more; a head's own output is the diagonal block of that product, and
+the caller takes its V half at the very end. The matrix unit is bound by
+the K/V tiles it has to load either way, so the off-diagonal products
+cost nothing that matters.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .backend import compiler_params, on_tpu
+
+_NEG_INF = -1e30  # finite: a masked score never makes inf - inf
+_LANES = 128
+_STEP_TOKENS = 128  # tokens a step scores: one full contraction of p @ rows
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _sublanes(dtype) -> int:
+    """Rows of one native tile of ``dtype`` (8 of 32 bits, 16 of 16)."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
+def unsupported(head_dim: int, block_size: int, dtype) -> str:
+    """Why a pool of this geometry cannot take the kernel ('' if it
+    can): the kernel copies whole pages and multiplies whole rows, so a
+    head has to fill whole 128-lane tiles and a page whole sublane tiles
+    (a row or a page the runtime would pad is not what the copies
+    assume)."""
+    if (2 * head_dim) % _LANES:
+        return (f"a head's K|V is {2 * head_dim} lanes, not a multiple of "
+                f"{_LANES}")
+    if block_size % _sublanes(dtype) or _STEP_TOKENS % block_size:
+        return (f"a page of {block_size} tokens is not a whole number of "
+                f"{_sublanes(dtype)}-row tiles dividing {_STEP_TOKENS}")
+    return ""
+
+
+def vmem_scratch_bytes(n_head: int, head_dim: int, block_size: int,
+                       dtype) -> int:
+    """VMEM the kernel's scratch takes (both page buffers, the
+    accumulator, the two statistics), for the compile report."""
+    hp = _round_up(n_head, _sublanes(dtype))
+    hw = n_head * 2 * head_dim
+    return (2 * _STEP_TOKENS * hw * jnp.dtype(dtype).itemsize
+            + hp * hw * 4 + 2 * hp * _LANES * 4)
+
+
+def _kernel(tables_ref, lens_ref, q_ref, pool_ref, o_ref,
+            buf, sem, cur, m_scr, l_scr, acc_scr,
+            *, block_size, pages_per_step, max_blocks, head_lanes, scale):
+    b, nb = pl.program_id(0), pl.num_programs(0)
+    bs, pps, w = block_size, pages_per_step, head_lanes
+    step_tokens = pps * bs
+    hp, hw = acc_scr.shape
+
+    def n_pages(i):
+        return jnp.minimum(lens_ref[i] // bs + 1, max_blocks)
+
+    def copies(i, c, slot, act):
+        """``act`` on the copy of each page slot ``i`` has in step ``c``
+        (pages past the context are neither started nor waited for)."""
+        first = c * pps
+
+        def one(j, carry):
+            act(pltpu.make_async_copy(
+                pool_ref.at[tables_ref[i * max_blocks + first + j]],
+                buf.at[slot, pl.ds(pl.multiple_of(j * bs, bs), bs)],
+                sem.at[slot]))
+            return carry
+
+        jax.lax.fori_loop(0, jnp.minimum(pps, n_pages(i) - first), one, 0)
+
+    @pl.when(b == 0)
+    def _first():
+        # rows no copy has filled are multiplied by weights of exactly 0:
+        # they must not hold what VMEM held before (0 * NaN)
+        buf[...] = jnp.zeros_like(buf)
+        cur[0] = 0
+        copies(0, 0, 0, lambda dma: dma.start())
+
+    pos = lens_ref[b]
+    n_steps = pl.cdiv(n_pages(b), pps)
+    m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    # row h of the block-diagonal views owns lanes [h * w, (h + 1) * w)
+    row = jax.lax.broadcasted_iota(jnp.int32, (hp, hw), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (hp, hw), 1)
+    diag = (lane >= row * w) & (lane < (row + 1) * w)
+    qblk = jnp.where(diag, q_ref[...], 0.0).astype(buf.dtype)  # [hp, hw]
+
+    def step(c, slot):
+        nxt = 1 - slot
+
+        @pl.when(c + 1 < n_steps)
+        def _():
+            copies(b, c + 1, nxt, lambda dma: dma.start())
+
+        @pl.when((c + 1 == n_steps) & (b + 1 < nb))
+        def _():
+            copies(b + 1, 0, nxt, lambda dma: dma.start())
+
+        copies(b, c, slot, lambda dma: dma.wait())
+        rows = buf[slot]  # [step_tokens, hw]: K|V of every head
+        s = jax.lax.dot_general(
+            qblk, rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [hp, step_tokens]
+        col = c * step_tokens + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 1)
+        s = jnp.where(col <= pos, s, _NEG_INF)
+        m_prev, l_prev = m_scr[:, :1], l_scr[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+        pv = jax.lax.dot_general(
+            p.astype(rows.dtype), rows, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)  # [hp, hw]
+        acc_scr[...] = acc_scr[...] * alpha + pv
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+        return nxt
+
+    cur[0] = jax.lax.fori_loop(0, n_steps, step, cur[0])
+    # position 0 is never masked, so every sum is positive
+    out = jnp.where(diag, acc_scr[...] / l_scr[:, :1], 0.0)
+    o_ref[...] = jnp.sum(out, axis=0, keepdims=True)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _paged_attention(q, pool, tables, context_lens, *, scale, interpret):
+    B, H, hd = q.shape
+    _, bs, hw = pool.shape
+    w = 2 * hd
+    max_blocks = tables.shape[1]
+    pps = _STEP_TOKENS // bs
+    hp = _round_up(H, _sublanes(pool.dtype))
+    # q over the K lanes of its head, zeros over the V lanes; float32
+    # holds the model's values exactly and is what the kernel selects on
+    qp = jnp.pad(q.astype(jnp.float32), ((0, 0), (0, 0), (0, hd)))
+    row = pl.BlockSpec((None, 1, hw), lambda b, *_: (b, 0, 0))
+    o = pl.pallas_call(
+        functools.partial(_kernel, block_size=bs, pages_per_step=pps,
+                          max_blocks=max_blocks, head_lanes=w, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B,),
+            in_specs=[row, pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=row,
+            scratch_shapes=[
+                pltpu.VMEM((2, pps * bs, hw), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((hp, _LANES), jnp.float32),
+                pltpu.VMEM((hp, _LANES), jnp.float32),
+                pltpu.VMEM((hp, hw), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, 1, hw), jnp.float32),
+        # the buffers and the copy in flight carry over from slot to slot
+        compiler_params=compiler_params(("arbitrary",)),
+        interpret=interpret,
+        name="paged_attention",
+    )(tables.reshape(-1).astype(jnp.int32), context_lens.astype(jnp.int32),
+      qp.reshape(B, 1, hw), pool)
+    return o.reshape(B, H, w)[..., hd:].reshape(B, H * hd).astype(q.dtype)
+
+
+def paged_attention(q, pool, tables, context_lens, scale, interpret=None):
+    """Attention of one new token a slot over that slot's paged context.
+
+    q ``[B, H, hd]`` (normed and rotated as the block has it); ``pool``
+    the KV pool as it rests, ``[rows, block_size, H * 2 * hd]``;
+    ``tables`` ``[B, max_blocks]`` the pool row-block of each of a slot's
+    pages (the layer's base already added); ``context_lens`` ``[B]`` the
+    position of the new token, whose K and V are in the pool already:
+    positions ``0 .. context_lens[b]`` are attended, the pages behind
+    them are never read. Returns ``o [B, H * hd]`` in q's dtype; scores,
+    softmax statistics and the weighted sum are float32. On a non-TPU
+    backend the kernel runs in the pallas interpreter.
+    """
+    why = unsupported(q.shape[-1], pool.shape[1], pool.dtype)
+    if why:
+        raise ValueError(f"paged_attention: {why}")
+    if interpret is None:
+        interpret = not on_tpu()
+    return _paged_attention(q, pool, tables, context_lens,
+                            scale=float(scale), interpret=bool(interpret))

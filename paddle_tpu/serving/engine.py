@@ -933,6 +933,11 @@ class ServingEngine:
             _ledger.add_slot_seconds(window * len(ready))
             if self.model.last_routing is not None:
                 _ledger.note_routing(*self.model.last_routing)
+            # what this tick's attention had to read, and its whole window
+            _ledger.note_attention(
+                sum(blocks_for_tokens(req.context_len + 1, self.block_size)
+                    for req in ready),
+                B * self.model.max_blocks_per_req)
             for req in ready:
                 req.out_tokens.append(int(nxt[req.slot]))
                 req.context_len += 1

@@ -9,8 +9,9 @@ cache is ONE device array of fixed-size blocks
 table* — the list of block ids its context occupies, the same ids in
 every layer: layer ``i`` keeps block ``b`` at row-block ``i * n_blocks +
 b``. A token's row holds, head by head, that head's K then its V. The
-decode program gathers a request's K/V through its table and scatters
-the new token's K/V into the tail slot, so the cache never compacts and
+decode program scatters the new token's K/V into the tail slot and reads
+a request's K/V through its table (page by page where it lies, in
+``ops/pallas/paged_attention``), so the cache never compacts and
 requests of wildly different lengths share one allocation. Block 0 (of
 every layer) is the reserved **scratch block**: padded table entries and
 inactive batch rows direct their (masked, never-read) reads and writes

@@ -34,7 +34,11 @@ the device, so wall - sync is host work a tick), for a model with
 experts the routing its decode ticks read back, summed over ticks and
 layers (``moe_assignments``: (live slot, expert) pairs, all computed;
 ``moe_experts_hit``: distinct experts with at least one; ``moe_max_load``:
-the busiest expert's pairs), and ``itl_gaps_s``, a
+the busiest expert's pairs), what the decode attention read
+(``attn_pages_read``: the KV pages its live slots' contexts occupy, summed
+over ticks, layers apart; ``attn_pages_window``: the pages of every slot's
+whole window, which the gathered formulation reads whatever is live: their
+ratio is the share of the window that holds anything), and ``itl_gaps_s``, a
 bounded sample of the gaps between a request's consecutive tokens, with
 ``itl_gaps_seen``, how many gaps it was drawn from (more than the sample
 holds: the oldest were dropped, and a percentile says so).
@@ -138,6 +142,10 @@ _ITL_SAMPLE = 8192
 
 # routing of a model with experts: serving/model.py::DecodeModel.decode
 MOE_COUNTERS = ("moe_assignments", "moe_experts_hit", "moe_max_load")
+# KV pages of a decode tick: engine.py::_decode_tick_phases
+ATTN_COUNTERS = ("attn_pages_read", "attn_pages_window")
+# summed per decode tick; in totals(), cleared by reset(), merged as sums
+TICK_COUNTERS = MOE_COUNTERS + ATTN_COUNTERS
 
 # fixed log-spaced bounds so per-replica histograms merge exactly across
 # restarts and ranks (1ms .. 120s covers CPU-sim ticks through pod SLOs)
@@ -361,7 +369,7 @@ class ServingLedger:
             self.decode_ticks = 0
             self.tick_wall_s = 0.0
             self.tick_sync_s = 0.0
-            self.moe = dict.fromkeys(MOE_COUNTERS, 0)
+            self.tick_counts = dict.fromkeys(TICK_COUNTERS, 0)
             self.itl_gaps: "collections.deque[float]" = collections.deque(
                 maxlen=_ITL_SAMPLE)
             self.itl_gaps_seen = 0
@@ -407,10 +415,17 @@ class ServingLedger:
     def note_routing(self, assignments: int, experts_hit: int,
                      max_load: int) -> None:
         """One decode tick's routing counts, each summed over layers."""
+        self._count(MOE_COUNTERS, (assignments, experts_hit, max_load))
+
+    def note_attention(self, pages_read: int, pages_window: int) -> None:
+        """One decode tick's KV pages: those its live slots' contexts
+        occupy (new token included), and those of every slot's window."""
+        self._count(ATTN_COUNTERS, (pages_read, pages_window))
+
+    def _count(self, names, values) -> None:
         with self._lock:
-            for k, v in zip(MOE_COUNTERS, (assignments, experts_hit,
-                                           max_load)):
-                self.moe[k] += int(v)
+            for k, v in zip(names, values):
+                self.tick_counts[k] += int(v)
 
     def note_token_gaps(self, gaps: Sequence[float]) -> None:
         """A retired request's inter-token gaps (seconds), into the
@@ -586,7 +601,7 @@ class ServingLedger:
             decode_ticks = self.decode_ticks
             tick_wall = self.tick_wall_s
             tick_sync = self.tick_sync_s
-            moe = dict(self.moe)
+            counts = dict(self.tick_counts)
             doc["itl_gaps_s"] = list(self.itl_gaps)
             doc["itl_gaps_seen"] = self.itl_gaps_seen
             attribution = json.loads(json.dumps(self.attribution))
@@ -610,8 +625,8 @@ class ServingLedger:
             decode_ticks += int(base.get("decode_ticks", 0))
             tick_wall += float(base.get("tick_wall_s", 0.0))
             tick_sync += float(base.get("tick_sync_s", 0.0))
-            for k in MOE_COUNTERS:
-                moe[k] += int(base.get(k, 0))
+            for k in TICK_COUNTERS:
+                counts[k] += int(base.get(k, 0))
             attribution = merge_attribution(base.get("attribution"),
                                             attribution)
             doc["resumed_from_journal"] = True
@@ -639,7 +654,7 @@ class ServingLedger:
             "decode_ticks": decode_ticks,
             "tick_wall_s": tick_wall,
             "tick_sync_s": tick_sync,
-            **moe,
+            **counts,
             "attribution": attribution,
         })
         return _finalize(doc, buckets, wall)
@@ -688,6 +703,12 @@ def note_routing(assignments: int, experts_hit: int, max_load: int) -> None:
     if not _monitor.enabled():
         return
     _LEDGER.note_routing(assignments, experts_hit, max_load)
+
+
+def note_attention(pages_read: int, pages_window: int) -> None:
+    if not _monitor.enabled():
+        return
+    _LEDGER.note_attention(pages_read, pages_window)
 
 
 def note_token_gaps(gaps: Sequence[float]) -> None:
@@ -818,6 +839,13 @@ def status() -> Dict[str, Any]:
         "uptime_seconds": time.time() - _LEDGER.started_unix,
         "reconciliation": reconcile_spans(doc),
     }
+    if doc["attn_pages_window"]:
+        # how much of every slot's window the decode ticks found live
+        out["attention"] = {
+            "pages_read": doc["attn_pages_read"],
+            "pages_window": doc["attn_pages_window"],
+            "window_share": doc["attn_pages_read"] / doc["attn_pages_window"],
+        }
     if (doc.get("attribution") or {}).get("n_requests"):
         out["request_attribution"] = attribution_summary(doc)
         out["attribution_reconciliation"] = reconcile_attribution(doc)
@@ -969,7 +997,7 @@ def merge_ledgers(docs: List[Dict[str, Any]]) -> Dict[str, Any]:
     span_s = slot_s = 0.0
     decode_ticks = 0
     tick_wall = tick_sync = 0.0
-    moe = dict.fromkeys(MOE_COUNTERS, 0)
+    counts = dict.fromkeys(TICK_COUNTERS, 0)
     ranks: List[int] = []
     roofline = None
     max_wall = 0.0
@@ -1016,8 +1044,8 @@ def merge_ledgers(docs: List[Dict[str, Any]]) -> Dict[str, Any]:
         decode_ticks += int(d.get("decode_ticks", 0))
         tick_wall += float(d.get("tick_wall_s", 0.0))
         tick_sync += float(d.get("tick_sync_s", 0.0))
-        for k in MOE_COUNTERS:
-            moe[k] += int(d.get(k, 0))
+        for k in TICK_COUNTERS:
+            counts[k] += int(d.get(k, 0))
         if d.get("rank") is not None:
             ranks.append(int(d["rank"]))
     # replica throughputs add over the LONGEST replica wall (concurrent
@@ -1048,7 +1076,7 @@ def merge_ledgers(docs: List[Dict[str, Any]]) -> Dict[str, Any]:
         "decode_ticks": decode_ticks,
         "tick_wall_s": tick_wall,
         "tick_sync_s": tick_sync,
-        **moe,
+        **counts,
         "attribution": attribution,
         "traffic": traffic,
         "autoscale": autoscale,

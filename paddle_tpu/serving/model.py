@@ -13,9 +13,13 @@ head. Pool, donation, programs, names and insight are one path —
 - **prefill**: the whole (bucket-padded) prompt in one causal pass,
   writing every position's K/V into the request's cache blocks and
   returning the first generated token;
-- **decode**: one token per active batch slot per tick, gathering each
-  request's context through its block table and scattering the new
-  token's K/V into the tail slot.
+- **decode**: one token per active batch slot per tick, scattering the
+  new token's K/V into the tail slot and attending over each request's
+  own context through its block table: on one device the pallas kernel
+  ``ops/pallas/paged_attention`` reads the pages a context occupies
+  where they lie; a mesh program (GSPMD cannot partition a Mosaic call)
+  and a pool whose row the runtime would pad gather the whole window
+  instead (``DecodeModel.attention_path``).
 
 Both are AOT-lowered through ``framework/xla_insight.capture`` — the
 same single compile that produces the executable also yields the
@@ -258,7 +262,8 @@ class DecodeModel:
             prefill_buckets = [int(x) for x in raw.split(",") if x.strip()]
         self.prefill_buckets = sorted(
             min(int(b), cfg.max_seq_len) for b in prefill_buckets)
-        # every request's gather window: the whole (block-padded) context
+        # every request's window: the whole (block-padded) context. The
+        # table's width, and what the gathered formulation reads
         self.max_blocks_per_req = blocks_for_tokens(cfg.max_seq_len,
                                                     self.block_size)
         self.gather_len = self.max_blocks_per_req * self.block_size
@@ -353,6 +358,21 @@ class DecodeModel:
         cfg = self.cfg
         return (cfg.n_layer * self.n_blocks, self.block_size,
                 cfg.n_head * 2 * cfg.head_dim)
+
+    def attention_path(self) -> Tuple[str, str]:
+        """How the decode program attends, and why: ``("kernel", "")`` is
+        ``ops/pallas/paged_attention`` over the pool as it lies;
+        ``("gather", reason)`` the XLA formulation over the gathered
+        window. Decided by what the model can see of itself, the same on
+        every backend."""
+        from ..ops.pallas import paged_attention as pa
+
+        if self.mesh is not None:
+            return "gather", ("a mesh program: GSPMD cannot partition a "
+                              "Mosaic call")
+        why = pa.unsupported(self.cfg.head_dim, self.block_size,
+                             self.cfg.dtype)
+        return ("gather", why) if why else ("kernel", "")
 
     def _pages_sharding(self):
         """KV pool placement, None off a mesh: the row shards over the
@@ -598,13 +618,16 @@ class DecodeModel:
 
     def _build_decode(self):
         """The continuous-batching decode program: one token per slot,
-        per-request context gathered through the block table. Inactive
-        slots carry all-zero tables (reads masked, writes land in the
-        scratch block) so the program is shape-stable at max_batch."""
+        attending over the slot's own context through its block table
+        (:meth:`attention_path`: the paged kernel, or the gathered
+        window). Inactive slots carry all-zero tables (reads masked,
+        writes land in the scratch block) so the program is shape-stable
+        at max_batch."""
         import jax
         import jax.numpy as jnp
 
         from ..ops import moe
+        from ..ops.pallas.paged_attention import paged_attention
 
         cfg, BS, NB = self.cfg, self.block_size, self.n_blocks
         B, H, hd = self.max_batch, cfg.n_head, cfg.head_dim
@@ -613,23 +636,20 @@ class DecodeModel:
         scale = 1.0 / math.sqrt(hd)
         barange = jnp.arange(B)
 
-        @jax.jit  # one trace for all layers: see _layer_params
-        def layer(lp, i, x, pages, block_tables, blk, slot, valid, rot,
-                  live):
-            ln = _LAYER
-            h = self._ln_p(lp, x, f"{ln}.ln1")
-            q, k, v = self._qkv(lp, h, rot, (B,))
-            # the layer is part of the block index: no slice of the pool
-            # is ever materialised
-            with jax.named_scope("attn/kv_write"):
-                pages = pages.at[i * NB + blk, slot].set(_kv_rows(k, v))
+        def attend_paged(q, pages, tables, pos, valid):
+            # the new token's K and V are in the pool: the kernel reads
+            # the pages up to `pos` where they lie, and no other
+            with jax.named_scope("attn/paged"):
+                return paged_attention(q, pages, tables, pos, scale)
+
+        def attend_gathered(q, pages, tables, pos, valid):
             with jax.named_scope("attn/kv_gather"):
                 # [B, MAXB, BS, H*2*hd] seen as [B, S/T, H, T, 2*hd]: T
                 # tokens of one head are one (T, 128) tile of the gathered
                 # rows as they lie in HBM, so this view moves nothing. (A
                 # [B, S, H, 2*hd] view wants H on the sublanes and relays
                 # every layer's context out: a third of the tick.)
-                ctx = pages[i * NB + block_tables].reshape(
+                ctx = pages[tables].reshape(
                     B, S // T, T, H, 2 * hd).transpose(0, 1, 3, 2, 4)
                 kk, vv = ctx[..., :hd], ctx[..., hd:]
             with jax.named_scope("attn/scores"):
@@ -637,8 +657,23 @@ class DecodeModel:
                     B, H, S) * scale
                 s = jnp.where(valid[:, None, :], s, _NEG)
                 a = jax.nn.softmax(s, axis=-1).reshape(B, H, S // T, T)
-                o = jnp.einsum("bhjt,bjhtd->bhd", a, vv).reshape(B, -1)
-            x = x + self._linear(lp, o, f"{ln}.attn.proj")
+                return jnp.einsum("bhjt,bjhtd->bhd", a, vv).reshape(B, -1)
+
+        kernel = self.attention_path()[0] == "kernel"
+        attend = attend_paged if kernel else attend_gathered
+
+        @jax.jit  # one trace for all layers: see _layer_params
+        def layer(lp, i, x, pages, block_tables, blk, slot, pos, valid,
+                  rot, live):
+            ln = _LAYER
+            h = self._ln_p(lp, x, f"{ln}.ln1")
+            q, k, v = self._qkv(lp, h, rot, (B,))
+            # the layer is part of the block index: no slice of the pool
+            # is ever materialised
+            with jax.named_scope("attn/kv_write"):
+                pages = pages.at[i * NB + blk, slot].set(_kv_rows(k, v))
+            x = x + self._linear(lp, attend(q, pages, i * NB + block_tables,
+                                            pos, valid), f"{ln}.attn.proj")
             x, idx = self._ffn(lp, x)
             return x, pages, (None if idx is None else
                               moe.routing_counts(idx, live, cfg.n_experts))
@@ -649,15 +684,17 @@ class DecodeModel:
                 x = self._embed(p, tokens, pos)  # [B, D]
             blk = block_tables[barange, pos // BS]  # [B]
             slot = pos % BS
-            valid = (jnp.arange(S)[None, :] <= pos[:, None])  # [B, S]
+            # the gathered window's mask; the kernel masks by `pos`
+            valid = (None if kernel else
+                     jnp.arange(S)[None, :] <= pos[:, None])  # [B, S]
             rot = self._rot(pos)
             # a slot in use has a prompt behind it; an empty one is at 0
             live = pos > 0 if cfg.mlp == "moe" else None
             routing = []
             for i in range(cfg.n_layer):
                 x, pages, counts = layer(_layer_params(p, i), i, x, pages,
-                                         block_tables, blk, slot, valid,
-                                         rot, live)
+                                         block_tables, blk, slot, pos,
+                                         valid, rot, live)
                 routing.append(counts)
             with jax.named_scope("lm_head"):
                 x = self._ln_p(p, x, "gpt.lnf")

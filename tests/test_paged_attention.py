@@ -1,0 +1,233 @@
+"""The paged-attention kernel (interpreted here) against the gather
+formulation it replaces in the decode program, on the same pool.
+
+The reference below is ``serving/model.py::_build_decode``'s
+``attend_gathered`` with float32 arithmetic: gather every slot's whole
+window through its table, mask by position, softmax, weigh. The kernel
+reads only the pages a context occupies, so what lies anywhere else may be
+anything: the pools here hold NaN in every page no table names.
+"""
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from paddle_tpu.ops.pallas import paged_attention as pa
+from paddle_tpu.ops.pallas.paged_attention import paged_attention
+
+BS, MAXB, NB = 16, 12, 48  # tokens a page, pages a slot's window, pages a layer
+FULL = MAXB * BS - 1  # the last position of the window
+
+
+def gathered(q, pool, tables, lens, scale):
+    B, H, hd = q.shape
+    S = tables.shape[1] * pool.shape[1]
+    ctx = pool[tables].reshape(B, S, H, 2 * hd).astype(jnp.float32)
+    kk, vv = ctx[..., :hd], ctx[..., hd:]
+    s = jnp.einsum("bhd,bshd->bhs", q.astype(jnp.float32), kk) * scale
+    valid = jnp.arange(S)[None, :] <= lens[:, None]
+    a = jax.nn.softmax(jnp.where(valid[:, None, :], s, -1e30), axis=-1)
+    return jnp.einsum("bhs,bshd->bhd", a, vv).reshape(B, -1)
+
+
+def make_case(lens, H, hd, dtype, layer=0, seed=0):
+    """q, a pool of ``layer + 1`` layers, layer-folded tables and lens:
+    each slot owns scattered pages of layer ``layer`` for its context
+    (an empty slot none); table entries behind them name scratch block 0;
+    every page that no table names holds NaN, those of other layers too."""
+    r = np.random.RandomState(seed)
+    B, row = len(lens), H * 2 * hd
+    pool = np.full(((layer + 1) * NB, BS, row), np.nan, np.float32)
+    base = layer * NB
+    tables = np.zeros((B, MAXB), np.int32)
+    free = list(r.permutation(np.arange(1, NB)))
+    pool[base] = r.randn(BS, row)  # whatever idle slots last wrote there
+    for b, n in enumerate(lens):
+        for j in range(0 if n == 0 else n // BS + 1):
+            tables[b, j] = free.pop()
+            pool[base + tables[b, j]] = r.randn(BS, row)
+    q = jnp.asarray(r.randn(B, H, hd), dtype)
+    return (q, jnp.asarray(pool, dtype), jnp.asarray(tables + base),
+            jnp.asarray(np.asarray(lens, np.int32)))
+
+
+def check(q, pool, tables, lens):
+    scale = 1.0 / math.sqrt(q.shape[-1])
+    out = np.asarray(paged_attention(q, pool, tables, lens, scale), np.float32)
+    assert out.shape == (q.shape[0], q.shape[1] * q.shape[2]) and not np.isnan(out).any()
+    want = np.asarray(gathered(q, pool, tables, lens, scale))
+    tol = 2e-5 if pool.dtype == jnp.float32 else 2e-2  # as tests/test_flash_attention.py
+    np.testing.assert_allclose(out, want, rtol=tol, atol=tol)
+    return out
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("H,hd", [(5, 64), (3, 128)])
+@pytest.mark.parametrize("n", [1, BS - 1, BS, BS + 1, 8 * BS - 1, 8 * BS, FULL])
+def test_one_length_against_the_gathered_window(n, H, hd, dtype):
+    """A context of n + 1 tokens (the new one at position n): around a
+    page's edge, around a step's edge (8 pages), and the whole window."""
+    check(*make_case([n], H, hd, dtype))
+
+
+@pytest.mark.parametrize("H,hd", [(5, 64), (25, 64), (16, 128)])
+def test_ragged_batch_with_an_empty_slot_beside_full_ones(H, hd):
+    """Every slot by its own length; slot 0 is empty (position 0, a table
+    of scratch blocks): it costs one page and disturbs no neighbour."""
+    lens = [0, FULL, 1, BS, 0, 3 * BS + 5, FULL, BS - 1]
+    q, pool, tables, ln = make_case(lens, H, hd, "bfloat16")
+    out = check(q, pool, tables, ln)
+    # an empty slot attends to position 0 of the scratch block alone: its V
+    v0 = np.asarray(pool[tables[0, 0], 0].astype(jnp.float32)).reshape(H, 2, hd)[:, 1]
+    np.testing.assert_allclose(out[0], v0.reshape(-1), rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("layer", [0, 3])
+def test_layer_base_is_part_of_the_table(layer):
+    """The layer is folded into the page index (``i * NB + block``): the
+    kernel reads layer ``layer``'s pages and no other layer's (NaN)."""
+    check(*make_case([2 * BS + 3, 0, FULL], 5, 64, "bfloat16", layer=layer))
+
+
+def test_pages_a_slot_does_not_own_never_reach_its_output():
+    """NaN everywhere but in the pages the tables name; and a slot's
+    result is the same whatever lies in its neighbours' pages."""
+    lens = [BS + 2, 5 * BS, 9]
+    q, pool, tables, ln = make_case(lens, 5, 64, "float32")
+    out = check(q, pool, tables, ln)
+    other = np.array(pool)
+    for j in range(lens[1] // BS + 1):  # slot 1's pages, rewritten
+        other[int(tables[1, j])] = 7.0
+    again = np.asarray(paged_attention(q, jnp.asarray(other), tables, ln, 1.0 / 8.0))
+    np.testing.assert_array_equal(again[[0, 2]], out[[0, 2]])
+    assert not np.allclose(again[1], out[1])
+
+
+def test_positions_behind_the_new_token_are_masked_in_its_page():
+    """What the last page holds past position n (an earlier owner's rows)
+    does not count: the result is that of a pool holding zeros there."""
+    n = 2 * BS + 4
+    q, pool, tables, ln = make_case([n], 5, 64, "float32")
+    last = int(tables[0, n // BS])
+    loud = np.array(pool)
+    loud[last, n % BS + 1:] = 1e4
+    a = paged_attention(q, pool, tables, ln, 0.125)
+    b = paged_attention(q, jnp.asarray(loud), tables, ln, 0.125)
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_tables_may_share_pages():
+    """Two slots whose tables name the SAME pages (a shared prefix, or
+    idle slots on scratch block 0) read them independently."""
+    q, pool, tables, ln = make_case([3 * BS + 1, 3 * BS + 1, 0, 0], 5, 64, "bfloat16")
+    tables = tables.at[1].set(tables[0])
+    out = check(q, pool, tables, ln)
+    assert not np.allclose(out[0], out[1])  # same pages, another q
+
+
+@pytest.mark.parametrize("hd,bs,dtype,ok", [
+    (64, 16, "bfloat16", True), (128, 16, "bfloat16", True), (64, 8, "float32", True),
+    (32, 16, "bfloat16", False),  # 64 lanes a head: the runtime pads the row
+    (96, 16, "bfloat16", False),  # 192 lanes
+    (64, 8, "bfloat16", False),  # half a bfloat16 tile a page
+    (64, 48, "float32", False),  # a page that does not divide a step
+])
+def test_geometries_the_kernel_takes_and_refuses(hd, bs, dtype, ok):
+    assert (pa.unsupported(hd, bs, dtype) == "") == ok
+    if not ok:
+        with pytest.raises(ValueError, match="paged_attention"):
+            paged_attention(jnp.zeros((1, 2, hd), dtype), jnp.zeros((4, bs, 4 * hd), dtype),
+                            jnp.zeros((1, 2), jnp.int32), jnp.zeros((1,), jnp.int32), 1.0)
+
+
+def test_decode_model_picks_the_path_from_what_it_is():
+    """One device and a head of whole 128-lane tiles take the kernel; a
+    narrower head, or a mesh, keeps the gathered window. No knob."""
+    from paddle_tpu import serving
+
+    def model(n_head, d_model, **kw):
+        cfg = serving.GPTConfig(vocab_size=64, n_layer=1, n_head=n_head, d_model=d_model,
+                                max_seq_len=64)
+        return serving.DecodeModel(cfg, max_batch=2, n_blocks=8, block_size=16,
+                                   prefill_buckets=[16], **kw)
+
+    assert model(2, 128).attention_path() == ("kernel", "")
+    path, why = model(4, 128).attention_path()
+    assert path == "gather" and "64 lanes" in why
+    path, why = model(2, 128, recipe="tp").attention_path()
+    assert path == "gather" and "mesh" in why
+
+
+# -- the engine through the kernel (a head of 64: one 128-lane tile) -----
+
+
+@pytest.fixture(scope="module")
+def kernel_model():
+    from paddle_tpu import serving
+
+    cfg = serving.GPTConfig(vocab_size=128, n_layer=2, n_head=2, d_model=128, max_seq_len=64)
+    dm = serving.DecodeModel(cfg, max_batch=4, n_blocks=16, block_size=16, prefill_buckets=[16, 32],
+                             seed=3)
+    assert dm.attention_path() == ("kernel", "")
+    return dm
+
+
+def test_engine_tokens_through_the_kernel_are_the_full_forwards_argmax(kernel_model):
+    """Continuous batching over the kernel: every served token is the
+    greedy token of the non-paged forward, and a request decodes to the
+    same tokens alone as beside others (contexts cross a page's edge)."""
+    from paddle_tpu import serving
+    from paddle_tpu.serving import ledger
+
+    ledger.reset()
+    r = np.random.RandomState(0)
+    prompts = [list(r.randint(1, 128, size=n)) for n in (5, 14, 9, 16)]
+    eng = serving.ServingEngine(kernel_model)
+    handles = [eng.submit(p, max_new_tokens=6) for p in prompts]
+    eng.run_until_idle()
+    batched = [h.result(timeout=5) for h in handles]
+    for p, got in zip(prompts, batched):
+        toks = list(p)
+        for _ in range(6):
+            toks.append(int(kernel_model.full_logits(np.asarray(toks))[0, -1].argmax()))
+        assert toks[len(p):] == got
+    alone = serving.ServingEngine(kernel_model)
+    for p, got in zip(prompts, batched):
+        h = alone.submit(p, max_new_tokens=6)
+        alone.run_until_idle()
+        assert h.result(timeout=5) == got
+    ledger.reset()
+
+
+def test_ledger_counts_the_pages_a_tick_reads_and_the_window(kernel_model, tmp_path):
+    """``attn_pages_read``: per decode tick, the pages its live slots'
+    contexts occupy with the new token; ``attn_pages_window``: every
+    slot's whole window. In totals(), on /status, in the journal, merged
+    as sums, cleared by reset()."""
+    import json
+
+    from paddle_tpu import serving
+    from paddle_tpu.serving import ledger
+
+    ledger.reset()
+    eng = serving.ServingEngine(kernel_model)
+    handles = [eng.submit(list(range(1, n + 1)), max_new_tokens=4) for n in (14, 3)]
+    eng.run_until_idle()
+    assert all(len(h.result(timeout=5)) == 4 for h in handles)
+    t = ledger.totals()
+    # 3 decode ticks each: contexts 14, 15, 16 read 1, 1, 2 pages (the new
+    # token opens the second page at position 16); 3, 4, 5 read 1 each
+    assert t["decode_ticks"] == 3 and t["attn_pages_read"] == (1 + 1 + 2) + 3
+    assert t["attn_pages_window"] == 3 * kernel_model.max_batch * kernel_model.max_blocks_per_req == 48
+    att = ledger.status()["attention"]
+    assert att == {"pages_read": 7, "pages_window": 48, "window_share": 7 / 48}
+    with open(ledger.flush(str(tmp_path / "serving.rank0.json"))) as f:
+        journal = json.load(f)
+    assert (journal["attn_pages_read"], journal["attn_pages_window"]) == (7, 48)
+    merged = ledger.merge_ledgers([journal, journal])
+    assert (merged["attn_pages_read"], merged["attn_pages_window"]) == (14, 96)
+    ledger.reset()
+    assert ledger.totals()["attn_pages_read"] == ledger.totals()["attn_pages_window"] == 0
+    assert "attention" not in ledger.status()

@@ -76,6 +76,21 @@ def test_fused_adam_compiles(tpu_arg):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("B,H,hd,rows", [(12, 25, 64, 48 * 432), (24, 16, 128, 12 * 960)])
+def test_paged_attention_compiles_at_both_serving_cells_shapes(tpu_arg, B, H, hd, rows):
+    """The decode kernel alone, over a whole cell's pool (described, not
+    allocated): a head of one 128-lane tile (GPT-2 XL) and of two (OLMoE)."""
+    from paddle_tpu.ops.pallas.paged_attention import paged_attention
+
+    text = _compiled_text(
+        functools.partial(paged_attention, scale=1.0 / math.sqrt(hd), interpret=False),
+        tpu_arg((B, H, hd), jnp.bfloat16), tpu_arg((rows, 16, H * 2 * hd), jnp.bfloat16),
+        tpu_arg((B, 64), jnp.int32), tpu_arg((B,), jnp.int32))
+    assert _kernel_names(text) == ["paged_attention"]
+    # the pool goes to the kernel as it rests: no copy or relayout of it
+    assert not re.search(rf"bf16\[{rows},16,{H * 2 * hd}\][^\n]* (copy|transpose|reshape)\(", text)
+
+
 def test_oversize_tile_is_refused(tpu_arg):
     """The check is live: a (2048, 8192) f32 score tile cannot fit VMEM,
     and the compile-only target says so like the chip would."""
@@ -197,16 +212,49 @@ def test_serving_programs_carry_their_names(serving_programs, name):
 
 
 @pytest.mark.parametrize("name,scopes", [
-    ("decode_tick", ("embed", "jit(layer)/attn/kv_write", "jit(layer)/attn/kv_gather",
-                     "jit(layer)/attn/scores", "jit(layer)/mlp", "lm_head")),
+    ("decode_tick", ("embed", "jit(layer)/attn/kv_write", "jit(layer)/attn/paged", "jit(layer)/mlp",
+                     "lm_head")),
     ("prefill_256", ("embed", "jit(layer)/attn/kv_write", "jit(layer)/attn/scores", "jit(layer)/mlp",
                      "lm_head"))])
 def test_serving_programs_carry_their_scopes_in_op_name(serving_programs, name, scopes):
     """The layer body is an inner jit named ``layer`` (traced once for all
-    layers), so its scopes read ``jit(<program>)/jit(layer)/attn/...``."""
+    layers), so its scopes read ``jit(<program>)/jit(layer)/attn/...``. On
+    one device the decode tick attends inside ``attn/paged`` (the kernel);
+    no window is gathered, so no ``attn/kv_gather`` is left."""
     ops = set(re.findall(r'op_name="([^"]*)"', serving_programs.text[name]))
     for scope in scopes:
         assert any(f"jit({name})/{scope}/" in o for o in ops), (scope, sorted(ops)[:20])
+    assert not any("/attn/kv_gather/" in o for o in ops)
+
+
+def test_compile_report_says_how_the_decode_tick_attends(serving_programs):
+    """tools/serve_compile_report.py: the kernel's calls and VMEM scratch
+    per program, and for a model the kernel cannot take, why, unrun."""
+    import serve_compile_report as report
+
+    from paddle_tpu import serving
+
+    dm, facts = serving_programs.dm, serving_programs.facts
+    assert facts["decode_tick"]["mosaic_kernels"] == {"paged_attention": dm.cfg.n_layer}
+    assert facts["prefill_256"]["mosaic_kernels"] == {}
+    att = report.attention_facts(dm, facts["decode_tick"]["mosaic_kernels"])
+    assert att["decode_path"] == "kernel" and att["paged_attention_calls"] == 2
+    # two buffers of 128 rows of 3,200 bf16 lanes, the float32 accumulator, two statistics
+    assert att["vmem_scratch_bytes"] == 2 * 128 * 3200 * 2 + 32 * 3200 * 4 + 2 * 32 * 128 * 4
+    assert att["vmem_scratch_bytes"] < 16 * 2 ** 20
+    narrow = report.abstract_model(
+        serving.GPTConfig(vocab_size=64, n_layer=1, n_head=50, d_model=1600, max_seq_len=64,
+                          dtype="bfloat16"), max_batch=2, n_blocks=8, block_size=16, prefill_buckets=[16])
+    att = report.attention_facts(narrow, {})
+    assert att["decode_path"] == "gather" and "64 lanes" in att["why"] and att["vmem_scratch_bytes"] == 0
+
+
+def test_decode_tick_holds_one_paged_attention_kernel_a_layer(serving_programs):
+    """25 heads of 64: a head's K|V is ONE 128-lane tile of the row."""
+    dm = serving_programs.dm
+    assert dm.attention_path() == ("kernel", "")
+    assert _kernel_names(serving_programs.text["decode_tick"]) == ["paged_attention"] * dm.cfg.n_layer
+    assert _kernel_names(serving_programs.text["prefill_256"]) == []
 
 
 # The KV pool stays where it is (PERF.md, PR 25): XLA:TPU stores an array in
@@ -237,13 +285,19 @@ def test_serving_program_copies_neither_pool_nor_gathered_context(serving_progra
 
 @pytest.mark.parametrize("name", _SERVING_PROGRAMS)
 def test_serving_program_holds_no_second_pool(serving_programs, name):
-    """Temporaries stay under the pool plus the one gathered context a
-    layer works on (2 layers here: the pool alone is smaller than that)."""
+    """Temporaries stay under the pool plus one layer's activations: no
+    gathered context is among them any more (78.6 MB a layer before the
+    kernel read the pages where they lie)."""
     dm = serving_programs.dm
+    cfg = dm.cfg
     pool_bytes = 2 * math.prod(dm.pool_shape())
-    context_bytes = 2 * dm.max_batch * dm.gather_len * dm.pool_shape()[-1]
+    rows = dm.max_batch if name == "decode_tick" else 256
+    activations = rows * (cfg.ffn_dim + 8 * cfg.d_model + cfg.vocab_size) * 4
     temp = serving_programs.facts[name]["memory"]["temp_size_in_bytes"]
-    assert temp < pool_bytes + context_bytes, (temp, pool_bytes, context_bytes)
+    assert temp < pool_bytes + activations, (temp, pool_bytes, activations)
+    if name == "decode_tick":
+        context_bytes = 2 * dm.max_batch * dm.gather_len * dm.pool_shape()[-1]
+        assert temp < context_bytes / 4, (temp, context_bytes)
 
 
 @pytest.mark.parametrize("name", _SERVING_PROGRAMS)
@@ -322,8 +376,7 @@ def olmoe_programs(tpu_device):
 
 @pytest.mark.parametrize("name,scopes", [
     ("decode_tick", ("embed", "jit(layer)/attn/qk_norm", "jit(layer)/attn/rope", "jit(layer)/attn/kv_write",
-                     "jit(layer)/attn/kv_gather", "jit(layer)/attn/scores", "jit(layer)/moe/route",
-                     "jit(layer)/moe/experts", "lm_head")),
+                     "jit(layer)/attn/paged", "jit(layer)/moe/route", "jit(layer)/moe/experts", "lm_head")),
     ("prefill_256", ("embed", "jit(layer)/attn/qk_norm", "jit(layer)/attn/rope", "jit(layer)/attn/kv_write",
                      "jit(layer)/attn/scores", "jit(layer)/moe/route", "jit(layer)/moe/experts", "lm_head"))])
 def test_olmoe_programs_carry_their_names_and_scopes(olmoe_programs, name, scopes):
@@ -336,7 +389,15 @@ def test_olmoe_programs_carry_their_names_and_scopes(olmoe_programs, name, scope
     ops = set(re.findall(r'op_name="([^"]*)"', text))
     for scope in scopes:
         assert any(f"jit({name})/{scope}/" in o for o in ops), (scope, sorted(ops)[:20])
-    assert not any("/mlp/" in o for o in ops)
+    assert not any("/mlp/" in o or "/attn/kv_gather/" in o for o in ops)
+
+
+def test_olmoe_decode_tick_holds_one_paged_attention_kernel_a_layer(olmoe_programs):
+    """16 heads of 128: a head's K and V are two aligned 128-lane tiles."""
+    dm = olmoe_programs.dm
+    assert dm.attention_path() == ("kernel", "")
+    assert _kernel_names(olmoe_programs.text["decode_tick"]) == ["paged_attention"] * dm.cfg.n_layer
+    assert _kernel_names(olmoe_programs.text["prefill_256"]) == []
 
 
 @pytest.mark.parametrize("name", _SERVING_PROGRAMS)
@@ -365,18 +426,18 @@ def test_olmoe_program_moves_neither_context_nor_expert_weights(olmoe_programs, 
 
 @pytest.mark.parametrize("name", _SERVING_PROGRAMS)
 def test_olmoe_temporaries_are_bounded_and_twelve_layers_fit_the_chip(olmoe_programs, name):
-    """Temporaries: under one gathered context plus one layer's expert
-    activations. The whole cell: 12 layers of weights, their pool, these
-    temporaries (a layer's are reused by the next) and the code, under the
-    chip's 16 GB with a tenth to spare."""
+    """Temporaries: under one layer's expert activations alone (the decode
+    tick gathers no context: 201 MB a layer before the kernel). The whole
+    cell: 12 layers of weights, their pool, these temporaries (a layer's
+    are reused by the next) and the code, under the chip's 16 GB with a
+    tenth to spare."""
     from benchmark import arch, manifest
 
     dm, mem = olmoe_programs.dm, olmoe_programs.facts[name]["memory"]
     cfg = dm.cfg
-    context = 2 * dm.max_batch * dm.gather_len * dm.pool_shape()[-1]
     rows = dm.max_batch if name == "decode_tick" else 256
     activations = cfg.n_experts * rows * (2 * cfg.ffn_dim * 2 + cfg.d_model * (2 + 4))
-    assert mem["temp_size_in_bytes"] < context + activations, (mem, context, activations)
+    assert mem["temp_size_in_bytes"] < activations, (mem, activations)
     conf = manifest.cell(manifest.load(), "olmoe-serve-batch")["config"]
     weights = 2 * arch.of(conf).n_params(conf)
     pool = 12 * 960 * 16 * 4096 * 2

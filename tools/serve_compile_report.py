@@ -5,8 +5,11 @@ attached, so the questions that decide a serving change's memory traffic
 are answered here in seconds and at no chip time: which layout the KV
 pool has at the program's edge, whether the output aliases it, which
 whole-array ``copy`` (and unfused ``reshape`` / ``transpose``: a relayout)
-instructions the compiler put into the program, and ``memory_analysis()``. Sizes and instruction names only: nothing runs, so
-no time comes out of this tool.
+instructions the compiler put into the program, which Mosaic kernels it
+holds, how the decode tick attends (the ``paged_attention`` kernel, or the
+gathered window and why: a model whose K|V row is not whole 128-lane tiles
+shows here, before a chip run) and ``memory_analysis()``. Sizes and
+instruction names only: nothing runs, so no time comes out of this tool.
 
 The programs are the ones ``DecodeModel`` serves with: its own functions
 under its own jit wrapper (donation, shardings) at its own pool
@@ -93,14 +96,24 @@ def serving_programs(dm) -> Dict[str, Tuple[Any, tuple]]:
 
 
 def compile_on(jit_fn, args, device):
-    """Compile ``jit_fn`` at ``args`` with every leaf placed on ``device``."""
+    """Compile ``jit_fn`` at ``args`` with every leaf placed on ``device``.
+    A pallas kernel in it is lowered through Mosaic, as on the chip: the
+    package decides that by ``on_tpu()``, which sees this host's CPU."""
+    import importlib
+
     import jax
     from jax.sharding import SingleDeviceSharding
 
     sh = SingleDeviceSharding(device)
     placed = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), args)
-    return jit_fn.lower(*placed).compile()
+    # by name: the package's attribute `flash_attention` is a function
+    kernel = importlib.import_module("paddle_tpu.ops.pallas.paged_attention")
+    on_tpu, kernel.on_tpu = kernel.on_tpu, lambda: True
+    try:
+        return jit_fn.lower(*placed).compile()
+    finally:
+        kernel.on_tpu = on_tpu
 
 
 def entry_instructions(hlo_text: str) -> List[Dict[str, Any]]:
@@ -121,6 +134,22 @@ def entry_instructions(hlo_text: str) -> List[Dict[str, Any]]:
     return out
 
 
+def attention_facts(dm, mosaic_kernels: Dict[str, int]) -> Dict[str, Any]:
+    """How ``dm``'s decode tick attends (``DecodeModel.attention_path``)
+    beside what a compiled program holds of it: the kernel's calls (one a
+    layer in the decode tick, none in a prefill) and its VMEM scratch."""
+    from paddle_tpu.ops.pallas import paged_attention as pa
+
+    path, why = dm.attention_path()
+    calls = mosaic_kernels.get("paged_attention", 0)
+    cfg = dm.cfg
+    return {"decode_path": path,
+            "why": why or "one device, heads of whole 128-lane tiles, pages of whole tiles",
+            "paged_attention_calls": calls,
+            "vmem_scratch_bytes": pa.vmem_scratch_bytes(
+                cfg.n_head, cfg.head_dim, dm.block_size, cfg.dtype) if calls else 0}
+
+
 def describe(compiled, pool_shape: Tuple[int, ...]) -> Dict[str, Any]:
     """What the compiled program does with a pool of ``pool_shape``."""
     text = compiled.as_text()
@@ -137,6 +166,9 @@ def describe(compiled, pool_shape: Tuple[int, ...]) -> Dict[str, Any]:
         (i["op"], f"{i['dtype']}[{','.join(map(str, i['dims']))}]", i["elements"])
         for i in instrs if i["op"] in ("copy", "reshape", "transpose"))
     mem = compiled.memory_analysis()
+    kernels = collections.Counter(
+        re.sub(r"\.\d+$", "", n) for n in re.findall(
+            r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text))
     return {
         "module": re.match(r"HloModule (\S+?),", text).group(1),
         "pool": {"shape": list(pool), "parameter": param_no,
@@ -147,6 +179,7 @@ def describe(compiled, pool_shape: Tuple[int, ...]) -> Dict[str, Any]:
                  "layouts_in_program": sorted({i["layout"] or "" for i in instrs
                                                if i["dims"] == pool and i["op"] != "parameter"})},
         "aliased_parameters": aliased,
+        "mosaic_kernels": dict(sorted(kernels.items())),
         "top_level_copies": [{"op": op, "shape": shape, "count": n, "elements": elements}
                              for (op, shape, elements), n in sorted(copies.items())],
         "memory": {k: int(getattr(mem, k)) for k in
@@ -192,7 +225,9 @@ def main(argv=None) -> int:
             os.makedirs(a.hlo_dir, exist_ok=True)
             with open(os.path.join(a.hlo_dir, f"{name}.hlo"), "w") as f:
                 f.write(compiled.as_text())
-        print(json.dumps({name: describe(compiled, dm.pool_shape())}, indent=1))
+        facts = describe(compiled, dm.pool_shape())
+        facts["attention"] = attention_facts(dm, facts["mosaic_kernels"])
+        print(json.dumps({name: facts}, indent=1))
     return 0
 
 
