@@ -42,15 +42,13 @@ ledger triplet:
   ICI-bytes correction (``planner.calibrate`` /
   ``topology.roofline(payload_by_link_class=...)``).
 
-Journal contract (the goodput/memwatch one, comms-shaped):
-``PADDLE_TPU_COMMSWATCH_DIR/commswatch.rank<k>.json``, atomic writes,
-pristine-guard restart resume, rank re-anchor via
-``monitor.set_trainer_rank``, cross-rank :func:`merge_ledgers`.
+Journal (paddle_tpu/journal.py has the contract):
+``PADDLE_TPU_COMMSWATCH_DIR/commswatch.rank<k>.json``, cross-rank
+:func:`merge_ledgers`.
 
 Env knobs (declared in paddle_tpu/flags.py):
   PADDLE_TPU_COMMSWATCH                 ledger on/off (default on)
   PADDLE_TPU_COMMSWATCH_DIR             journal directory (persistence)
-  PADDLE_TPU_COMMSWATCH_FLUSH_STEPS     journal flush cadence (50)
   PADDLE_TPU_COMMSWATCH_PROBE_EVERY     barrier-skew probe cadence in
                                         steps (0 = off)
   PADDLE_TPU_COMMSWATCH_SKEW_FLOOR_MS   skew episode floor (50ms)
@@ -60,18 +58,16 @@ Env knobs (declared in paddle_tpu/flags.py):
 """
 from __future__ import annotations
 
-import atexit
 import collections
-import glob
-import json
 import math
 import os
 import sys
 import threading
 import time
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional
 
 from . import flags as _flags
+from . import journal as _journal
 from . import monitor as _monitor
 
 __all__ = [
@@ -505,11 +501,6 @@ class CommsLedger:
 
 
 _LEDGER = CommsLedger()
-_JOURNAL_DIR: Optional[str] = None
-_FLUSH_STEPS = max(
-    1, int(_flags.env_flag("PADDLE_TPU_COMMSWATCH_FLUSH_STEPS")))
-_steps_since_flush = 0
-_atexit_registered = False
 _PROBE_SEQ = 0
 
 
@@ -519,9 +510,8 @@ def ledger() -> CommsLedger:
 
 def reset() -> None:
     """Drop everything recorded (journal base included); tests."""
-    global _steps_since_flush
     _LEDGER.reset()
-    _steps_since_flush = 0
+    _JOURNAL.reset()
 
 
 def record_bandwidth(kind: str, axis: str, payload_bytes: float,
@@ -566,7 +556,6 @@ def end_step(collective_seconds: float = 0.0,
     step's ``collective`` bucket seconds, so every step driver — hapi
     fit, bench, custom loops — participates for free) and run the
     sampled barrier-skew probe when the cadence hits."""
-    global _steps_since_flush
     if not enabled():
         return None
     closed = _LEDGER.end_step(collective_seconds, step=step)
@@ -575,14 +564,8 @@ def end_step(collective_seconds: float = 0.0,
             if row["bytes_per_sec"]:
                 _M_AXIS_BPS.labels(axis=axis).set(row["bytes_per_sec"])
     maybe_probe(step)
-    if _JOURNAL_DIR is not None and closed is not None:
-        _steps_since_flush += 1
-        if _steps_since_flush >= _FLUSH_STEPS:
-            _steps_since_flush = 0
-            try:
-                flush()
-            except OSError:
-                pass  # a full disk must not kill the training loop
+    if closed is not None:
+        _JOURNAL.flush_if_due()
     return closed
 
 
@@ -766,107 +749,21 @@ def reconcile(doc: Optional[Dict[str, Any]] = None,
 
 
 # ---------------------------------------------------------------------------
-# journal persistence (the goodput/memwatch contract, comms-shaped)
+# journal persistence (journal.py has the contract)
 # ---------------------------------------------------------------------------
 
 
-def journal_path(dir: Optional[str] = None) -> str:
-    base = dir or _JOURNAL_DIR or "."
-    return os.path.join(base,
-                        f"commswatch.rank{_monitor.trainer_rank()}.json")
+def _unused() -> bool:
+    return (_LEDGER.steps == 0 and _LEDGER.probes == 0
+            and not _LEDGER.bandwidth)
 
 
 def configure(dir: Optional[str] = None,
               flush_steps: Optional[int] = None,
               resume: bool = True) -> None:
     """Set up journal persistence; with ``resume``, an existing journal
-    seeds the step/episode base — but only while the in-process ledger
-    is still pristine (the goodput double-count guard)."""
-    global _JOURNAL_DIR, _FLUSH_STEPS, _atexit_registered
-    if dir:
-        _JOURNAL_DIR = dir
-        pristine = (_LEDGER.base is None and _LEDGER.steps == 0
-                    and _LEDGER.probes == 0 and not _LEDGER.bandwidth)
-        if resume and pristine:
-            path = journal_path(dir)
-            if os.path.exists(path):
-                try:
-                    _LEDGER.base = load_journal(path)
-                except (OSError, ValueError):
-                    _LEDGER.base = None  # torn/alien file: start fresh
-        if not _atexit_registered:
-            _atexit_registered = True
-            atexit.register(_flush_at_exit)
-    if flush_steps is not None:
-        _FLUSH_STEPS = max(1, int(flush_steps))
-
-
-def disable_persistence() -> None:
-    """Supervisor hook (distributed/launch.py): its own exit must never
-    clobber a real rank's journal."""
-    global _JOURNAL_DIR
-    _JOURNAL_DIR = None
-
-
-def _rank_changed() -> None:
-    """monitor.set_trainer_rank() notification — mirror of
-    goodput._rank_changed: drop the old identity's base, re-resume
-    against the new rank's journal while still pristine."""
-    if _JOURNAL_DIR is None:
-        return
-    _LEDGER.base = None
-    if _LEDGER.steps == 0 and _LEDGER.probes == 0:
-        path = journal_path()
-        if os.path.exists(path):
-            try:
-                _LEDGER.base = load_journal(path)
-            except (OSError, ValueError):
-                _LEDGER.base = None
-
-
-def _flush_at_exit() -> None:
-    try:
-        flush()
-    except OSError:
-        pass
-
-
-def flush(path: Optional[str] = None) -> Optional[str]:
-    """Write the ledger journal (atomic temp + os.replace). No-op when
-    persistence is unconfigured and no path given."""
-    if path is None:
-        if _JOURNAL_DIR is None:
-            return None
-        path = journal_path()
-    return _monitor.atomic_write_text(path, json.dumps(totals(), indent=1))
-
-
-def load_journal(path: str) -> Dict[str, Any]:
-    with open(path) as f:
-        doc = json.load(f)
-    if doc.get("schema") != SCHEMA:
-        raise ValueError(f"{path}: not a commswatch journal (schema "
-                         f"{doc.get('schema')!r})")
-    return doc
-
-
-def load_journals(dir: str,
-                  ranks: Optional[Sequence[int]] = None
-                  ) -> Optional[Dict[str, Any]]:
-    """Merge per-rank commswatch journals in ``dir`` (obs_report
-    --comms, launch teardown). ``ranks`` limits to this job's
-    membership."""
-    want = set(int(r) for r in ranks) if ranks is not None else None
-    docs = []
-    for path in sorted(glob.glob(
-            os.path.join(dir, "commswatch.rank*.json"))):
-        try:
-            doc = load_journal(path)
-        except (OSError, ValueError):
-            continue
-        if want is None or int(doc.get("rank", -1)) in want:
-            docs.append(doc)
-    return merge_ledgers(docs) if docs else None
+    seeds the step/episode base."""
+    _JOURNAL.configure(dir, every=flush_steps, resume=resume)
 
 
 def merge_ledgers(docs: List[Dict[str, Any]]) -> Dict[str, Any]:
@@ -1016,12 +913,13 @@ def render_summary(doc: Dict[str, Any], title: str = "interconnect") -> str:
     return "\n".join(lines)
 
 
-# env-driven wiring: under launch.py (or a user export) every rank
-# persists its interconnect ledger with no code change
-_env_dir = _flags.env_flag("PADDLE_TPU_COMMSWATCH_DIR")
-if _env_dir:
-    try:
-        os.makedirs(_env_dir, exist_ok=True)
-        configure(dir=_env_dir)
-    except OSError:
-        pass  # unwritable dir: accounting stays in-process only
+# under launch.py (or a user export of PADDLE_TPU_COMMSWATCH_DIR) every
+# rank persists its interconnect ledger with no code change
+_JOURNAL = _journal.Journal(
+    globals(), _LEDGER, "commswatch", SCHEMA, "PADDLE_TPU_COMMSWATCH_DIR",
+    snapshot=totals, unused=_unused, merge=merge_ledgers)
+journal_path = _JOURNAL.path
+disable_persistence = _JOURNAL.disable_persistence
+flush = _JOURNAL.flush
+load_journal = _JOURNAL.load
+load_journals = _JOURNAL.load_merged

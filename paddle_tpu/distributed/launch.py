@@ -139,24 +139,16 @@ def get_cluster_endpoints(ips: List[str], nproc: int, port: int) -> List[str]:
 
 def _shed_rank_observability() -> None:
     """The launcher imports paddle_tpu itself, so with the
-    rank-observability env exported (PADDLE_TPU_STATUS_PORT /
-    PADDLE_TPU_GOODPUT_DIR) the import wiring gave THIS process a rank
-    identity it must not keep: release the status port (or rank 0's
-    bind at base+0 fails) and drop journal persistence (or the
-    launcher's exit flush clobbers rank 0's journal)."""
+    rank-observability env exported (PADDLE_TPU_STATUS_PORT, the
+    PADDLE_TPU_*_DIR journal directories) the import wiring gave THIS
+    process a rank identity it must not keep: release the status port
+    (or rank 0's bind at base+0 fails) and drop journal persistence (or
+    the launcher's exit flush clobbers rank 0's journals)."""
     try:
-        from .. import commswatch, dynamics, goodput, memwatch, status
-        from ..serving import ledger as serving_ledger
+        from .. import journal, status
 
         status.stop_status_server()
-        goodput.disable_persistence()
-        memwatch.disable_persistence()
-        dynamics.disable_persistence()
-        commswatch.disable_persistence()
-        # the serving env shares the shedding idiom: a supervisor that
-        # inherited PADDLE_TPU_SERVE_DIR must not clobber replica 0's
-        # serving journal with its own (empty) exit flush
-        serving_ledger.disable_persistence()
+        journal.disable_persistence()
     except Exception:
         pass  # observability shedding must never block the launch
 

@@ -52,19 +52,17 @@ Env knobs (declared in paddle_tpu/flags.py):
 """
 from __future__ import annotations
 
-import atexit
 import collections
-import glob
 import json
 import math
 import os
-import re
 import sys
 import threading
 import time
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 from . import flags as _flags
+from . import journal as _journal
 from . import monitor as _monitor
 
 __all__ = [
@@ -465,10 +463,6 @@ class DynamicsLedger:
 
 
 _LEDGER = DynamicsLedger()
-_JOURNAL_DIR: Optional[str] = None
-_FLUSH_STEPS = max(1, int(_flags.env_flag("PADDLE_TPU_DYNAMICS_FLUSH_STEPS")))
-_steps_since_flush = 0
-_atexit_registered = False
 
 
 def ledger() -> DynamicsLedger:
@@ -477,9 +471,8 @@ def ledger() -> DynamicsLedger:
 
 def reset() -> None:
     """Drop everything recorded (journal base included); tests."""
-    global _steps_since_flush
     _LEDGER.reset()
-    _steps_since_flush = 0
+    _JOURNAL.reset()
 
 
 def feed(loss: Optional[float] = None, grad_norm: Optional[float] = None,
@@ -498,7 +491,6 @@ def end_step(step: Optional[int] = None) -> Optional[dict]:
     step driver participates for free). Feeds the metric series, the
     flight recorder and the journal flush cadence; emits ONE stderr
     warning per started anomaly episode."""
-    global _steps_since_flush
     if not enabled():
         return None
     closed = _LEDGER.end_step(step=step)
@@ -508,14 +500,7 @@ def end_step(step: Optional[int] = None) -> Optional[dict]:
     # run from the ledger's on_finalize hook (_post_finalize below): for
     # sync steps that already happened inside end_step; an async-loss
     # step reports when its device scalars land (the next step / drain)
-    if _JOURNAL_DIR is not None:
-        _steps_since_flush += 1
-        if _steps_since_flush >= _FLUSH_STEPS:
-            _steps_since_flush = 0
-            try:
-                flush()
-            except OSError:
-                pass  # a full disk must not kill the training loop
+    _JOURNAL.flush_if_due()
     return closed
 
 
@@ -726,123 +711,43 @@ def layer_breakdown(named_params: Iterable[Tuple[str, Any, Any]],
 
 
 # ---------------------------------------------------------------------------
-# journal persistence (the goodput/memwatch contract, line-oriented:
-# header line + one JSON line per closed step)
+# journal persistence (journal.py has the contract), line-oriented:
+# header line + one JSON line per closed step
 # ---------------------------------------------------------------------------
 
 
-def journal_path(dir: Optional[str] = None) -> str:
-    base = dir or _JOURNAL_DIR or "."
-    return os.path.join(base,
-                        f"dynamics.rank{_monitor.trainer_rank()}.jsonl")
+def _unused() -> bool:
+    return _LEDGER.steps == 0 and not _LEDGER.open
+
+
+def _encode(doc: Dict[str, Any]) -> str:
+    """Line 1 is the header doc, each following line one closed step:
+    greppable, tail-able, and append-shaped without sacrificing the
+    atomicity whole-file replacement buys."""
+    doc = dict(doc)
+    series = doc.pop("series", [])
+    lines = [json.dumps(doc)]
+    lines.extend(json.dumps(s) for s in series)
+    return "\n".join(lines) + "\n"
+
+
+def _decode(text: str) -> Dict[str, Any]:
+    """One doc back: the header fields plus the step records under
+    "series"."""
+    lines = [ln for ln in text.splitlines() if ln.strip()]
+    if not lines:
+        raise ValueError("empty dynamics journal")
+    header = json.loads(lines[0])
+    header["series"] = [json.loads(ln) for ln in lines[1:]]
+    return header
 
 
 def configure(dir: Optional[str] = None,
               flush_steps: Optional[int] = None,
               resume: bool = True) -> None:
     """Set up journal persistence; with `resume`, an existing journal
-    seeds the step count, anomaly totals and the trajectory prefix — but
-    only while the in-process ledger is still pristine (the goodput
-    double-count guard)."""
-    global _JOURNAL_DIR, _FLUSH_STEPS, _atexit_registered
-    if dir:
-        _JOURNAL_DIR = dir
-        pristine = (_LEDGER.base is None and _LEDGER.steps == 0
-                    and not _LEDGER.open)
-        if resume and pristine:
-            path = journal_path(dir)
-            if os.path.exists(path):
-                try:
-                    _LEDGER.base = load_journal(path)
-                except (OSError, ValueError):
-                    _LEDGER.base = None  # torn/alien file: start fresh
-        if not _atexit_registered:
-            _atexit_registered = True
-            atexit.register(_flush_at_exit)
-    if flush_steps is not None:
-        _FLUSH_STEPS = max(1, int(flush_steps))
-
-
-def disable_persistence() -> None:
-    """Supervisor hook (distributed/launch.py): its own exit must never
-    clobber a real rank's journal."""
-    global _JOURNAL_DIR
-    _JOURNAL_DIR = None
-
-
-def _rank_changed() -> None:
-    """monitor.set_trainer_rank() notification — mirror of
-    goodput._rank_changed: drop the old identity's base, re-resume
-    against the new rank's journal while still pristine."""
-    if _JOURNAL_DIR is None:
-        return
-    _LEDGER.base = None
-    if _LEDGER.steps == 0 and not _LEDGER.open:
-        path = journal_path()
-        if os.path.exists(path):
-            try:
-                _LEDGER.base = load_journal(path)
-            except (OSError, ValueError):
-                _LEDGER.base = None
-
-
-def _flush_at_exit() -> None:
-    try:
-        flush()
-    except OSError:
-        pass
-
-
-def flush(path: Optional[str] = None) -> Optional[str]:
-    """Write the journal (atomic temp + os.replace, like every other
-    ledger): line 1 is the header doc, each following line one closed
-    step — greppable, tail-able, and append-shaped without sacrificing
-    the atomicity whole-file replacement buys. No-op when persistence is
-    unconfigured and no path given."""
-    if path is None:
-        if _JOURNAL_DIR is None:
-            return None
-        path = journal_path()
-    doc = totals()
-    series = doc.pop("series", [])
-    lines = [json.dumps(doc)]
-    lines.extend(json.dumps(s) for s in series)
-    return _monitor.atomic_write_text(path, "\n".join(lines) + "\n")
-
-
-def load_journal(path: str) -> Dict[str, Any]:
-    """Read a dynamics journal back into one doc: the header fields plus
-    the step records under "series"."""
-    with open(path) as f:
-        lines = [ln for ln in f.read().splitlines() if ln.strip()]
-    if not lines:
-        raise ValueError(f"{path}: empty dynamics journal")
-    header = json.loads(lines[0])
-    if header.get("schema") != SCHEMA:
-        raise ValueError(f"{path}: not a dynamics journal (schema "
-                         f"{header.get('schema')!r})")
-    header["series"] = [json.loads(ln) for ln in lines[1:]]
-    return header
-
-
-_JOURNAL_FILE_RE = re.compile(r"dynamics\.rank(\d+)\.jsonl$")
-
-
-def load_journals(dir: str,
-                  ranks: Optional[Sequence[int]] = None
-                  ) -> Optional[Dict[str, Any]]:
-    """Merge per-rank dynamics journals in `dir` (launch teardown,
-    obs_report --dynamics). `ranks` limits to this job's membership."""
-    want = set(int(r) for r in ranks) if ranks is not None else None
-    docs = []
-    for path in sorted(glob.glob(os.path.join(dir, "dynamics.rank*.jsonl"))):
-        try:
-            doc = load_journal(path)
-        except (OSError, ValueError):
-            continue
-        if want is None or int(doc.get("rank", -1)) in want:
-            docs.append(doc)
-    return merge_ledgers(docs) if docs else None
+    seeds the step count, anomaly totals and the trajectory prefix."""
+    _JOURNAL.configure(dir, every=flush_steps, resume=resume)
 
 
 # the desync probe's final-comparison window (closed steps per rank) and
@@ -967,12 +872,15 @@ def render_summary(doc: Dict[str, Any], title: str = "dynamics") -> str:
     return "\n".join(lines)
 
 
-# env-driven wiring: under launch.py (or a user export) every rank
-# persists its dynamics journal with no code change
-_env_dir = _flags.env_flag("PADDLE_TPU_DYNAMICS_DIR")
-if _env_dir:
-    try:
-        os.makedirs(_env_dir, exist_ok=True)
-        configure(dir=_env_dir)
-    except OSError:
-        pass  # unwritable dir: telemetry stays in-process only
+# under launch.py (or a user export of PADDLE_TPU_DYNAMICS_DIR) every
+# rank persists its dynamics ledger with no code change
+_JOURNAL = _journal.Journal(
+    globals(), _LEDGER, "dynamics", SCHEMA, "PADDLE_TPU_DYNAMICS_DIR",
+    snapshot=totals, unused=_unused, merge=merge_ledgers,
+    every=_flags.env_flag("PADDLE_TPU_DYNAMICS_FLUSH_STEPS"),
+    ext=".jsonl", encode=_encode, decode=_decode)
+journal_path = _JOURNAL.path
+disable_persistence = _JOURNAL.disable_persistence
+flush = _JOURNAL.flush
+load_journal = _JOURNAL.load
+load_journals = _JOURNAL.load_merged

@@ -227,9 +227,6 @@ define_env_flag(
     "atomic writes) into this directory; a restarted rank resumes its "
     "lifetime peak from it")
 define_env_flag(
-    "PADDLE_TPU_MEMWATCH_FLUSH_STEPS", 50,
-    "flush the memwatch journal every N closed steps (plus once at exit)")
-define_env_flag(
     "PADDLE_TPU_MEMWATCH_LEAK_STEPS", 30,
     "steady-state leak detector: this many consecutive closed steps of "
     "monotonic bytes_in_use growth raise a leak-suspect event")
@@ -281,10 +278,6 @@ define_env_flag(
     "persist the per-rank interconnect ledger journal "
     "(commswatch.rank<k>.json, atomic writes) into this directory; a "
     "restarted rank resumes its step/episode base from it")
-define_env_flag(
-    "PADDLE_TPU_COMMSWATCH_FLUSH_STEPS", 50,
-    "flush the commswatch journal every N closed steps (plus once at "
-    "exit)")
 define_env_flag(
     "PADDLE_TPU_COMMSWATCH_PROBE_EVERY", 0,
     "barrier-skew straggler probe cadence: every N closed training "

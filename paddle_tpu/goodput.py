@@ -43,15 +43,13 @@ Env knobs (declared in paddle_tpu/flags.py):
 """
 from __future__ import annotations
 
-import atexit
-import glob
-import json
 import os
 import threading
 import time
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional
 
 from . import flags as _flags
+from . import journal as _journal
 from . import monitor as _monitor
 
 __all__ = [
@@ -251,10 +249,6 @@ class GoodputLedger:
 
 
 _LEDGER = GoodputLedger()
-_JOURNAL_DIR: Optional[str] = None
-_FLUSH_STEPS = max(1, int(_flags.env_flag("PADDLE_TPU_GOODPUT_FLUSH_STEPS")))
-_steps_since_flush = 0
-_atexit_registered = False
 
 
 def ledger() -> GoodputLedger:
@@ -263,9 +257,8 @@ def ledger() -> GoodputLedger:
 
 def reset() -> None:
     """Drop all recorded attribution (journal base included); tests."""
-    global _steps_since_flush
     _LEDGER.reset()
-    _steps_since_flush = 0
+    _JOURNAL.reset()
 
 
 def add(bucket: str, seconds: float) -> None:
@@ -288,7 +281,6 @@ def end_step(wall_seconds: float, samples: Optional[float] = None,
              step: Optional[int] = None) -> Optional[dict]:
     """Close the current step (drivers: hapi fit loop, custom loops).
     Feeds the goodput metric series and the journal flush cadence."""
-    global _steps_since_flush
     if not _monitor.enabled():
         return None
     closed = _LEDGER.end_step(wall_seconds, samples=samples, step=step)
@@ -325,14 +317,7 @@ def end_step(wall_seconds: float, samples: Optional[float] = None,
         _M_FRACTION.set(t["goodput_fraction"])
     if t["step_seconds_ema"] is not None:
         _M_STEP_EMA.set(t["step_seconds_ema"])
-    if _JOURNAL_DIR is not None:
-        _steps_since_flush += 1
-        if _steps_since_flush >= _FLUSH_STEPS:
-            _steps_since_flush = 0
-            try:
-                flush()
-            except OSError:
-                pass  # a full disk must not kill the training loop
+    _JOURNAL.flush_if_due()
     return closed
 
 
@@ -378,115 +363,18 @@ def status() -> Dict[str, Any]:
 # ---------------------------------------------------------------------------
 
 
-def journal_path(dir: Optional[str] = None) -> str:
-    base = dir or _JOURNAL_DIR or "."
-    return os.path.join(base,
-                        f"goodput.rank{_monitor.trainer_rank()}.json")
+def _unused() -> bool:
+    return _LEDGER.steps == 0 and _LEDGER.mark() == 0.0
 
 
 def configure(dir: Optional[str] = None,
               flush_steps: Optional[int] = None,
               resume: bool = True) -> None:
-    """Set up journal persistence: totals flush to
-    `<dir>/goodput.rank<k>.json` every `flush_steps` closed steps and at
-    exit. With `resume`, an existing journal seeds the cumulative base so
-    a restarted rank keeps its lifetime totals — but only while the
-    in-process ledger is still pristine: once steps have been recorded
-    (and possibly flushed), re-loading the journal as base would count
-    them twice."""
-    global _JOURNAL_DIR, _FLUSH_STEPS, _atexit_registered
-    if dir:
-        _JOURNAL_DIR = dir
-        pristine = (_LEDGER.base is None and _LEDGER.steps == 0
-                    and _LEDGER.mark() == 0.0)
-        if resume and pristine:
-            path = journal_path(dir)
-            if os.path.exists(path):
-                try:
-                    _LEDGER.base = load_journal(path)
-                except (OSError, ValueError):
-                    _LEDGER.base = None  # torn/alien file: start fresh
-        if not _atexit_registered:
-            _atexit_registered = True
-            atexit.register(_flush_at_exit)
-    if flush_steps is not None:
-        _FLUSH_STEPS = max(1, int(flush_steps))
-
-
-def disable_persistence() -> None:
-    """Drop journal persistence for THIS process (the atexit flush
-    becomes a no-op). A supervisor that imports the package with the
-    rank-observability env inherited — distributed/launch.py — calls
-    this so its own exit can never clobber a real rank's journal."""
-    global _JOURNAL_DIR
-    _JOURNAL_DIR = None
-
-
-def _rank_changed() -> None:
-    """monitor.set_trainer_rank() notification: the resumed base (if
-    any) belongs to the OLD rank's journal — drop it, and re-resume
-    against the new identity while the ledger is still pristine. Keeps
-    custom rank wiring (profiler.set_rank after import) from counting
-    another rank's lifetime totals as this rank's."""
-    if _JOURNAL_DIR is None:
-        return
-    _LEDGER.base = None
-    if _LEDGER.steps == 0 and _LEDGER.mark() == 0.0:
-        path = journal_path()
-        if os.path.exists(path):
-            try:
-                _LEDGER.base = load_journal(path)
-            except (OSError, ValueError):
-                _LEDGER.base = None
-
-
-def _flush_at_exit() -> None:
-    try:
-        flush()
-    except OSError:
-        pass
-
-
-def flush(path: Optional[str] = None) -> Optional[str]:
-    """Write the cumulative ledger journal (atomic: temp + os.replace —
-    the status server and external readers can never observe a torn
-    file). Journals persist the CLOSED-step view only, so their buckets
-    and wall_seconds agree and cross-rank merges stay bounded at 100%.
-    No-op when persistence is unconfigured and no path given."""
-    if path is None:
-        if _JOURNAL_DIR is None:
-            return None
-        path = journal_path()
-    return _monitor.atomic_write_text(
-        path, json.dumps(totals(include_open=False), indent=1))
-
-
-def load_journal(path: str) -> Dict[str, Any]:
-    with open(path) as f:
-        doc = json.load(f)
-    if doc.get("schema") != SCHEMA:
-        raise ValueError(f"{path}: not a goodput journal (schema "
-                         f"{doc.get('schema')!r})")
-    return doc
-
-
-def load_journals(dir: str,
-                  ranks: Optional[Sequence[int]] = None
-                  ) -> Optional[Dict[str, Any]]:
-    """Merge per-rank journals in `dir` into the job-level ledger
-    (launch.py teardown summary, obs_report --goodput). `ranks` limits
-    the merge to this job's membership, so stale journals from an
-    earlier, larger run sharing the directory don't skew the summary."""
-    want = set(int(r) for r in ranks) if ranks is not None else None
-    docs = []
-    for path in sorted(glob.glob(os.path.join(dir, "goodput.rank*.json"))):
-        try:
-            doc = load_journal(path)
-        except (OSError, ValueError):
-            continue  # a torn file cannot happen (atomic), an alien can
-        if want is None or int(doc.get("rank", -1)) in want:
-            docs.append(doc)
-    return merge_ledgers(docs) if docs else None
+    """Set up journal persistence (journal.py has the contract): totals
+    flush to `<dir>/goodput.rank<k>.json` every `flush_steps` closed
+    steps and at exit; with `resume`, an existing journal seeds the
+    cumulative base so a restarted rank keeps its lifetime totals."""
+    _JOURNAL.configure(dir, every=flush_steps, resume=resume)
 
 
 def merge_ledgers(docs: List[Dict[str, Any]]) -> Dict[str, Any]:
@@ -573,12 +461,17 @@ def attribute_events(events: List[dict]) -> Dict[str, float]:
     return out
 
 
-# env-driven wiring: under launch.py (or a user export) every rank
-# persists its ledger with no code change
-_env_dir = _flags.env_flag("PADDLE_TPU_GOODPUT_DIR")
-if _env_dir:
-    try:
-        os.makedirs(_env_dir, exist_ok=True)
-        configure(dir=_env_dir)
-    except OSError:
-        pass  # unwritable dir: accounting stays in-process only
+# Journals persist the CLOSED-step view only, so their buckets and
+# wall_seconds agree and cross-rank merges stay bounded at 100%. Under
+# launch.py (or a user export of PADDLE_TPU_GOODPUT_DIR) every rank
+# persists its ledger with no code change.
+_JOURNAL = _journal.Journal(
+    globals(), _LEDGER, "goodput", SCHEMA, "PADDLE_TPU_GOODPUT_DIR",
+    snapshot=lambda: totals(include_open=False), unused=_unused,
+    merge=merge_ledgers,
+    every=_flags.env_flag("PADDLE_TPU_GOODPUT_FLUSH_STEPS"))
+journal_path = _JOURNAL.path
+disable_persistence = _JOURNAL.disable_persistence
+flush = _JOURNAL.flush
+load_journal = _JOURNAL.load
+load_journals = _JOURNAL.load_merged

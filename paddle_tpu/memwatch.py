@@ -39,15 +39,12 @@ perf_gate already gates MFU. The design deliberately mirrors goodput.py:
 Env knobs (declared in paddle_tpu/flags.py):
   PADDLE_TPU_MEMWATCH                sampling + ledger on/off (default on)
   PADDLE_TPU_MEMWATCH_DIR            journal directory (enables persistence)
-  PADDLE_TPU_MEMWATCH_FLUSH_STEPS    journal flush cadence in steps (50)
   PADDLE_TPU_MEMWATCH_LEAK_STEPS     monotonic-growth window (30 steps)
   PADDLE_TPU_MEMWATCH_LEAK_MIN_MB    minimum growth across the window (8)
 """
 from __future__ import annotations
 
-import atexit
 import collections
-import glob
 import json
 import os
 import re
@@ -57,6 +54,7 @@ import time
 from typing import Any, Dict, List, Optional, Sequence
 
 from . import flags as _flags
+from . import journal as _journal
 from . import monitor as _monitor
 
 __all__ = [
@@ -242,10 +240,6 @@ class MemLedger:
 
 
 _LEDGER = MemLedger()
-_JOURNAL_DIR: Optional[str] = None
-_FLUSH_STEPS = max(1, int(_flags.env_flag("PADDLE_TPU_MEMWATCH_FLUSH_STEPS")))
-_steps_since_flush = 0
-_atexit_registered = False
 
 
 def ledger() -> MemLedger:
@@ -254,9 +248,8 @@ def ledger() -> MemLedger:
 
 def reset() -> None:
     """Drop everything recorded (journal base included); tests."""
-    global _steps_since_flush
     _LEDGER.reset()
-    _steps_since_flush = 0
+    _JOURNAL.reset()
 
 
 def sample(device=None, stats: Optional[Dict[str, Any]] = None
@@ -286,7 +279,6 @@ def end_step(step: Optional[int] = None) -> Optional[dict]:
     When no sample landed in the open step (a driver that never touched
     the executor), one fresh sample is taken so the step still records
     a real watermark; samples fed explicitly are never overwritten."""
-    global _steps_since_flush
     if not enabled():
         return None
     if _LEDGER.open_samples == 0:
@@ -306,14 +298,7 @@ def end_step(step: Optional[int] = None) -> Optional[dict]:
               f"{leak['growth_bytes'] / 1e6:.1f}MB over {leak['steps']} "
               f"consecutive steps (now {leak['bytes_in_use'] / 1e6:.1f}MB)",
               file=sys.stderr)
-    if _JOURNAL_DIR is not None:
-        _steps_since_flush += 1
-        if _steps_since_flush >= _FLUSH_STEPS:
-            _steps_since_flush = 0
-            try:
-                flush()
-            except OSError:
-                pass  # a full disk must not kill the training loop
+    _JOURNAL.flush_if_due()
     return closed
 
 
@@ -351,105 +336,20 @@ def status() -> Dict[str, Any]:
 
 
 # ---------------------------------------------------------------------------
-# journal persistence (the goodput.py contract, memory-shaped)
+# journal persistence (journal.py has the contract)
 # ---------------------------------------------------------------------------
 
 
-def journal_path(dir: Optional[str] = None) -> str:
-    base = dir or _JOURNAL_DIR or "."
-    return os.path.join(base,
-                        f"memwatch.rank{_monitor.trainer_rank()}.json")
+def _unused() -> bool:
+    return _LEDGER.steps == 0 and _LEDGER.samples == 0
 
 
 def configure(dir: Optional[str] = None,
               flush_steps: Optional[int] = None,
               resume: bool = True) -> None:
     """Set up journal persistence; with `resume`, an existing journal
-    seeds the lifetime peak/step base — but only while the in-process
-    ledger is still pristine (same double-count guard as goodput)."""
-    global _JOURNAL_DIR, _FLUSH_STEPS, _atexit_registered
-    if dir:
-        _JOURNAL_DIR = dir
-        pristine = _LEDGER.base is None and _LEDGER.steps == 0 \
-            and _LEDGER.samples == 0
-        if resume and pristine:
-            path = journal_path(dir)
-            if os.path.exists(path):
-                try:
-                    _LEDGER.base = load_journal(path)
-                except (OSError, ValueError):
-                    _LEDGER.base = None  # torn/alien file: start fresh
-        if not _atexit_registered:
-            _atexit_registered = True
-            atexit.register(_flush_at_exit)
-    if flush_steps is not None:
-        _FLUSH_STEPS = max(1, int(flush_steps))
-
-
-def disable_persistence() -> None:
-    """Supervisor hook (distributed/launch.py): its own exit must never
-    clobber a real rank's journal."""
-    global _JOURNAL_DIR
-    _JOURNAL_DIR = None
-
-
-def _rank_changed() -> None:
-    """monitor.set_trainer_rank() notification — mirror of
-    goodput._rank_changed: drop the old identity's base, re-resume
-    against the new rank's journal while still pristine."""
-    if _JOURNAL_DIR is None:
-        return
-    _LEDGER.base = None
-    if _LEDGER.steps == 0 and _LEDGER.samples == 0:
-        path = journal_path()
-        if os.path.exists(path):
-            try:
-                _LEDGER.base = load_journal(path)
-            except (OSError, ValueError):
-                _LEDGER.base = None
-
-
-def _flush_at_exit() -> None:
-    try:
-        flush()
-    except OSError:
-        pass
-
-
-def flush(path: Optional[str] = None) -> Optional[str]:
-    """Write the ledger journal (atomic temp + os.replace). No-op when
-    persistence is unconfigured and no path given."""
-    if path is None:
-        if _JOURNAL_DIR is None:
-            return None
-        path = journal_path()
-    return _monitor.atomic_write_text(path, json.dumps(totals(), indent=1))
-
-
-def load_journal(path: str) -> Dict[str, Any]:
-    with open(path) as f:
-        doc = json.load(f)
-    if doc.get("schema") != SCHEMA:
-        raise ValueError(f"{path}: not a memwatch journal (schema "
-                         f"{doc.get('schema')!r})")
-    return doc
-
-
-def load_journals(dir: str,
-                  ranks: Optional[Sequence[int]] = None
-                  ) -> Optional[Dict[str, Any]]:
-    """Merge per-rank memwatch journals in `dir` (obs_report --memwatch,
-    launch teardown). `ranks` limits to this job's membership."""
-    want = set(int(r) for r in ranks) if ranks is not None else None
-    docs = []
-    for path in sorted(glob.glob(os.path.join(dir, "memwatch.rank*.json"))):
-        try:
-            doc = load_journal(path)
-        except (OSError, ValueError):
-            continue
-        if want is None or int(doc.get("rank", -1)) in want:
-            docs.append(doc)
-    return merge_ledgers(docs) if docs else None
+    seeds the lifetime peak/step base."""
+    _JOURNAL.configure(dir, every=flush_steps, resume=resume)
 
 
 def merge_ledgers(docs: List[Dict[str, Any]]) -> Dict[str, Any]:
@@ -724,7 +624,7 @@ def dump_postmortem(doc: Dict[str, Any],
     still carries the report in-process either way."""
     global _POSTMORTEM_SEQ
     base = (dir or _flags.env_flag("PADDLE_TPU_XLA_DUMP_DIR")
-            or _JOURNAL_DIR
+            or _JOURNAL.dir
             or _flags.env_flag("PADDLE_TPU_MEMWATCH_DIR") or None)
     if not base:
         return None
@@ -780,12 +680,13 @@ def oom_error(exc: BaseException, program=None, scope=None,
     return err
 
 
-# env-driven wiring: under launch.py (or a user export) every rank
-# persists its memory ledger with no code change
-_env_dir = _flags.env_flag("PADDLE_TPU_MEMWATCH_DIR")
-if _env_dir:
-    try:
-        os.makedirs(_env_dir, exist_ok=True)
-        configure(dir=_env_dir)
-    except OSError:
-        pass  # unwritable dir: accounting stays in-process only
+# under launch.py (or a user export of PADDLE_TPU_MEMWATCH_DIR) every
+# rank persists its memory ledger with no code change
+_JOURNAL = _journal.Journal(
+    globals(), _LEDGER, "memwatch", SCHEMA, "PADDLE_TPU_MEMWATCH_DIR",
+    snapshot=totals, unused=_unused, merge=merge_ledgers)
+journal_path = _JOURNAL.path
+disable_persistence = _JOURNAL.disable_persistence
+flush = _JOURNAL.flush
+load_journal = _JOURNAL.load
+load_journals = _JOURNAL.load_merged

@@ -54,7 +54,8 @@ __all__ = [
     "enabled", "enable", "snapshot", "to_prometheus", "write_snapshot",
     "reset_metrics",
     "stat_add", "stat_set", "stat_get", "stat_reset", "stats",
-    "trainer_rank", "set_trainer_rank", "atomic_write_text",
+    "trainer_rank", "set_trainer_rank", "on_rank_change",
+    "atomic_write_text",
     "FlightRecorder", "enable_flight_recorder", "flight_recorder",
     "flight_record", "note_progress", "progress_count",
     "dump_flight_record", "install_dump_handlers",
@@ -654,6 +655,14 @@ def progress_count() -> int:
 
 
 _RANK_OVERRIDE: Optional[int] = None
+_RANK_CALLBACKS: List[Callable[[], None]] = []
+
+
+def on_rank_change(fn: Callable[[], None]) -> None:
+    """Call `fn()` after every set_trainer_rank that changes the rank:
+    how a layer above (the rank-keyed journals, journal.py) learns its
+    identity moved, without this module importing it."""
+    _RANK_CALLBACKS.append(fn)
 
 
 def set_trainer_rank(rank: int) -> None:
@@ -664,30 +673,8 @@ def set_trainer_rank(rank: int) -> None:
     changed = _RANK_OVERRIDE != int(rank)
     _RANK_OVERRIDE = int(rank)
     if changed:
-        try:  # the goodput journal is rank-keyed: re-anchor its resume
-            from . import goodput as _goodput
-
-            _goodput._rank_changed()
-        except Exception:
-            pass
-        try:  # the memwatch journal shares the rank-keyed contract
-            from . import memwatch as _memwatch
-
-            _memwatch._rank_changed()
-        except Exception:
-            pass
-        try:  # so does the training-dynamics journal
-            from . import dynamics as _dynamics
-
-            _dynamics._rank_changed()
-        except Exception:
-            pass
-        try:  # and the interconnect ledger journal
-            from . import commswatch as _commswatch
-
-            _commswatch._rank_changed()
-        except Exception:
-            pass
+        for fn in _RANK_CALLBACKS:
+            fn()
 
 
 def trainer_rank() -> int:

@@ -72,9 +72,7 @@ taxonomy, never a silent pass):
 """
 from __future__ import annotations
 
-import atexit
 import collections
-import glob
 import json
 import os
 import threading
@@ -82,6 +80,7 @@ import time
 from typing import Any, Dict, List, Optional, Sequence
 
 from .. import flags as _flags
+from .. import journal as _journal
 from .. import monitor as _monitor
 
 __all__ = [
@@ -661,10 +660,6 @@ class ServingLedger:
 
 
 _LEDGER = ServingLedger()
-_JOURNAL_DIR: Optional[str] = None
-_FLUSH_TICKS = max(1, int(_flags.env_flag("PADDLE_TPU_SERVE_FLUSH_TICKS")))
-_ticks_since_flush = 0
-_atexit_registered = False
 
 
 def ledger() -> ServingLedger:
@@ -672,9 +667,8 @@ def ledger() -> ServingLedger:
 
 
 def reset() -> None:
-    global _ticks_since_flush
     _LEDGER.reset()
-    _ticks_since_flush = 0
+    _JOURNAL.reset()
 
 
 def add(bucket: str, seconds: float) -> None:
@@ -718,18 +712,10 @@ def note_token_gaps(gaps: Sequence[float]) -> None:
 
 
 def end_tick(wall_seconds: float, **kw) -> Optional[dict]:
-    global _ticks_since_flush
     if not _monitor.enabled():
         return None
     closed = _LEDGER.end_tick(wall_seconds, **kw)
-    if _JOURNAL_DIR is not None:
-        _ticks_since_flush += 1
-        if _ticks_since_flush >= _FLUSH_TICKS:
-            _ticks_since_flush = 0
-            try:
-                flush()
-            except OSError:
-                pass  # a full disk must not kill the serving loop
+    _JOURNAL.flush_if_due()
     return closed
 
 
@@ -853,80 +839,32 @@ def status() -> Dict[str, Any]:
 
 
 # ---------------------------------------------------------------------------
-# journal persistence (the goodput.py idiom, serving-flavored)
+# journal persistence (journal.py has the contract)
 # ---------------------------------------------------------------------------
 
 
-def journal_path(dir: Optional[str] = None) -> str:
-    base = dir or _JOURNAL_DIR or "."
-    return os.path.join(base,
-                        f"serving.rank{_monitor.trainer_rank()}.json")
+def _unused() -> bool:
+    return _LEDGER.ticks == 0 and _LEDGER.mark() == 0.0
 
 
-def configure(dir: Optional[str] = None,
-              flush_ticks: Optional[int] = None,
-              resume: bool = True) -> None:
-    """Set up journal persistence; with `resume`, an existing journal
-    seeds the cumulative base — only while the in-process ledger is
-    still pristine (recorded ticks re-loaded as base would count
-    twice)."""
-    global _JOURNAL_DIR, _FLUSH_TICKS, _atexit_registered
-    if dir:
-        _JOURNAL_DIR = dir
-        pristine = (_LEDGER.base is None and _LEDGER.ticks == 0
-                    and _LEDGER.mark() == 0.0)
-        if resume and pristine:
-            path = journal_path(dir)
-            if os.path.exists(path):
-                try:
-                    _LEDGER.base = load_journal(path)
-                except (OSError, ValueError):
-                    _LEDGER.base = None  # torn/alien file: start fresh
-        if not _atexit_registered:
-            _atexit_registered = True
-            atexit.register(_flush_at_exit)
-    if flush_ticks is not None:
-        _FLUSH_TICKS = max(1, int(flush_ticks))
-
-
-def disable_persistence() -> None:
-    """Drop journal persistence for THIS process — the supervisor
-    (distributed/launch.py) sheds the inherited serving env so its exit
-    flush can never clobber a real replica's journal."""
-    global _JOURNAL_DIR
-    _JOURNAL_DIR = None
-
-
-def _flush_at_exit() -> None:
-    try:
-        flush()
-    except OSError:
-        pass
-
-
-def flush(path: Optional[str] = None) -> Optional[str]:
-    """Write the cumulative serving journal (atomic temp + os.replace).
-    No-op when persistence is unconfigured and no path given."""
-    if path is None:
-        if _JOURNAL_DIR is None:
-            return None
-        path = journal_path()
+def _snapshot() -> Dict[str, Any]:
+    """What a flush writes: the closed ticks' totals and the three
+    reconciliations."""
     doc = totals(include_open=False)
     # a sample of this process's run and its count, not totals
     del doc["itl_gaps_s"], doc["itl_gaps_seen"]
     doc["span_reconciliation"] = reconcile_spans(doc)
     doc["roofline_reconciliation"] = reconcile_roofline(doc)
     doc["attribution_reconciliation"] = reconcile_attribution(doc)
-    return _monitor.atomic_write_text(path, json.dumps(doc, indent=1))
-
-
-def load_journal(path: str) -> Dict[str, Any]:
-    with open(path) as f:
-        doc = json.load(f)
-    if doc.get("schema") != SCHEMA:
-        raise ValueError(f"{path}: not a serving journal (schema "
-                         f"{doc.get('schema')!r})")
     return doc
+
+
+def configure(dir: Optional[str] = None,
+              flush_ticks: Optional[int] = None,
+              resume: bool = True) -> None:
+    """Set up journal persistence; with `resume`, an existing journal
+    seeds the cumulative base."""
+    _JOURNAL.configure(dir, every=flush_ticks, resume=resume)
 
 
 def load_journals(dir: str,
@@ -948,21 +886,13 @@ def load_journals(dir: str,
       death (inside every survivor's lifetime) so its work still
       counts, and a warm-restarted replica resumes its journal with the
       ORIGINAL started_unix, so resuming never outdates its peers."""
-    want = set(int(r) for r in ranks) if ranks is not None else None
-    docs = []
-    paths = sorted(
-        glob.glob(os.path.join(dir, "serving.rank*.json"))
-        + glob.glob(os.path.join(dir, "serving.router.json")))
-    for path in paths:
-        try:
-            doc = load_journal(path)
-        except (OSError, ValueError):
-            continue
-        # the router journal rides the rank filter free: it is a front
-        # tier, not a replica, and carries no rank of its own
-        if (doc.get("role") == "router" or want is None
-                or int(doc.get("rank", -1)) in want):
-            docs.append(doc)
+    docs = _JOURNAL.load_all(dir, ranks)
+    # the router journal rides the rank filter free: it is a front
+    # tier, not a replica, and carries no rank of its own
+    try:
+        docs.append(load_journal(os.path.join(dir, "serving.router.json")))
+    except (OSError, ValueError):
+        pass
     stale_filtered = 0
     if drop_stale and len(docs) > 1:
         newest_start = max(float(d.get("started_unix") or 0.0)
@@ -1289,12 +1219,13 @@ def reconcile_roofline(doc: Optional[Dict[str, Any]] = None,
     return out
 
 
-# env-driven wiring: under launch.py --serve (or a user export) every
-# replica persists its serving ledger with no code change
-_env_dir = _flags.env_flag("PADDLE_TPU_SERVE_DIR")
-if _env_dir:
-    try:
-        os.makedirs(_env_dir, exist_ok=True)
-        configure(dir=_env_dir)
-    except OSError:
-        pass  # unwritable dir: accounting stays in-process only
+# under launch.py --serve (or a user export of PADDLE_TPU_SERVE_DIR)
+# every replica persists its serving ledger with no code change
+_JOURNAL = _journal.Journal(
+    globals(), _LEDGER, "serving", SCHEMA, "PADDLE_TPU_SERVE_DIR",
+    snapshot=_snapshot, unused=_unused,
+    every=_flags.env_flag("PADDLE_TPU_SERVE_FLUSH_TICKS"))
+journal_path = _JOURNAL.path
+disable_persistence = _JOURNAL.disable_persistence
+flush = _JOURNAL.flush
+load_journal = _JOURNAL.load

@@ -29,12 +29,16 @@ import jax  # noqa: E402
 jax.config.update("jax_platforms", "cpu")
 
 
-# build the native core on fresh checkouts (a few seconds, once)
+# build the native libraries on fresh checkouts (a few seconds, once):
+# the core, feed and ps tables, and the C inference ABI that
+# test_c_api_runs_saved_model links
 import subprocess  # noqa: E402
 
 _repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if not os.path.exists(os.path.join(_repo, "paddle_tpu", "lib", "libpaddle_tpu_core.so")):
-    subprocess.run(["make", "-C", os.path.join(_repo, "csrc")], check=False, capture_output=True)
+if not all(os.path.exists(os.path.join(_repo, "paddle_tpu", "lib", f"libpaddle_tpu_{_n}.so"))
+           for _n in ("core", "capi")):
+    subprocess.run(["make", "-C", os.path.join(_repo, "csrc"), "all", "capi"],
+                   check=False, capture_output=True)
 
 
 def pytest_configure(config):
