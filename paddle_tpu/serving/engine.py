@@ -35,6 +35,18 @@ generated prefix folded into the prompt (recompute-on-resume), so tight
 SLOs survive loose ones — the test observes both the eviction and the
 freed blocks' reuse.
 
+The decode loop keeps ONE tick in flight ahead of the host: tick N+1 is
+built from what was DISPATCHED (a context's length and a request's
+budget are known when a tick goes out; only its tokens are not),
+enqueued on tick N's own output still on the device, and only then is N
+read back, so the host's work on a tick runs beside the device's on the
+one before. Wherever the host needs what it has not read (an admission
+with its prefill, an eviction, a failed program, ``stop()``, no request
+left to dispatch)
+the tick in flight is read first (``_drain``) and the order is the
+serial one; the ledger counts both (``ticks_ahead``,
+``pipeline_drains``).
+
 Threading: ``start()`` runs the scheduler on a daemon thread (the
 serve_bench / replica mode); without ``start()`` the engine is driven
 synchronously (``run_until_idle`` / ``drive``), which is how tests and
@@ -113,7 +125,11 @@ class ServeRequest:
     # recompute-on-resume, but still part of the request's output
     generated_prefix: List[int] = field(default_factory=list)
     blocks: List[int] = field(default_factory=list)
+    # positions whose K/V the programs dispatched so far write (a decode
+    # tick counts when it is enqueued, not when it is read)
     context_len: int = 0
+    # tokens of decode ticks dispatched and not read back yet
+    unread: int = 0
     prompt_len: int = 0
     slot: int = -1
     status: str = QUEUED
@@ -127,6 +143,22 @@ class ServeRequest:
     @property
     def deadline_abs(self) -> float:
         return self.t_submit / 1e9 + self.deadline_s
+
+
+@dataclass
+class _Tick:
+    """A decode tick between its enqueue and its read."""
+
+    no: int
+    # (request, slot) as dispatched: a slot may change hands before the read
+    slots: List[Tuple[ServeRequest, int]]
+    nxt: Any  # the program's second output, on the device
+    # perf_counter_ns where its window starts: the end of the read of the
+    # tick before it, or its own put when nothing was in flight then
+    t0: int
+    # perf_counter_ns up to which the ledger's decode_compute bucket has
+    # that window already (the ledger's ticks close under it)
+    charged: int
 
 
 class RequestHandle:
@@ -252,6 +284,11 @@ class ServingEngine:
         # admitted one-shot executes waiting for a thread to claim them
         self._exec_ready: List[ServeRequest] = []
         self._tick_no = 0
+        self._inflight: Optional[_Tick] = None  # enqueued, not read yet
+        # running totals a step takes the difference of, so that a drain
+        # anywhere in it counts: tokens read back, ns inside device_sync
+        self._decoded = 0
+        self._sync_ns = 0
         self._step_lock = threading.RLock()
         self._wake = threading.Condition()
         self._thread: Optional[threading.Thread] = None
@@ -400,6 +437,14 @@ class ServingEngine:
         if self._thread is not None:
             self._thread.join(timeout=30)
             self._thread = None
+        if self._inflight is not None:
+            # nothing the device was given stays unread
+            with self._step_lock, _profiler.span("engine/step", cat="engine"):
+                t0, decoded0 = self._tick_start(), self._decoded
+                self._drain("stop")
+                active = len(self.active())
+                self._retire_finished()
+                self._close_tick(t0, self._decoded - decoded0, active)
         if flush:
             try:
                 _ledger.flush()
@@ -506,7 +551,7 @@ class ServingEngine:
         claims them (the stepping thread in step(), each request's OWN
         waiting thread in drive())."""
         with _profiler.span("engine/step", cat="engine"):
-            t0 = time.perf_counter()
+            t0, decoded0 = self._tick_start(), self._decoded
             with _profiler.span("engine/admit", cat="engine") as sp:
                 self._reap_stale()
                 admitted = self._admit()
@@ -520,25 +565,47 @@ class ServingEngine:
                     self._run_prefill(req)
                 else:
                     self._exec_ready.append(req)
-            decoded = 0
-            if any(r is not None and r.status == RUNNING and
-                   r.kind == "generate" for r in self._slots):
+            if self._inflight is not None or any(
+                    r is not None and r.status == RUNNING and
+                    r.kind == "generate" for r in self._slots):
                 gen_work = True
-                decoded = self._decode_tick()
+                self._decode_tick()
             active = len([r for r in self.active() if r.kind == "generate"])
             with _profiler.span("engine/retire", cat="engine"):
                 self._retire_finished()
             if gen_work:
                 with _profiler.span("engine/ledger", cat="engine"):
-                    _ledger.end_tick(
-                        time.perf_counter() - t0,
-                        decoded_tokens=decoded,
-                        active=active,
-                        max_batch=self.max_batch,
-                        kv_used=self.allocator.used(),
-                        kv_total=self.allocator.capacity,
-                        queued=self.queue.depth())
+                    self._close_tick(t0, self._decoded - decoded0, active)
         return gen_work or bool(admitted)
+
+    def _tick_start(self) -> float:
+        """Where the ledger's next tick starts (``perf_counter``): now,
+        or, under a tick in flight, where its last one closed, so that a
+        window that runs across scheduler steps falls into the ledger's
+        ticks whole."""
+        if self._inflight is None:
+            return time.perf_counter()
+        return self._inflight.charged / 1e9
+
+    def _close_tick(self, t0: float, decoded: int, active: int) -> None:
+        """Close the ledger's tick over the wall since ``t0``: ``decoded``
+        tokens were read back in it, for ``active`` requests in slots
+        (counted before retirement cleared the finished ones). Of a tick
+        in flight the ledger gets the part of the window that lies before
+        this close; its read gives the rest."""
+        now = time.perf_counter_ns()
+        tick = self._inflight
+        if tick is not None:
+            _ledger.add("decode_compute", (now - tick.charged) / 1e9)
+            tick.charged = now
+        _ledger.end_tick(
+            now / 1e9 - t0,
+            decoded_tokens=decoded,
+            active=active,
+            max_batch=self.max_batch,
+            kv_used=self.allocator.used(),
+            kv_total=self.allocator.capacity,
+            queued=self.queue.depth())
 
     def _claim_execute(self, prefer: Optional[ServeRequest] = None) -> bool:
         """Claim ONE admitted execute request and run its thunk on the
@@ -748,6 +815,10 @@ class ServingEngine:
                         deferred.append(req)
                         break
                 req.blocks = blocks
+                # the prefill's token is needed before the next tick can
+                # be built: the tick in flight is read first, and the wait
+                # is part of this request's admission
+                self._drain("prefill")
             req.t_admit = time.perf_counter_ns()
             req.status = RUNNING
             req.slot = slot
@@ -775,10 +846,16 @@ class ServingEngine:
             len(v.blocks) for v in victims)
         if reclaimable < need:
             return False
+        # a victim's generated tokens become its prompt, so the tick in
+        # flight is read first; a victim that read finished retires, and
+        # its blocks are free without a preemption
+        if self._drain("evict"):
+            self._retire_finished()
         for victim in victims:
             if self.allocator.available() >= need:
                 break
-            self._preempt(victim)
+            if victim.status == RUNNING:
+                self._preempt(victim)
         return self.allocator.available() >= need
 
     def _preempt(self, req: ServeRequest) -> None:
@@ -810,15 +887,23 @@ class ServingEngine:
         req.blocks = []
         self._fail(req, why)
 
-    def _restore_pages(self) -> None:
+    def _restore_pages(self, on_device: bool = False) -> None:
         """After a program raised: the pool is DONATED to every prefill
         and decode program, so one that failed after dispatch took the
         handle with it, and every running context with the handle. Then
         serve on from a zeroed pool, the running requests preempted for
         re-prefill as an eviction would. A program refused before
-        dispatch (a bad argument) consumed nothing, and nothing is done."""
-        if not self.pages.is_deleted():
-            return
+        dispatch (a bad argument) consumed nothing, and nothing is done.
+        ``on_device``: the program failed while it ran, and its output,
+        the pool this engine holds, is no pool."""
+        if not on_device:
+            if not self.pages.is_deleted():
+                return
+            # the tick in flight gave its pool to the program that failed,
+            # but its tokens are sound: read them before they are folded
+            # into prompts (a read that fails comes back through here)
+            if self._drain("error") and not self.pages.is_deleted():
+                return
         self.pages = self.model.init_pages()
         lost = [r for r in self._slots if r is not None
                 and r.status == RUNNING and r.kind == "generate"]
@@ -876,35 +961,40 @@ class ServingEngine:
             if len(req.out_tokens) >= req.max_new_tokens:
                 req.status = DONE
 
-    def _decode_tick(self) -> int:
-        """One batched decode dispatch. Returns the number of tokens
-        decoded (counted HERE, before retirement clears finished
-        requests from their slots)."""
+    def _decode_tick(self) -> None:
+        """One decode dispatch: tick N+1 is enqueued, then tick N, in
+        flight since the last call, is read."""
         self._tick_no += 1
         # serving chaos sites, seed-deterministic (paddle_tpu/chaos.py):
         # replica_kill dies NOW with slots full of in-flight state — the
         # shape router failover + warm restart must survive; decode_stall
-        # wedges the tick so SLO-at-risk hedging has something to hedge
+        # wedges the tick so SLO-at-risk hedging has something to hedge.
+        # Where either is armed the tick in flight is read first: a kill
+        # or a stall then finds the engine as a serial loop would leave it
         if _chaos.enabled():
+            if _chaos.armed("replica_kill") or _chaos.armed("decode_stall"):
+                self._drain("stop")
             _chaos.replica_kill(self._tick_no)
             _chaos.delay("decode_stall", where=f"decode_tick/{self._tick_no}")
+        sync0 = self._sync_ns
         with _profiler.span("engine/decode_tick", cat="engine",
                             tick=self._tick_no) as tick:
-            decoded, sync_s = self._decode_tick_phases(tick)
-        if decoded:
-            # the same two intervals the spans show: the tick, and inside
-            # it the host blocked on the device
-            _ledger.note_decode_tick(tick.seconds, sync_s)
-        return decoded
+            dispatched, ahead = self._decode_tick_phases(tick)
+        # the same two intervals the spans show: the tick, and inside it
+        # the host blocked on the device
+        _ledger.note_decode_tick(tick.seconds, (self._sync_ns - sync0) / 1e9,
+                                 dispatched, ahead)
 
-    def _decode_tick_phases(self, tick) -> Tuple[int, float]:
-        """The tick inside its span. Returns (tokens decoded, seconds of
-        ``tick/device_sync``)."""
+    def _decode_tick_phases(self, tick) -> Tuple[bool, bool]:
+        """The tick inside its span. Returns whether a program went out,
+        and whether it went out ahead of the read of the one before."""
         with _profiler.span("tick/grow_blocks", cat="engine"):
             ready = self._grow_blocks()
         tick.set(slots=len(ready))
         if not ready:
-            return 0, 0.0
+            # nobody is left to dispatch: the last tick is simply read
+            self._drain("empty")
+            return False, False
         with _profiler.span("tick/build_inputs", cat="engine"):
             B = self.max_batch
             tables = np.zeros((B, self.model.max_blocks_per_req), np.int32)
@@ -913,43 +1003,105 @@ class ServingEngine:
             for req in ready:
                 tables[req.slot, :len(req.blocks)] = req.blocks
                 lens[req.slot] = req.context_len
-                toks[req.slot] = req.out_tokens[-1]
-        # tick/put_inputs, tick/enqueue, tick/device_sync: returns with
-        # pages and tokens ready, and with those spans' stamps
+                # a last token still unread stays on the device: -1 makes
+                # the program take it from the tick in flight's output
+                toks[req.slot] = -1 if req.unread else req.out_tokens[-1]
+            # what this tick's attention has to read, and its whole window
+            _ledger.note_attention(
+                sum(blocks_for_tokens(req.context_len + 1, self.block_size)
+                    for req in ready),
+                B * self.model.max_blocks_per_req)
+        prev = self._inflight
+        # tick/put_inputs, tick/enqueue: returns at once, pool and tokens
+        # still being computed
         try:
-            pages, nxt, (t0, t_sync, t1) = self.model.decode(
-                self.pages, tables, lens, toks)
+            self.pages, nxt, t_put = self.model.decode_enqueue(
+                self.pages, tables, lens, toks,
+                None if prev is None else prev.nxt)
         except Exception as e:  # the engine outlives a failed program
             for req in ready:
                 self._drop(req, f"decode program failed: "
                            f"{type(e).__name__}: {e}")
             self._restore_pages()
-            return 0, 0.0
+            return False, False
+        for req in ready:
+            req.context_len += 1
+            req.unread += 1
+        tick = self._inflight = _Tick(
+            self._tick_no, [(r, r.slot) for r in ready], nxt, t_put, t_put)
+        if prev is not None:
+            t_read = self._read_tick(prev)
+            if t_read is not None:
+                tick.t0 = tick.charged = t_read
+        return True, prev is not None
+
+    def _drain(self, cause: str) -> bool:
+        """Read the tick in flight, if there is one, because the host
+        needs what it has not read (``cause``: ``ledger.DRAIN_CAUSES``).
+        Returns whether there was one."""
+        tick, self._inflight = self._inflight, None
+        if tick is None:
+            return False
+        _ledger.note_pipeline_drain(cause)
+        self._read_tick(tick)
+        return True
+
+    def _read_tick(self, tick: _Tick) -> Optional[int]:
+        """The read half of a decode tick: wait for its tokens, hand each
+        to its request, close the tick's window at the end of the read
+        (returned, ``perf_counter_ns``: where the window of a tick
+        enqueued behind this one starts, so that the windows of
+        consecutive ticks, and a prefill's between them, never overlap).
+        None when the program turns out to have failed."""
+        try:
+            with _profiler.span("tick/device_sync", cat="engine") as sync:
+                nxt, routing = self.model.decode_read(tick.nxt)
+        except Exception as e:
+            # the program failed on the device, and the tick enqueued
+            # behind it took its tokens and its pool: both go, each
+            # request once
+            behind, self._inflight = self._inflight, None
+            if behind is not None:
+                _ledger.note_pipeline_drain("error")
+            for req, _ in tick.slots + (behind.slots if behind else []):
+                if req.status == RUNNING:
+                    self._drop(req, f"decode program failed: "
+                               f"{type(e).__name__}: {e}")
+            self._restore_pages(on_device=True)
+            return None
+        self._sync_ns += sync.t1_ns - sync.t0_ns
         with _profiler.span("tick/bookkeeping", cat="engine"):
-            self.pages = pages
-            window = (t1 - t0) / 1e9
-            _ledger.add("decode_compute", window)
+            t0, t1 = tick.t0, sync.t1_ns
+            # a request failed since the dispatch (reaped, dropped with a
+            # later tick) takes no token
+            live = [(r, slot) for r, slot in tick.slots if r.status == RUNNING]
+            _ledger.add("decode_compute", (t1 - tick.charged) / 1e9)
             # the engine-side leg of the span reconciliation: slot-seconds
-            _ledger.add_slot_seconds(window * len(ready))
-            if self.model.last_routing is not None:
-                _ledger.note_routing(*self.model.last_routing)
-            # what this tick's attention had to read, and its whole window
-            _ledger.note_attention(
-                sum(blocks_for_tokens(req.context_len + 1, self.block_size)
-                    for req in ready),
-                B * self.model.max_blocks_per_req)
-            for req in ready:
-                req.out_tokens.append(int(nxt[req.slot]))
-                req.context_len += 1
-                req.tick_windows.append((t0, t1, self._tick_no))
-                if len(req.out_tokens) >= req.max_new_tokens:
+            _ledger.add_slot_seconds((t1 - t0) / 1e9 * len(live))
+            if routing is not None:
+                _ledger.note_routing(*routing)
+            for req, slot in live:
+                req.out_tokens.append(int(nxt[slot]))
+                req.unread -= 1
+                req.tick_windows.append((t0, t1, tick.no))
+                if not req.unread and self._spent(req):
                     req.status = DONE
-        return len(ready), (t1 - t_sync) / 1e9
+            self._decoded += len(live)
+        return t1
+
+    def _spent(self, req: ServeRequest) -> bool:
+        """Every token this request may have has been dispatched: its
+        budget, or the context envelope. Both are known at dispatch."""
+        return (len(req.out_tokens) + req.unread >= req.max_new_tokens
+                or req.context_len + 1 >= self.model.cfg.max_seq_len)
 
     def _grow_blocks(self) -> List[ServeRequest]:
         """Grow each running context into its next block where needed;
         a request that cannot get one is preempted (self-victim =
-        failure). Returns the requests that enter this tick's batch."""
+        failure). Returns the requests that enter this tick's batch: a
+        request whose last token is in flight is left out (it is marked
+        DONE when that token is read), so no tick computes a token past
+        a budget or writes K/V past a request's blocks."""
         active = [r for r in self._slots
                   if r is not None and r.status == RUNNING
                   and r.kind == "generate"]
@@ -957,6 +1109,10 @@ class ServingEngine:
         for req in active:
             if req.status != RUNNING or req.slot < 0:
                 continue  # preempted by an earlier iteration's eviction
+            if self._spent(req):
+                if not req.unread:
+                    req.status = DONE  # context envelope reached
+                continue
             need = blocks_for_tokens(req.context_len + 1, self.block_size)
             if need > len(req.blocks):
                 grown = self.allocator.alloc(need - len(req.blocks),
@@ -969,9 +1125,6 @@ class ServingEngine:
                         self._drop(req, "kv blocks exhausted")
                         continue
                 req.blocks.extend(grown)
-            if req.context_len + 1 >= self.model.cfg.max_seq_len:
-                req.status = DONE  # context envelope reached
-                continue
             ready.append(req)
         # an eviction later in the growth loop may have preempted a
         # request already collected: only still-running slot-holders

@@ -29,8 +29,13 @@ give over a whole run, from the same ``perf_counter`` intervals the
 engine's spans show in a trace: ``decode_ticks`` (ticks that dispatched
 a decode program; ``ticks`` also counts prefill-only and queue-wait
 ticks), ``tick_wall_s`` (the ``engine/decode_tick`` spans, summed),
-``tick_sync_s`` (the ``tick/device_sync`` spans: the host waiting on
-the device, so wall - sync is host work a tick), for a model with
+``tick_sync_s`` (the ``tick/device_sync`` spans inside them: the host
+waiting on the device, so wall - sync is host work a tick),
+``ticks_ahead`` (decode ticks enqueued while the tick before them was
+still unread: over ``decode_ticks``, the share of ticks whose host work
+ran beside the device's) and ``pipeline_drains`` by cause (the times the
+engine read the tick in flight before going on, because the host needed
+what it had not read: ``serving/engine.py`` has the causes), for a model with
 experts the routing its decode ticks read back, summed over ticks and
 layers (``moe_assignments``: (live slot, expert) pairs, all computed;
 ``moe_experts_hit``: distinct experts with at least one; ``moe_max_load``:
@@ -86,7 +91,8 @@ from .. import monitor as _monitor
 __all__ = [
     "BUCKETS", "PRODUCTIVE_BUCKETS", "ATTRIBUTION_BUCKETS",
     "ServingLedger", "ledger", "reset",
-    "add", "mark", "add_slot_seconds", "note_decode_tick", "note_token_gaps",
+    "add", "mark", "add_slot_seconds", "note_decode_tick",
+    "note_pipeline_drain", "note_token_gaps",
     "end_tick", "record_request",
     "record_attribution", "attribution_summary", "reconcile_attribution",
     "totals", "summary",
@@ -144,7 +150,9 @@ MOE_COUNTERS = ("moe_assignments", "moe_experts_hit", "moe_max_load")
 # KV pages of a decode tick: engine.py::_decode_tick_phases
 ATTN_COUNTERS = ("attn_pages_read", "attn_pages_window")
 # summed per decode tick; in totals(), cleared by reset(), merged as sums
-TICK_COUNTERS = MOE_COUNTERS + ATTN_COUNTERS
+TICK_COUNTERS = MOE_COUNTERS + ATTN_COUNTERS + ("ticks_ahead",)
+# why the engine read the tick in flight early: engine.py::_drain
+DRAIN_CAUSES = ("prefill", "evict", "error", "stop", "empty")
 
 # fixed log-spaced bounds so per-replica histograms merge exactly across
 # restarts and ranks (1ms .. 120s covers CPU-sim ticks through pod SLOs)
@@ -369,6 +377,7 @@ class ServingLedger:
             self.tick_wall_s = 0.0
             self.tick_sync_s = 0.0
             self.tick_counts = dict.fromkeys(TICK_COUNTERS, 0)
+            self.pipeline_drains = dict.fromkeys(DRAIN_CAUSES, 0)
             self.itl_gaps: "collections.deque[float]" = collections.deque(
                 maxlen=_ITL_SAMPLE)
             self.itl_gaps_seen = 0
@@ -401,15 +410,27 @@ class ServingLedger:
         with self._lock:
             self.decode_slot_seconds += float(seconds)
 
-    def note_decode_tick(self, wall_seconds: float,
-                         sync_seconds: float) -> None:
-        """One tick that dispatched a decode program: the seconds of its
-        ``engine/decode_tick`` span and, inside it, of the blocking
-        read-back (``tick/device_sync``)."""
+    def note_decode_tick(self, wall_seconds: float, sync_seconds: float,
+                         dispatched: bool = True,
+                         ahead: bool = False) -> None:
+        """One ``engine/decode_tick`` span: its seconds and, inside it,
+        those of the blocking read-back (``tick/device_sync``); whether
+        it ``dispatched`` a decode program (the last tick of a run is only
+        read) and whether that went out ``ahead``, with the tick before
+        it still unread."""
         with self._lock:
-            self.decode_ticks += 1
+            self.decode_ticks += int(dispatched)
+            self.tick_counts["ticks_ahead"] += int(ahead)
             self.tick_wall_s += float(wall_seconds)
             self.tick_sync_s += float(sync_seconds)
+
+    def note_pipeline_drain(self, cause: str) -> None:
+        """The engine read the tick in flight before going on."""
+        if cause not in DRAIN_CAUSES:
+            raise _invalid(
+                f"drain cause {cause!r} is not one of {DRAIN_CAUSES}")
+        with self._lock:
+            self.pipeline_drains[cause] += 1
 
     def note_routing(self, assignments: int, experts_hit: int,
                      max_load: int) -> None:
@@ -601,6 +622,7 @@ class ServingLedger:
             tick_wall = self.tick_wall_s
             tick_sync = self.tick_sync_s
             counts = dict(self.tick_counts)
+            drains = dict(self.pipeline_drains)
             doc["itl_gaps_s"] = list(self.itl_gaps)
             doc["itl_gaps_seen"] = self.itl_gaps_seen
             attribution = json.loads(json.dumps(self.attribution))
@@ -626,6 +648,8 @@ class ServingLedger:
             tick_sync += float(base.get("tick_sync_s", 0.0))
             for k in TICK_COUNTERS:
                 counts[k] += int(base.get(k, 0))
+            for k, v in (base.get("pipeline_drains") or {}).items():
+                drains[k] = drains.get(k, 0) + int(v)
             attribution = merge_attribution(base.get("attribution"),
                                             attribution)
             doc["resumed_from_journal"] = True
@@ -654,6 +678,7 @@ class ServingLedger:
             "tick_wall_s": tick_wall,
             "tick_sync_s": tick_sync,
             **counts,
+            "pipeline_drains": drains,
             "attribution": attribution,
         })
         return _finalize(doc, buckets, wall)
@@ -687,10 +712,17 @@ def add_slot_seconds(seconds: float) -> None:
     _LEDGER.add_slot_seconds(seconds)
 
 
-def note_decode_tick(wall_seconds: float, sync_seconds: float) -> None:
+def note_decode_tick(wall_seconds: float, sync_seconds: float,
+                     dispatched: bool = True, ahead: bool = False) -> None:
     if not _monitor.enabled():
         return
-    _LEDGER.note_decode_tick(wall_seconds, sync_seconds)
+    _LEDGER.note_decode_tick(wall_seconds, sync_seconds, dispatched, ahead)
+
+
+def note_pipeline_drain(cause: str) -> None:
+    if not _monitor.enabled():
+        return
+    _LEDGER.note_pipeline_drain(cause)
 
 
 def note_routing(assignments: int, experts_hit: int, max_load: int) -> None:
@@ -825,6 +857,14 @@ def status() -> Dict[str, Any]:
         "uptime_seconds": time.time() - _LEDGER.started_unix,
         "reconciliation": reconcile_spans(doc),
     }
+    if doc["decode_ticks"]:
+        # how often the next tick went out before the last one was read
+        out["pipeline"] = {
+            "decode_ticks": doc["decode_ticks"],
+            "ticks_ahead": doc["ticks_ahead"],
+            "ahead_share": doc["ticks_ahead"] / doc["decode_ticks"],
+            "drains": doc["pipeline_drains"],
+        }
     if doc["attn_pages_window"]:
         # how much of every slot's window the decode ticks found live
         out["attention"] = {
@@ -928,6 +968,7 @@ def merge_ledgers(docs: List[Dict[str, Any]]) -> Dict[str, Any]:
     decode_ticks = 0
     tick_wall = tick_sync = 0.0
     counts = dict.fromkeys(TICK_COUNTERS, 0)
+    drains = dict.fromkeys(DRAIN_CAUSES, 0)
     ranks: List[int] = []
     roofline = None
     max_wall = 0.0
@@ -976,6 +1017,8 @@ def merge_ledgers(docs: List[Dict[str, Any]]) -> Dict[str, Any]:
         tick_sync += float(d.get("tick_sync_s", 0.0))
         for k in TICK_COUNTERS:
             counts[k] += int(d.get(k, 0))
+        for k, v in (d.get("pipeline_drains") or {}).items():
+            drains[k] = drains.get(k, 0) + int(v)
         if d.get("rank") is not None:
             ranks.append(int(d["rank"]))
     # replica throughputs add over the LONGEST replica wall (concurrent
@@ -1007,6 +1050,7 @@ def merge_ledgers(docs: List[Dict[str, Any]]) -> Dict[str, Any]:
         "tick_wall_s": tick_wall,
         "tick_sync_s": tick_sync,
         **counts,
+        "pipeline_drains": drains,
         "attribution": attribution,
         "traffic": traffic,
         "autoscale": autoscale,

@@ -19,7 +19,10 @@ head. Pool, donation, programs, names and insight are one path —
   ``ops/pallas/paged_attention`` reads the pages a context occupies
   where they lie; a mesh program (GSPMD cannot partition a Mosaic call)
   and a pool whose row the runtime would pad gather the whole window
-  instead (``DecodeModel.attention_path``).
+  instead (``DecodeModel.attention_path``). A tick is two calls,
+  ``decode_enqueue`` and ``decode_read``, and takes a slot's last token
+  from the host or, unread, from the output of the tick before it
+  (``prev``): the engine enqueues tick N+1 before it reads tick N.
 
 Both are AOT-lowered through ``framework/xla_insight.capture`` — the
 same single compile that produces the executable also yields the
@@ -306,7 +309,11 @@ class DecodeModel:
         self._decode_fn = None
         self._prefill_fns: Dict[int, Any] = {}
         self._score_fns: Dict[int, Any] = {}
-        self.last_routing: Optional[np.ndarray] = None  # see decode()
+        # what a decode tick takes as `prev` when no tick ran before it:
+        # the shape of its own second output (behind the tokens of a model
+        # with experts ride the three ops/moe.py::routing_counts)
+        self._no_prev = np.zeros(
+            (self.max_batch + (3 if cfg.mlp == "moe" else 0),), np.int32)
 
     # -- placement ------------------------------------------------------
 
@@ -678,7 +685,10 @@ class DecodeModel:
             return x, pages, (None if idx is None else
                               moe.routing_counts(idx, live, cfg.n_experts))
 
-        def decode_tick(p, pages, block_tables, context_lens, tokens):
+        def decode_tick(p, pages, block_tables, context_lens, tokens, prev):
+            # a slot whose last token the host has not read sends -1: the
+            # token is the previous tick's own output, still on the device
+            tokens = jnp.where(tokens < 0, prev[:B], tokens)
             pos = context_lens  # [B]: the new token's position
             with jax.named_scope("embed"):
                 x = self._embed(p, tokens, pos)  # [B, D]
@@ -757,7 +767,7 @@ class DecodeModel:
         if kind == "decode":
             B = self.max_batch
             args = (self.params, pages, i32(B, self.max_blocks_per_req),
-                    i32(B), i32(B))
+                    i32(B), i32(B), i32(*self._no_prev.shape))
         elif kind == "score":
             args = (self.params, i32(1, bucket), i32())
         else:
@@ -793,7 +803,8 @@ class DecodeModel:
             return jax.jit(fn, in_shardings=(param_sh, repl, repl),
                            out_shardings=(repl, repl))
         pages_sh = self._pages_sharding()
-        n_host = 3  # (tables, lens, tokens) or (tokens, length, block_ids)
+        # (tables, lens, tokens, prev) or (tokens, length, block_ids)
+        n_host = 4 if kind == "decode" else 3
         in_sh = (param_sh, pages_sh) + (repl,) * n_host
         return jax.jit(fn, in_shardings=in_sh,
                        out_shardings=(pages_sh, repl),
@@ -834,17 +845,16 @@ class DecodeModel:
             jax.block_until_ready(pages)
         return pages, first
 
-    def decode(self, pages, block_tables: np.ndarray,
-               context_lens: np.ndarray, tokens: np.ndarray):
-        """One decode tick at max_batch. Returns (pages, next[B] np,
-        stamps): both arrays ready, and the ``perf_counter_ns`` stamps of
-        the spans below, (start, start of ``tick/device_sync``, end), so
-        the engine's windows and the ledger's ``tick_sync_s`` are the
-        intervals a trace shows. A model with experts leaves the tick's
-        routing counts in ``last_routing`` (assignments of live slots,
-        distinct experts hit, the largest expert's load, each summed over
-        the layers): they come back behind the tokens, in the one read."""
-        import jax
+    def decode_enqueue(self, pages, block_tables: np.ndarray,
+                       context_lens: np.ndarray, tokens: np.ndarray,
+                       prev=None):
+        """The first half of one decode tick at max_batch: put the inputs
+        and enqueue the program; nothing is waited for. ``tokens`` holds
+        each slot's last token, or -1 where that token is ``prev``'s: the
+        second output of the tick before, as it left the device, unread.
+        Returns (pages, next, t0): the pool's successor and the tick's
+        tokens, both still on the device (:meth:`decode_read` is the
+        sync), and the ``perf_counter_ns`` stamp of ``tick/put_inputs``."""
         import jax.numpy as jnp
 
         if self._decode_fn is None:
@@ -852,15 +862,22 @@ class DecodeModel:
         with _profiler.span("tick/put_inputs", cat="engine") as put:
             args = (jnp.asarray(np.asarray(block_tables, np.int32)),
                     jnp.asarray(np.asarray(context_lens, np.int32)),
-                    jnp.asarray(np.asarray(tokens, np.int32)))
+                    jnp.asarray(np.asarray(tokens, np.int32)),
+                    jnp.asarray(self._no_prev) if prev is None else prev)
         with _profiler.span("tick/enqueue", cat="engine"):
             pages, nxt = self._decode_fn(self.params, pages, *args)
-        with _profiler.span("tick/device_sync", cat="engine") as sync:
-            nxt = np.asarray(nxt)
-            jax.block_until_ready(pages)
-        if self.cfg.mlp == "moe":
-            nxt, self.last_routing = np.split(nxt, [self.max_batch])
-        return pages, nxt, (put.t0_ns, sync.t0_ns, sync.t1_ns)
+        return pages, nxt, put.t0_ns
+
+    def decode_read(self, nxt):
+        """The second half: wait for a tick's tokens. Returns (next[B] np,
+        routing): for a model with experts the tick's routing counts
+        (assignments of live slots, distinct experts hit, the largest
+        expert's load, each summed over the layers) come back behind the
+        tokens, in the one read; None for any other."""
+        nxt = np.asarray(nxt)
+        if self.cfg.mlp != "moe":
+            return nxt, None
+        return tuple(np.split(nxt, [self.max_batch]))
 
     def warm(self, full: bool = False) -> None:
         """Compile the decode program (and the smallest prefill bucket)

@@ -184,9 +184,15 @@ def _keys(doc):
     return None
 
 
+# keys a ledger's journal gained since the fixtures were written (PR 27's
+# tree): a file without them is still a base this tree resumes
+_ADDED = {"serving": {"ticks_ahead", "pipeline_drains"}}
+
+
 @_ledgers()
 def test_parent_commit_journal_resumes_and_reflushes(name, tmp_path):
     mod, step, count, _ = _LEDGERS[name]
+    added = _ADDED.get(name, set())
     fixture = os.path.join(_FIXTURES,
                            os.path.basename(mod.journal_path()))
     parent = mod.load_journal(fixture)
@@ -195,9 +201,12 @@ def test_parent_commit_journal_resumes_and_reflushes(name, tmp_path):
     step(0)
     step(1)
     ours = mod.load_journal(mod.flush(str(tmp_path / "same_state")))
-    assert _keys(ours) == _keys(parent)
-    with open(fixture) as f, open(tmp_path / "same_state") as g:
-        assert len(f.read().splitlines()) == len(g.read().splitlines())
+    assert added <= set(ours)
+    assert _keys({k: v for k, v in ours.items() if k not in added}) \
+        == _keys(parent)
+    if not added:
+        with open(fixture) as f, open(tmp_path / "same_state") as g:
+            assert len(f.read().splitlines()) == len(g.read().splitlines())
     # and the parent's file is a base this tree resumes and extends
     shutil.copy(fixture, tmp_path)
     mod.reset()
@@ -207,7 +216,7 @@ def test_parent_commit_journal_resumes_and_reflushes(name, tmp_path):
     step(2)
     again = mod.load_journal(mod.flush())
     assert again[count] == 3
-    assert set(again) == set(parent) | {"resumed_from_journal"}
+    assert set(again) == set(parent) | added | {"resumed_from_journal"}
 
 
 _LEDGER_MODULES = ("goodput", "memwatch", "dynamics", "commswatch",

@@ -90,16 +90,33 @@ def test_score_matches_the_reference(model, prompt):
     assert abs(total - want.sum()) <= 1e-3
 
 
-def test_prefill_then_decode_through_the_cache_follows_the_references_full_forward(model, prompt):
-    """Served greedily through the engine, then the reference's ONE
+@pytest.mark.parametrize("others,max_new", [((), 16), ((9, 30, 17), 36)], ids=["alone", "four_long"])
+def test_prefill_then_decode_through_the_cache_follows_the_references_full_forward(model, prompt, others, max_new):
+    """Tokens served by the engine, against the reference's teacher-forced
     forward over prompt + answer: at every position the served token is
-    the reference's argmax (its logit within TOL of the best)."""
-    (tokens,) = serve(model, [prompt], max_new=16)
-    seq = prompt + tokens
-    logits, _ = ref_logits(model.params, seq)
-    rows = logits[len(prompt) - 1:len(seq) - 1]
-    gaps = rows.max(-1) - rows[np.arange(len(tokens)), tokens]
-    assert gaps.max() <= TOL, gaps
+    the reference's argmax (its logit within TOL of the best). Every tick
+    but the first after an admission is enqueued on the last one's unread
+    tokens, routing counts behind them."""
+    rng = np.random.RandomState(5)
+    prompts = [prompt] + [rng.randint(0, V, n).tolist() for n in others]
+    ledger.reset()
+    answers = serve(model, prompts, max_new=max_new)
+    doc = ledger.totals()
+    ledger.reset()
+    for p, tokens in zip(prompts, answers):
+        seq = p + tokens
+        logits, _ = ref_logits(model.params, seq)
+        rows = logits[len(p) - 1:len(seq) - 1]
+        gaps = rows.max(-1) - rows[np.arange(len(tokens)), tokens]
+        assert len(tokens) == max_new and gaps.max() <= TOL, gaps
+    # a prefill reads the tick in flight first, once per admission that
+    # found one; every other tick goes out ahead
+    drains = doc["pipeline_drains"]
+    assert drains["prefill"] <= len(others) and drains["empty"] >= 1
+    assert doc["ticks_ahead"] == doc["decode_ticks"] - drains["prefill"] - drains["empty"]
+    if others:
+        assert doc["ticks_ahead"] / doc["decode_ticks"] > 0.8, doc["pipeline_drains"]
+    assert doc["moe_assignments"] == doc["decode_tokens"] * L * K
 
 
 def test_a_request_alone_and_in_a_full_batch_bit_for_bit(model, prompt):
@@ -121,11 +138,12 @@ def test_a_request_alone_and_in_a_full_batch_bit_for_bit(model, prompt):
         pages, _ = model.prefill(pages, np.asarray(others[s - 1]), n, [1 + 2 * s, 2 + 2 * s])
         full_t[s, :2] = [1 + 2 * s, 2 + 2 * s]
         full_l[s], full_k[s] = n, 11
-    pages, a, _ = model.decode(pages, tables, lens, toks)
-    lone_routing = model.last_routing.copy()
-    pages, b, _ = model.decode(pages, full_t, full_l, full_k)
+    pages, nxt, _ = model.decode_enqueue(pages, tables, lens, toks)
+    a, lone_routing = model.decode_read(nxt)
+    pages, nxt, _ = model.decode_enqueue(pages, full_t, full_l, full_k)
+    b, full_routing = model.decode_read(nxt)
     assert a[0] == b[0]
-    assert lone_routing[0] == L * K and model.last_routing[0] == 4 * L * K
+    assert lone_routing[0] == L * K and full_routing[0] == 4 * L * K
 
 
 def test_every_token_to_one_expert_nothing_dropped_still_exact(prompt):

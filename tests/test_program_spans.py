@@ -31,8 +31,11 @@ ENGINE_SPANS = {
     "tick/build_inputs": "engine/decode_tick",
     "tick/put_inputs": ("engine/decode_tick", "engine/prefill"),
     "tick/enqueue": ("engine/decode_tick", "engine/prefill"),
-    "tick/device_sync": ("engine/decode_tick", "engine/prefill"),
-    "tick/bookkeeping": "engine/decode_tick"}
+    # the read half of a tick: in the tick that follows it, or where the
+    # tick in flight had to be read first (an admission, an eviction)
+    "tick/device_sync": ("engine/decode_tick", "engine/prefill",
+                         "engine/admit"),
+    "tick/bookkeeping": ("engine/decode_tick", "engine/admit")}
 N_STEPS = 3  # the first compiles: two steady-state runs
 
 
@@ -150,10 +153,19 @@ def test_span_counts_follow_the_work(traced):
     # startup + N_STEPS runs, each with all four phases
     for name in EXECUTOR_SPANS:
         assert len(_named(traced, name)) == 1 + N_STEPS, name
-    # 3 requests of 5 tokens: 3 prefills, 4 decode ticks (continuous batch)
+    # 3 requests of 5 tokens: 3 prefills, 4 decode ticks (continuous
+    # batch), each but the first enqueued before the one before it was
+    # read, and a fifth span that only reads the last
     assert len(_named(traced, "engine/prefill")) == 3
     ticks = _named(traced, "engine/decode_tick")
-    assert len(ticks) == traced["totals"]["decode_ticks"] == 4
+    totals = traced["totals"]
+    assert len(ticks) - 1 == totals["decode_ticks"] == 4
+    assert totals["ticks_ahead"] == 3
+    assert totals["pipeline_drains"] == {
+        "prefill": 0, "evict": 0, "error": 0, "stop": 0, "empty": 1}
+    for name in ("tick/device_sync", "tick/bookkeeping"):
+        assert len([e for e in _named(traced, name) if any(
+            t["t0"] <= e["t0"] and e["t1"] <= t["t1"] for t in ticks)]) == 4
     # the budget of the issue: at most 12 span entries a decode tick
     # (the last step that ticked: every prefill is behind it)
     step = [s for s in _named(traced, "engine/step")
@@ -175,8 +187,8 @@ def test_span_attributes(traced):
     assert {e["attrs"]["prompt_len"] for e in pre} == {4}
     ticks = sorted(_named(traced, "engine/decode_tick"), key=lambda e: e["t0"])
     numbers = [e["attrs"]["tick"] for e in ticks]
-    assert numbers == list(range(numbers[0], numbers[0] + 4))
-    assert [e["attrs"]["slots"] for e in ticks] == [3, 3, 3, 3]
+    assert numbers == list(range(numbers[0], numbers[0] + 5))
+    assert [e["attrs"]["slots"] for e in ticks] == [3, 3, 3, 3, 0]
     admits = _named(traced, "engine/admit")
     assert sum(e["attrs"]["admitted"] for e in admits) == 3
     assert all("queued" in e["attrs"] for e in admits)

@@ -66,11 +66,23 @@ class FailingReplica:
         return {"draining": True}
 
 
-def test_engine_buckets_sum_to_e2e(tiny_model):
+@pytest.mark.parametrize("staggered", [False, True],
+                         ids=["together", "staggered"])
+def test_engine_buckets_sum_to_e2e(tiny_model, staggered):
     """Every retired request's engine-side buckets reconstruct its
-    measured submit->done wall, and only typed bucket names appear."""
+    measured submit->done wall, and only typed bucket names appear.
+    With one decode tick in flight ahead of the host a tick's window
+    runs from the read before it to its own read: a request's windows
+    (its prefill, then its ticks) stay disjoint, also where a later
+    admission read the tick in flight first (`staggered`), and the
+    ledger's two legs and its buckets still close."""
     eng = serving.ServingEngine(tiny_model)
-    hs = [eng.submit([3 + i, 5, 7], max_new_tokens=4) for i in range(3)]
+    hs = [eng.submit([3 + i, 5, 7], max_new_tokens=4 + 4 * staggered)
+          for i in range(3)]
+    if staggered:
+        for _ in range(3):
+            eng.step()
+        hs.append(eng.submit([9, 5, 7], max_new_tokens=5))
     eng.run_until_idle()
     for h in hs:
         h.result(timeout=10)
@@ -79,9 +91,19 @@ def test_engine_buckets_sum_to_e2e(tiny_model):
         assert set(attr) <= set(serving_ledger.ATTRIBUTION_BUCKETS), attr
         got = sum(attr.values())
         assert got == pytest.approx(h.engine_e2e_s, rel=1e-3, abs=1e-6)
+        req = h._req
+        spans = [(req.t_prefill0, req.t_prefill1)] + [
+            (t0, t1) for t0, t1, _ in req.tick_windows]
+        assert all(a1 <= b0 < b1
+                   for (_, a1), (b0, b1) in zip(spans, spans[1:])), spans
     doc = serving_ledger.totals()
+    assert doc["pipeline_drains"]["prefill"] == int(staggered)
+    assert serving_ledger.reconcile_spans(doc)["verdict"] == "within_bound"
+    assert doc["request_span_seconds"] == pytest.approx(
+        doc["decode_slot_seconds"], rel=1e-9)
+    assert abs(sum(doc["buckets"].values()) - doc["wall_seconds"]) < 1e-6
     rec = serving_ledger.reconcile_attribution(doc)
-    assert rec["available"] and rec["n_requests"] == 3, rec
+    assert rec["available"] and rec["n_requests"] == len(hs), rec
     assert rec["verdict"] == "within_bound", rec
     assert rec["residual_p50"] <= 1e-3, rec
 

@@ -266,25 +266,53 @@ def test_admission_queue_slo_ordering(tiny_model):
     assert h2._req.t_done < h1._req.t_done
 
 
-def test_continuous_batching_bit_match(tiny_model):
+@pytest.fixture(scope="module")
+def roomy_model():
+    """tiny_model's widths with blocks for four long answers at once."""
+    cfg = serving.GPTConfig(vocab_size=128, n_layer=2, n_head=2,
+                            d_model=32, max_seq_len=64)
+    return serving.DecodeModel(cfg, max_batch=4, n_blocks=40, block_size=8,
+                               prefill_buckets=[16, 32], seed=1)
+
+
+@pytest.mark.parametrize("model_name,lens,budgets", [
+    ("tiny_model", (5, 11, 7, 14), (6, 6, 6, 6)),
+    # long answers of mixed lengths: nearly every tick goes out ahead
+    ("roomy_model", (3, 9, 5, 12), (33, 40, 36, 48)),
+])
+def test_continuous_batching_bit_match(request, model_name, lens, budgets):
     """The acceptance property: batched continuous decode produces
     BIT-IDENTICAL tokens to sequential decode for the same prompts (and
-    both match the full-context greedy reference)."""
+    both match the full-context greedy reference), with each tick
+    enqueued on the last one's unread tokens."""
+    model = request.getfixturevalue(model_name)
     r = np.random.RandomState(0)
-    prompts = [list(r.randint(1, 128, size=n)) for n in (5, 11, 7, 14)]
+    prompts = [list(r.randint(1, 128, size=n)) for n in lens]
 
-    eng = _engine(tiny_model)
-    handles = [eng.submit(p, max_new_tokens=6) for p in prompts]
+    eng = _engine(model)
+    handles = [eng.submit(p, max_new_tokens=n)
+               for p, n in zip(prompts, budgets)]
     eng.run_until_idle()
     batched = [h.result(timeout=5) for h in handles]
     # the ledger's decode-token count includes every request's FINAL
-    # tick (retirement must not eat it): 6 tokens = 1 prefill + 5 ticks
-    assert serving_ledger.totals()["decode_tokens"] == 4 * 5
+    # tick (retirement must not eat it): n tokens = 1 prefill + n-1 ticks
+    doc = serving_ledger.totals()
+    assert doc["decode_tokens"] == sum(budgets) - len(budgets)
+    # one program a tick of the longest answer, all but the first ahead;
+    # all four were admitted in one step, so no prefill found a tick in
+    # flight, and only the very last tick was read with nothing behind it
+    assert doc["decode_ticks"] == max(budgets) - 1
+    assert doc["ticks_ahead"] == doc["decode_ticks"] - 1
+    assert doc["ticks_ahead"] / doc["decode_ticks"] > (
+        0.8 if max(budgets) >= 32 else 0.0)
+    assert doc["pipeline_drains"] == {
+        "prefill": 0, "evict": 0, "error": 0, "stop": 0, "empty": 1}
+    assert serving_ledger.reconcile_spans(doc)["ok"]
 
-    eng_seq = _engine(tiny_model)
+    eng_seq = _engine(model)
     sequential = []
-    for p in prompts:
-        h = eng_seq.submit(p, max_new_tokens=6)
+    for p, n in zip(prompts, budgets):
+        h = eng_seq.submit(p, max_new_tokens=n)
         eng_seq.run_until_idle()
         sequential.append(h.result(timeout=5))
 
@@ -292,11 +320,16 @@ def test_continuous_batching_bit_match(tiny_model):
 
     # full-context greedy reference (non-paged forward)
     for p, got in zip(prompts, batched):
-        toks = list(p)
-        for _ in range(6):
-            logits = tiny_model.full_logits(np.asarray(toks))
-            toks.append(int(logits[0, -1].argmax()))
-        assert toks[len(p):] == got
+        assert _greedy_teacher_forced(model, p, got) == got
+
+
+def _greedy_teacher_forced(model, prompt, answer):
+    """The greedy token at every position of `answer`, from ONE non-paged
+    forward over prompt + answer: equal to `answer` exactly when each of
+    its tokens is the argmax given everything before it."""
+    logits = model.full_logits(np.asarray(list(prompt) + list(answer)))[0]
+    return [int(row.argmax())
+            for row in logits[len(prompt) - 1:len(prompt) - 1 + len(answer)]]
 
 
 def _greedy_reference(model, prompt, n):
@@ -317,8 +350,8 @@ def test_program_consumes_the_pool_it_is_given(tiny_model, program):
         out, _ = tiny_model.prefill(pages, np.asarray([3, 4, 5]), 3, [1])
     else:
         tables = np.zeros((4, tiny_model.max_blocks_per_req), np.int32)
-        out, _, _ = tiny_model.decode(pages, tables, np.zeros(4, np.int32),
-                                      np.zeros(4, np.int32))
+        out, _, _ = tiny_model.decode_enqueue(
+            pages, tables, np.zeros(4, np.int32), np.zeros(4, np.int32))
     assert pages.is_deleted() and not out.is_deleted()
     assert out.shape == pages.shape and out.sharding == pages.sharding
 
@@ -328,7 +361,8 @@ def test_engine_holds_only_the_newest_pool(tiny_model):
     seen = [eng.pages]
     h = eng.submit([3, 4, 5], max_new_tokens=4)
     while eng.step():
-        seen.append(eng.pages)
+        if eng.pages is not seen[-1]:  # the last step only reads
+            seen.append(eng.pages)
     assert h.result(timeout=5) == _greedy_reference(tiny_model, [3, 4, 5], 4)
     assert len(seen) >= 3 and seen[-1] is eng.pages
     assert all(p.is_deleted() for p in seen[:-1])
@@ -390,6 +424,186 @@ def test_engine_survives_a_program_failing_after_dispatch(
     eng.run_until_idle()
     assert nxt.result(timeout=5) == _greedy_reference(
         tiny_model, [11, 12, 13], 4)
+
+
+def _spy_on_enqueue(model, monkeypatch):
+    """Record (tables, lens, tokens) of every decode tick enqueued."""
+    calls, real = [], model.decode_enqueue
+
+    def spy(pages, tables, lens, toks, prev=None):
+        calls.append((tables.copy(), lens.copy(), toks.copy()))
+        return real(pages, tables, lens, toks, prev)
+
+    monkeypatch.setattr(model, "decode_enqueue", spy)
+    return calls
+
+
+def _edge_last_token_in_flight(model, monkeypatch):
+    """A request whose last token is in flight is not dispatched again,
+    and no tick writes K/V past a request's blocks."""
+    calls = _spy_on_enqueue(model, monkeypatch)
+    eng = _engine(model)
+    prompt = [3, 4, 5, 6, 7, 8]
+    h = eng.submit(prompt, max_new_tokens=3)
+    eng.step()  # prefill (token 1), tick 1 out (token 2)
+    eng.step()  # tick 2 out on tick 1's unread token (token 3), tick 1 read
+    assert len(calls) == 2 and h._req.unread == 1 and not h.done
+    assert eng.step() and h.done  # nothing left to dispatch: tick 2 read
+    assert len(calls) == 2 and not eng.step()
+    assert h.result(timeout=5) == _greedy_reference(model, prompt, 3)
+    for tables, lens, toks in calls:
+        # the one live row writes inside a block it holds, never past
+        # the position of the last token it may compute from
+        assert lens[0] <= len(prompt) + 3 - 2
+        assert tables[0, lens[0] // 8] != 0 and not lens[1:].any()
+    assert [c[2][0] for c in calls] == [h.result()[0], -1]
+
+
+def _edge_block_boundary(model, monkeypatch):
+    """The context crosses into a new block on the lookahead tick: the
+    block is grown from what was dispatched, before anything is read."""
+    calls = _spy_on_enqueue(model, monkeypatch)
+    eng = _engine(model)
+    prompt = [9, 2, 4, 6, 1, 3, 5]  # 7 tokens: tick 1 fills block one
+    h = eng.submit(prompt, max_new_tokens=5)
+    eng.run_until_idle()
+    assert h.result(timeout=5) == _greedy_reference(model, prompt, 5)
+    (t1, l1, k1), (t2, l2, k2) = calls[:2]
+    assert (l1[0], k1[0] >= 0, t1[0, 1]) == (7, True, 0)
+    assert (l2[0], k2[0]) == (8, -1) and t2[0, 1] != 0
+    assert serving_ledger.totals()["ticks_ahead"] == 3
+
+
+def _edge_evict_in_flight(model, monkeypatch):
+    """An eviction with a tick in flight reads it first: the victim's
+    generated tokens are folded into its prompt exactly once."""
+    eng = serving.ServingEngine(model, n_blocks=4)
+    eng.allocator = BlockAllocator(4, block_size=8)
+    loose_p = list(np.random.RandomState(1).randint(1, 128, size=20))
+    loose = eng.submit(loose_p, max_new_tokens=4, deadline_s=100.0)
+    eng.step()  # prefill + tick 1 in flight; all 3 blocks held
+    assert eng._inflight is not None and loose._req.unread == 1
+    tight = eng.submit([9, 8, 7], max_new_tokens=2, deadline_s=0.5)
+    eng.step()
+    assert loose._req.evictions == 1
+    assert len(loose._req.generated_prefix) == 2  # prefill's + tick 1's
+    eng.run_until_idle()
+    assert tight.result(timeout=5) == _greedy_reference(model, [9, 8, 7], 2)
+    assert loose.result(timeout=5) == _greedy_reference(model, loose_p, 4)
+    assert serving_ledger.totals()["pipeline_drains"]["evict"] == 1
+    assert eng.allocator.used() == 0
+
+
+def _edge_error_behind(model, monkeypatch):
+    """A decode program that fails on the device, found at its read with
+    the next tick already behind it: the requests of both go once, the
+    pool is rebuilt, and the engine serves the next request."""
+    model.warm()
+    reads, real = [], model.decode_read
+
+    def flaky(nxt):
+        reads.append(1)
+        if len(reads) == 2:
+            raise RuntimeError("injected: failed on the device")
+        return real(nxt)
+
+    monkeypatch.setattr(model, "decode_read", flaky)
+    eng = _engine(model)
+    a = eng.submit([3, 4, 5, 6], max_new_tokens=6)
+    b = eng.submit([7, 8], max_new_tokens=3)  # its last token is tick 2's
+    for _ in range(3):
+        eng.step()  # tick 3 out (a alone), then tick 2's read raises
+    lost = eng.pages
+    assert eng._inflight is None and not lost.is_deleted()
+    for h in (a, b):
+        assert h.done and "decode program failed: RuntimeError: injected" \
+            in h._req.error
+    doc = serving_ledger.totals()
+    assert doc["requests"]["failed"] == 2
+    assert doc["pipeline_drains"]["error"] == 1
+    assert eng.allocator.used() == 0 and not eng.active()
+    nxt = eng.submit([11, 12, 13], max_new_tokens=4)
+    eng.run_until_idle()
+    assert nxt.result(timeout=5) == _greedy_reference(model, [11, 12, 13], 4)
+
+
+def _edge_stop_in_flight(model, monkeypatch):
+    """stop() leaves nothing the device was given unread."""
+    eng = _engine(model)
+    h = eng.submit([3, 4, 5], max_new_tokens=4)
+    eng.step()
+    assert eng._inflight is not None and len(h._req.out_tokens) == 1
+    eng.stop(flush=False)
+    assert eng._inflight is None and len(h._req.out_tokens) == 2
+    doc = serving_ledger.totals()
+    assert doc["pipeline_drains"]["stop"] == 1 and doc["decode_tokens"] == 1
+    assert abs(sum(doc["buckets"].values()) - doc["wall_seconds"]) < 1e-6
+    eng.run_until_idle()  # and the engine can go on from there
+    assert h.result(timeout=5) == _greedy_reference(model, [3, 4, 5], 4)
+
+
+def _edge_drain_in_flight(model, monkeypatch):
+    """drain() with a tick in flight: admitted work completes, and the
+    replica is not `drained` until the last tick has been read."""
+    eng = _engine(model)
+    h = eng.submit([3, 4, 5], max_new_tokens=3)
+    eng.step()
+    eng.drain()
+    assert eng._inflight is not None and not eng.drained()
+    with pytest.raises(paddle.errors.Unavailable):
+        eng.submit([1, 2], max_new_tokens=2)
+    eng.run_until_idle()
+    assert eng.drained() and eng._inflight is None
+    assert h.result(timeout=5) == _greedy_reference(model, [3, 4, 5], 3)
+
+
+def _edge_budget(n):
+    def case(model, monkeypatch):
+        """An answer of one token is the prefill's; of two, one tick
+        that nothing is ever enqueued behind."""
+        calls = _spy_on_enqueue(model, monkeypatch)
+        eng = _engine(model)
+        h = eng.submit([5, 6, 7, 8], max_new_tokens=n)
+        eng.run_until_idle()
+        assert h.result(timeout=5) == _greedy_reference(model, [5, 6, 7, 8], n)
+        doc = serving_ledger.totals()
+        assert len(calls) == doc["decode_ticks"] == n - 1
+        assert doc["ticks_ahead"] == 0
+        assert doc["pipeline_drains"]["empty"] == n - 1
+        assert eng._inflight is None and eng.allocator.used() == 0
+    return case
+
+
+def _edge_late_admission(model, monkeypatch):
+    """One `prefill` drain per admission that found a tick in flight."""
+    eng = _engine(model)
+    prompts = [[3, 4, 5], [6, 7], [8, 9, 10], [11, 12]]
+    hs = [eng.submit(prompts[0], max_new_tokens=12)]
+    for _ in range(3):
+        eng.step()
+    hs.append(eng.submit(prompts[1], max_new_tokens=6))  # finds a tick
+    eng.step()
+    assert serving_ledger.totals()["pipeline_drains"]["prefill"] == 1
+    hs += [eng.submit(p, max_new_tokens=5) for p in prompts[2:]]
+    eng.step()  # two admissions in one step: only the first finds one
+    assert serving_ledger.totals()["pipeline_drains"]["prefill"] == 2
+    eng.run_until_idle()
+    for p, n, h in zip(prompts, (12, 6, 5, 5), hs):
+        assert h.result(timeout=5) == _greedy_reference(model, p, n)
+
+
+@pytest.mark.parametrize("case", [
+    _edge_last_token_in_flight, _edge_block_boundary, _edge_evict_in_flight,
+    _edge_error_behind, _edge_stop_in_flight, _edge_drain_in_flight,
+    _edge_budget(1), _edge_budget(2), _edge_late_admission,
+], ids=["last_token_in_flight", "block_boundary", "evict_in_flight",
+        "error_behind", "stop_in_flight", "drain_in_flight",
+        "max_new_tokens_1", "max_new_tokens_2", "late_admission"])
+def test_one_tick_in_flight_edges(tiny_model, monkeypatch, case):
+    """The corners of the one-tick lookahead (serving/engine.py): what
+    the host has not read yet is never needed unread, never computed
+    twice, and never lost."""
+    case(tiny_model, monkeypatch)
 
 
 def test_kv_eviction_under_pressure(tiny_model):
@@ -560,6 +774,11 @@ def test_serving_ledger_journal_resume_and_merge(tiny_model, tmp_path):
     assert resumed.get("resumed_from_journal")
     assert resumed["requests"]["ok"] == 1
     assert resumed["ticks"] == doc0["ticks"]
+    # 3 tokens: two ticks, the second ahead, the last one read alone
+    for doc in (doc0, loaded, resumed):
+        assert (doc["decode_ticks"], doc["ticks_ahead"]) == (2, 1)
+        assert doc["pipeline_drains"] == {
+            "prefill": 0, "evict": 0, "error": 0, "stop": 0, "empty": 1}
     serving_ledger.disable_persistence()
 
     # merge two replicas: counts add, histograms add exactly
@@ -575,7 +794,13 @@ def test_serving_ledger_journal_resume_and_merge(tiny_model, tmp_path):
     assert merged["wall_seconds"] == pytest.approx(
         2 * loaded["wall_seconds"])
     assert merged["span_reconciliation"]["verdict"] == "within_bound"
+    assert (merged["decode_ticks"], merged["ticks_ahead"]) == (4, 2)
+    assert merged["pipeline_drains"]["empty"] == 2
     assert serving_ledger.render_summary(merged).startswith("== serving")
+    serving_ledger.reset()
+    cleared = serving_ledger.totals()
+    assert cleared["ticks_ahead"] == 0
+    assert not any(cleared["pipeline_drains"].values())
 
 
 def test_lifecycle_spans_merge_into_timeline(tiny_model, tmp_path):
@@ -654,6 +879,11 @@ def test_status_serving_section(tiny_model):
     assert abs(sum(s["buckets"].values()) - s["wall_seconds"]) < 1e-6
     assert s["top_badput"] is not None
     assert s["reconciliation"]["verdict"] == "within_bound"
+    # how often a tick went out before the last one was read, and why not
+    assert s["pipeline"] == {
+        "decode_ticks": 2, "ticks_ahead": 1, "ahead_share": 0.5,
+        "drains": {"prefill": 0, "evict": 0, "error": 0, "stop": 0,
+                   "empty": 1}}
 
 
 def test_disabled_mode_inert(tmp_path):

@@ -443,3 +443,21 @@ def test_olmoe_temporaries_are_bounded_and_twelve_layers_fit_the_chip(olmoe_prog
     pool = 12 * 960 * 16 * 4096 * 2
     total = weights + pool + mem["temp_size_in_bytes"] + mem["generated_code_size_in_bytes"] * 6
     assert total < 0.9 * 16e9, total
+
+
+@pytest.mark.parametrize("programs,behind", [("serving_programs", 0), ("olmoe_programs", 3)])
+def test_decode_tick_takes_a_slots_unread_token_from_the_tick_before(request, programs, behind):
+    """The one-tick lookahead (serving/engine.py) costs the program one
+    argument, the tick before's own second output (`behind`: the routing
+    counts of a model with experts ride behind the tokens), and one
+    `select` over the token ids; the pool's aliasing, layout and copies
+    are held by the tests above, on this same program."""
+    import serve_compile_report as report
+
+    progs = request.getfixturevalue(programs)
+    B = progs.dm.max_batch
+    entry = report.entry_instructions(progs.text["decode_tick"])
+    ints = sorted(i["dims"] for i in entry if i["op"] == "parameter" and i["dtype"] == "s32")
+    # context lengths, tokens and `prev`, then the block tables
+    assert ints == sorted([(B,), (B,), (B + behind,), (B, progs.dm.max_blocks_per_req)])
+    assert re.search(r"pred\[%d\]\S* compare\(" % B, progs.text["decode_tick"])
