@@ -6,9 +6,14 @@ Counterpart of the reference appender
 op per differentiated forward op, seeds the loss gradient with a
 fill_constant(1.0), and sums duplicated gradients. Unlike the reference —
 where every op type ships a hand-written grad-op maker and grad kernels —
-grad ops here default to a generic rule whose lowering is `jax.vjp` of the
-forward lowering (framework/registry.py), so autodiff coverage tracks op
-coverage automatically.
+grad ops here default to a generic rule that applies the `jax.vjp` pullback
+of the forward lowering (framework/registry.py), so autodiff coverage tracks
+op coverage automatically. A generic grad op carries its forward op's inputs
+and, as `__out__<slot>`, its outputs: by those output names the executor
+finds the forward op in the block it traces, differentiates it where it is
+traced, and hands the grad op that pullback (executor._GradPairing), so a
+forward rule is traced once. Where the forward is not in the trace the grad
+op makes the pullback afresh from the inputs it carries.
 """
 from __future__ import annotations
 
@@ -250,12 +255,18 @@ def _clone_segment(
     dep: Optional[Variable],
 ) -> Dict[str, Variable]:
     """Re-emit `seg_ops` with renamed outputs; boundary inputs are read
-    through `recompute_barrier`. Returns original-name -> clone Variable
-    (checkpoint outputs stay on their saved originals). Ops whose every
-    output is saved need no clone. RNG-consuming clones keep the original
-    op's attrs (same `_rng_id`), so dropout masks replay bit-identically."""
+    through `recompute_barrier`. Returns original-name -> the Variable the
+    segment's grad ops read in its place: a clone's output, a boundary
+    input's barriered value, a saved checkpoint's throwaway duplicate. So
+    a grad op names exactly what its CLONE read and wrote, which is how
+    the executor pairs the two (the clone is differentiated where it is
+    traced; the original forward stays plain). Downstream forward ops keep
+    reading the saved originals. Ops whose every output is saved need no
+    clone. RNG-consuming clones keep the original op's attrs (same
+    `_rng_id`), so dropout masks replay bit-identically."""
     subst: Dict[str, Variable] = {}
     barriered: Dict[str, Variable] = {}
+    dups: Dict[str, Variable] = {}
     internal = set()
     for op in seg_ops:
         internal.update(op.output_arg_names())
@@ -309,6 +320,7 @@ def _clone_segment(
                         name=unique_name.generate(v.name + "@RECOMPUTE.dup"),
                         shape=v.shape, dtype=v.dtype, stop_gradient=True,
                     )
+                    dups[v.name] = nv
                 else:
                     nv = block.create_var(
                         name=unique_name.generate(v.name + "@RECOMPUTE"),
@@ -318,7 +330,7 @@ def _clone_segment(
                 vals.append(nv)
             new_outputs[slot] = vals
         block.append_op(op.type, inputs=new_inputs, outputs=new_outputs, attrs=op.all_attrs())
-    return subst
+    return {**barriered, **dups, **subst}
 
 
 def gradients(targets, inputs, target_gradients=None, no_grad_set=None):

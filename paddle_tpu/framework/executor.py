@@ -32,7 +32,7 @@ from . import core, registry
 from . import errors as _errs
 from . import shard_insight as _shard_insight
 from . import xla_insight as _insight
-from .program import Program, Variable, default_main_program
+from .program import Block, Program, Variable, default_main_program
 from .registry import LoweringContext
 from .scope import Scope, global_scope
 
@@ -75,6 +75,93 @@ _M_CACHE_SIZE = _monitor.gauge(
 _M_NONFINITE = _monitor.counter(
     "executor_nonfinite_total",
     "numerics-sentinel / FLAGS_check_nan_inf probe failures")
+_M_GRAD_PAIRED = _monitor.counter(
+    "executor_grad_paired_total",
+    "generic grad ops that applied the pullback their forward op made "
+    "where it was traced (counted when a block is traced, not per step)")
+_M_GRAD_RETRACED = _monitor.counter(
+    "executor_grad_retraced_total",
+    "generic grad ops that traced their forward rule a second time: the "
+    "forward op is not in the trace, or an input was rewritten since "
+    "(counted when a block is traced; the op types are in the flight "
+    "recorder's grad_retraced events)")
+
+
+def _slot_args(pvs, prefix: str = "") -> Dict[str, Tuple[str, ...]]:
+    """{slot: variable names} of a desc's filled slots that start with
+    `prefix`, the prefix dropped."""
+    return {pv.parameter[len(prefix):]: tuple(pv.arguments) for pv in pvs
+            if pv.arguments and pv.parameter.startswith(prefix)}
+
+
+class _GradPairing:
+    """One trace of one block: which forward ops are differentiated where
+    they are traced, and the pullbacks they leave for their grad ops.
+
+    A generic `<op>_grad` finds its forward by the forward's output
+    variable names, which it carries as its `__out__<slot>` inputs
+    (backward.py), and pairs with it if it also names the same input
+    variables (a recompute segment's grad ops name the CLONED forward
+    ops, so the clone is differentiated and the original stays plain).
+    Whether the pullback is used is settled when the grad op is traced:
+    only if it reads the very values the forward consumed."""
+
+    def __init__(self, ops):
+        # keyed by id(op): a pipeline section's positions are not the block's
+        self.cot_slots: Dict[int, set] = {}  # forward op -> slots to differentiate
+        self.forward_of: Dict[int, int] = {}  # grad op -> forward op
+        self.waiting: Dict[int, int] = {}  # forward op -> grad ops still to come
+        self.pullbacks: Dict[int, registry.Pullback] = {}
+        self.paired = 0
+        self.retraced: List[str] = []
+        if not any(op.type.endswith("_grad") for op in ops):
+            return  # a forward-only block (inference, startup, most sub-blocks)
+        writer: Dict[tuple, Any] = {}  # (op type, its outputs) -> the last such op
+
+        def key(type, outs):
+            return (type, tuple(sorted(outs.items())))
+
+        for op in ops:
+            if op.type in _STRUCTURAL_OPS:
+                continue
+            fwd_def = registry.generic_grad_forward(op.type)
+            if fwd_def is not None:
+                slots = _slot_args(op.desc.inputs)
+                fwd = writer.get(key(fwd_def.type, _slot_args(op.desc.inputs, "__out__")))
+                if (fwd is not None and _slot_args(fwd.desc.inputs)
+                        == registry.forward_inputs(slots)):
+                    self.forward_of[id(op)] = id(fwd)
+                    self.waiting[id(fwd)] = self.waiting.get(id(fwd), 0) + 1
+                    self.cot_slots.setdefault(id(fwd), set()).update(
+                        registry.cotangent_slots(slots))
+            writer[key(op.type, _slot_args(op.desc.outputs))] = op
+
+    def lower(self, opdef, ctx, op, ins, attrs):
+        """`run_lowering` of `op`, with the pairing."""
+        if isinstance(opdef.lower, registry.GenericGrad):
+            fwd = self.forward_of.get(id(op))
+            pullback = self.pullbacks.get(fwd)
+            if fwd is not None:
+                self.waiting[fwd] -= 1
+                if not self.waiting[fwd]:  # last consumer: keep nothing alive
+                    self.pullbacks.pop(fwd, None)
+            if pullback is not None and pullback.read_by(ins):
+                self.paired += 1
+                return pullback(ins)
+            self.retraced.append(op.type)
+        elif id(op) in self.cot_slots:
+            outs, self.pullbacks[id(op)] = registry.lower_differentiated(
+                opdef, ctx, ins, attrs, self.cot_slots[id(op)])
+            return outs
+        return registry.run_lowering(opdef, ctx, ins, attrs)
+
+    def report(self) -> None:
+        _M_GRAD_PAIRED.inc(self.paired)
+        if self.retraced:
+            _M_GRAD_RETRACED.inc(len(self.retraced))
+            _monitor.flight_record(
+                "grad_retraced", ",".join(sorted(set(self.retraced))),
+                ops=len(self.retraced), paired=self.paired)
 
 
 def lower_block(
@@ -82,13 +169,20 @@ def lower_block(
     block,
     env: Dict[str, Any],
     gc_plan: Optional[Dict[int, List[str]]] = None,
+    after_op=None,
 ) -> Dict[str, Any]:
-    """Trace every op of `block` in program order, threading values through
-    `env` (name -> jax value). Shared with control-flow op lowerings, which
-    call it recursively on sub-blocks. `gc_plan` (from the native core,
-    framework/native.py — reference executor.cc:474-480 per-op GC) names
-    the temporaries that die after each op; dropping them keeps the trace
-    env from pinning dead intermediates."""
+    """Trace every op of `block` (anything with `.ops`: a pipeline section
+    too) in program order, threading values through `env` (name -> jax
+    value). Shared with control-flow op lowerings, which call it
+    recursively on sub-blocks. A forward op whose generic grad op is in
+    the same block is differentiated here, where it is traced, and the
+    grad op applies that pullback (`_GradPairing`), so a forward rule is
+    traced once. `gc_plan` (from the native core, framework/native.py —
+    reference executor.cc:474-480 per-op GC) names the temporaries that
+    die after each op; dropping them keeps the trace env from pinning dead
+    intermediates. `after_op(i, op, env)` runs after each lowered op."""
+    pairing = _GradPairing(block.ops)
+    numbered = isinstance(block, Block)  # a section's positions are not the block's
     for i, op in enumerate(block.ops):
         if op.type not in _STRUCTURAL_OPS:
             # every HLO instruction carries its Paddle op in op_name
@@ -100,12 +194,16 @@ def lower_block(
                        if _profiler.tracing_active()
                        else contextlib.nullcontext())
             with jax.named_scope(op.type), op_span:
-                lower_op(ctx, op, env, op_idx=i)
+                lower_op(ctx, op, env, op_idx=i if numbered else None,
+                         pairing=pairing)
             if ctx.var_constraints and ctx.mesh is not None:
                 _apply_var_constraints(ctx, op, env)
+            if after_op is not None:
+                after_op(i, op, env)
         if gc_plan:
             for name in gc_plan.get(i, ()):
                 env.pop(name, None)
+    pairing.report()
     return env
 
 
@@ -164,7 +262,8 @@ def _apply_var_constraints(ctx: LoweringContext, op, env: Dict[str, Any]) -> Non
 
 
 def lower_op(ctx: LoweringContext, op, env: Dict[str, Any],
-             op_idx: Optional[int] = None) -> None:
+             op_idx: Optional[int] = None,
+             pairing: Optional[_GradPairing] = None) -> None:
     try:
         opdef = registry.get_op_def(op.type)
     except NotImplementedError as e:
@@ -184,7 +283,10 @@ def lower_op(ctx: LoweringContext, op, env: Dict[str, Any],
             ins[pv.parameter] = vals
     attrs = op.all_attrs()
     try:
-        outs = registry.run_lowering(opdef, ctx, ins, attrs)
+        if pairing is None:
+            outs = registry.run_lowering(opdef, ctx, ins, attrs)
+        else:
+            outs = pairing.lower(opdef, ctx, op, ins, attrs)
     except _errs.EnforceError as e:
         # an inner op (control-flow sub-block) may already have claimed
         # the provenance slot; set_op_provenance attaches only once
@@ -570,27 +672,22 @@ class Executor:
                                   var_constraints=var_constraints)
             ctx.program = program
             probes = []
-            if not check_nan:
-                lower_block(ctx, block, env, gc_plan=plan)
-            else:
+
+            def probe(i, op, env):
                 # FLAGS_check_nan_inf debug mode (reference
                 # operator.cc:1056 per-op CheckNanInf scan): probe every
                 # float output; the host run raises on the first bad op
-                for i, op in enumerate(block.ops):
-                    if op.type not in _STRUCTURAL_OPS:
-                        with jax.named_scope(op.type):
-                            lower_op(ctx, op, env, op_idx=i)
-                        for name in op.output_arg_names():
-                            val = env.get(name)
-                            if val is not None and jnp.issubdtype(
-                                jnp.result_type(val), jnp.inexact
-                            ):
-                                probes.append(jnp.all(jnp.isfinite(val)))
-                                if len(nan_probes) < len(probes):
-                                    nan_probes.append((i, op.type, name))
-                    if plan:
-                        for name in plan.get(i, ()):
-                            env.pop(name, None)
+                for name in op.output_arg_names():
+                    val = env.get(name)
+                    if val is not None and jnp.issubdtype(
+                        jnp.result_type(val), jnp.inexact
+                    ):
+                        probes.append(jnp.all(jnp.isfinite(val)))
+                        if len(nan_probes) < len(probes):
+                            nan_probes.append((i, op.type, name))
+
+            lower_block(ctx, block, env, gc_plan=plan,
+                        after_op=probe if check_nan else None)
             fetches = [env[n] for n in fetch_names]
             new_params = {n: env[n] for n in updated_names}
             next_seed_step = seed_step + jnp.asarray([0, 1], jnp.uint32)
@@ -775,25 +872,18 @@ class Executor:
                 for n in op.output_arg_names():
                     if n not in outs and (is_persistable(n) or n in fetch_names):
                         outs.append(n)
-            sec_ops = list(sec.ops)
             out_names = list(outs)
 
             mesh = getattr(program, "_mesh", None)
 
             sec_constraints = _compile_constraints(program)
 
-            def make_fn(sec=sec, sec_ops=sec_ops, out_names=out_names,
-                        mesh=mesh):
+            def make_fn(sec=sec, out_names=out_names, mesh=mesh):
                 def fn(inputs, rng_key):
                     ctx = LoweringContext(rng_key=rng_key, mesh=mesh,
                                           var_constraints=sec_constraints)
                     ctx.program = program
-                    env = dict(inputs)
-                    for op in sec_ops:
-                        with jax.named_scope(op.type):
-                            lower_op(ctx, op, env)
-                        if ctx.var_constraints and ctx.mesh is not None:
-                            _apply_var_constraints(ctx, op, env)
+                    env = lower_block(ctx, sec, dict(inputs))
                     return {n: env[n] for n in out_names}
 
                 fn.__name__ = fn.__qualname__ = (

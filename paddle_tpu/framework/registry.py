@@ -10,8 +10,10 @@ jit-compiles it, so "kernel choice" (operator.cc:1068) becomes XLA's job.
 
 Three reference subsystems collapse into this design:
   * InferShape (shape_inference.h) -> `jax.eval_shape` over the lowering rule;
-  * grad-op makers (grad_op_desc_maker.h) -> a generic `<op>_grad` whose
-    lowering is `jax.vjp` of the forward rule;
+  * grad-op makers (grad_op_desc_maker.h) -> a generic `<op>_grad` that
+    applies the `jax.vjp` pullback of the forward rule: the one its
+    forward op made where the executor traced it, or, where that forward
+    is not in the trace, one made afresh from the forward inputs;
   * AMP autocast lists -> dtype promotion inside rules (bf16-first).
 Custom overrides remain possible per op for all three.
 """
@@ -64,9 +66,6 @@ class OpDef:
     lower: LowerFn
     # custom builder-time inference: fn(op) -> None, sets output var shapes
     infer: Optional[Callable] = None
-    # custom grad lowering (same signature as lower; ins additionally holds
-    # forward outputs and `<slot>@GRAD` cotangents). None -> generic vjp.
-    grad_lower: Optional[LowerFn] = None
     # input slots that never receive gradient (e.g. integer indices)
     no_grad_inputs: frozenset = field(default_factory=frozenset)
     # custom desc-level grad maker: fn(op, grad_out_names) -> list of
@@ -97,7 +96,6 @@ def register_op(
     type: str,
     *,
     infer: Optional[Callable] = None,
-    grad_lower: Optional[LowerFn] = None,
     no_grad_inputs: Sequence[str] = (),
     grad_maker: Optional[Callable] = None,
     stop_gradient: bool = False,
@@ -114,7 +112,6 @@ def register_op(
             type=type,
             lower=fn,
             infer=infer,
-            grad_lower=grad_lower,
             no_grad_inputs=frozenset(no_grad_inputs),
             grad_maker=grad_maker,
             stop_gradient=stop_gradient,
@@ -290,56 +287,117 @@ def _is_diff_dtype(x) -> bool:
     return jnp.issubdtype(jnp.result_type(x), jnp.inexact)
 
 
-def _make_generic_grad_def(fwd: OpDef) -> OpDef:
-    """Build `<op>_grad` whose lowering is jax.vjp over the forward rule.
+class Pullback:
+    """What differentiating one forward op where it was traced leaves for
+    its `<op>_grad`: the values the forward consumed, its cotangent-
+    carrying outputs, and the `jax.vjp` pullback over the differentiable
+    inputs. Calling it on a grad op's `ins` builds the cotangents (zeros
+    for a missing `@GRAD`) and returns `{<in_slot>@GRAD: arrays}`."""
+
+    def __init__(self, fwd_ins: InsDict, outs: Dict[str, List[Any]], vjp):
+        self.fwd_ins = fwd_ins
+        self.outs = outs
+        self.vjp = vjp
+
+    def read_by(self, ins: InsDict) -> bool:
+        """Does a grad op's `ins` hold, for every forward input, the very
+        value the forward consumed? (A variable rewritten in between is
+        another object, and its grad op must differentiate afresh.)"""
+        theirs = forward_inputs(ins)
+        return theirs.keys() == self.fwd_ins.keys() and all(
+            len(theirs[slot]) == len(mine)
+            and all(a is b for a, b in zip(theirs[slot], mine))
+            for slot, mine in self.fwd_ins.items())
+
+    def __call__(self, ins: InsDict) -> Dict[str, Any]:
+        cot = {}
+        for slot, arrs in self.outs.items():
+            gs = list(ins.get(slot + GRAD_SUFFIX, []))
+            gs += [None] * (len(arrs) - len(gs))
+            cot[slot] = [jnp.zeros_like(a) if g is None else g
+                         for a, g in zip(arrs, gs)]
+        (gins,) = self.vjp(cot)
+        return {slot + GRAD_SUFFIX: arrs for slot, arrs in gins.items()}
+
+
+def forward_inputs(ins: InsDict) -> InsDict:
+    """The forward op's own inputs among a generic grad op's: not the
+    `<slot>@GRAD` cotangents, and not the forward outputs, which grad-op
+    builders tag `__out__<slot>` to tell them from same-named inputs."""
+    return {k: v for k, v in ins.items()
+            if not k.endswith(GRAD_SUFFIX) and not k.startswith("__out__")}
+
+
+def cotangent_slots(slots) -> set:
+    """Forward output slots that the grad-op slots `slots` bring a
+    cotangent for."""
+    return {k[: -len(GRAD_SUFFIX)] for k in slots if k.endswith(GRAD_SUFFIX)}
+
+
+def lower_differentiated(fwd: OpDef, ctx: LoweringContext, ins: InsDict,
+                         attrs, cot_slots) -> tuple:
+    """Trace `fwd`'s rule ONCE, under `jax.vjp` over its differentiable
+    inputs: returns (every output slot, normalized; the `Pullback`).
+    Only float outputs in `cot_slots` are differentiated; the rest ride
+    along as aux (integer outputs included), so the caller can still
+    write every output slot."""
+    diff, fixed = {}, {}
+    for slot, arrs in ins.items():
+        if slot in fwd.no_grad_inputs or not all(_is_diff_dtype(a) for a in arrs):
+            fixed[slot] = arrs
+        else:
+            diff[slot] = arrs
+
+    def f(diff_):
+        outs = run_lowering(fwd, ctx, {**fixed, **diff_}, attrs)
+        carrying = {
+            k: v for k, v in outs.items()
+            if k in cot_slots and all(_is_diff_dtype(a) for a in v)
+        }
+        return carrying, outs
+
+    carrying, vjp, outs = jax.vjp(f, diff, has_aux=True)
+    return outs, Pullback(ins, carrying, vjp)
+
+
+class GenericGrad:
+    """Lowering rule of every `<op>_grad` without a rule of its own: the
+    pullback of the forward rule. The executor hands a grad op the
+    pullback its forward op made where it was traced
+    (executor._GradPairing); called as a rule, as here, it differentiates
+    the forward rule afresh over the forward inputs the grad op carries,
+    which traces that rule a second time (XLA merges the copy where it
+    lowered to XLA operations, and runs a Mosaic kernel twice).
 
     Grad-op contract (mirrors reference GradOpDescMaker conventions):
-      inputs : forward input slots, forward output slots, and
-               `<out_slot>@GRAD` cotangent slots;
+      inputs : forward input slots, forward output slots (`__out__<slot>`),
+               and `<out_slot>@GRAD` cotangent slots;
       outputs: `<in_slot>@GRAD` for differentiable forward inputs.
     """
 
-    def glower(ctx: LoweringContext, ins: InsDict, attrs) -> Dict[str, Any]:
-        fwd_in = {
-            k: v
-            for k, v in ins.items()
-            if not k.endswith(GRAD_SUFFIX) and _slot_is_fwd_input(k, ins)
-        }
-        # split differentiable vs fixed inputs
-        diff = {}
-        fixed = {}
-        for slot, arrs in fwd_in.items():
-            if slot in fwd.no_grad_inputs or not all(_is_diff_dtype(a) for a in arrs):
-                fixed[slot] = arrs
-            else:
-                diff[slot] = arrs
+    def __init__(self, fwd: OpDef):
+        self.fwd = fwd
 
-        def f(diff_):
-            outs = run_lowering(fwd, ctx, {**fixed, **diff_}, attrs)
-            # only float, cotangent-carrying outputs matter for the vjp
-            return {
-                k: v
-                for k, v in outs.items()
-                if (k + GRAD_SUFFIX) in ins and all(_is_diff_dtype(a) for a in v)
-            }
+    def __call__(self, ctx: LoweringContext, ins: InsDict, attrs) -> Dict[str, Any]:
+        _, pullback = lower_differentiated(
+            self.fwd, ctx, forward_inputs(ins), attrs, cotangent_slots(ins))
+        return pullback(ins)
 
-        outs, vjp = jax.vjp(f, diff)
-        cot = {}
-        for slot, arrs in outs.items():
-            gs = ins.get(slot + GRAD_SUFFIX, [])
-            cot[slot] = [
-                g if g is not None else jnp.zeros_like(a)
-                for a, g in zip(arrs, list(gs) + [None] * (len(arrs) - len(gs)))
-            ]
-        (gins,) = vjp(cot)
-        return {slot + GRAD_SUFFIX: arrs for slot, arrs in gins.items()}
 
-    def _slot_is_fwd_input(slot: str, ins: InsDict) -> bool:
-        # forward outputs are also fed to the grad op (for custom rules that
-        # want them); the generic vjp recomputes, so exclude pure outputs.
-        # Convention: grad-op builders tag forward-output slots as
-        # "__out__<slot>" to disambiguate from same-named inputs.
-        return not slot.startswith("__out__")
+def generic_grad_forward(type: str) -> Optional[OpDef]:
+    """The forward OpDef if op `type` lowers by the generic grad rule."""
+    if not type.endswith("_grad"):
+        return None
+    try:
+        rule = get_op_def(type).lower
+    except NotImplementedError:
+        return None
+    return rule.fwd if isinstance(rule, GenericGrad) else None
+
+
+def _make_generic_grad_def(fwd: OpDef) -> OpDef:
+    """Build `<op>_grad`: the `GenericGrad` rule, and an inference that
+    gives each `<in_slot>@GRAD` the shape and dtype of its input."""
 
     def ginfer(op) -> None:
         # d(input) has the shape/dtype of the input itself
@@ -354,7 +412,7 @@ def _make_generic_grad_def(fwd: OpDef) -> OpDef:
 
     return OpDef(
         type=fwd.type + "_grad",
-        lower=glower,
+        lower=GenericGrad(fwd),
         infer=ginfer,
         stop_gradient=True,
         uses_rng=fwd.uses_rng,

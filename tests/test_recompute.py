@@ -203,3 +203,66 @@ def test_recompute_emits_real_rematerialization():
     # fwd GPT dots (~1/3 of fwd+bwd) are re-emitted per checkpointed
     # segment: 153 -> 195 measured on the 6-layer config
     assert rec_dots >= plain_dots * 1.25, (plain_dots, rec_dots)
+
+
+def test_recompute_grad_ops_take_the_pullback_of_their_clone():
+    """A segment's grad ops name what the CLONED forward ops read and wrote,
+    so the clone is the op that is differentiated where it is traced and
+    the original forward stays plain (recomputed once, not twice); only the
+    op that produces a checkpoint has no clone, and its grad op
+    differentiates afresh. Loss and every parameter after three steps
+    match the program without recompute."""
+    from paddle_tpu import monitor
+    from paddle_tpu.distributed.fleet.meta_optimizers import RecomputeOptimizer
+    from paddle_tpu.framework import Executor, Scope, program_guard, registry, unique_name
+    from paddle_tpu.framework.executor import _GradPairing
+    from paddle_tpu.models.gpt import GPTConfig, build_train_program
+
+    def train(with_recompute):
+        cfg = GPTConfig(vocab_size=64, n_layer=3, n_head=2, d_model=32, max_seq_len=16)
+        with unique_name.guard():
+            main, startup, io = build_train_program(cfg, batch=4, seq=16)
+            main.random_seed = startup.random_seed = 5
+            with program_guard(main, startup):
+                opt = SGD(learning_rate=0.1)
+                if with_recompute:
+                    RecomputeOptimizer(opt, {"checkpoints": [v.name for v in io["checkpoints"]]}).minimize(io["loss"])
+                else:
+                    opt.minimize(io["loss"])
+        scope, exe = Scope(), Executor()
+        exe.run(startup, scope=scope)
+        r = np.random.RandomState(0)
+        feed = {"tokens": r.randint(0, 64, (4, 16)).astype("int64"),
+                "labels": r.randint(0, 64, (4, 16)).astype("int64")}
+        counters = [monitor.default_registry().get(f"executor_grad_{k}_total") for k in ("paired", "retraced")]
+        before = [c.value for c in counters]
+        losses = [float(exe.run(main, feed=feed, fetch_list=[io["loss"]], scope=scope)[0]) for _ in range(3)]
+        params = {p.name: np.asarray(scope.get(p.name)) for p in main.all_parameters()}
+        return main, losses, params, [c.value - b for c, b in zip(counters, before)]
+
+    paddle.enable_static()
+    try:
+        _, plain_losses, plain_params, plain_counts = train(False)
+        main, losses, params, counts = train(True)
+    finally:
+        paddle.disable_static()
+    np.testing.assert_allclose(losses, plain_losses, rtol=1e-5, atol=1e-6)
+    for name, want in plain_params.items():
+        np.testing.assert_allclose(params[name], want, rtol=1e-4, atol=1e-6, err_msg=name)
+
+    ops = main.global_block().ops
+    plan = _GradPairing(ops)
+    by_id = {id(op): op for op in ops}
+    generic = [op for op in ops if registry.generic_grad_forward(op.type) is not None]
+    reads_clone = [op for op in generic if any("@RECOMPUTE" in n for n in op.input_arg_names())]
+    assert len(reads_clone) > 40
+    alone = [op for op in reads_clone if id(op) not in plan.forward_of]
+    # one op a segment produces the checkpoint and is not cloned
+    assert len(alone) == 3 and all(op.type == "elementwise_add_grad" for op in alone)
+    for op in reads_clone:
+        if id(op) in plan.forward_of:
+            # the CLONE is differentiated where it is traced; its original stays plain
+            fwd = by_id[plan.forward_of[id(op)]]
+            assert all("@RECOMPUTE" in n for n in fwd.output_arg_names()), op.type
+    assert "fused_attention_tpu" in {by_id[j].type for j in plan.forward_of.values()}
+    assert counts == [len(generic) - 3, 3] and plain_counts[1] == 0

@@ -348,6 +348,70 @@ def test_executor_step_carries_paddle_ops_in_op_name_and_a_role_name(tpu_arg):
         assert any(f"jit(train_step)/{paddle_op}/" in o for o in ops), paddle_op
 
 
+def test_train_step_runs_every_pallas_forward_once_and_backward_under_its_grad_op(tpu_arg, monkeypatch):
+    """The chip's train step at GPT-2 small's widths, seq 1024 (so flash
+    dispatches) and the pallas CE, 2 layers: a forward op is differentiated
+    where it is traced, so each forward kernel is in the program once (XLA
+    does not merge Mosaic calls: a second trace of the rule would be a
+    second kernel), and the backward kernels sit under their grad op's scope."""
+    import sys
+
+    import numpy as np
+
+    import paddle_tpu as paddle
+    from paddle_tpu import monitor
+    from paddle_tpu.framework import Executor, Scope, program_guard
+    from paddle_tpu.models.gpt import GPTConfig, build_train_program
+    from paddle_tpu.optimizer import SGD
+
+    for mod in ("flash_attention", "fused_lmhead_ce", "backend"):
+        monkeypatch.setattr(sys.modules[f"paddle_tpu.ops.pallas.{mod}"], "on_tpu", lambda: True)
+    n_layer, B, T = 2, 2, 1024
+    counters = {n: monitor.default_registry().get(n) for n in
+                ("executor_grad_paired_total", "executor_grad_retraced_total")}
+    paddle.enable_static()
+    try:
+        cfg = GPTConfig(vocab_size=50304, n_layer=n_layer, n_head=12, d_model=768, max_seq_len=T,
+                        dropout=0.0, dtype="bfloat16", fused_lm_head="pallas")
+        main, startup, io = build_train_program(cfg, batch=B, seq=T)
+        with program_guard(main, startup):
+            SGD(learning_rate=0.1).minimize(io["loss"])
+        assert io["lm_head_impl"] == "pallas"
+        scope, exe = Scope(), Executor()
+        exe.run(startup, scope=scope)
+        feeds = {"tokens": jnp.zeros((B, T), jnp.int32), "labels": jnp.zeros((B, T), jnp.int32)}
+        for n, fn in (getattr(main, "_extra_feeds", None) or {}).items():
+            feeds[n] = jnp.asarray(fn())
+        compiled = exe._get_compiled(main, feeds, [io["loss"].name], scope)
+    finally:
+        paddle.disable_static()
+    before = {n: c.value for n, c in counters.items()}
+    spec = lambda a: tpu_arg(np.shape(a), a.dtype)  # noqa: E731
+    text = compiled.fn.lower(
+        {k: spec(v) for k, v in feeds.items()},
+        {n: spec(scope.get(n)) for n in compiled.mutable_names},
+        {n: spec(scope.get(n)) for n in compiled.const_names},
+        tpu_arg((2,), jnp.uint32)).compile().as_text()
+    generic_grads = sum(op.type.endswith("_grad") for op in main.global_block().ops)
+    assert counters["executor_grad_paired_total"].value - before["executor_grad_paired_total"] == generic_grads
+    assert counters["executor_grad_retraced_total"].value == before["executor_grad_retraced_total"]
+
+    calls = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"[^\n]*op_name=\"([^\"]*)\"", text)
+    assert len(calls) == text.count('custom_call_target="tpu_custom_call"')
+    own = _own_names([re.sub(r"\.\d+$", "", n) for n, _ in calls])
+    assert {k: own.count(k) for k in set(own)} == {
+        "flash_fwd": n_layer, "flash_dq": n_layer, "flash_dkv": n_layer,
+        "lmhead_ce_stats": 1, "lmhead_ce_dx": 1, "lmhead_ce_dw": 1}
+    fwd_rx = _metric_pattern("fwd_passes_per_step")
+    assert sum(bool(fwd_rx.search(re.sub(r"\.\d+$", "", n))) for n, _ in calls) == n_layer
+    scope_of = {"flash_fwd": "fused_attention_tpu/", "flash_dq": "fused_attention_tpu_grad/",
+                "flash_dkv": "fused_attention_tpu_grad/", "lmhead_ce_stats": "fused_lm_head_ce/",
+                "lmhead_ce_dx": "fused_lm_head_ce_grad/", "lmhead_ce_dw": "fused_lm_head_ce_grad/"}
+    for name, op_name in calls:
+        (kernel,) = _own_names([name])
+        assert op_name.startswith("jit(train_step)/" + scope_of[kernel]), (name, op_name)
+
+
 # The same programs for a block of another kind: OLMoE's (RMSNorm, RoPE, q/k
 # norm, 64 experts of which a token takes 8, untied head) at the widths of
 # the cell olmoe-serve-batch, 2 of its 12 layers, no weight allocated.

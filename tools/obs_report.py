@@ -129,6 +129,11 @@ def _executor_section(snap) -> Dict[str, Any]:
         "cache_hit_rate": round(hits / lookups, 4) if lookups else None,
         "cache_size": _scalar(snap, "executor_cache_size"),
         "run_total": _scalar(snap, "executor_run_total"),
+        # generic grad ops by how they were lowered when their block was
+        # traced: with the pullback their forward op made, or by tracing
+        # the forward rule a second time (a Mosaic kernel then runs twice)
+        "grad_paired": _scalar(snap, "executor_grad_paired_total"),
+        "grad_retraced": _scalar(snap, "executor_grad_retraced_total"),
         "compile_seconds": hist_summary(
             _hist_entry(snap, "executor_compile_seconds")),
         "run_seconds": hist_summary(_hist_entry(snap, "executor_run_seconds")),
