@@ -68,25 +68,84 @@ class GPTConfig:
     norm_eps: float = 1e-5
     position: str = "learned"     # or "rope": rotate-half on q and k
     rope_theta: float = 10000.0
-    qk_norm: bool = False         # the norm over q and k, all heads' lanes
+    # the norm over q and k: True over all heads' lanes at once, "head"
+    # over each head's own head_dim lanes (one gain of head_dim)
+    qk_norm: object = False
     bias: bool = True             # biases on the projections
-    mlp: str = "gelu"             # or "moe": router + SwiGLU experts of d_ff
+    # the feed-forward: "gelu" (fc_in, erf GELU, fc_out), "swiglu" (gate,
+    # up, down) or "moe": router + SwiGLU experts of d_ff
+    mlp: str = "gelu"
     n_experts: int = 0
     experts_per_token: int = 0            # their softmax weights as they are
+    # K|V heads, each shared by n_head / n_kv_head query heads in order
+    # (grouped-query attention); None: one per query head
+    n_kv_head: Optional[int] = None
+    # layers of more than one kind, one entry a layer (None: all alike).
+    # layer_ops: "attn", or "conv": the gated short convolution, x W_in
+    # split into B | C | X, causal depthwise taps over B * X, C * that
+    # through W_out, whose last conv_kernel - 1 gated inputs are a decode
+    # slot's state. layer_mlps: each layer's feed-forward, as `mlp`; a
+    # "swiglu" layer of a model with experts is d_ff_dense wide
+    layer_ops: Optional[Tuple[str, ...]] = None
+    layer_mlps: Optional[Tuple[str, ...]] = None
+    d_ff_dense: Optional[int] = None
+    conv_kernel: int = 3
+    conv_bias: bool = False
+    # the router: scores "softmax" over the experts, or "sigmoid" of each
+    # logit; router_bias adds a per-expert bias to the scores that SELECT
+    # the top-k and never to the weights; norm_topk rescales the k weights
+    # to sum to 1; routed_scale multiplies them
+    router_score: str = "softmax"
+    router_bias: bool = False
+    norm_topk: bool = False
+    routed_scale: float = 1.0
+
+    def __post_init__(self):
+        for name in ("layer_ops", "layer_mlps"):
+            kinds = getattr(self, name)
+            if kinds is not None:
+                if len(kinds) != self.n_layer:
+                    raise ValueError(f"{name} names {len(kinds)} layers, "
+                                     f"n_layer is {self.n_layer}")
+                setattr(self, name, tuple(kinds))
 
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_head
 
     @property
+    def kv_heads(self) -> int:
+        return self.n_kv_head or self.n_head
+
+    @property
     def ffn_dim(self) -> int:
         return self.d_ff or 4 * self.d_model
+
+    def layer_kind(self, i: int) -> Tuple[str, str]:
+        """Layer ``i``'s (operator, feed-forward)."""
+        return (self.layer_ops[i] if self.layer_ops else "attn",
+                self.layer_mlps[i] if self.layer_mlps else self.mlp)
+
+    def layers_of(self, op: str) -> List[int]:
+        """The layers whose operator is ``op``, in order: an attention
+        layer's place here is its share of the KV pool, a conv layer's its
+        share of the state pool."""
+        return [i for i in range(self.n_layer) if self.layer_kind(i)[0] == op]
+
+    def mlp_width(self, mlp: str) -> int:
+        """Width of a feed-forward of kind ``mlp`` in this model."""
+        if mlp == "swiglu" and self.d_ff_dense:
+            return self.d_ff_dense
+        return self.ffn_dim
 
     def block(self) -> tuple:
         """The block's description as one hashable value."""
         return (self.norm, self.norm_eps, self.position, self.rope_theta,
                 self.qk_norm, self.bias, self.mlp, self.n_experts,
-                self.experts_per_token, self.tie_embeddings)
+                self.experts_per_token, self.n_kv_head, self.layer_ops,
+                self.layer_mlps, self.d_ff_dense, self.conv_kernel,
+                self.conv_bias, self.router_score, self.router_bias,
+                self.norm_topk, self.routed_scale, self.tie_embeddings)
 
 
 def _param(helper: LayerHelper, name: str, shape, dtype, std: float = 0.02, zeros=False):

@@ -4,6 +4,10 @@
     top-k of p, its weights used as they are (not rescaled to sum to 1)
     y[n]     = sum_{e in topk(n)} p[n, e] * down_e(silu(gate_e x[n]) * up_e x[n])
 
+(the default router; ``route`` also scores by a sigmoid, selects under a
+per-expert bias that the weights do not carry, and rescales the top-k
+weights to sum to 1, as the block's description asks)
+
 Pure ``jax.numpy`` on stacked expert weights (``gate``, ``up``:
 ``[E, D, F]``; ``down``: ``[E, F, D]``), for the serving programs: no
 graph op is registered, training a model with experts is not built yet.
@@ -24,15 +28,36 @@ from __future__ import annotations
 __all__ = ["route", "experts", "routing_counts"]
 
 
-def route(x, router_w, k: int):
+def route(x, router_w, k: int, score: str = "softmax", bias=None,
+          norm_topk: bool = False, scale: float = 1.0):
     """Routing of tokens ``x`` [N, D]: (dense weights [N, E] float32,
-    zero off each token's top-k; the top-k expert ids [N, k])."""
+    zero off each token's top-k; the top-k expert ids [N, k]).
+
+    ``score``: "softmax" over the experts, or "sigmoid" of each logit.
+    ``bias`` [E] is added to the scores that SELECT the top-k only: the
+    weights are the chosen experts' unbiased scores. ``norm_topk`` divides
+    them by their sum (+ 1e-6, as the published forward has it), ``scale``
+    multiplies them."""
     import jax
     import jax.numpy as jnp
 
     logits = jnp.dot(x, router_w, preferred_element_type=jnp.float32)
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    w, idx = jax.lax.top_k(probs, k)
+    logits = logits.astype(jnp.float32)
+    if score == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+    elif score == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"unknown router score {score!r}")
+    if bias is None:
+        w, idx = jax.lax.top_k(probs, k)
+    else:
+        _, idx = jax.lax.top_k(probs + bias.astype(jnp.float32), k)
+        w = jnp.take_along_axis(probs, idx, axis=-1)
+    if norm_topk:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+    if scale != 1.0:
+        w = w * scale
     rows = jnp.arange(x.shape[0])[:, None]
     dense = jnp.zeros(probs.shape, jnp.float32).at[rows, idx].set(w)
     return dense, idx

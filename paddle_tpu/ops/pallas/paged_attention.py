@@ -26,6 +26,14 @@ ONE more; a head's own output is the diagonal block of that product, and
 the caller takes its V half at the very end. The matrix unit is bound by
 the K/V tiles it has to load either way, so the off-diagonal products
 cost nothing that matters.
+
+Grouped queries: a row of ``n_kv`` K|V heads under ``n_kv * group`` query
+heads, query head ``i`` over K|V head ``i // group``. The caller hands q
+group-major, ``[group, row]``: line ``r`` holds, over the K lanes of K|V
+head ``j``, query head ``j * group + r``. Matrix row ``r * n_kv + j`` is
+that head, its diagonal block the lanes of K|V head ``j``, so each line
+of the output is again a sum over ``n_kv`` aligned rows whose blocks do
+not overlap. With ``group == 1`` this is the kernel above, op for op.
 """
 from __future__ import annotations
 
@@ -52,12 +60,18 @@ def _sublanes(dtype) -> int:
     return 32 // jnp.dtype(dtype).itemsize
 
 
-def unsupported(head_dim: int, block_size: int, dtype) -> str:
+def unsupported(head_dim: int, block_size: int, dtype,
+                n_head: int = 1, n_kv_head: int = 1) -> str:
     """Why a pool of this geometry cannot take the kernel ('' if it
     can): the kernel copies whole pages and multiplies whole rows, so a
     head has to fill whole 128-lane tiles and a page whole sublane tiles
     (a row or a page the runtime would pad is not what the copies
-    assume)."""
+    assume). Grouped queries (``n_head`` over fewer ``n_kv_head``) sum
+    each output line over ``n_kv_head`` rows of the float32 accumulator,
+    which have to be whole 8-row tiles."""
+    if n_head != n_kv_head and (n_head % n_kv_head or n_kv_head % 8):
+        return (f"{n_head} query heads over {n_kv_head} K|V heads: not a "
+                f"whole group over whole 8-row tiles")
     if (2 * head_dim) % _LANES:
         return (f"a head's K|V is {2 * head_dim} lanes, not a multiple of "
                 f"{_LANES}")
@@ -68,18 +82,19 @@ def unsupported(head_dim: int, block_size: int, dtype) -> str:
 
 
 def vmem_scratch_bytes(n_head: int, head_dim: int, block_size: int,
-                       dtype) -> int:
+                       dtype, n_kv_head: int = 0) -> int:
     """VMEM the kernel's scratch takes (both page buffers, the
     accumulator, the two statistics), for the compile report."""
     hp = _round_up(n_head, _sublanes(dtype))
-    hw = n_head * 2 * head_dim
+    hw = (n_kv_head or n_head) * 2 * head_dim
     return (2 * _STEP_TOKENS * hw * jnp.dtype(dtype).itemsize
             + hp * hw * 4 + 2 * hp * _LANES * 4)
 
 
 def _kernel(tables_ref, lens_ref, q_ref, pool_ref, o_ref,
             buf, sem, cur, m_scr, l_scr, acc_scr,
-            *, block_size, pages_per_step, max_blocks, head_lanes, scale):
+            *, block_size, pages_per_step, max_blocks, head_lanes, scale,
+            n_kv=0, group=1):
     b, nb = pl.program_id(0), pl.num_programs(0)
     bs, pps, w = block_size, pages_per_step, head_lanes
     step_tokens = pps * bs
@@ -119,8 +134,20 @@ def _kernel(tables_ref, lens_ref, q_ref, pool_ref, o_ref,
     # row h of the block-diagonal views owns lanes [h * w, (h + 1) * w)
     row = jax.lax.broadcasted_iota(jnp.int32, (hp, hw), 0)
     lane = jax.lax.broadcasted_iota(jnp.int32, (hp, hw), 1)
-    diag = (lane >= row * w) & (lane < (row + 1) * w)
-    qblk = jnp.where(diag, q_ref[...], 0.0).astype(buf.dtype)  # [hp, hw]
+    if group == 1:
+        diag = (lane >= row * w) & (lane < (row + 1) * w)
+        q_rows = q_ref[...]
+    else:
+        # row r * n_kv + j: query head j * group + r, over K|V head j
+        kv = jax.lax.rem(row, n_kv)
+        diag = ((lane >= kv * w) & (lane < (kv + 1) * w)
+                & (row < group * n_kv))
+        q_rows = jnp.concatenate(
+            [jnp.broadcast_to(q_ref[r:r + 1, :], (n_kv, hw))
+             for r in range(group)]
+            + [jnp.zeros((hp - group * n_kv, hw), q_ref.dtype)]
+            * (hp > group * n_kv))
+    qblk = jnp.where(diag, q_rows, 0.0).astype(buf.dtype)  # [hp, hw]
 
     def step(c, slot):
         nxt = 1 - slot
@@ -157,7 +184,12 @@ def _kernel(tables_ref, lens_ref, q_ref, pool_ref, o_ref,
     cur[0] = jax.lax.fori_loop(0, n_steps, step, cur[0])
     # position 0 is never masked, so every sum is positive
     out = jnp.where(diag, acc_scr[...] / l_scr[:, :1], 0.0)
-    o_ref[...] = jnp.sum(out, axis=0, keepdims=True)
+    if group == 1:
+        o_ref[...] = jnp.sum(out, axis=0, keepdims=True)
+    else:
+        for r in range(group):
+            o_ref[r:r + 1, :] = jnp.sum(out[r * n_kv:(r + 1) * n_kv],
+                                        axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -165,16 +197,21 @@ def _paged_attention(q, pool, tables, context_lens, *, scale, interpret):
     B, H, hd = q.shape
     _, bs, hw = pool.shape
     w = 2 * hd
+    n_kv = hw // w
+    group = H // n_kv
     max_blocks = tables.shape[1]
     pps = _STEP_TOKENS // bs
     hp = _round_up(H, _sublanes(pool.dtype))
     # q over the K lanes of its head, zeros over the V lanes; float32
     # holds the model's values exactly and is what the kernel selects on
     qp = jnp.pad(q.astype(jnp.float32), ((0, 0), (0, 0), (0, hd)))
-    row = pl.BlockSpec((None, 1, hw), lambda b, *_: (b, 0, 0))
+    if group > 1:  # group-major lines: see the module's docstring
+        qp = qp.reshape(B, n_kv, group, w).transpose(0, 2, 1, 3)
+    row = pl.BlockSpec((None, group, hw), lambda b, *_: (b, 0, 0))
     o = pl.pallas_call(
         functools.partial(_kernel, block_size=bs, pages_per_step=pps,
-                          max_blocks=max_blocks, head_lanes=w, scale=scale),
+                          max_blocks=max_blocks, head_lanes=w, scale=scale,
+                          n_kv=n_kv, group=group),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(B,),
@@ -188,13 +225,15 @@ def _paged_attention(q, pool, tables, context_lens, *, scale, interpret):
                 pltpu.VMEM((hp, _LANES), jnp.float32),
                 pltpu.VMEM((hp, hw), jnp.float32),
             ]),
-        out_shape=jax.ShapeDtypeStruct((B, 1, hw), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, group, hw), jnp.float32),
         # the buffers and the copy in flight carry over from slot to slot
         compiler_params=compiler_params(("arbitrary",)),
         interpret=interpret,
         name="paged_attention",
     )(tables.reshape(-1).astype(jnp.int32), context_lens.astype(jnp.int32),
-      qp.reshape(B, 1, hw), pool)
+      qp.reshape(B, group, hw), pool)
+    if group > 1:  # back to query-head order
+        o = o.reshape(B, group, n_kv, w).transpose(0, 2, 1, 3)
     return o.reshape(B, H, w)[..., hd:].reshape(B, H * hd).astype(q.dtype)
 
 
@@ -202,7 +241,8 @@ def paged_attention(q, pool, tables, context_lens, scale, interpret=None):
     """Attention of one new token a slot over that slot's paged context.
 
     q ``[B, H, hd]`` (normed and rotated as the block has it); ``pool``
-    the KV pool as it rests, ``[rows, block_size, H * 2 * hd]``;
+    the KV pool as it rests, ``[rows, block_size, H_kv * 2 * hd]`` (``H_kv
+    == H``, or fewer K|V heads that groups of query heads share);
     ``tables`` ``[B, max_blocks]`` the pool row-block of each of a slot's
     pages (the layer's base already added); ``context_lens`` ``[B]`` the
     position of the new token, whose K and V are in the pool already:
@@ -211,7 +251,8 @@ def paged_attention(q, pool, tables, context_lens, scale, interpret=None):
     softmax statistics and the weighted sum are float32. On a non-TPU
     backend the kernel runs in the pallas interpreter.
     """
-    why = unsupported(q.shape[-1], pool.shape[1], pool.dtype)
+    why = unsupported(q.shape[-1], pool.shape[1], pool.dtype, q.shape[1],
+                      pool.shape[2] // (2 * q.shape[-1]))
     if why:
         raise ValueError(f"paged_attention: {why}")
     if interpret is None:

@@ -33,7 +33,10 @@ Under KV pressure the engine preempts: the running request with the
 LATEST absolute deadline loses its blocks and re-queues with its
 generated prefix folded into the prompt (recompute-on-resume), so tight
 SLOs survive loose ones — the test observes both the eviction and the
-freed blocks' reuse.
+freed blocks' reuse. A model with conv layers keeps per-slot state
+beside the blocks (``self.state``, serving/model.py): the prefill of an
+admission or a resume writes the slot's state whole, so a slot that
+changes hands carries nothing over.
 
 The decode loop keeps ONE tick in flight ahead of the host: tick N+1 is
 built from what was DISPATCHED (a context's length and a request's
@@ -280,6 +283,11 @@ class ServingEngine:
         self.allocator = BlockAllocator(n_kv, self.block_size)
         self.queue = AdmissionQueue()
         self.pages = model.init_pages() if model is not None else None
+        # what a slot owns beside its blocks: the conv layers' state pool,
+        # indexed by slot (None for a model that keeps none). A prefill
+        # writes its request's slot whole, so retirement, eviction and a
+        # resume after either leave nothing to clear
+        self.state = model.init_state() if model is not None else None
         self._slots: List[Optional[ServeRequest]] = [None] * self.max_batch
         # admitted one-shot executes waiting for a thread to claim them
         self._exec_ready: List[ServeRequest] = []
@@ -905,6 +913,7 @@ class ServingEngine:
             if self._drain("error") and not self.pages.is_deleted():
                 return
         self.pages = self.model.init_pages()
+        self.state = self.model.init_state()  # donated with the pool
         lost = [r for r in self._slots if r is not None
                 and r.status == RUNNING and r.kind == "generate"]
         for req in lost:
@@ -943,14 +952,20 @@ class ServingEngine:
                             request_id=req.request_id):
             req.t_prefill0 = time.perf_counter_ns()
             try:
-                # returns with pages and token ready (tick/device_sync)
-                pages, tok = self.model.prefill(
-                    self.pages, req.prompt, req.prompt_len, req.blocks)
+                # returns with pools and token ready (tick/device_sync).
+                # The slot's state is written behind the tick in flight's
+                # read of it: _admit drained that tick before this slot
+                # changed hands
+                pages, state, tok = self.model.prefill(
+                    self.pages, self.state, req.prompt, req.prompt_len,
+                    req.blocks, req.slot)
             except Exception as e:
                 self._drop(req, f"{type(e).__name__}: {e}")
                 self._restore_pages()
                 return
-            self.pages = pages
+            self.pages, self.state = pages, state
+            if state is not None:
+                _ledger.note_state_write(state.nbytes)
             req.t_prefill1 = time.perf_counter_ns()
             if not req.t_first_token:  # a re-prefill after eviction is not
                 req.t_first_token = req.t_prefill1  # the user's first token
@@ -1010,13 +1025,14 @@ class ServingEngine:
             _ledger.note_attention(
                 sum(blocks_for_tokens(req.context_len + 1, self.block_size)
                     for req in ready),
-                B * self.model.max_blocks_per_req)
+                B * self.model.max_blocks_per_req,
+                len(self.model.attn_layers))
         prev = self._inflight
         # tick/put_inputs, tick/enqueue: returns at once, pool and tokens
         # still being computed
         try:
-            self.pages, nxt, t_put = self.model.decode_enqueue(
-                self.pages, tables, lens, toks,
+            self.pages, self.state, nxt, t_put = self.model.decode_enqueue(
+                self.pages, self.state, tables, lens, toks,
                 None if prev is None else prev.nxt)
         except Exception as e:  # the engine outlives a failed program
             for req in ready:

@@ -3,12 +3,16 @@
 The vLLM-style design adapted to the repo's functional-XLA runtime: the
 cache is ONE device array of fixed-size blocks
 
-    pages[n_layer * n_blocks, block_size, n_head * 2 * head_dim]
+    pages[n_attn_layers * n_blocks, block_size, kv_heads * 2 * head_dim]
 
 (``DecodeModel.pool_shape``), and a request owns an ordered *block
 table* — the list of block ids its context occupies, the same ids in
-every layer: layer ``i`` keeps block ``b`` at row-block ``i * n_blocks +
-b``. A token's row holds, head by head, that head's K then its V. The
+every layer that attends: the ``a``-th of them keeps block ``b`` at
+row-block ``a * n_blocks + b`` (a layer of another kind, such as a gated
+short convolution, owns no share: what it keeps per request lies in the
+state pool, by decode slot and not by block, ``DecodeModel.state_shape``).
+A token's row holds, K|V head by K|V head (one a query head, or one a
+group of them), that head's K then its V. The
 decode program scatters the new token's K/V into the tail slot and reads
 a request's K/V through its table (page by page where it lies, in
 ``ops/pallas/paged_attention``), so the cache never compacts and

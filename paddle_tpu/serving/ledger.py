@@ -43,7 +43,11 @@ the busiest expert's pairs), what the decode attention read
 (``attn_pages_read``: the KV pages its live slots' contexts occupy, summed
 over ticks, layers apart; ``attn_pages_window``: the pages of every slot's
 whole window, which the gathered formulation reads whatever is live: their
-ratio is the share of the window that holds anything), and ``itl_gaps_s``, a
+ratio is the share of the window that holds anything; ``attn_layers``: how
+many layers attend, and so read those pages, a gauge), what a model with
+conv layers keeps per slot (``state_writes``: slot states written by
+prefills, one per admission or resume; ``state_pool_bytes``: the state
+pool's size, a gauge), and ``itl_gaps_s``, a
 bounded sample of the gaps between a request's consecutive tokens, with
 ``itl_gaps_seen``, how many gaps it was drawn from (more than the sample
 holds: the oldest were dropped, and a percentile says so).
@@ -149,8 +153,14 @@ _ITL_SAMPLE = 8192
 MOE_COUNTERS = ("moe_assignments", "moe_experts_hit", "moe_max_load")
 # KV pages of a decode tick: engine.py::_decode_tick_phases
 ATTN_COUNTERS = ("attn_pages_read", "attn_pages_window")
-# summed per decode tick; in totals(), cleared by reset(), merged as sums
-TICK_COUNTERS = MOE_COUNTERS + ATTN_COUNTERS + ("ticks_ahead",)
+# summed per decode tick (state_writes: per prefill); in totals(), cleared
+# by reset(), merged as sums
+TICK_COUNTERS = (MOE_COUNTERS + ATTN_COUNTERS
+                 + ("ticks_ahead", "state_writes"))
+# facts of the model being served, noted with the counts above so that a
+# reset() between warm-up and a window loses nothing: in totals(), cleared
+# by reset(), merged as the largest
+GAUGES = ("attn_layers", "state_pool_bytes")
 # why the engine read the tick in flight early: engine.py::_drain
 DRAIN_CAUSES = ("prefill", "evict", "error", "stop", "empty")
 
@@ -377,6 +387,7 @@ class ServingLedger:
             self.tick_wall_s = 0.0
             self.tick_sync_s = 0.0
             self.tick_counts = dict.fromkeys(TICK_COUNTERS, 0)
+            self.gauges = dict.fromkeys(GAUGES, 0)
             self.pipeline_drains = dict.fromkeys(DRAIN_CAUSES, 0)
             self.itl_gaps: "collections.deque[float]" = collections.deque(
                 maxlen=_ITL_SAMPLE)
@@ -437,10 +448,21 @@ class ServingLedger:
         """One decode tick's routing counts, each summed over layers."""
         self._count(MOE_COUNTERS, (assignments, experts_hit, max_load))
 
-    def note_attention(self, pages_read: int, pages_window: int) -> None:
+    def note_attention(self, pages_read: int, pages_window: int,
+                       layers: int) -> None:
         """One decode tick's KV pages: those its live slots' contexts
-        occupy (new token included), and those of every slot's window."""
-        self._count(ATTN_COUNTERS, (pages_read, pages_window))
+        occupy (new token included), and those of every slot's window, in
+        each of the ``layers`` that attend."""
+        with self._lock:
+            self._count(ATTN_COUNTERS, (pages_read, pages_window))
+            self.gauges["attn_layers"] = int(layers)
+
+    def note_state_write(self, pool_bytes: int) -> None:
+        """A prefill wrote its request's conv state into its decode slot,
+        in a state pool of ``pool_bytes``."""
+        with self._lock:
+            self._count(("state_writes",), (1,))
+            self.gauges["state_pool_bytes"] = int(pool_bytes)
 
     def _count(self, names, values) -> None:
         with self._lock:
@@ -621,7 +643,7 @@ class ServingLedger:
             decode_ticks = self.decode_ticks
             tick_wall = self.tick_wall_s
             tick_sync = self.tick_sync_s
-            counts = dict(self.tick_counts)
+            counts = dict(self.tick_counts, **self.gauges)
             drains = dict(self.pipeline_drains)
             doc["itl_gaps_s"] = list(self.itl_gaps)
             doc["itl_gaps_seen"] = self.itl_gaps_seen
@@ -648,6 +670,8 @@ class ServingLedger:
             tick_sync += float(base.get("tick_sync_s", 0.0))
             for k in TICK_COUNTERS:
                 counts[k] += int(base.get(k, 0))
+            for k in GAUGES:
+                counts[k] = max(counts[k], int(base.get(k, 0)))
             for k, v in (base.get("pipeline_drains") or {}).items():
                 drains[k] = drains.get(k, 0) + int(v)
             attribution = merge_attribution(base.get("attribution"),
@@ -731,10 +755,17 @@ def note_routing(assignments: int, experts_hit: int, max_load: int) -> None:
     _LEDGER.note_routing(assignments, experts_hit, max_load)
 
 
-def note_attention(pages_read: int, pages_window: int) -> None:
+def note_attention(pages_read: int, pages_window: int,
+                   layers: int) -> None:
     if not _monitor.enabled():
         return
-    _LEDGER.note_attention(pages_read, pages_window)
+    _LEDGER.note_attention(pages_read, pages_window, layers)
+
+
+def note_state_write(pool_bytes: int) -> None:
+    if not _monitor.enabled():
+        return
+    _LEDGER.note_state_write(pool_bytes)
 
 
 def note_token_gaps(gaps: Sequence[float]) -> None:
@@ -871,6 +902,14 @@ def status() -> Dict[str, Any]:
             "pages_read": doc["attn_pages_read"],
             "pages_window": doc["attn_pages_window"],
             "window_share": doc["attn_pages_read"] / doc["attn_pages_window"],
+            "layers": doc["attn_layers"],
+        }
+    if doc["state_pool_bytes"]:
+        # what the conv layers keep per slot, and how often a slot's state
+        # was written by a prefill (an admission or a resume)
+        out["state_pool"] = {
+            "bytes": doc["state_pool_bytes"],
+            "slot_writes": doc["state_writes"],
         }
     if (doc.get("attribution") or {}).get("n_requests"):
         out["request_attribution"] = attribution_summary(doc)
@@ -967,7 +1006,7 @@ def merge_ledgers(docs: List[Dict[str, Any]]) -> Dict[str, Any]:
     span_s = slot_s = 0.0
     decode_ticks = 0
     tick_wall = tick_sync = 0.0
-    counts = dict.fromkeys(TICK_COUNTERS, 0)
+    counts = dict.fromkeys(TICK_COUNTERS + GAUGES, 0)
     drains = dict.fromkeys(DRAIN_CAUSES, 0)
     ranks: List[int] = []
     roofline = None
@@ -1017,6 +1056,8 @@ def merge_ledgers(docs: List[Dict[str, Any]]) -> Dict[str, Any]:
         tick_sync += float(d.get("tick_sync_s", 0.0))
         for k in TICK_COUNTERS:
             counts[k] += int(d.get(k, 0))
+        for k in GAUGES:
+            counts[k] = max(counts[k], int(d.get(k, 0)))
         for k, v in (d.get("pipeline_drains") or {}).items():
             drains[k] = drains.get(k, 0) + int(v)
         if d.get("rank") is not None:

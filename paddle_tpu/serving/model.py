@@ -8,7 +8,12 @@ the configuration's description of it (``GPTConfig.norm``, ``position``,
 (LayerNorm, learned positions, erf GELU, biases, tied head), OLMoE's
 with RMSNorm, rotate-half RoPE, a norm over q and k, bias-free
 projections, a router + expert layer (``ops/moe.py``) and an untied
-head. Pool, donation, programs, names and insight are one path —
+head; and blocks whose layers are of more than one kind
+(``GPTConfig.layer_ops`` / ``layer_mlps``): attention over fewer K|V
+heads than query heads, gated short convolutions that keep a few gated
+inputs per decode slot instead of K and V, dense SwiGLU layers before
+the expert ones. Pool, donation, programs, names and insight are one
+path —
 
 - **prefill**: the whole (bucket-padded) prompt in one causal pass,
   writing every position's K/V into the request's cache blocks and
@@ -33,9 +38,16 @@ bench reconciles measured tokens/s against).
 
 The KV pool passes through both as ONE donated array in the layout the
 chip keeps at rest (``DecodeModel.pool_shape``: rows of whole 128-lane
-tiles, the layer folded into the block index; serving/kv_cache.py says
-why): each program consumes the pool it is given and returns the same
-buffer updated in place, so a caller holds only the newest handle.
+tiles, the attention layer folded into the block index; serving/
+kv_cache.py says why): each program consumes the pool it is given and
+returns the same buffer updated in place, so a caller holds only the
+newest handle. A model with conv layers has a second donated array
+beside it, the state pool (``DecodeModel.state_shape``): per conv layer
+and decode slot the last ``conv_kernel - 1`` gated inputs. Prefill
+writes a request's final state into its slot, whole (a prompt shorter
+than the state leaves zeros in front), so a slot never shows its last
+tenant's; every decode tick reads each slot's, shifts it by the new
+input and writes it back. A model without conv layers has none (None).
 
 Sharding comes STRAIGHT off ``parallel/recipes.py``: a resolved recipe
 supplies the mesh and the parameter rules (``GPT_TP_RULES`` — qkv/ffn-in
@@ -70,6 +82,7 @@ __all__ = ["GPTConfig", "DecodeModel", "init_params", "calibrate"]
 
 _NEG = -1e30  # finite mask value: garbage behind it stays non-NaN
 _SUBLANES = 8  # rows of one (8, 128) tile, the unit the TPU lays arrays out in
+_FFN_ROWS = 512  # positions full_logits puts through a feed-forward at once
 
 
 def param_table(cfg: GPTConfig) -> Dict[str, Tuple[tuple, float]]:
@@ -79,8 +92,11 @@ def param_table(cfg: GPTConfig) -> Dict[str, Tuple[tuple, float]]:
     stands for zeros (biases) and -1 for ones (norm gains). Expert
     weights are stacked per layer: ``moe.gate.w`` and ``moe.up.w``
     ``[E, D, F]``, ``moe.down.w`` ``[E, F, D]``; an untied head is
-    ``gpt.lm_head.w`` ``[D, V]`` (the training graph's name and shape)."""
-    d, v, dff = cfg.d_model, cfg.vocab_size, cfg.ffn_dim
+    ``gpt.lm_head.w`` ``[D, V]`` (the training graph's name and shape).
+    A layer has the weights of its own kind (``cfg.layer_kind``): ``attn.*``
+    (k and v ``kv_heads * head_dim`` wide) or ``conv.*``; ``mlp.fc_*``,
+    ``mlp.gate|up|down`` or ``moe.*``."""
+    d, v = cfg.d_model, cfg.vocab_size
     res_std = 0.02 / math.sqrt(2 * cfg.n_layer)
     t: Dict[str, Tuple[tuple, float]] = {"gpt.wte": ((v, d), 0.02)}
 
@@ -99,20 +115,44 @@ def param_table(cfg: GPTConfig) -> Dict[str, Tuple[tuple, float]]:
     norm("gpt.lnf")
     if not cfg.tie_embeddings:
         t["gpt.lm_head.w"] = ((d, v), 0.02)
+    d_kv = cfg.kv_heads * cfg.head_dim
     for i in range(cfg.n_layer):
         ln = f"gpt.h{i}"
-        for part in ("q", "k", "v"):
-            linear(f"{ln}.attn.{part}", d, d)
-        linear(f"{ln}.attn.proj", d, d, res_std)
-        if cfg.qk_norm:
-            norm(f"{ln}.attn.q_norm")
-            norm(f"{ln}.attn.k_norm")
-        if cfg.mlp == "moe":
+        op, mlp = cfg.layer_kind(i)
+        dff = cfg.mlp_width(mlp)
+        if op == "conv":
+            # B | C | X in one projection; the taps [kernel, D], tap j
+            # on the input conv_kernel - 1 - j positions back
+            t[f"{ln}.conv.in_proj.w"] = ((d, 3 * d), 0.02)
+            t[f"{ln}.conv.taps.w"] = ((cfg.conv_kernel, d), 0.5)
+            t[f"{ln}.conv.out_proj.w"] = ((d, d), res_std)
+            if cfg.conv_bias:
+                for part, width in (("in_proj", 3 * d), ("taps", d),
+                                    ("out_proj", d)):
+                    t[f"{ln}.conv.{part}.b"] = ((width,), 0.0)
+        else:
+            linear(f"{ln}.attn.q", d, d)
+            linear(f"{ln}.attn.k", d, d_kv)
+            linear(f"{ln}.attn.v", d, d_kv)
+            linear(f"{ln}.attn.proj", d, d, res_std)
+            if cfg.qk_norm == "head":
+                norm(f"{ln}.attn.q_norm", (cfg.head_dim,))
+                norm(f"{ln}.attn.k_norm", (cfg.head_dim,))
+            elif cfg.qk_norm:
+                norm(f"{ln}.attn.q_norm")
+                norm(f"{ln}.attn.k_norm", (d_kv,))
+        if mlp == "moe":
             e = cfg.n_experts
             t[f"{ln}.moe.router.w"] = ((d, e), 0.02)
+            if cfg.router_bias:
+                t[f"{ln}.moe.router.bias"] = ((e,), 0.05)
             t[f"{ln}.moe.gate.w"] = ((e, d, dff), 0.02)
             t[f"{ln}.moe.up.w"] = ((e, d, dff), 0.02)
             t[f"{ln}.moe.down.w"] = ((e, dff, d), res_std)
+        elif mlp == "swiglu":
+            linear(f"{ln}.mlp.gate", d, dff)
+            linear(f"{ln}.mlp.up", d, dff)
+            linear(f"{ln}.mlp.down", dff, d, res_std)
         else:
             linear(f"{ln}.mlp.fc_in", d, dff)
             linear(f"{ln}.mlp.fc_out", dff, d, res_std)
@@ -253,6 +293,13 @@ class DecodeModel:
         import jax.numpy as jnp
 
         self.cfg = cfg
+        # the kinds of layer this model has, in order of first appearance
+        # (one traced body each), and which layers own a share of a pool
+        self.kinds = list(dict.fromkeys(
+            cfg.layer_kind(i) for i in range(cfg.n_layer)))
+        self.attn_layers = cfg.layers_of("attn")
+        self.conv_layers = cfg.layers_of("conv")
+        self.routes = any(mlp == "moe" for _, mlp in self.kinds)
         self.max_batch = int(max_batch if max_batch is not None
                              else _flags.env_flag("PADDLE_TPU_SERVE_MAX_BATCH"))
         self.n_blocks = int(n_blocks if n_blocks is not None
@@ -280,12 +327,19 @@ class DecodeModel:
         if self.recipe is not None and self.recipe.n_devices > 1:
             import jax
 
-            if cfg.mlp == "moe":
+            if self.routes:
                 raise NotImplementedError(
                     f"recipe {self.recipe.name!r} places the model on "
                     f"{self.recipe.n_devices} devices, and a model with "
                     f"experts is served on one: parallel/recipes.py has no "
                     f"placement for the moe.* weights (the `ep` axis) yet")
+            if self.conv_layers:
+                raise NotImplementedError(
+                    f"recipe {self.recipe.name!r} places the model on "
+                    f"{self.recipe.n_devices} devices, and a model with "
+                    f"conv layers is served on one: parallel/recipes.py has "
+                    f"no placement for the conv.* weights or the state pool "
+                    f"yet")
 
             # a recipe smaller than the host's device pool runs on the
             # leading devices (the CPU-sim tests resolve tp=2 on the
@@ -313,7 +367,7 @@ class DecodeModel:
         # the shape of its own second output (behind the tokens of a model
         # with experts ride the three ops/moe.py::routing_counts)
         self._no_prev = np.zeros(
-            (self.max_batch + (3 if cfg.mlp == "moe" else 0),), np.int32)
+            (self.max_batch + (3 if self.routes else 0),), np.int32)
 
     # -- placement ------------------------------------------------------
 
@@ -347,10 +401,12 @@ class DecodeModel:
             _DictScope(self.params), self.mesh, self.rules)
 
     def pool_shape(self) -> Tuple[int, int, int]:
-        """The KV pool ``[n_layer * n_blocks, block_size, n_head * 2 *
-        head_dim]``: layer ``i``'s block ``b`` is row-block ``i * n_blocks
-        + b``, and a token's row holds, head by head, that head's K then
-        its V (``2 * head_dim`` = 128 lanes a head at GPT-2's 64).
+        """The KV pool ``[n_attn_layers * n_blocks, block_size, kv_heads
+        * 2 * head_dim]``: the ``a``-th attention layer's block ``b`` is
+        row-block ``a * n_blocks + b`` (a layer that does not attend owns
+        nothing here), and a token's row holds, K|V head by K|V head, that
+        head's K then its V (``2 * head_dim`` = 128 lanes a head at
+        GPT-2's 64).
 
         Why this shape: the TPU runtime stores an array in the most
         compact tiled layout FOR ITS SHAPE, and a program whose gather
@@ -363,8 +419,21 @@ class DecodeModel:
         prints it for any widths). Where a model's row is not such a
         multiple the runtime pads it, and nothing here needs to know."""
         cfg = self.cfg
-        return (cfg.n_layer * self.n_blocks, self.block_size,
-                cfg.n_head * 2 * cfg.head_dim)
+        return (len(self.attn_layers) * self.n_blocks, self.block_size,
+                cfg.kv_heads * 2 * cfg.head_dim)
+
+    def state_shape(self) -> Optional[Tuple[int, int, int, int]]:
+        """The state pool ``[n_conv_layers, conv_kernel - 1, max_batch,
+        d_model]``: per conv layer and decode slot the gated inputs of the
+        last ``conv_kernel - 1`` positions, oldest first. The slots lie on
+        the sublanes (whole tiles at 16 slots or more), so a tick reads
+        and writes a layer's states as one dense ``[kernel - 1, B, D]``
+        slab and a prefill one ``[kernel - 1, 1, D]`` column of it. None
+        for a model without conv layers."""
+        if not self.conv_layers:
+            return None
+        return (len(self.conv_layers), self.cfg.conv_kernel - 1,
+                self.max_batch, self.cfg.d_model)
 
     def attention_path(self) -> Tuple[str, str]:
         """How the decode program attends, and why: ``("kernel", "")`` is
@@ -378,7 +447,8 @@ class DecodeModel:
             return "gather", ("a mesh program: GSPMD cannot partition a "
                               "Mosaic call")
         why = pa.unsupported(self.cfg.head_dim, self.block_size,
-                             self.cfg.dtype)
+                             self.cfg.dtype, self.cfg.n_head,
+                             self.cfg.kv_heads)
         return ("gather", why) if why else ("kernel", "")
 
     def _pages_sharding(self):
@@ -396,7 +466,7 @@ class DecodeModel:
         rows, bs, _ = self.pool_shape()
         spec = PartitionSpec(None, None, self.recipe.layout.tp_axis)
         return NamedSharding(self.mesh, clean_spec(
-            spec, (rows, bs, self.cfg.n_head), self.mesh))
+            spec, (rows, bs, self.cfg.kv_heads), self.mesh))
 
     def init_pages(self):
         """A zeroed KV pool (:meth:`pool_shape`; block 0 of every layer
@@ -406,6 +476,14 @@ class DecodeModel:
 
         return jnp.zeros(self.pool_shape(), self.cfg.dtype,
                          device=self._pages_sharding())
+
+    def init_state(self):
+        """A zeroed state pool (:meth:`state_shape`), or None where the
+        model keeps none. Donated and returned like the KV pool."""
+        import jax.numpy as jnp
+
+        shape = self.state_shape()
+        return None if shape is None else jnp.zeros(shape, self.cfg.dtype)
 
     # -- shared forward pieces -----------------------------------------
 
@@ -424,9 +502,13 @@ class DecodeModel:
         return ((x - mu) / jnp.sqrt(var + self.cfg.norm_eps)
                 * p[f"{name}.scale"] + p[f"{name}.bias"])
 
-    def _mlp(self, p, x, ln):
+    def _mlp(self, p, x, ln, mlp: str = "gelu"):
         import jax
 
+        if mlp == "swiglu":
+            h = (jax.nn.silu(self._linear(p, x, f"{ln}.mlp.gate"))
+                 * self._linear(p, x, f"{ln}.mlp.up"))
+            return self._linear(p, h, f"{ln}.mlp.down")
         h = jax.nn.gelu(self._linear(p, x, f"{ln}.mlp.fc_in"),
                         approximate=False)
         return self._linear(p, h, f"{ln}.mlp.fc_out")
@@ -451,48 +533,172 @@ class DecodeModel:
         return jnp.cos(ang), jnp.sin(ang)
 
     def _qkv(self, lp, h, rot, lead: tuple):
-        """q, k, v ``[*lead, H, hd]`` of normed hidden ``h`` ``[*lead,
-        D]``: the projections, the block's norm over all of q's and k's
-        lanes where it has one, the split into heads, RoPE by ``rot``.
-        The K that leaves here is the K the pool keeps."""
+        """q ``[*lead, H, hd]``, k and v ``[*lead, H_kv, hd]`` of normed
+        hidden ``h`` ``[*lead, D]``: the projections, the split into
+        heads, the block's norm over q and k where it has one (over all
+        of a token's lanes before the split, or over each head's own
+        after it), RoPE by ``rot``. The K that leaves here is the K the
+        pool keeps."""
         import jax
 
         cfg, ln = self.cfg, _LAYER
         q = self._linear(lp, h, f"{ln}.attn.q")
         k = self._linear(lp, h, f"{ln}.attn.k")
         v = self._linear(lp, h, f"{ln}.attn.v")
-        if cfg.qk_norm:
+
+        def qk_norm(q, k):
             with jax.named_scope("attn/qk_norm"):
-                q = _rms(q, lp[f"{ln}.attn.q_norm.scale"], cfg.norm_eps)
-                k = _rms(k, lp[f"{ln}.attn.k_norm.scale"], cfg.norm_eps)
-        q, k, v = (a.reshape(*lead, cfg.n_head, cfg.head_dim)
-                   for a in (q, k, v))
+                return (_rms(q, lp[f"{ln}.attn.q_norm.scale"], cfg.norm_eps),
+                        _rms(k, lp[f"{ln}.attn.k_norm.scale"], cfg.norm_eps))
+
+        if cfg.qk_norm and cfg.qk_norm != "head":
+            q, k = qk_norm(q, k)
+        q = q.reshape(*lead, cfg.n_head, cfg.head_dim)
+        k, v = (a.reshape(*lead, cfg.kv_heads, cfg.head_dim) for a in (k, v))
+        if cfg.qk_norm == "head":
+            q, k = qk_norm(q, k)
         if rot is not None:
             with jax.named_scope("attn/rope"):
                 q, k = _rope(q, rot), _rope(k, rot)
         return q, k, v
 
-    def _ffn(self, lp, x):
-        """The block's second half on the residual stream ``x``: norm,
-        MLP or experts, residual add. Returns (x, each row's top-k expert
-        ids ``[rows, k]``, or None where the block has no experts)."""
+    def _ffn(self, lp, x, mlp: str):
+        """A layer's second half on the residual stream ``x``: norm, the
+        feed-forward of kind ``mlp``, residual add. Returns (x, each row's
+        top-k expert ids ``[rows, k]``, or None where the layer has no
+        experts)."""
         import jax
 
         cfg, ln = self.cfg, _LAYER
-        if cfg.mlp != "moe":
+        if mlp != "moe":
             with jax.named_scope("mlp"):
                 return x + self._mlp(
-                    lp, self._ln_p(lp, x, f"{ln}.ln2"), ln), None
+                    lp, self._ln_p(lp, x, f"{ln}.ln2"), ln, mlp), None
         from ..ops import moe
 
+        # what the router's description departs from ops/moe.py's defaults
+        # by (softmax scores, weights as they are), and no more
+        how: Dict[str, Any] = {}
+        if cfg.router_score != "softmax":
+            how["score"] = cfg.router_score
+        if cfg.router_bias:
+            how["bias"] = lp[f"{ln}.moe.router.bias"]
+        if cfg.norm_topk:
+            how["norm_topk"] = True
+        if cfg.routed_scale != 1.0:
+            how["scale"] = cfg.routed_scale
         h = self._ln_p(lp, x, f"{ln}.ln2").reshape(-1, cfg.d_model)
         with jax.named_scope("moe/route"):
             dense, idx = moe.route(h, lp[f"{ln}.moe.router.w"],
-                                   cfg.experts_per_token)
+                                   cfg.experts_per_token, **how)
         with jax.named_scope("moe/experts"):
             y = moe.experts(h, dense, lp[f"{ln}.moe.gate.w"],
                             lp[f"{ln}.moe.up.w"], lp[f"{ln}.moe.down.w"])
         return x + y.reshape(x.shape), idx
+
+    def _conv_in(self, lp, h):
+        """A conv layer's input side: ``h W_in`` split into B, C, X."""
+        import jax
+        import jax.numpy as jnp
+
+        with jax.named_scope("conv/in_proj"):
+            bcx = h @ lp[f"{_LAYER}.conv.in_proj.w"]
+            if self.cfg.conv_bias:
+                bcx = bcx + lp[f"{_LAYER}.conv.in_proj.b"]
+            return jnp.split(bcx, 3, axis=-1)
+
+    def _conv_out(self, lp, c, taps_sum):
+        """The output side: ``(C * conv) W_out``; ``taps_sum`` float32."""
+        import jax
+
+        with jax.named_scope("conv/mix"):
+            if self.cfg.conv_bias:
+                taps_sum = taps_sum + lp[f"{_LAYER}.conv.taps.b"]
+            y = c * taps_sum.astype(c.dtype)
+        with jax.named_scope("conv/out_proj"):
+            y = y @ lp[f"{_LAYER}.conv.out_proj.w"]
+            return (y + lp[f"{_LAYER}.conv.out_proj.b"]
+                    if self.cfg.conv_bias else y)
+
+    def _conv_prompt(self, lp, i, h, L: int, state_dest):
+        """A conv layer over the whole prompt ``h`` [1, L, D], causal and
+        depthwise: position t sums tap j over the gated input ``kernel - 1
+        - j`` positions back, zeros before position 0. ``state_dest =
+        (state, length, slot)`` is prefill's: the gated inputs of the last
+        ``kernel - 1`` positions before ``length`` go to conv layer ``i``'s
+        state of ``slot``. Returns (the operator's output, state_dest)."""
+        import jax
+        import jax.numpy as jnp
+
+        K = self.cfg.conv_kernel
+        b, c, x = self._conv_in(lp, h)
+        with jax.named_scope("conv/mix"):
+            # the gated input z of position t at row t + K - 1, zeros in front
+            zp = jnp.pad(b * x, ((0, 0), (K - 1, 0), (0, 0)))
+            taps = lp[f"{_LAYER}.conv.taps.w"].astype(jnp.float32)
+            mixed = sum(taps[j] * zp[:, j:j + L].astype(jnp.float32)
+                        for j in range(K))
+        if state_dest is not None:
+            state, length, slot = state_dest
+            with jax.named_scope("conv/state_write"):
+                # positions length - K + 1 .. length - 1 lie K - 1 rows
+                # further down: rows length .. length + K - 2
+                last = jax.lax.dynamic_slice_in_dim(zp[0], length, K - 1)
+                state = jax.lax.dynamic_update_slice(
+                    state, last[None, :, None, :].astype(state.dtype),
+                    (i, 0, slot, 0))
+            state_dest = (state, length, slot)
+        return self._conv_out(lp, c, mixed), state_dest
+
+    def _conv_step(self, lp, i, h, state):
+        """A conv layer on one new token a slot, ``h`` [B, D]: the taps
+        over conv layer ``i``'s state of every slot and the new gated
+        input, then the state shifted by it. Returns (output, state)."""
+        import jax
+        import jax.numpy as jnp
+
+        K = self.cfg.conv_kernel
+        b, c, x = self._conv_in(lp, h)
+        with jax.named_scope("conv/mix"):
+            z = b * x
+            taps = lp[f"{_LAYER}.conv.taps.w"].astype(jnp.float32)
+            past = jax.lax.dynamic_index_in_dim(state, i, keepdims=False)
+            mixed = taps[K - 1] * z.astype(jnp.float32) + sum(
+                taps[j] * past[j].astype(jnp.float32) for j in range(K - 1))
+        with jax.named_scope("conv/state_write"):
+            shifted = jnp.concatenate(
+                [past[1:], z[None].astype(state.dtype)], axis=0)
+            state = jax.lax.dynamic_update_slice(
+                state, shifted[None], (i, 0, 0, 0))
+        return self._conv_out(lp, c, mixed), state
+
+    def _grouped(self, kv):
+        """K or V ``[..., H_kv, hd]`` as every query head sees it, ``[...,
+        H, hd]``: query head ``i`` reads K|V head ``i // group``."""
+        import jax.numpy as jnp
+
+        group = self.cfg.n_head // self.cfg.kv_heads
+        return kv if group == 1 else jnp.repeat(kv, group, axis=-2)
+
+    def _layer_fn(self, body, kind):
+        """``body`` as the traced layer of ``kind``: an inner jit, traced
+        once for all layers of its kind (``_layer_params`` gives them one
+        argument tree). A model of one kind of layer has the one body
+        ``layer``; of several, each is named by its kind
+        (``layer_conv_moe``), so that a trace tells them apart."""
+        import jax
+
+        name = "layer" if len(self.kinds) == 1 else "layer_" + "_".join(kind)
+        body.__name__ = body.__qualname__ = name
+        return jax.jit(body)
+
+    def _place(self, i: int):
+        """Layer ``i``'s kind, and its place among the layers that share
+        its pool: the attention layers' KV pool or the conv layers' state
+        pool."""
+        kind = self.cfg.layer_kind(i)
+        own = self.conv_layers if kind[0] == "conv" else self.attn_layers
+        return kind, own.index(i)
 
     def _logits(self, p, x):
         """Vocabulary logits of final-normed hidden ``x`` [..., D]."""
@@ -508,22 +714,20 @@ class DecodeModel:
                 return b
         return None
 
-    def _prompt_trunk(self, p, tokens, L: int, kv_dest=None):
-        """The full-prompt causal transformer forward shared by prefill
-        and scoring: [1, L] tokens -> final-LN hidden states [1, L, D].
-        ``kv_dest = (pages, blk, slot)`` is prefill's: the pool, and per
-        position the block and slot its K/V go to; every layer scatters
-        there. Scoring keeps nothing (None). Returns (hidden, pages)."""
+    def _prompt_op(self, op: str, L: int, lp, i, x, causal, kv_dest,
+                   state_dest, rot):
+        """A layer's first half over the whole prompt ``x`` [1, L, D]:
+        norm, the operator ``op`` (the ``i``-th of the layers that share
+        its pool), residual add. Returns (x, kv_dest, state_dest)."""
         import jax
         import jax.numpy as jnp
 
-        cfg, NB = self.cfg, self.n_blocks
-        scale = 1.0 / math.sqrt(cfg.head_dim)
-
-        @jax.jit  # one trace for all layers: see _layer_params
-        def layer(lp, i, x, causal, kv_dest, rot):
-            ln = _LAYER
-            h = self._ln_p(lp, x, f"{ln}.ln1")
+        cfg, NB, ln = self.cfg, self.n_blocks, _LAYER
+        h = self._ln_p(lp, x, f"{ln}.ln1")
+        if op == "conv":
+            y, state_dest = self._conv_prompt(lp, i, h, L, state_dest)
+            x = x + y
+        else:
             q, k, v = self._qkv(lp, h, rot, (1, L))
             if kv_dest is not None:
                 pages, blk, slot = kv_dest
@@ -531,43 +735,74 @@ class DecodeModel:
                     pages = pages.at[i * NB + blk, slot].set(
                         _kv_rows(k[0], v[0]))
                 kv_dest = (pages, blk, slot)
+            k, v = self._grouped(k), self._grouped(v)
             with jax.named_scope("attn/scores"):
-                s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+                s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (
+                    1.0 / math.sqrt(cfg.head_dim))
                 s = jnp.where(causal[None, None], s, _NEG)
                 a = jax.nn.softmax(s, axis=-1)
                 o = jnp.einsum("bhqk,bkhd->bqhd", a, v).reshape(1, L, -1)
             x = x + self._linear(lp, o, f"{ln}.attn.proj")
-            return self._ffn(lp, x)[0], kv_dest
+        return x, kv_dest, state_dest
 
+    def _prompt_trunk(self, p, tokens, L: int, kv_dest=None,
+                      state_dest=None):
+        """The full-prompt causal transformer forward shared by prefill
+        and scoring: [1, L] tokens -> final-LN hidden states [1, L, D].
+        ``kv_dest = (pages, blk, slot)`` is prefill's: the pool, and per
+        position the block and slot its K/V go to; every attention layer
+        scatters there. ``state_dest = (state, length, slot)`` likewise:
+        every conv layer writes the prompt's final state into the decode
+        slot. Scoring keeps nothing (None). Returns (hidden, pages,
+        state)."""
+        import jax
+        import jax.numpy as jnp
+
+        def traced(kind):
+            op, mlp = kind
+
+            def layer(lp, i, x, causal, kv_dest, state_dest, rot):
+                x, kv_dest, state_dest = self._prompt_op(
+                    op, L, lp, i, x, causal, kv_dest, state_dest, rot)
+                return self._ffn(lp, x, mlp)[0], kv_dest, state_dest
+            return self._layer_fn(layer, kind)
+
+        layers = {kind: traced(kind) for kind in self.kinds}
         pos = jnp.arange(L)
         with jax.named_scope("embed"):
             x = self._embed(p, tokens, pos)  # [1, L, D]
         causal = pos[:, None] >= pos[None, :]
         rot = self._rot(pos[None])
-        for i in range(cfg.n_layer):
-            x, kv_dest = layer(_layer_params(p, i), i, x, causal, kv_dest,
-                               rot)
+        for i in range(self.cfg.n_layer):
+            kind, own = self._place(i)
+            x, kv_dest, state_dest = layers[kind](
+                _layer_params(p, i), own, x, causal, kv_dest, state_dest, rot)
         return (self._ln_p(p, x, "gpt.lnf"),
-                None if kv_dest is None else kv_dest[0])
+                None if kv_dest is None else kv_dest[0],
+                None if state_dest is None else state_dest[0])
 
     def _build_prefill(self, L: int):
         """The bucket-L prefill program: causal pass over [1, L], K/V
-        scattered into the request's blocks, argmax token at length-1."""
+        scattered into the request's blocks, the conv layers' final state
+        into decode slot ``slot_id`` (``state`` and ``slot_id`` None for a
+        model that keeps none), argmax token at length-1."""
         import jax
         import jax.numpy as jnp
 
         BS = self.block_size
 
-        def prefill(p, pages, tokens, length, block_ids):
+        def prefill(p, pages, state, tokens, length, block_ids, slot_id):
             pos = jnp.arange(L)
             blk = jnp.where(pos < length, block_ids[pos // BS], 0)
             slot = jnp.where(pos < length, pos % BS, 0)
-            x, pages = self._prompt_trunk(p, tokens, L, (pages, blk, slot))
+            x, pages, state = self._prompt_trunk(
+                p, tokens, L, (pages, blk, slot),
+                None if state is None else (state, length, slot_id))
             with jax.named_scope("lm_head"):
                 last = jnp.take(x, length - 1, axis=1)  # [1, D]
                 logits = self._logits(p, last)  # [1, V]
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return pages, nxt
+            return pages, nxt, state  # None: no third result
 
         return self._compile(prefill, "prefill", L)
 
@@ -587,7 +822,7 @@ class DecodeModel:
         from ..ops.pallas.fused_lmhead_ce import lmhead_ce
 
         def score(p, tokens, length):
-            x, _ = self._prompt_trunk(p, tokens, L)
+            x = self._prompt_trunk(p, tokens, L)[0]
             # positions 0..L-2 predict tokens 1..L-1; padded tail masked
             head = (p["gpt.wte"] if self.cfg.tie_embeddings
                     else p["gpt.lm_head.w"].T)
@@ -627,9 +862,11 @@ class DecodeModel:
         """The continuous-batching decode program: one token per slot,
         attending over the slot's own context through its block table
         (:meth:`attention_path`: the paged kernel, or the gathered
-        window). Inactive slots carry all-zero tables (reads masked,
-        writes land in the scratch block) so the program is shape-stable
-        at max_batch."""
+        window); a conv layer reads, shifts and writes its slots' states.
+        Inactive slots carry all-zero tables (reads masked, writes land in
+        the scratch block; a conv layer's write lands in the idle slot's
+        own state, which the next prefill into it replaces whole) so the
+        program is shape-stable at max_batch."""
         import jax
         import jax.numpy as jnp
 
@@ -637,7 +874,8 @@ class DecodeModel:
         from ..ops.pallas.paged_attention import paged_attention
 
         cfg, BS, NB = self.cfg, self.block_size, self.n_blocks
-        B, H, hd = self.max_batch, cfg.n_head, cfg.head_dim
+        B, H, hd = self.max_batch, cfg.kv_heads, cfg.head_dim
+        G = cfg.n_head // H  # query heads over one K|V head
         S = self.gather_len
         T = math.gcd(S, _SUBLANES)
         scale = 1.0 / math.sqrt(hd)
@@ -660,32 +898,46 @@ class DecodeModel:
                     B, S // T, T, H, 2 * hd).transpose(0, 1, 3, 2, 4)
                 kk, vv = ctx[..., :hd], ctx[..., hd:]
             with jax.named_scope("attn/scores"):
-                s = jnp.einsum("bhd,bjhtd->bhjt", q, kk).reshape(
-                    B, H, S) * scale
+                q = q.reshape(B, H, G, hd)
+                s = jnp.einsum("bhgd,bjhtd->bhgjt", q, kk).reshape(
+                    B, H * G, S) * scale
                 s = jnp.where(valid[:, None, :], s, _NEG)
-                a = jax.nn.softmax(s, axis=-1).reshape(B, H, S // T, T)
-                return jnp.einsum("bhjt,bjhtd->bhd", a, vv).reshape(B, -1)
+                a = jax.nn.softmax(s, axis=-1).reshape(B, H, G, S // T, T)
+                return jnp.einsum("bhgjt,bjhtd->bhgd", a, vv).reshape(B, -1)
 
         kernel = self.attention_path()[0] == "kernel"
         attend = attend_paged if kernel else attend_gathered
 
-        @jax.jit  # one trace for all layers: see _layer_params
-        def layer(lp, i, x, pages, block_tables, blk, slot, pos, valid,
-                  rot, live):
-            ln = _LAYER
-            h = self._ln_p(lp, x, f"{ln}.ln1")
-            q, k, v = self._qkv(lp, h, rot, (B,))
-            # the layer is part of the block index: no slice of the pool
-            # is ever materialised
-            with jax.named_scope("attn/kv_write"):
-                pages = pages.at[i * NB + blk, slot].set(_kv_rows(k, v))
-            x = x + self._linear(lp, attend(q, pages, i * NB + block_tables,
-                                            pos, valid), f"{ln}.attn.proj")
-            x, idx = self._ffn(lp, x)
-            return x, pages, (None if idx is None else
-                              moe.routing_counts(idx, live, cfg.n_experts))
+        def traced(kind):
+            op, mlp = kind
 
-        def decode_tick(p, pages, block_tables, context_lens, tokens, prev):
+            def layer(lp, i, x, pages, state, block_tables, blk, slot, pos,
+                      valid, rot, live):
+                ln = _LAYER
+                h = self._ln_p(lp, x, f"{ln}.ln1")
+                if op == "conv":
+                    y, state = self._conv_step(lp, i, h, state)
+                    x = x + y
+                else:
+                    q, k, v = self._qkv(lp, h, rot, (B,))
+                    # the layer is part of the block index: no slice of
+                    # the pool is ever materialised
+                    with jax.named_scope("attn/kv_write"):
+                        pages = pages.at[i * NB + blk, slot].set(
+                            _kv_rows(k, v))
+                    x = x + self._linear(
+                        lp, attend(q, pages, i * NB + block_tables, pos,
+                                   valid), f"{ln}.attn.proj")
+                x, idx = self._ffn(lp, x, mlp)
+                return x, pages, state, (
+                    None if idx is None else
+                    moe.routing_counts(idx, live, cfg.n_experts))
+            return self._layer_fn(layer, kind)
+
+        layers = {kind: traced(kind) for kind in self.kinds}
+
+        def decode_tick(p, pages, state, block_tables, context_lens, tokens,
+                        prev):
             # a slot whose last token the host has not read sends -1: the
             # token is the previous tick's own output, still on the device
             tokens = jnp.where(tokens < 0, prev[:B], tokens)
@@ -699,13 +951,15 @@ class DecodeModel:
                      jnp.arange(S)[None, :] <= pos[:, None])  # [B, S]
             rot = self._rot(pos)
             # a slot in use has a prompt behind it; an empty one is at 0
-            live = pos > 0 if cfg.mlp == "moe" else None
+            live = pos > 0 if self.routes else None
             routing = []
             for i in range(cfg.n_layer):
-                x, pages, counts = layer(_layer_params(p, i), i, x, pages,
-                                         block_tables, blk, slot, pos,
-                                         valid, rot, live)
-                routing.append(counts)
+                kind, own = self._place(i)
+                x, pages, state, counts = layers[kind](
+                    _layer_params(p, i), own, x, pages, state, block_tables,
+                    blk, slot, pos, valid, rot, live)
+                if counts is not None:
+                    routing.append(counts)
             with jax.named_scope("lm_head"):
                 x = self._ln_p(p, x, "gpt.lnf")
                 logits = self._logits(p, x)  # [B, V]
@@ -713,7 +967,7 @@ class DecodeModel:
             if live is not None:
                 # the routing counts ride behind the tokens: one read-back
                 nxt = jnp.concatenate([nxt, sum(routing)])
-            return pages, nxt
+            return pages, nxt, state  # None: no third result
 
         return self._compile(decode_tick, "decode")
 
@@ -738,7 +992,8 @@ class DecodeModel:
         insight, executable = xla_insight.capture(
             jit_fn, args, key_hash=key, label=label,
             fetch_names=(("nll", "total_nll") if kind == "score"
-                         else ("pages", "next_tokens")))
+                         else ("pages", "next_tokens")
+                         + (("state",) if self.conv_layers else ())))
         name = kind if bucket is None else f"{kind}@{bucket}"
         if insight is not None:
             self.insights[name] = insight
@@ -764,15 +1019,21 @@ class DecodeModel:
 
         pages = jax.ShapeDtypeStruct(self.pool_shape(), self.cfg.dtype,
                                      sharding=self._pages_sharding())
+        # None where the model keeps no state: no argument of the program
+        state = slot = None
+        if self.state_shape() is not None:
+            state = jax.ShapeDtypeStruct(self.state_shape(), self.cfg.dtype)
+            slot = i32()
         if kind == "decode":
             B = self.max_batch
-            args = (self.params, pages, i32(B, self.max_blocks_per_req),
-                    i32(B), i32(B), i32(*self._no_prev.shape))
+            args = (self.params, pages, state,
+                    i32(B, self.max_blocks_per_req), i32(B), i32(B),
+                    i32(*self._no_prev.shape))
         elif kind == "score":
             args = (self.params, i32(1, bucket), i32())
         else:
-            args = (self.params, pages, i32(1, bucket), i32(),
-                    i32(self.max_blocks_per_req))
+            args = (self.params, pages, state, i32(1, bucket), i32(),
+                    i32(self.max_blocks_per_req), slot)
         return self._jit_for(fn, kind), args
 
     @staticmethod
@@ -784,12 +1045,13 @@ class DecodeModel:
 
     def _jit_for(self, fn, kind: str):
         """The jit wrapper of a serving program. Prefill and decode
-        DONATE the pool (argument 1): the returned pool is the same
-        buffer updated in place, and the array passed in is deleted."""
+        DONATE the pools (arguments 1 and 2, the KV pool and the state
+        pool): each returned pool is the same buffer updated in place, and
+        the array passed in is deleted."""
         import jax
         from jax.sharding import NamedSharding, PartitionSpec
 
-        donate = () if kind == "score" else (1,)
+        donate = () if kind == "score" else (1, 2)
         if self.mesh is None:
             return jax.jit(fn, donate_argnums=donate)
         repl = NamedSharding(self.mesh, PartitionSpec())
@@ -803,21 +1065,26 @@ class DecodeModel:
             return jax.jit(fn, in_shardings=(param_sh, repl, repl),
                            out_shardings=(repl, repl))
         pages_sh = self._pages_sharding()
-        # (tables, lens, tokens, prev) or (tokens, length, block_ids)
+        # no state pool on a mesh (__init__ refuses the model), then
+        # (tables, lens, tokens, prev) or (tokens, length, block_ids, None)
         n_host = 4 if kind == "decode" else 3
-        in_sh = (param_sh, pages_sh) + (repl,) * n_host
+        in_sh = (param_sh, pages_sh, None) + (repl,) * n_host
+        if kind != "decode":
+            in_sh += (None,)
         return jax.jit(fn, in_shardings=in_sh,
-                       out_shardings=(pages_sh, repl),
+                       out_shardings=(pages_sh, repl, None),
                        donate_argnums=donate)
 
     # -- public API (host-array in, host-scalar-friendly out) ----------
 
-    def prefill(self, pages, tokens: np.ndarray, length: int,
-                block_ids: Sequence[int]):
-        """Run the prompt through the smallest bucket that holds it.
-        Returns (pages, first_token:int), both ready. Raises
-        InvalidArgument when no bucket fits (the engine fails the
-        request, not the batch)."""
+    def prefill(self, pages, state, tokens: np.ndarray, length: int,
+                block_ids: Sequence[int], slot: int = 0):
+        """Run the prompt through the smallest bucket that holds it,
+        leaving its K/V in ``block_ids`` and, where the model keeps a
+        state pool, its conv layers' final state in decode slot ``slot``
+        (``state`` is None otherwise). Returns (pages, state,
+        first_token:int), all ready. Raises InvalidArgument when no
+        bucket fits (the engine fails the request, not the batch)."""
         import jax
         import jax.numpy as jnp
 
@@ -837,23 +1104,25 @@ class DecodeModel:
         ids[:len(blocks)] = blocks
         with _profiler.span("tick/put_inputs", cat="engine"):
             args = (jnp.asarray(padded), jnp.int32(int(length)),
-                    jnp.asarray(ids))
+                    jnp.asarray(ids),
+                    None if state is None else jnp.int32(int(slot)))
         with _profiler.span("tick/enqueue", cat="engine"):
-            pages, tok = self._prefill_fns[L](self.params, pages, *args)
+            pages, tok, state = self._prefill_fns[L](
+                self.params, pages, state, *args)
         with _profiler.span("tick/device_sync", cat="engine"):
             first = int(tok[0])
-            jax.block_until_ready(pages)
-        return pages, first
+            jax.block_until_ready((pages, state))
+        return pages, state, first
 
-    def decode_enqueue(self, pages, block_tables: np.ndarray,
+    def decode_enqueue(self, pages, state, block_tables: np.ndarray,
                        context_lens: np.ndarray, tokens: np.ndarray,
                        prev=None):
         """The first half of one decode tick at max_batch: put the inputs
         and enqueue the program; nothing is waited for. ``tokens`` holds
         each slot's last token, or -1 where that token is ``prev``'s: the
         second output of the tick before, as it left the device, unread.
-        Returns (pages, next, t0): the pool's successor and the tick's
-        tokens, both still on the device (:meth:`decode_read` is the
+        Returns (pages, state, next, t0): the pools' successors and the
+        tick's tokens, all still on the device (:meth:`decode_read` is the
         sync), and the ``perf_counter_ns`` stamp of ``tick/put_inputs``."""
         import jax.numpy as jnp
 
@@ -865,17 +1134,18 @@ class DecodeModel:
                     jnp.asarray(np.asarray(tokens, np.int32)),
                     jnp.asarray(self._no_prev) if prev is None else prev)
         with _profiler.span("tick/enqueue", cat="engine"):
-            pages, nxt = self._decode_fn(self.params, pages, *args)
-        return pages, nxt, put.t0_ns
+            pages, nxt, state = self._decode_fn(self.params, pages, state,
+                                                *args)
+        return pages, state, nxt, put.t0_ns
 
     def decode_read(self, nxt):
         """The second half: wait for a tick's tokens. Returns (next[B] np,
         routing): for a model with experts the tick's routing counts
         (assignments of live slots, distinct experts hit, the largest
-        expert's load, each summed over the layers) come back behind the
+        expert's load, each summed over the expert layers) come back behind the
         tokens, in the one read; None for any other."""
         nxt = np.asarray(nxt)
-        if self.cfg.mlp != "moe":
+        if not self.routes:
             return nxt, None
         return tuple(np.split(nxt, [self.max_batch]))
 
@@ -899,12 +1169,10 @@ class DecodeModel:
     def full_logits(self, tokens: np.ndarray, with_routing: bool = False):
         """Non-paged reference forward over [1, T] — the ground truth
         the engine's batched output is checked against. ``with_routing``
-        adds every position's top-k expert ids in every layer, ``[T,
-        n_layer, k]`` (a model with experts)."""
-        import jax
+        adds every position's top-k expert ids in every expert layer,
+        ``[T, n_expert_layers, k]`` (a model with experts)."""
         import jax.numpy as jnp
 
-        cfg = self.cfg
         t = np.asarray(tokens, np.int32).reshape(1, -1)
         T = t.shape[1]
         p = self.params
@@ -913,17 +1181,18 @@ class DecodeModel:
         causal = pos[:, None] >= pos[None, :]
         rot = self._rot(pos[None])
         routing = []
-        for i in range(cfg.n_layer):
-            lp, ln = _layer_params(p, i), _LAYER
-            h = self._ln_p(lp, x, f"{ln}.ln1")
-            q, k, v = self._qkv(lp, h, rot, (1, T))
-            s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(cfg.head_dim)
-            s = jnp.where(causal[None, None], s, _NEG)
-            a = jax.nn.softmax(s, axis=-1)
-            o = jnp.einsum("bhqk,bkhd->bqhd", a, v).reshape(1, T, -1)
-            x = x + self._linear(lp, o, f"{ln}.attn.proj")
-            x, idx = self._ffn(lp, x)
-            routing.append(idx)
+        for i in range(self.cfg.n_layer):
+            (op, mlp), own = self._place(i)
+            lp = _layer_params(p, i)
+            x = self._prompt_op(op, T, lp, own, x, causal, None, None, rot)[0]
+            # the feed-forward is row by row: a few hundred positions at a
+            # time, so that a long sequence never holds every expert's
+            # activations for all of it at once
+            parts = [self._ffn(lp, x[:, a:a + _FFN_ROWS], mlp)
+                     for a in range(0, T, _FFN_ROWS)]
+            x = jnp.concatenate([y for y, _ in parts], axis=1)
+            if parts[0][1] is not None:
+                routing.append(jnp.concatenate([idx for _, idx in parts]))
         logits = np.asarray(self._logits(p, self._ln_p(p, x, "gpt.lnf")))
         if with_routing:
             return logits, np.stack([np.asarray(r) for r in routing], axis=1)

@@ -128,19 +128,19 @@ def test_a_request_alone_and_in_a_full_batch_bit_for_bit(model, prompt):
     # and the decode program itself: the same row, alone or among others
     pages = model.init_pages()
     B, nb = model.max_batch, model.max_blocks_per_req
-    pages, _ = model.prefill(pages, np.asarray(prompt), len(prompt), [1, 2])
+    pages, _, _ = model.prefill(pages, None, np.asarray(prompt), len(prompt), [1, 2])
     tables = np.zeros((B, nb), np.int32)
     tables[0, :2] = [1, 2]
     lens, toks = np.zeros(B, np.int32), np.zeros(B, np.int32)
     lens[0], toks[0] = len(prompt), 7
     full_t, full_l, full_k = tables.copy(), lens.copy(), toks.copy()
     for s, n in ((1, 9), (2, 30), (3, 17)):
-        pages, _ = model.prefill(pages, np.asarray(others[s - 1]), n, [1 + 2 * s, 2 + 2 * s])
+        pages, _, _ = model.prefill(pages, None, np.asarray(others[s - 1]), n, [1 + 2 * s, 2 + 2 * s])
         full_t[s, :2] = [1 + 2 * s, 2 + 2 * s]
         full_l[s], full_k[s] = n, 11
-    pages, nxt, _ = model.decode_enqueue(pages, tables, lens, toks)
+    pages, _, nxt, _ = model.decode_enqueue(pages, None, tables, lens, toks)
     a, lone_routing = model.decode_read(nxt)
-    pages, nxt, _ = model.decode_enqueue(pages, full_t, full_l, full_k)
+    pages, _, nxt, _ = model.decode_enqueue(pages, None, full_t, full_l, full_k)
     b, full_routing = model.decode_read(nxt)
     assert a[0] == b[0]
     assert lone_routing[0] == L * K and full_routing[0] == 4 * L * K

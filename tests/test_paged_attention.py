@@ -24,7 +24,8 @@ FULL = MAXB * BS - 1  # the last position of the window
 def gathered(q, pool, tables, lens, scale):
     B, H, hd = q.shape
     S = tables.shape[1] * pool.shape[1]
-    ctx = pool[tables].reshape(B, S, H, 2 * hd).astype(jnp.float32)
+    kv = pool.shape[2] // (2 * hd)  # K|V heads a row; query head i reads head i // (H / kv)
+    ctx = jnp.repeat(pool[tables].reshape(B, S, kv, 2 * hd).astype(jnp.float32), H // kv, axis=2)
     kk, vv = ctx[..., :hd], ctx[..., hd:]
     s = jnp.einsum("bhd,bshd->bhs", q.astype(jnp.float32), kk) * scale
     valid = jnp.arange(S)[None, :] <= lens[:, None]
@@ -32,13 +33,15 @@ def gathered(q, pool, tables, lens, scale):
     return jnp.einsum("bhs,bshd->bhd", a, vv).reshape(B, -1)
 
 
-def make_case(lens, H, hd, dtype, layer=0, seed=0):
+def make_case(lens, H, hd, dtype, layer=0, seed=0, kv=None):
     """q, a pool of ``layer + 1`` layers, layer-folded tables and lens:
     each slot owns scattered pages of layer ``layer`` for its context
     (an empty slot none); table entries behind them name scratch block 0;
-    every page that no table names holds NaN, those of other layers too."""
+    every page that no table names holds NaN, those of other layers too.
+    ``kv``: K|V heads a row, fewer than the ``H`` query heads (grouped
+    queries); None: one a query head."""
     r = np.random.RandomState(seed)
-    B, row = len(lens), H * 2 * hd
+    B, row = len(lens), (kv or H) * 2 * hd
     pool = np.full(((layer + 1) * NB, BS, row), np.nan, np.float32)
     base = layer * NB
     tables = np.zeros((B, MAXB), np.int32)
@@ -82,6 +85,33 @@ def test_ragged_batch_with_an_empty_slot_beside_full_ones(H, hd):
     # an empty slot attends to position 0 of the scratch block alone: its V
     v0 = np.asarray(pool[tables[0, 0], 0].astype(jnp.float32)).reshape(H, 2, hd)[:, 1]
     np.testing.assert_allclose(out[0], v0.reshape(-1), rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("H,kv,hd", [(32, 8, 64), (16, 8, 128)])
+def test_grouped_queries_read_their_groups_kv_head(H, kv, hd, dtype):
+    """32 query heads over 8 K|V heads: head ``i`` attends K|V head ``i //
+    4`` and no other (the gathered formulation repeats K and V four
+    times), ragged, an empty slot among full ones, on a later layer."""
+    lens = [0, FULL, 1, BS, 3 * BS + 5, 8 * BS - 1]
+    q, pool, tables, ln = make_case(lens, H, hd, dtype, layer=1, kv=kv)
+    out = check(q, pool, tables, ln)
+    # a K|V head's four query heads differ (their q does), and moving query
+    # head 5's q moves its own output alone
+    q2 = q.at[:, 5].add(1.0)
+    again = np.asarray(paged_attention(q2, pool, tables, ln, 1.0 / math.sqrt(hd)), np.float32)
+    same = np.isclose(again.reshape(len(lens), H, hd), out.reshape(len(lens), H, hd)).all(-1)
+    assert same[1:, [h for h in range(H) if h != 5]].all() and not same[1:, 5].any()
+
+
+def test_a_group_that_does_not_fill_the_accumulators_tiles_is_refused():
+    assert "4 query heads over 2 K|V heads" in pa.unsupported(64, 16, jnp.bfloat16, 4, 2)
+    assert "5 query heads over 2" in pa.unsupported(64, 16, jnp.bfloat16, 5, 2)
+    assert pa.unsupported(64, 16, jnp.bfloat16, 32, 8) == pa.unsupported(64, 16, jnp.bfloat16, 25, 25) == ""
+    q, pool, tables, ln = make_case([5], 4, 64, "float32", kv=2)
+    with pytest.raises(ValueError, match="not a whole group"):
+        paged_attention(q, pool, tables, ln, 0.125)
+    assert pa.vmem_scratch_bytes(32, 64, 16, jnp.bfloat16, 8) < pa.vmem_scratch_bytes(32, 64, 16, jnp.bfloat16)
 
 
 @pytest.mark.parametrize("layer", [0, 3])
@@ -222,12 +252,14 @@ def test_ledger_counts_the_pages_a_tick_reads_and_the_window(kernel_model, tmp_p
     assert t["decode_ticks"] == 3 and t["attn_pages_read"] == (1 + 1 + 2) + 3
     assert t["attn_pages_window"] == 3 * kernel_model.max_batch * kernel_model.max_blocks_per_req == 48
     att = ledger.status()["attention"]
-    assert att == {"pages_read": 7, "pages_window": 48, "window_share": 7 / 48}
+    assert att == {"pages_read": 7, "pages_window": 48, "window_share": 7 / 48,
+                   "layers": kernel_model.cfg.n_layer}  # every layer of this model attends
     with open(ledger.flush(str(tmp_path / "serving.rank0.json"))) as f:
         journal = json.load(f)
     assert (journal["attn_pages_read"], journal["attn_pages_window"]) == (7, 48)
     merged = ledger.merge_ledgers([journal, journal])
     assert (merged["attn_pages_read"], merged["attn_pages_window"]) == (14, 96)
+    assert journal["attn_layers"] == merged["attn_layers"] == kernel_model.cfg.n_layer  # a gauge: not summed
     ledger.reset()
     assert ledger.totals()["attn_pages_read"] == ledger.totals()["attn_pages_window"] == 0
     assert "attention" not in ledger.status()

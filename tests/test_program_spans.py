@@ -268,3 +268,75 @@ def test_span_with_tracing_on_keeps_attributes_and_seconds():
     assert sp.seconds >= 0 and abs(ev["dur"] - sp.seconds * 1e6) < 1e-6
     chrome = profiler._chrome_trace([ev])["traceEvents"][-1]
     assert chrome["args"]["queued"] == 2 and chrome["args"]["admitted"] == 1
+
+
+# -- a model whose layers are of three kinds (tests/test_lfm2_serving.py) ----
+
+LFM2_SCOPES = {
+    "layer_conv_swiglu": ("conv/in_proj", "conv/mix", "conv/state_write", "conv/out_proj", "mlp"),
+    "layer_attn_moe": ("attn/qk_norm", "attn/rope", "attn/kv_write", "moe/route", "moe/experts"),
+    "layer_conv_moe": ("conv/in_proj", "conv/mix", "conv/state_write", "conv/out_proj", "moe/route",
+                       "moe/experts"),
+}
+
+
+@pytest.fixture(scope="module")
+def lfm2_programs():
+    """op names of a tiny three-kind model's decode tick and prefill, as
+    compiled here (the scopes are the tracer's: the same on every backend)."""
+    import re
+    import sys
+
+    from test_lfm2_serving import tiny_cfg, tiny_model
+
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "tools"))
+    import serve_compile_report as report
+
+    dm = tiny_model(tiny_cfg("kernel"))
+    return dm, {name: set(re.findall(r'op_name="([^"]*)"', jit_fn.lower(*args).compile().as_text()))
+                for name, (jit_fn, args) in report.serving_programs(dm).items()}
+
+
+@pytest.mark.parametrize("program,attends", [("decode_tick", "attn/paged"), ("prefill_32", "attn/scores")])
+@pytest.mark.parametrize("layer", sorted(LFM2_SCOPES))
+def test_layers_of_three_kinds_carry_their_names_and_scopes(lfm2_programs, program, attends, layer):
+    """One inner jit a KIND of layer, named by it, so that a trace tells
+    conv + dense, attention + experts and conv + experts apart; under
+    each, the scopes of what that kind computes and no other kind's."""
+    ops = lfm2_programs[1][program]
+    own = LFM2_SCOPES[layer] + ((attends,) if "attn" in layer else ())
+    for scope in own:
+        assert any(f"jit({program})/jit({layer})/{scope}/" in o for o in ops), (scope, sorted(ops)[:10])
+    others = {s for v in LFM2_SCOPES.values() for s in v} | {"attn/paged", "attn/scores"}
+    for scope in others - set(own):
+        assert not any(f"jit({layer})/{scope}/" in o for o in ops), scope
+    assert not any("/jit(layer)/" in o for o in ops)  # that name is a one-kind model's
+
+
+def test_state_pool_counters_follow_the_work(lfm2_programs):
+    """``state_writes``: one a prefill (admission or resume);
+    ``state_pool_bytes`` and ``attn_layers``: gauges of the model served;
+    the pages counted are the attention layers' alone. In totals(), on
+    /status, merged (sums; gauges as the largest), cleared by reset()."""
+    from paddle_tpu.serving import ledger
+
+    dm = lfm2_programs[0]
+    ledger.reset()
+    eng = serving.ServingEngine(dm)
+    handles = [eng.submit(list(range(1, n + 1)), max_new_tokens=4) for n in (14, 3)]
+    eng.run_until_idle()
+    assert all(len(h.result(timeout=5)) == 4 for h in handles)
+    doc = ledger.totals()
+    nbytes = 3 * 2 * 4 * 1024 * 4  # conv layers x gated inputs x slots x hidden x float32
+    assert (doc["state_writes"], doc["state_pool_bytes"], doc["attn_layers"]) == (2, nbytes, 1)
+    assert doc["attn_pages_read"] == (1 + 1 + 2) + 3  # a layer's pages, as tests/test_paged_attention.py
+    st = ledger.status()
+    assert st["state_pool"] == {"bytes": nbytes, "slot_writes": 2} and st["attention"]["layers"] == 1
+    merged = ledger.merge_ledgers([doc, doc])
+    assert (merged["state_writes"], merged["state_pool_bytes"], merged["attn_layers"]) == (4, nbytes, 1)
+    # the routing counters ride behind the tokens, over the three expert layers
+    assert doc["moe_assignments"] == doc["decode_tokens"] * 3 * 2
+    ledger.reset()
+    doc = ledger.totals()
+    assert doc["state_writes"] == doc["state_pool_bytes"] == doc["attn_layers"] == 0
+    assert "state_pool" not in ledger.status()

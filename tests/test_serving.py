@@ -347,11 +347,11 @@ def test_program_consumes_the_pool_it_is_given(tiny_model, program):
     pages = tiny_model.init_pages()
     assert pages.shape == tiny_model.pool_shape() == (2 * 16, 8, 2 * 2 * 16)
     if program == "prefill":
-        out, _ = tiny_model.prefill(pages, np.asarray([3, 4, 5]), 3, [1])
+        out, _, _ = tiny_model.prefill(pages, None, np.asarray([3, 4, 5]), 3, [1])
     else:
         tables = np.zeros((4, tiny_model.max_blocks_per_req), np.int32)
-        out, _, _ = tiny_model.decode_enqueue(
-            pages, tables, np.zeros(4, np.int32), np.zeros(4, np.int32))
+        out, _, _, _ = tiny_model.decode_enqueue(
+            pages, None, tables, np.zeros(4, np.int32), np.zeros(4, np.int32))
     assert pages.is_deleted() and not out.is_deleted()
     assert out.shape == pages.shape and out.sharding == pages.sharding
 
@@ -430,9 +430,9 @@ def _spy_on_enqueue(model, monkeypatch):
     """Record (tables, lens, tokens) of every decode tick enqueued."""
     calls, real = [], model.decode_enqueue
 
-    def spy(pages, tables, lens, toks, prev=None):
+    def spy(pages, state, tables, lens, toks, prev=None):
         calls.append((tables.copy(), lens.copy(), toks.copy()))
-        return real(pages, tables, lens, toks, prev)
+        return real(pages, state, tables, lens, toks, prev)
 
     monkeypatch.setattr(model, "decode_enqueue", spy)
     return calls

@@ -525,3 +525,160 @@ def test_decode_tick_takes_a_slots_unread_token_from_the_tick_before(request, pr
     # context lengths, tokens and `prev`, then the block tables
     assert ints == sorted([(B,), (B,), (B + behind,), (B, progs.dm.max_blocks_per_req)])
     assert re.search(r"pred\[%d\]\S* compare\(" % B, progs.text["decode_tick"])
+
+
+# -- LFM2-MoE: layers of three kinds, two pools (tests/test_lfm2_serving.py) --
+
+@pytest.fixture(scope="module")
+def lfm2_programs(tpu_device):
+    """The cell's decode tick and its bucket-256 prefill at the published
+    widths, cut to the first four layers (conv + dense twice, attention +
+    experts, conv + experts: every kind), compiled for the described chip."""
+    import os
+    import sys
+    import types
+
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools"))
+    import serve_compile_report as report
+
+    dm = report.cell_model("lfm2-serve-reason", n_layer=4)
+    out = types.SimpleNamespace(dm=dm, text={}, facts={}, state={})
+    for name, (jit_fn, args) in report.serving_programs(dm).items():
+        if name == "prefill_128":
+            continue
+        compiled = report.compile_on(jit_fn, args, tpu_device)
+        out.text[name] = compiled.as_text()
+        out.facts[name] = report.describe(compiled, dm.pool_shape())
+        out.state[name] = report.describe(compiled, dm.state_shape())["pool"]
+    return out
+
+
+@pytest.mark.parametrize("name,attends", [("decode_tick", "attn/paged"), ("prefill_256", "attn/scores")])
+def test_lfm2_programs_carry_their_names_and_each_kinds_scopes(lfm2_programs, name, attends):
+    from benchmark import manifest
+
+    text = lfm2_programs.text[name]
+    assert re.search(rf"HloModule jit_{name}\b", text)
+    metric = "lfm2_decode_program_ms" if name == "decode_tick" else "lfm2_prefill_program_ms"
+    assert re.search(manifest.layer_metric(metric)["args"]["pattern"], f"jit_{name}")
+    ops = set(re.findall(r'op_name="([^"]*)"', text))
+    conv = ("conv/in_proj", "conv/mix", "conv/state_write", "conv/out_proj")
+    attn = ("attn/qk_norm", "attn/rope", "attn/kv_write", attends)
+    moe = ("moe/route", "moe/experts")
+    for layer, scopes in (("layer_conv_swiglu", conv + ("mlp",)), ("layer_attn_moe", attn + moe),
+                          ("layer_conv_moe", conv + moe)):
+        for scope in scopes:
+            assert any(f"jit({name})/jit({layer})/{scope}/" in o for o in ops), (layer, scope)
+    assert not any("/attn/kv_gather/" in o or "/jit(layer)/" in o for o in ops)
+
+
+def test_lfm2_decode_tick_holds_one_grouped_query_kernel_an_attention_layer(lfm2_programs):
+    """32 query heads over 8 K|V heads of 64: the kernel path, not the
+    gathered window; the conv layers call no kernel."""
+    dm = lfm2_programs.dm
+    assert dm.attention_path() == ("kernel", "") and len(dm.attn_layers) == 1
+    assert (dm.cfg.n_head, dm.cfg.kv_heads, dm.cfg.head_dim) == (32, 8, 64)
+    assert _kernel_names(lfm2_programs.text["decode_tick"]) == ["paged_attention"]
+    assert _kernel_names(lfm2_programs.text["prefill_256"]) == []
+    facts = lfm2_programs.facts["decode_tick"]
+    assert facts["mosaic_kernels"] == {"paged_attention": 1}
+
+
+@pytest.mark.parametrize("name", _SERVING_PROGRAMS)
+def test_lfm2_both_pools_stay_in_place_unpadded(lfm2_programs, name):
+    """The KV pool is the attention layers' alone, 8 K|V heads a row; the
+    state pool is a second donated array. Both alias their outputs, rest in
+    the layout their updates work on, and nothing else is aliased."""
+    dm, pool, state = lfm2_programs.dm, lfm2_programs.facts[name]["pool"], lfm2_programs.state[name]
+    assert dm.pool_shape() == (1 * 10368, 16, 1024) and dm.state_shape() == (3, 2, 64, 2048)
+    for p in (pool, state):
+        assert p["parameter"] is not None and p["aliased_to_output"], p
+        # one tiling throughout; the compiler may hold the small state pool
+        # in fast memory between its layers (the `S(1)` of a layout)
+        assert {re.sub(r"S\(\d\)$", "", lay) for lay in p["layouts_in_program"]} == {p["layout"]}, p
+    assert pool["layouts_in_program"] == [pool["layout"]], pool
+    assert pool["layout"].startswith("2,1,0:T(8,128)") and state["layout"].startswith("3,2,1,0:T(8,128)")
+    assert lfm2_programs.facts[name]["aliased_parameters"] == sorted([pool["parameter"], state["parameter"]])
+    need = (10368 * 16 * 1024 + 3 * 2 * 64 * 2048) * 2
+    assert abs(lfm2_programs.facts[name]["memory"]["alias_size_in_bytes"] - need) <= 0.01 * need
+
+
+@pytest.mark.parametrize("name", _SERVING_PROGRAMS)
+def test_lfm2_program_moves_neither_pool_nor_expert_weights(lfm2_programs, name):
+    cfg = lfm2_programs.dm.cfg
+    limit = cfg.n_experts * cfg.d_model * cfg.ffn_dim  # one stacked expert weight; the pool is larger
+    big = [c for c in lfm2_programs.facts[name]["top_level_copies"] if c["elements"] >= limit]
+    assert not big, big
+
+
+# -- the programs of the configurations the benchmark already had ------------
+
+# sha256 (first 16 hex digits) of each program's StableHLO text as lowered
+# for the described chip on the PARENT of the PR that gave layers kinds and
+# the kernel grouped queries (ba29c55), with the Mosaic kernel's serialised
+# body cut out (it carries source line numbers; the kernel's own jaxpr is
+# held below instead).
+_PARENT_PROGRAMS = {
+    ("gpt2", "decode_tick"): "cf354808014e7cc1", ("gpt2", "prefill_32"): "7b7f087b9f441263",
+    ("olmoe", "decode_tick"): "653b0a7cdc6d0601", ("olmoe", "prefill_32"): "d4e27627cb07bdd5"}
+_PARENT_KERNEL = {(4, 4, 64, 64, 8): "90ef1bd43a930eb2", (12, 25, 64, 864, 64): "9394cc5543d1440f",
+                  (24, 16, 128, 1920, 64): "9c0520193a38207e"}
+
+
+def _sha(text):
+    import hashlib
+
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.fixture(scope="module")
+def lowered_small(tpu_device):
+    """{(block, program): StableHLO text} of a small GPT-2 and a small
+    OLMoE description, lowered (not compiled) for the described chip."""
+    import os
+    import sys
+
+    from paddle_tpu import serving
+
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools"))
+    import serve_compile_report as report
+
+    sizes = dict(vocab_size=512, n_layer=2, d_model=256, max_seq_len=128, dtype="bfloat16")
+    blocks = {"gpt2": serving.GPTConfig(n_head=4, **sizes),
+              "olmoe": serving.GPTConfig(n_head=2, d_ff=64, tie_embeddings=False, norm="rmsnorm",
+                                         position="rope", qk_norm=True, bias=False, mlp="moe",
+                                         n_experts=8, experts_per_token=2, **sizes)}
+    out = {}
+    for tag, cfg in blocks.items():
+        dm = report.abstract_model(cfg, max_batch=4, n_blocks=32, block_size=16, prefill_buckets=[32])
+        for name, (jit_fn, args) in report.serving_programs(dm).items():
+            out[tag, name] = report.lower_on(jit_fn, args, tpu_device).as_text()
+    return out
+
+
+@pytest.mark.parametrize("block,program", sorted(_PARENT_PROGRAMS))
+def test_programs_of_one_kind_of_layer_lower_as_on_the_parent(lowered_small, block, program):
+    """A model of one kind of layer, one K|V head a query head and no conv
+    state gets the program it got before any of that existed: the same
+    arguments, the same inner ``layer``, op for op."""
+    text = lowered_small[block, program]
+    assert ("tpu_custom_call" in text) == (program == "decode_tick")
+    body_cut = re.sub(r'(\\22body\\22: \\22)[A-Za-z0-9+/=]+', r"\1", text)
+    assert _sha(body_cut) == _PARENT_PROGRAMS[block, program]
+
+
+@pytest.mark.parametrize("B,H,hd,rows,maxb", sorted(_PARENT_KERNEL))
+def test_paged_attention_with_a_kv_head_a_query_head_is_the_parents_call(B, H, hd, rows, maxb):
+    """``n_kv_head == n_head``: the kernel and the call around it trace to
+    the jaxpr they traced to before grouped queries."""
+    from paddle_tpu.ops.pallas import paged_attention as pa
+
+    def call(q, pool, tables, lens):
+        return pa._paged_attention(q, pool, tables, lens, scale=0.125, interpret=False)
+
+    jaxpr = jax.make_jaxpr(call)(
+        jax.ShapeDtypeStruct((B, H, hd), jnp.bfloat16), jax.ShapeDtypeStruct((rows, 16, H * 2 * hd), jnp.bfloat16),
+        jax.ShapeDtypeStruct((B, maxb), jnp.int32), jax.ShapeDtypeStruct((B,), jnp.int32))
+    assert _sha(str(jaxpr)) == _PARENT_KERNEL[B, H, hd, rows, maxb]

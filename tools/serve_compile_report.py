@@ -77,6 +77,8 @@ def cell_model(cell: str, n_layer=None):
     conf, e = dict(c["config"]), c["traffic"]["engine"]
     if n_layer:
         conf["n_layer"] = n_layer
+        if "layer_types" in conf:  # a model of several kinds of layer: its first ones
+            conf["layer_types"] = conf["layer_types"][:n_layer]
     cfg = serving.GPTConfig(**arch.of(conf).gpt_config(conf, e))
     return abstract_model(cfg, **arch.engine_args(e))
 
@@ -96,7 +98,12 @@ def serving_programs(dm) -> Dict[str, Tuple[Any, tuple]]:
 
 
 def compile_on(jit_fn, args, device):
-    """Compile ``jit_fn`` at ``args`` with every leaf placed on ``device``.
+    """Compile ``jit_fn`` at ``args`` with every leaf placed on ``device``."""
+    return lower_on(jit_fn, args, device).compile()
+
+
+def lower_on(jit_fn, args, device):
+    """Lower ``jit_fn`` at ``args`` with every leaf placed on ``device``.
     A pallas kernel in it is lowered through Mosaic, as on the chip: the
     package decides that by ``on_tpu()``, which sees this host's CPU."""
     import importlib
@@ -111,7 +118,7 @@ def compile_on(jit_fn, args, device):
     kernel = importlib.import_module("paddle_tpu.ops.pallas.paged_attention")
     on_tpu, kernel.on_tpu = kernel.on_tpu, lambda: True
     try:
-        return jit_fn.lower(*placed).compile()
+        return jit_fn.lower(*placed)
     finally:
         kernel.on_tpu = on_tpu
 
@@ -143,11 +150,13 @@ def attention_facts(dm, mosaic_kernels: Dict[str, int]) -> Dict[str, Any]:
     path, why = dm.attention_path()
     calls = mosaic_kernels.get("paged_attention", 0)
     cfg = dm.cfg
-    return {"decode_path": path,
+    return {"decode_path": path, "attention_layers": len(dm.attn_layers),
+            "query_heads_a_kv_head": cfg.n_head // cfg.kv_heads,
             "why": why or "one device, heads of whole 128-lane tiles, pages of whole tiles",
             "paged_attention_calls": calls,
             "vmem_scratch_bytes": pa.vmem_scratch_bytes(
-                cfg.n_head, cfg.head_dim, dm.block_size, cfg.dtype) if calls else 0}
+                cfg.n_head, cfg.head_dim, dm.block_size, cfg.dtype,
+                cfg.kv_heads) if calls else 0}
 
 
 def describe(compiled, pool_shape: Tuple[int, ...]) -> Dict[str, Any]:
@@ -227,6 +236,8 @@ def main(argv=None) -> int:
                 f.write(compiled.as_text())
         facts = describe(compiled, dm.pool_shape())
         facts["attention"] = attention_facts(dm, facts["mosaic_kernels"])
+        if dm.state_shape() is not None:  # the conv layers' second donated pool
+            facts["state_pool"] = describe(compiled, dm.state_shape())["pool"]
         print(json.dumps({name: facts}, indent=1))
     return 0
 
