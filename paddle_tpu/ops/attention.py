@@ -76,6 +76,81 @@ def scaled_dot_product_attention(q, k, v, attn_mask=None, dropout_p=0.0, is_caus
     )
 
 
+# Tile table of the flash kernels, by what the op can see when it is traced
+# (sequence lengths, layout, causal): per kernel the (bq candidates, bk
+# candidates), of which the first that divides the length is taken. Swept on
+# a TPU v5e by PR 35 with tools/flash_sweep.py at the shape the benchmark
+# cell gpt2s-train-1k runs (B 32, T 1024, 12 heads of 64, BTHD, causal),
+# each kernel alone and then the whole train step, which decides (PERF.md
+# section 6 has the numbers). What the sweep taught: a kernel's time is the
+# score area it computes PLUS a cost per accumulator row and step of its
+# sequential sweep (running max and sum, the accumulator's rescale and round
+# trip, the row statistics' transposes), and at heads of 64 the second is
+# as large as the first: tiles of 256 x 256 computed 62.5% of the score
+# square where the tiles before computed 100% / 75%, and LOST 8.6% of the
+# train step. So where the widest tile covers the sweep (lengths up to
+# 1024) the forward and dq take ONE kv step, which the kernels run without
+# the running statistics' round trip and trimmed to what lies under the
+# diagonal (ops/pallas/flash_attention.py: `single`, `_trims`); dkv, which
+# keeps no running statistics, takes two q steps of 512. Longer sequences
+# and non-causal calls keep the tiles they had (from a T = 2048 sweep before
+# PR 1; re-measured by PR 35 at T 2048, B 16: still the best of those tried).
+_WIDE = (1024, 512, 256, 128)
+_FLASH_TILES = {
+    # (layout, causal, lengths <= 1024): {kernel: (bq candidates, bk candidates)}
+    ("BTHD", True, True): {"fwd": ((256, 128), _WIDE), "dq": ((128,), _WIDE),
+                           "dkv": ((512, 256, 128), (256, 128))},
+    ("BTHD", True, False): {"fwd": ((256, 128), _WIDE), "dq": ((512,), (512,)), "dkv": ((512,), (512,))},
+    ("BTHD", False, True): {"fwd": ((256, 128), _WIDE), "dq": ((512,), (512,)), "dkv": ((512,), (512,))},
+    ("BHTD", True, True): {"fwd": (_WIDE, _WIDE), "dq": ((512, 256, 128), _WIDE), "dkv": ((512, 256, 128), _WIDE)},
+    ("BHTD", True, False): {"fwd": ((512, 256, 128), _WIDE)},
+    ("BHTD", False, True): {"fwd": ((512, 256, 128), _WIDE)},
+}
+_FLASH_TILES["BTHD", False, False] = _FLASH_TILES["BTHD", False, True]
+_FLASH_TILES["BHTD", False, False] = _FLASH_TILES["BHTD", False, True]
+
+
+def _first_dividing(cands, n):
+    return next((b for b in cands if n % b == 0), None)
+
+
+def _flash_tiles(tq, tk, layout, causal):
+    """(bq, bk, bwd_blocks) of the table above for one call; bq or bk is
+    None where no candidate divides the length (the XLA path then), and
+    bwd_blocks where a backward kernel has no entry or none of its
+    candidates divides (the backward then takes the forward's tiles)."""
+    table = _FLASH_TILES[layout, bool(causal), max(tq, tk) <= _WIDE[0]]
+    pick = lambda kernel: tuple(  # noqa: E731
+        _first_dividing(c, n) for c, n in zip(table.get(kernel, ((), ())), (tq, tk)))
+    bwd = pick("dq") + pick("dkv")
+    return pick("fwd") + (None if None in bwd else bwd,)
+
+
+def _swept_tiles(tq, tk, layout, causal):
+    """The same under tools/flash_sweep.py's knobs, where the environment
+    holds them: PADDLE_TPU_FLASH_BLOCKS ("bq,..;bk,.." or one shared list)
+    replaces the forward candidates and ties the backward to them unless
+    PADDLE_TPU_FLASH_BWD_BLOCKS ("bq_dq,bk_dq;bq_dkv,bk_dkv") names its
+    own."""
+    import os
+
+    env_blocks = os.environ.get("PADDLE_TPU_FLASH_BLOCKS")
+    env_bwd = os.environ.get("PADDLE_TPU_FLASH_BWD_BLOCKS")
+    bq, bk, bwd_blocks = _flash_tiles(tq, tk, layout, causal)
+    if env_blocks:
+        qs, _, ks = env_blocks.partition(";")
+        bq = _first_dividing([int(b) for b in qs.split(",")], tq)
+        bk = _first_dividing([int(b) for b in (ks or qs).split(",")], tk)
+        bwd_blocks = None
+    if env_bwd:
+        bwd_blocks = tuple(int(x) for pair in env_bwd.split(";") for x in pair.split(","))
+        if len(bwd_blocks) != 4:
+            raise ValueError(
+                f"PADDLE_TPU_FLASH_BWD_BLOCKS={env_bwd!r}: expected "
+                f"'bq_dq,bk_dq;bq_dkv,bk_dkv'")
+    return bq, bk, bwd_blocks
+
+
 @register_op("fused_attention_tpu", no_grad_inputs=("Mask",), uses_rng=True)
 def _fused_attention_tpu(ctx, ins, attrs):
     q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
@@ -89,7 +164,6 @@ def _fused_attention_tpu(ctx, ins, attrs):
     use_flash = attrs.get("use_flash", True) and not os.environ.get(
         "PADDLE_TPU_DISABLE_FLASH"
     )
-    _env_blocks = os.environ.get("PADDLE_TPU_FLASH_BLOCKS")
     seq_ax = 1 if layout == "BTHD" else 2
 
     # context parallelism: with a mesh carrying the sequence axis, run the
@@ -121,10 +195,12 @@ def _fused_attention_tpu(ctx, ins, attrs):
         )
         if layout == "BTHD":
             out = out.transpose(0, 2, 1, 3)
-    # crossover measured on v5e before PR 1 (flash sweeps, another
-    # installation): XLA's fused attention won at T=512 (the flash grid
-    # overhead dominates), the pallas kernel from ~1k up. Not re-measured
-    # on current code. PADDLE_TPU_FLASH_MIN_SEQ overrides for re-measurement.
+    # The flash kernels take sequences from 1024 up (the shape the
+    # benchmark cell gpt2s-train-1k trains at and PR 35 swept); below that
+    # XLA's fused attention runs. The crossover itself was measured before
+    # PR 1 on another installation (XLA won at T=512, where the flash grid
+    # overhead dominates) and not since: PADDLE_TPU_FLASH_MIN_SEQ overrides
+    # it for re-measurement.
     min_seq = int(os.environ.get("PADDLE_TPU_FLASH_MIN_SEQ", 1024))
     # GSPMD cannot partition a Mosaic call, and the flash kernel has no
     # shard_map region of its own yet: a mesh program that did not take
@@ -132,48 +208,10 @@ def _fused_attention_tpu(ctx, ins, attrs):
     single_device = mesh is None or mesh.size == 1
     if out is None and use_flash and single_device and mask is None and q.shape[seq_ax] >= min_seq and q.shape[-1] in (64, 128, 256):
         tq, tk = q.shape[seq_ax], k.shape[seq_ax]
-        # tilings from the same pre-PR-1 sweep (T=2048, full GPT train
-        # step): fwd (256, 1024) + bwd (512,512;512,512) beat the shared
-        # (256, 512) — the wide fwd kv block halves the sequential-sweep
-        # rescale work (it needs the raised per-kernel vmem limit, see
-        # pallas/backend.VMEM_LIMIT), while the backward prefers square
-        # 512 tiles.
-        if layout == "BTHD":
-            cand_q, cand_k = (256, 128), (1024, 512, 256, 128)
-        else:
-            cand_q, cand_k = (512, 256, 128), (1024, 512, 256, 128)
-        if _env_blocks:
-            if ";" in _env_blocks:
-                qs, ks = _env_blocks.split(";", 1)
-                cand_q = tuple(int(b) for b in qs.split(","))
-                cand_k = tuple(int(b) for b in ks.split(","))
-            else:
-                cand_q = cand_k = tuple(int(b) for b in _env_blocks.split(","))
-        bq = next((b for b in cand_q if tq % b == 0), None)
-        bk = next((b for b in cand_k if tk % b == 0), None)
+        bq, bk, bwd_blocks = _swept_tiles(tq, tk, layout, is_causal)
         if bq is None or bk is None:
             _warn_xla_path(f"seq lengths ({tq},{tk}) not divisible by 128")
         else:
-            # Default backward tiling: square 512 blocks, independent of
-            # the wide fwd kv block — but only when NO sweep knob is set,
-            # so a shared-blocks sweep via PADDLE_TPU_FLASH_BLOCKS keeps
-            # its fwd+bwd meaning.
-            bwd_blocks = None
-            env_bwd = os.environ.get("PADDLE_TPU_FLASH_BWD_BLOCKS")
-            if (layout == "BTHD" and not _env_blocks and not env_bwd
-                    and tq % 512 == 0 and tk % 512 == 0):
-                bwd_blocks = (512, 512, 512, 512)
-            if env_bwd:  # "bq_dq,bk_dq;bq_dkv,bk_dkv" (sweep knob)
-                dq_s, dkv_s = env_bwd.split(";")
-                bwd_blocks = tuple(
-                    int(x) for pair in (dq_s, dkv_s)
-                    for x in pair.split(",")
-                )
-                if len(bwd_blocks) != 4:
-                    raise ValueError(
-                        f"PADDLE_TPU_FLASH_BWD_BLOCKS={env_bwd!r}: expected "
-                        f"'bq_dq,bk_dq;bq_dkv,bk_dkv'"
-                    )
             # no try/except: once the shape selects the kernel, a kernel
             # that fails to trace is an error on every backend — the XLA
             # path is a shape-based choice, never a rescue

@@ -24,16 +24,163 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ... import monitor as _monitor
 from .backend import compiler_params, on_tpu
 
 _NEG_INF = -1e30  # finite stand-in for -inf: avoids inf-inf=nan in rescaling
+
+_M_TILES = _monitor.counter(
+    "flash_tiles_total",
+    "score tiles (squares of a grid tile's smaller side) of the flash "
+    "kernels by what the causal schedule does with them: skipped (above "
+    "the diagonal: no work, no copy, or trimmed off a tile that crosses "
+    "it), interior (below it, no mask arithmetic), diagonal (masked). "
+    "Counted over a call's whole grid when the kernel is traced, not per "
+    "step",
+    labelnames=("kernel", "cls"))
+_KERNELS, _CLASSES = ("fwd", "dq", "dkv"), ("skipped", "interior", "diagonal")
+
+
+def tile_counts():
+    """{kernel: {cls: flash_tiles_total as it stands}}, for the tools and
+    tests that read a share of the score square from it."""
+    return {k: {c: _M_TILES.labels(kernel=k, cls=c).value for c in _CLASSES}
+            for k in _KERNELS}
+
+
+# ------------------------------------------------- the causal tile schedule
+#
+# A (bq, bk) score tile at grid position (iq, ik) is one of three classes
+# (bottom-right aligned mask, offset = Tk - T, as _sdpa_xla's tril):
+#   skipped   every column lies above the diagonal: no arithmetic, and the
+#             index maps below fetch nothing for it
+#   interior  every column lies at or below it: no mask arithmetic
+#   diagonal  crosses it: masked, and computed only as far as the diagonal
+#             reaches into it (_trims)
+# A kernel's time is the score area it computes plus a cost per accumulator
+# row and step of its sequential sweep (running max and sum, rescale, row
+# statistics), so the dispatcher's tiles are wide along the sweep and the
+# saving under the mask comes from trimming, not from many small tiles
+# (ops/attention.py::_FLASH_TILES). A non-causal call has interior tiles
+# only.
+
+
+def _tile_classes(nq, nk, bq, bk, offset, causal, trims):
+    """(skipped, interior, diagonal) of one (nq, nk) grid of score tiles,
+    counted in squares of the tile's smaller side, so that what a tile
+    crossing the diagonal leaves out (`trims`) counts as skipped."""
+    g = min(bq, bk)
+    per_tile = max(bq, bk) // g
+    if not causal:
+        return 0, nq * nk * per_tile, 0
+    interior = diagonal = 0
+    for iq in range(nq):
+        for ik in range(nk):
+            cross = iq * bq + offset - ik * bk
+            if cross >= bk - 1:
+                interior += per_tile
+            elif cross + bq - 1 >= 0:
+                r0, r1, c0, c1 = trims[abs(cross) // g] if len(trims) > 1 else trims[0]
+                diagonal += (r1 - r0) * (c1 - c0) // g ** 2
+    return nq * nk * per_tile - interior - diagonal, interior, diagonal
+
+
+def _count_tiles(kernel, q, k, bthd, block_q, block_k, causal):
+    """Bump flash_tiles_total for one call of `kernel` as it is traced into
+    a program: every score grid of the call (batch, and heads where the
+    grid walks them)."""
+    B, H, T, _, Tk = _dims(q, k, bthd)
+    bq, bk = min(block_q, T), min(block_k, Tk)
+    nq, nk = T // bq, Tk // bk
+    trims = _trims(bq, bk, Tk - T, nq if kernel == "dkv" else nk)
+    classes = _tile_classes(nq, nk, bq, bk, Tk - T, causal, trims)
+    for cls, n in zip(_CLASSES, classes):
+        _M_TILES.labels(kernel=kernel, cls=cls).inc((B if bthd else B * H) * n)
+
+
+def _tile_index(causal, swap_grid, bq, bk, nq, nk, offset):
+    """(qi, ki): the q and kv tile an operand's BlockSpec fetches at the
+    last two grid coordinates (i, j); j is the sequential sweep (kv tiles
+    for forward and dq; q tiles for dkv, `swap_grid`). Under the causal
+    mask the sweep's index is clamped to the last kv tile the q rows need
+    (first q tile the kv columns need), so a skipped step names the block
+    its neighbour already holds and Pallas issues no copy for it. A
+    non-causal call keeps the bare indices."""
+    if not causal:
+        if swap_grid:
+            return (lambda i, j: j), (lambda i, j: i)
+        return (lambda i, j: i), (lambda i, j: j)
+
+    def last_k(iq):
+        need = jnp.maximum(iq * bq + (bq - 1 + offset), 0)
+        return jnp.minimum(jax.lax.div(need, bk), nk - 1)
+
+    def first_q(ik):
+        need = jnp.maximum(ik * bk - offset, 0)
+        return jnp.minimum(jax.lax.div(need, bq), nq - 1)
+
+    if swap_grid:
+        return (lambda i, j: jnp.maximum(j, first_q(i))), (lambda i, j: i)
+    return (lambda i, j: i), (lambda i, j: jnp.minimum(j, last_k(i)))
+
+
+def _trims(block_q, block_k, offset, steps):
+    """The parts (r0, r1, c0, c1) of a (bq, bk) tile that a tile crossing
+    the diagonal can need, one per position it can cross at. Where one
+    side divides the other and the offset is whole small sides, a wide
+    tile (bk > bq) crosses at iq*bq + offset - ik*bk = j*bq and needs its
+    first (j + 1)*bq columns; a tall one (bq > bk) crosses at -j*bk and
+    needs its rows from j*bk on. The rest of such a tile is all mask and
+    is not computed. A square tile, or one whose crossing is not aligned,
+    has the one part: all of it. Each part is one more copy of the
+    kernel's unrolled body: with more than two, a kernel whose sequential
+    sweep has several `steps` ran three times slower than untrimmed (v5e,
+    T 2048, PR 35), so there the tile stays whole too."""
+    g = min(block_q, block_k)
+    whole = [(0, block_q, 0, block_k)]
+    if block_q == block_k or max(block_q, block_k) % g or offset % g:
+        return whole
+    if steps > 1 and max(block_q, block_k) // g > 2:
+        return whole
+    if block_k > block_q:
+        return [(0, block_q, 0, (j + 1) * g) for j in range(block_k // g)]
+    return [(j * g, block_q, 0, block_k) for j in range(block_q // g)]
+
+
+def _run_by_class(compute, iq, ik, block_q, block_k, offset, causal, trims):
+    """Run `compute(masked, part)` for the tile at (iq, ik) as its class
+    says: not at all, whole and without mask arithmetic, or masked and
+    trimmed to the part under the diagonal (`trims`)."""
+    whole = (0, block_q, 0, block_k)
+    if not causal:  # under pl.when, as such a call has always traced
+        pl.when(True)(lambda: compute(False, whole))
+        return
+    cross = iq * block_q + offset - ik * block_k  # where the diagonal enters the tile
+    run, full = cross + block_q - 1 >= 0, cross >= block_k - 1
+    pl.when(full)(lambda: compute(False, whole))
+    if len(trims) == 1:
+        pl.when(run & ~full)(lambda: compute(True, whole))
+        return
+    j = jax.lax.div(jnp.abs(cross), min(block_q, block_k))
+    for n, part in enumerate(trims):
+        pl.when(run & ~full & (j == n))(functools.partial(compute, True, part))
+
+
+def _keep(iq, ik, block_q, block_k, offset, part, k_major=False):
+    """The mask of one part of the tile at (iq, ik): (rows, columns), or
+    (columns, rows) for the k-major kernels."""
+    r0, r1, c0, c1 = part
+    shp = (c1 - c0, r1 - r0) if k_major else (r1 - r0, c1 - c0)
+    row = iq * block_q + r0 + jax.lax.broadcasted_iota(jnp.int32, shp, 1 if k_major else 0)
+    col = ik * block_k + c0 + jax.lax.broadcasted_iota(jnp.int32, shp, 0 if k_major else 1)
+    return col <= row + offset
 
 
 # ---------------------------------------------------------------- forward
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, scale, causal, block_q, block_k, offset):
+                *, scale, causal, block_q, block_k, offset, trims):
     iq, ik = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
 
@@ -43,35 +190,31 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # causal (bottom-right aligned, matching _sdpa_xla's tril(tk-tq)):
-    # skip kv blocks entirely above the shifted diagonal
-    run = (iq * block_q + block_q - 1 + offset >= ik * block_k) if causal else True
-
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
+    def _compute(masked, part):
+        rows, cols = slice(*part[:2]), slice(*part[2:])
+        q = q_ref[0, 0, rows]
+        k = k_ref[0, 0, cols]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # [BQ, BK]
-        if causal:
-            row = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            col = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(col <= row + offset, s, _NEG_INF)
+        if masked:
+            s = jnp.where(_keep(iq, ik, block_q, block_k, offset, part), s, _NEG_INF)
 
-        m_prev = m_scr[:, :1]
-        l_prev = l_scr[:, :1]
+        m_prev = m_scr[rows, :1]
+        l_prev = l_scr[rows, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
         l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
         pv = jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0, 0], (((1,), (0,)), ((), ())),
+            p.astype(v_ref.dtype), v_ref[0, 0, cols], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        acc_scr[:] = acc_scr[:] * alpha + pv
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        acc_scr[rows] = acc_scr[rows] * alpha + pv
+        m_scr[rows] = jnp.broadcast_to(m_new, (q.shape[0], m_scr.shape[1]))
+        l_scr[rows] = jnp.broadcast_to(l_new, (q.shape[0], l_scr.shape[1]))
+
+    _run_by_class(_compute, iq, ik, block_q, block_k, offset, causal, trims)
 
     @pl.when(ik == nk - 1)
     def _finish():
@@ -95,36 +238,31 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
 
 
 def _fwd_kernel_bthd(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
-                     acc_scr, *, scale, causal, block_q, block_k, offset, H):
+                     acc_scr, *, scale, causal, block_q, block_k, offset, trims,
+                     H, single):
     iq, ik = pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
     D = q_ref.shape[-1] // H
 
-    @pl.when(ik == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    # `single`: the kv sweep is one step that reaches every q row, so the
+    # running max / sum / accumulator start and end in it: nothing to
+    # initialise, read back or rescale.
+    if not single:
+        @pl.when(ik == 0)
+        def _init():
+            m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
+            l_scr[:] = jnp.zeros_like(l_scr)
+            acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # three block classes: skipped (above the causal diagonal), interior
-    # (fully below it — NO mask arithmetic, the dominant class), and
-    # diagonal-crossing (masked). The split halves the VPU work of the
-    # interior blocks; the scale is folded into q once per block instead
-    # of into every (BQ, BK) score tile.
-    if causal:
-        run = iq * block_q + block_q - 1 + offset >= ik * block_k
-        full = ik * block_k + block_k - 1 <= iq * block_q + offset
-    else:
-        run, full = True, True
-
-    def _compute(masked):
+    # interior tiles (the dominant class) skip the mask arithmetic, which
+    # halves their VPU work; the scale is folded into q once per block
+    # instead of into every (BQ, BK) score tile.
+    def _compute(masked, part):
+        rows, cols = slice(*part[:2]), slice(*part[2:])
         if masked:
-            shp = (block_q, block_k)
-            row = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, shp, 0)
-            col = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, shp, 1)
-            keep = col <= row + offset
-        kv, vv = k_ref[0], v_ref[0]  # (BK, H*D)
-        qv = (q_ref[0].astype(jnp.float32) * scale).astype(k_ref.dtype)
+            keep = _keep(iq, ik, block_q, block_k, offset, part)
+        kv, vv = k_ref[0, cols], v_ref[0, cols]  # (BK, H*D)
+        qv = (q_ref[0, rows].astype(jnp.float32) * scale).astype(k_ref.dtype)
         for h in range(H):
             q = qv[:, h * D:(h + 1) * D]  # (BQ, D)
             k = kv[:, h * D:(h + 1) * D]  # (BK, D)
@@ -134,34 +272,28 @@ def _fwd_kernel_bthd(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
             )  # (BQ, BK)
             if masked:
                 s = jnp.where(keep, s, _NEG_INF)
-            m_prev = m_scr[:, h:h + 1]
-            l_prev = l_scr[:, h:h + 1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            alpha = jnp.exp(m_prev - m_new)
-            l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-            pv = jax.lax.dot_general(
-                p.astype(vv.dtype), vv[:, h * D:(h + 1) * D],
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
             sl = slice(h * D, (h + 1) * D)
-            acc_scr[:, sl] = acc_scr[:, sl] * alpha + pv
-            m_scr[:, h:h + 1] = m_new
-            l_scr[:, h:h + 1] = l_new
+            pv_of = lambda p: jax.lax.dot_general(  # noqa: E731
+                p.astype(vv.dtype), vv[:, sl], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            if single:
+                m_new = jnp.max(s, axis=-1, keepdims=True)
+                p = jnp.exp(s - m_new)
+                l_new = jnp.sum(p, axis=-1, keepdims=True)
+                acc_scr[rows, sl] = pv_of(p)
+            else:
+                m_prev = m_scr[rows, h:h + 1]
+                l_prev = l_scr[rows, h:h + 1]
+                m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+                p = jnp.exp(s - m_new)
+                alpha = jnp.exp(m_prev - m_new)
+                l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+                pv = pv_of(p)
+                acc_scr[rows, sl] = acc_scr[rows, sl] * alpha + pv
+            m_scr[rows, h:h + 1] = m_new
+            l_scr[rows, h:h + 1] = l_new
 
-    if causal:
-        @pl.when(run & ~full)
-        def _compute_masked():
-            _compute(True)
-
-        @pl.when(full)
-        def _compute_full():
-            _compute(False)
-    else:
-        @pl.when(run)
-        def _compute_all():
-            _compute(False)
+    _run_by_class(_compute, iq, ik, block_q, block_k, offset, causal, trims)
 
     @pl.when(ik == nk - 1)
     def _finish():
@@ -174,36 +306,25 @@ def _fwd_kernel_bthd(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
             o_ref[0, :, sl] = (acc_scr[:, sl] / l_safe[:, h:h + 1]).astype(o_ref.dtype)
 
 
-def _specs(bq, bk, D, swap_grid=False):
-    """BHTD BlockSpecs for (q-tile, k-tile, row-stat-tile). swap_grid
-    flips the last two grid axes (the dkv kernel walks kv blocks in
-    parallel, q blocks sequentially)."""
-    if swap_grid:
-        qi = lambda b, h, ik, iq: iq
-        ki = lambda b, h, ik, iq: ik
-    else:
-        qi = lambda b, h, iq, ik: iq
-        ki = lambda b, h, iq, ik: ik
-    qspec = pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, qi(b, h, i, j), 0))
-    kspec = pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j: (b, h, ki(b, h, i, j), 0))
-    rspec = pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, qi(b, h, i, j), 0))
+def _specs(bq, bk, D, index):
+    """BHTD BlockSpecs for (q-tile, k-tile, row-stat-tile); `index` is
+    _tile_index's (qi, ki) over the last two grid axes."""
+    qi, ki = index
+    qspec = pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, qi(i, j), 0))
+    kspec = pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j: (b, h, ki(i, j), 0))
+    rspec = pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, qi(i, j), 0))
     return qspec, kspec, rspec
 
 
-def _specs_bthd(bq, bk, H, D, swap_grid=False):
+def _specs_bthd(bq, bk, H, D, index):
     """Flat-BTHD BlockSpecs over (B, T, H*D) operands and (B, H, T) row
-    stats: grid is (B, nq, nk) [or (B, nk, nq) swapped]; every block
+    stats: grid is (B, nq, nk) [or (B, nk, nq) for dkv]; every block
     carries all H heads as dense 64-aligned lane slices (see the layout
-    rationale above _fwd_kernel_bthd)."""
-    if swap_grid:
-        qi = lambda b, ik, iq: iq
-        ki = lambda b, ik, iq: ik
-    else:
-        qi = lambda b, iq, ik: iq
-        ki = lambda b, iq, ik: ik
-    qspec = pl.BlockSpec((1, bq, H * D), lambda b, i, j: (b, qi(b, i, j), 0))
-    kspec = pl.BlockSpec((1, bk, H * D), lambda b, i, j: (b, ki(b, i, j), 0))
-    rspec = pl.BlockSpec((1, H, bq), lambda b, i, j: (b, 0, qi(b, i, j)))
+    rationale above _fwd_kernel_bthd). `index` as in _specs."""
+    qi, ki = index
+    qspec = pl.BlockSpec((1, bq, H * D), lambda b, i, j: (b, qi(i, j), 0))
+    kspec = pl.BlockSpec((1, bk, H * D), lambda b, i, j: (b, ki(i, j), 0))
+    rspec = pl.BlockSpec((1, H, bq), lambda b, i, j: (b, 0, qi(i, j)))
     return qspec, kspec, rspec
 
 
@@ -215,10 +336,13 @@ def _dims(q, k, bthd):
     return B, H, T, D, k.shape[2]
 
 
+@functools.partial(jax.jit, static_argnames=("causal", "scale", "block_q", "block_k", "interpret", "bthd"))
 def _fwd(q, k, v, *, causal, scale, block_q, block_k, interpret, bthd=False):
     B, H, T, D, Tk = _dims(q, k, bthd)
     bq, bk = min(block_q, T), min(block_k, Tk)
     nq, nk = T // bq, Tk // bk
+    index = _tile_index(causal, False, bq, bk, nq, nk, Tk - T)
+    trims = tuple(_trims(bq, bk, Tk - T, nk))
     if bthd:
         # flatten heads onto lanes: free reshape, dense tiling (see the
         # layout rationale above _fwd_kernel_bthd)
@@ -227,9 +351,10 @@ def _fwd(q, k, v, *, causal, scale, block_q, block_k, interpret, bthd=False):
         v = v.reshape(B, Tk, H * D)
         kernel = functools.partial(
             _fwd_kernel_bthd, scale=scale, causal=causal, block_q=bq,
-            block_k=bk, offset=Tk - T, H=H,
+            block_k=bk, offset=Tk - T, trims=trims, H=H,
+            single=nk == 1 and bk >= bq and Tk >= T,
         )
-        qspec, kspec, rspec = _specs_bthd(bq, bk, H, D)
+        qspec, kspec, rspec = _specs_bthd(bq, bk, H, D, index)
         grid = (B, nq, nk)
         lse_shape = (B, H, T)
         dims = ("parallel", "parallel", "arbitrary")
@@ -247,9 +372,9 @@ def _fwd(q, k, v, *, causal, scale, block_q, block_k, interpret, bthd=False):
     else:
         kernel = functools.partial(
             _fwd_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
-            offset=Tk - T,
+            offset=Tk - T, trims=trims,
         )
-        qspec, kspec, rspec = _specs(bq, bk, D)
+        qspec, kspec, rspec = _specs(bq, bk, D, index)
         grid = (B, H, nq, nk)
         lse_shape = (B, H, T, 1)
         dims = ("parallel", "parallel", "parallel", "arbitrary")
@@ -281,7 +406,7 @@ def _fwd(q, k, v, *, causal, scale, block_q, block_k, interpret, bthd=False):
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   dq_scr, *, scale, causal, block_q, block_k, offset):
+                   dq_scr, *, scale, causal, block_q, block_k, offset, trims):
     iq, ik = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
 
@@ -289,30 +414,28 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    run = (iq * block_q + block_q - 1 + offset >= ik * block_k) if causal else True
-
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
+    def _compute(masked, part):
+        rows, cols = slice(*part[:2]), slice(*part[2:])
+        q = q_ref[0, 0, rows]
+        k = k_ref[0, 0, cols]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale
-        if causal:
-            row = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            col = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(col <= row + offset, s, _NEG_INF)
-        p = jnp.exp(s - lse_ref[0, 0])  # [BQ, BK]
-        do = do_ref[0, 0]
+        if masked:
+            s = jnp.where(_keep(iq, ik, block_q, block_k, offset, part), s, _NEG_INF)
+        p = jnp.exp(s - lse_ref[0, 0, rows])  # [BQ, BK]
+        do = do_ref[0, 0, rows]
         dp = jax.lax.dot_general(
-            do, v_ref[0, 0], (((1,), (1,)), ((), ())),
+            do, v_ref[0, 0, cols], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        ds = p * (dp - delta_ref[0, 0]) * scale
-        dq_scr[:] += jax.lax.dot_general(
+        ds = p * (dp - delta_ref[0, 0, rows]) * scale
+        dq_scr[rows] += jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+
+    _run_by_class(_compute, iq, ik, block_q, block_k, offset, causal, trims)
 
     @pl.when(ik == nk - 1)
     def _finish():
@@ -321,32 +444,26 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _bwd_dq_kernel_bthd(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                         dq_ref, dq_scr, *, scale, causal, block_q, block_k,
-                        offset, H):
+                        offset, trims, H, single):
     iq, ik = pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
     D = q_ref.shape[-1] // H
 
-    @pl.when(ik == 0)
-    def _init():
-        dq_scr[:] = jnp.zeros_like(dq_scr)
+    # `single` (as in the forward): dq is whole after the one kv step and
+    # is written where it goes, with no accumulator in between
+    if not single:
+        @pl.when(ik == 0)
+        def _init():
+            dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    # same block-class split as the forward: interior blocks skip the
-    # mask arithmetic. Both scale multiplies are folded out of the
-    # (BQ, BK) tiles: the first into q, the second into the dq finish.
-    if causal:
-        run = iq * block_q + block_q - 1 + offset >= ik * block_k
-        full = ik * block_k + block_k - 1 <= iq * block_q + offset
-    else:
-        run, full = True, True
-
-    def _compute(masked):
+    # Both scale multiplies are folded out of the (BQ, BK) tiles: the
+    # first into q, the second into the dq finish.
+    def _compute(masked, part):
+        rows, cols = slice(*part[:2]), slice(*part[2:])
         if masked:
-            shp = (block_q, block_k)
-            row = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, shp, 0)
-            col = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, shp, 1)
-            keep = col <= row + offset
-        kv, vv, dov = k_ref[0], v_ref[0], do_ref[0]
-        qv = (q_ref[0].astype(jnp.float32) * scale).astype(k_ref.dtype)
+            keep = _keep(iq, ik, block_q, block_k, offset, part)
+        kv, vv, dov = k_ref[0, cols], v_ref[0, cols], do_ref[0, rows]
+        qv = (q_ref[0, rows].astype(jnp.float32) * scale).astype(k_ref.dtype)
         for h in range(H):
             sl = slice(h * D, (h + 1) * D)
             q, k = qv[:, sl], kv[:, sl]
@@ -356,41 +473,34 @@ def _bwd_dq_kernel_bthd(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             )
             if masked:
                 s = jnp.where(keep, s, _NEG_INF)
-            lse_col = jnp.swapaxes(lse_ref[0, h:h + 1, :], 0, 1)  # (BQ, 1)
+            lse_col = jnp.swapaxes(lse_ref[0, h:h + 1, rows], 0, 1)  # (BQ, 1)
             p = jnp.exp(s - lse_col)
             do = dov[:, sl]
             dp = jax.lax.dot_general(
                 do, vv[:, sl], (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
-            delta_col = jnp.swapaxes(delta_ref[0, h:h + 1, :], 0, 1)
+            delta_col = jnp.swapaxes(delta_ref[0, h:h + 1, rows], 0, 1)
             ds = p * (dp - delta_col)
-            dq_scr[:, sl] += jax.lax.dot_general(
+            dq_of = lambda: jax.lax.dot_general(  # noqa: E731
                 ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
+                preferred_element_type=jnp.float32)
+            if single:
+                dq_ref[0, rows, sl] = (dq_of() * scale).astype(dq_ref.dtype)
+            else:
+                dq_scr[rows, sl] += dq_of()
 
-    if causal:
-        @pl.when(run & ~full)
-        def _compute_masked():
-            _compute(True)
+    _run_by_class(_compute, iq, ik, block_q, block_k, offset, causal, trims)
 
-        @pl.when(full)
-        def _compute_full():
-            _compute(False)
-    else:
-        @pl.when(run)
-        def _compute_all():
-            _compute(False)
-
-    @pl.when(ik == nk - 1)
-    def _finish():
-        dq_ref[0] = (dq_scr[:] * scale).astype(dq_ref.dtype)
+    if not single:
+        @pl.when(ik == nk - 1)
+        def _finish():
+            dq_ref[0] = (dq_scr[:] * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_scr, dv_scr,
-                    *, scale, causal, block_q, block_k, offset):
+                    *, scale, causal, block_q, block_k, offset, trims):
     ik, iq = pl.program_id(2), pl.program_id(3)
     nq = pl.num_programs(3)
 
@@ -399,36 +509,34 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    run = (iq * block_q + block_q - 1 + offset >= ik * block_k) if causal else True
-
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
+    def _compute(masked, part):
+        rows, cols = slice(*part[:2]), slice(*part[2:])
+        q = q_ref[0, 0, rows]
+        k = k_ref[0, 0, cols]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale
-        if causal:
-            row = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            col = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(col <= row + offset, s, _NEG_INF)
-        p = jnp.exp(s - lse_ref[0, 0])  # [BQ, BK]
-        do = do_ref[0, 0]
+        if masked:
+            s = jnp.where(_keep(iq, ik, block_q, block_k, offset, part), s, _NEG_INF)
+        p = jnp.exp(s - lse_ref[0, 0, rows])  # [BQ, BK]
+        do = do_ref[0, 0, rows]
         # dv += P^T dO
-        dv_scr[:] += jax.lax.dot_general(
+        dv_scr[cols] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         dp = jax.lax.dot_general(
-            do, v_ref[0, 0], (((1,), (1,)), ((), ())),
+            do, v_ref[0, 0, cols], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        ds = p * (dp - delta_ref[0, 0]) * scale
+        ds = p * (dp - delta_ref[0, 0, rows]) * scale
         # dk += dS^T Q
-        dk_scr[:] += jax.lax.dot_general(
+        dk_scr[cols] += jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+
+    _run_by_class(_compute, iq, ik, block_q, block_k, offset, causal, trims)
 
     @pl.when(iq == nq - 1)
     def _finish():
@@ -438,23 +546,20 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _bwd_dkv_kernel_bthd(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          dk_ref, dv_ref, dk_scr, dv_scr,
-                         *, scale, causal, block_q, block_k, offset, H):
+                         *, scale, causal, block_q, block_k, offset, trims,
+                         H, single):
     ik, iq = pl.program_id(1), pl.program_id(2)
     nq = pl.num_programs(2)
     D = q_ref.shape[-1] // H
 
-    @pl.when(iq == 0)
-    def _init():
-        dk_scr[:] = jnp.zeros_like(dk_scr)
-        dv_scr[:] = jnp.zeros_like(dv_scr)
+    # `single`: the q sweep is one step that reaches every kv column
+    if not single:
+        @pl.when(iq == 0)
+        def _init():
+            dk_scr[:] = jnp.zeros_like(dk_scr)
+            dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    if causal:
-        run = iq * block_q + block_q - 1 + offset >= ik * block_k
-        full = ik * block_k + block_k - 1 <= iq * block_q + offset
-    else:
-        run, full = True, True
-
-    def _compute(masked):
+    def _compute(masked, part):
         # k-major orientation: every product is a standard (M,K)x(K,N)
         # matmul — dim-0 contractions over strided-read tiles crash this
         # mosaic build, so P/dS are built transposed as (BK, BQ) instead
@@ -462,13 +567,11 @@ def _bwd_dkv_kernel_bthd(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         # layout hands lse/delta over as ready-made (1, BQ) rows.
         # Scale folding: q arrives pre-scaled, so st is already scaled
         # and dk += dS_noscale @ (q*scale) bakes the second multiply in.
+        rows, cols = slice(*part[:2]), slice(*part[2:])
         if masked:
-            shp = (block_k, block_q)
-            col = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, shp, 0)
-            row = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, shp, 1)
-            keep = col <= row + offset
-        kv, vv, dov = k_ref[0], v_ref[0], do_ref[0]
-        qv = (q_ref[0].astype(jnp.float32) * scale).astype(k_ref.dtype)
+            keep = _keep(iq, ik, block_q, block_k, offset, part, k_major=True)
+        kv, vv, dov = k_ref[0, cols], v_ref[0, cols], do_ref[0, rows]
+        qv = (q_ref[0, rows].astype(jnp.float32) * scale).astype(k_ref.dtype)
         for h in range(H):
             sl = slice(h * D, (h + 1) * D)
             q, k = qv[:, sl], kv[:, sl]
@@ -479,55 +582,51 @@ def _bwd_dkv_kernel_bthd(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             )
             if masked:
                 st = jnp.where(keep, st, _NEG_INF)
-            pt = jnp.exp(st - lse_ref[0, h:h + 1, :])  # (BK, BQ)
+            pt = jnp.exp(st - lse_ref[0, h:h + 1, rows])  # (BK, BQ)
             do = dov[:, sl]
             # dv += P^T dO
-            dv_scr[:, sl] += jax.lax.dot_general(
+            dv_of = lambda: jax.lax.dot_general(  # noqa: E731
                 pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
+                preferred_element_type=jnp.float32)
+            if single:
+                dv_ref[0, cols, sl] = dv_of().astype(dv_ref.dtype)
+            else:
+                dv_scr[cols, sl] += dv_of()
             # (BK, BQ) = V dO^T
             dpt = jax.lax.dot_general(
                 vv[:, sl], do, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
-            dst = pt * (dpt - delta_ref[0, h:h + 1, :])
+            dst = pt * (dpt - delta_ref[0, h:h + 1, rows])
             # dk += dS^T Q' (scale folded via q')
-            dk_scr[:, sl] += jax.lax.dot_general(
+            dk_of = lambda: jax.lax.dot_general(  # noqa: E731
                 dst.astype(q.dtype), q, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
+                preferred_element_type=jnp.float32)
+            if single:
+                dk_ref[0, cols, sl] = dk_of().astype(dk_ref.dtype)
+            else:
+                dk_scr[cols, sl] += dk_of()
 
-    if causal:
-        @pl.when(run & ~full)
-        def _compute_masked():
-            _compute(True)
+    _run_by_class(_compute, iq, ik, block_q, block_k, offset, causal, trims)
 
-        @pl.when(full)
-        def _compute_full():
-            _compute(False)
-    else:
-        @pl.when(run)
-        def _compute_all():
-            _compute(False)
-
-    @pl.when(iq == nq - 1)
-    def _finish():
-        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+    if not single:
+        @pl.when(iq == nq - 1)
+        def _finish():
+            dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+            dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5, 6))
 def _bwd(causal, scale, block_q, block_k, interpret, bthd, bwd_blocks,
          res, do):
     """bwd_blocks = (bq_dq, bk_dq, bq_dkv, bk_dkv): the two backward
-    passes CAN tile independently — the dq pass keeps a (bq, H*D)
+    passes CAN tile independently: the dq pass keeps a (bq, H*D)
     accumulator resident and sweeps kv sequentially, the dkv pass keeps
-    (bk, H*D) accumulators and sweeps q. Measured on v5e @ T=2048
-    (end-to-end GPT step, round 4): every decoupled candidate LOST to the
-    shared (256,512) tiling — (128,1024;1024,128) 202ms,
-    (128,512;512,128) 208ms, (256,1024;512,256) 196ms vs 194.5ms — the
-    128-tall blocks underfeed the MXU at H*D=768. Default (None) keeps
-    the forward tiling; the knob stays for re-sweeping on other chips."""
+    (bk, H*D) accumulators and sweeps q. None keeps the forward tiling.
+    The dispatcher's table (ops/attention.py::_FLASH_TILES) names the
+    tiles; PR 35 swept them on a TPU v5e at B 32, T 1024, 12 heads of 64,
+    BTHD, causal (tools/flash_sweep.py, each kernel alone and then the
+    whole train step; the numbers are in PERF.md section 6)."""
     q, k, v, out, lse = res
     B, H, T, D, Tk = _dims(q, k, bthd)
     bq_dq, bk_dq, bq_dkv, bk_dkv = bwd_blocks or (
@@ -535,6 +634,7 @@ def _bwd(causal, scale, block_q, block_k, interpret, bthd, bwd_blocks,
     )
     bq, bk = min(bq_dq, T), min(bk_dq, Tk)
     nq, nk = T // bq, Tk // bk
+    index = _tile_index(causal, False, bq, bk, nq, nk, Tk - T)
 
     if bthd:
         # (B, H, T) row stats to match the lse layout (see _specs_bthd)
@@ -552,22 +652,26 @@ def _bwd(causal, scale, block_q, block_k, interpret, bthd, bwd_blocks,
         )
 
     if bthd:
-        qspec, kspec, rspec = _specs_bthd(bq, bk, H, D)
+        qspec, kspec, rspec = _specs_bthd(bq, bk, H, D, index)
         dq_grid = (B, nq, nk)
         dims3 = ("parallel", "parallel", "arbitrary")
         dq_kernel, dkv_kernel = _bwd_dq_kernel_bthd, _bwd_dkv_kernel_bthd
         dq_scratch = [pltpu.VMEM((bq, H * D), jnp.float32)]
     else:
-        qspec, kspec, rspec = _specs(bq, bk, D)
+        qspec, kspec, rspec = _specs(bq, bk, D, index)
         dq_grid = (B, H, nq, nk)
         dims3 = ("parallel", "parallel", "parallel", "arbitrary")
         dq_kernel, dkv_kernel = _bwd_dq_kernel, _bwd_dkv_kernel
         dq_scratch = [pltpu.VMEM((bq, D), jnp.float32)]
-    extra = {"H": H} if bthd else {}
+    # `single`: one step of the sequential sweep that reaches every
+    # accumulator row (a tall tile's trims, and Tk < T, leave q rows out):
+    # the BTHD kernels then skip the accumulator round trip
+    extra = lambda single: {"H": H, "single": single} if bthd else {}  # noqa: E731
     dq = pl.pallas_call(
         functools.partial(
             dq_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
-            offset=Tk - T, **extra,
+            offset=Tk - T, trims=tuple(_trims(bq, bk, Tk - T, nk)),
+            **extra(nk == 1 and bk >= bq and Tk >= T),
         ),
         grid=dq_grid,
         in_specs=[qspec, kspec, kspec, qspec, rspec, rspec],
@@ -582,24 +686,26 @@ def _bwd(causal, scale, block_q, block_k, interpret, bthd, bwd_blocks,
     # kv sweep: grid walks kv blocks in parallel, q blocks sequentially
     bq, bk = min(bq_dkv, T), min(bk_dkv, Tk)
     nq, nk = T // bq, Tk // bk
+    index = _tile_index(causal, True, bq, bk, nq, nk, Tk - T)
     if bthd:
         dkv_scratch = [
             pltpu.VMEM((bk, H * D), jnp.float32),
             pltpu.VMEM((bk, H * D), jnp.float32),
         ]
-        qspec2, kspec2, rspec2 = _specs_bthd(bq, bk, H, D, swap_grid=True)
+        qspec2, kspec2, rspec2 = _specs_bthd(bq, bk, H, D, index)
         dkv_grid = (B, nk, nq)
     else:
         dkv_scratch = [
             pltpu.VMEM((bk, D), jnp.float32),
             pltpu.VMEM((bk, D), jnp.float32),
         ]
-        qspec2, kspec2, rspec2 = _specs(bq, bk, D, swap_grid=True)
+        qspec2, kspec2, rspec2 = _specs(bq, bk, D, index)
         dkv_grid = (B, H, nk, nq)
     dk, dv = pl.pallas_call(
         functools.partial(
             dkv_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
-            offset=Tk - T, **extra,
+            offset=Tk - T, trims=tuple(_trims(bq, bk, Tk - T, nq)),
+            **extra(nq == 1 and bq >= bk),
         ),
         grid=dkv_grid,
         in_specs=[qspec2, kspec2, kspec2, qspec2, rspec2, rspec2],
@@ -623,18 +729,22 @@ def _bwd(causal, scale, block_q, block_k, interpret, bthd, bwd_blocks,
 # ---------------------------------------------------------------- public
 
 
+# _fwd and _bwd are jitted, so the layers of a model that call them at one
+# shape share ONE trace and ONE Mosaic lowering of each kernel (36 lowerings
+# of a 12-layer step became 3); the rules below run once a call site, which
+# is where flash_tiles_total is counted.
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _flash(q, k, v, causal, scale, block_q, block_k, interpret, bthd,
            bwd_blocks):
-    out, _ = _fwd(
-        q, k, v, causal=causal, scale=scale,
-        block_q=block_q, block_k=block_k, interpret=interpret, bthd=bthd,
-    )
-    return out
+    return _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
+                      bthd, bwd_blocks)[0]
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret, bthd,
                bwd_blocks):
+    _count_tiles("fwd", q, k, bthd, block_q, block_k, causal)
     out, lse = _fwd(
         q, k, v, causal=causal, scale=scale,
         block_q=block_q, block_k=block_k, interpret=interpret, bthd=bthd,
@@ -644,6 +754,10 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret, bthd,
 
 def _flash_bwd(causal, scale, block_q, block_k, interpret, bthd, bwd_blocks,
                res, do):
+    q, k = res[:2]
+    tiles = bwd_blocks or (block_q, block_k, block_q, block_k)
+    _count_tiles("dq", q, k, bthd, tiles[0], tiles[1], causal)
+    _count_tiles("dkv", q, k, bthd, tiles[2], tiles[3], causal)
     return _bwd(causal, scale, block_q, block_k, interpret, bthd,
                 bwd_blocks, res, do)
 

@@ -194,3 +194,212 @@ def test_bwd_blocks_decoupled_grad_parity():
         for got, want in zip(g, g_ref):
             np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                        rtol=2e-3, atol=2e-3)
+
+
+# -- the tiles the dispatcher picks (PR 35): the causal schedule at the shape
+# the benchmark cell gpt2s-train-1k runs, scaled in batch and heads for the
+# interpreter --------------------------------------------------------------
+
+
+def _tiles_total():
+    """{(kernel, cls): count} of flash_tiles_total as it stands."""
+    import sys
+
+    fa = sys.modules["paddle_tpu.ops.pallas.flash_attention"]  # the package attribute is the function
+    return {(k, c): n for k, by_cls in fa.tile_counts().items() for c, n in by_cls.items()}
+
+
+def _tiles_of(fn, *args):
+    """Tiles by (kernel, cls) that tracing `fn` (nothing runs) counts."""
+    before = _tiles_total()
+    jax.make_jaxpr(fn)(*args)
+    after = _tiles_total()
+    return {key: after[key] - before[key] for key in after}
+
+
+def _attend(layout, causal):
+    """The op as the dispatcher lowers it: its own default tiles."""
+    from paddle_tpu.framework.registry import LoweringContext, get_op_def
+
+    opdef = get_op_def("fused_attention_tpu")
+
+    def f(q, k, v):
+        return opdef.lower(
+            LoweringContext(rng_key=jax.random.key(0)), {"Q": [q], "K": [k], "V": [v]},
+            {"is_causal": causal, "is_test": True, "layout": layout})["Out"]
+    return f
+
+
+def _qkv(layout, t, tk, seed, b=1, h=2, d=64):
+    r = np.random.RandomState(seed)
+    shape = lambda n: (b, n, h, d) if layout == "BTHD" else (b, h, n, d)  # noqa: E731
+    return tuple(jnp.asarray(r.randn(*shape(n)).astype("float32")) for n in (t, tk, tk))
+
+
+_DISPATCH_CASES = {"causal": (1024, 1024, True), "causal_tk_1536": (1024, 1536, True),
+                   "causal_tk_1152": (1024, 1152, True), "full": (1024, 1024, False)}
+
+
+@pytest.mark.parametrize("case", sorted(_DISPATCH_CASES))
+@pytest.mark.parametrize("layout", ["BTHD", "BHTD"])
+def test_dispatchers_tiles_match_xla(monkeypatch, layout, case):
+    """T 1024, heads of 64, the tiles the dispatcher picks by itself:
+    forward and dq / dk / dv against the XLA reference; with Tk > T the
+    mask is bottom-right aligned (offset 512: whole tiles; 128: the kv
+    tile falls to 128 and the backward takes the forward's tiles)."""
+    for knob in ("PADDLE_TPU_FLASH_BLOCKS", "PADDLE_TPU_FLASH_BWD_BLOCKS", "PADDLE_TPU_FLASH_MIN_SEQ"):
+        monkeypatch.delenv(knob, raising=False)
+    t, tk, causal = _DISPATCH_CASES[case]
+    q, k, v = _qkv(layout, t, tk, seed=11)
+    flash = _attend(layout, causal)
+    ref = lambda q, k, v: _sdpa_xla(q, k, v, is_causal=causal, layout=layout)  # noqa: E731
+    from paddle_tpu.ops import attention
+
+    n0 = attention.FLASH_DISPATCH_COUNT
+    out, vjp = jax.vjp(flash, q, k, v)
+    assert attention.FLASH_DISPATCH_COUNT == n0 + 1, "dispatcher fell back to the XLA path"
+    want, vjp_ref = jax.vjp(ref, q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=2e-5, atol=2e-5)
+    cot = 2.0 * want
+    for got, exp, name in zip(vjp(cot), vjp_ref(cot), "qkv"):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(exp), rtol=2e-4, atol=2e-4,
+                                   err_msg=f"d{name} mismatch")
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+@pytest.mark.parametrize("layout", ["BTHD", "BHTD"])
+def test_flash_tiles_total_says_what_the_causal_schedule_computes(monkeypatch, layout, kernel):
+    """The counter is bumped where a call is traced into a program, over
+    the call's whole grid, in squares of a tile's smaller side. At T 1024
+    causal every BTHD kernel computes at most 10 of 16 parts of the score
+    square (the tiles before PR 35: forward all of it, backward three
+    quarters); the BHTD forward's one square tile computes all of it and
+    its backward three quarters; a non-causal call computes all of it,
+    every tile interior; at T 2048 whole tiles lie below the diagonal."""
+    for knob in ("PADDLE_TPU_FLASH_BLOCKS", "PADDLE_TPU_FLASH_BWD_BLOCKS", "PADDLE_TPU_FLASH_MIN_SEQ"):
+        monkeypatch.delenv(knob, raising=False)
+
+    def traced(t, causal, b=2, h=2):
+        shape = (b, t, h, 64) if layout == "BTHD" else (b, h, t, 64)
+        a = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+        f = _attend(layout, causal)
+        n = _tiles_of(lambda q, k, v: jax.vjp(f, q, k, v)[1](q), a, a, a)
+        return {c: n[kernel, c] for c in ("skipped", "interior", "diagonal")}
+
+    def share(n):
+        return (n["interior"] + n["diagonal"]) / sum(n.values())
+
+    want = {"BTHD": {"fwd": 0.625, "dq": 0.5625, "dkv": 0.625},
+            "BHTD": {"fwd": 1.0, "dq": 0.75, "dkv": 0.75}}[layout][kernel]
+    at_1k = traced(1024, True)
+    assert share(at_1k) == want, at_1k
+    # the grid's planes are counted: batch, and heads where the grid walks them
+    assert sum(at_1k.values()) % (2 if layout == "BTHD" else 4) == 0
+    again = traced(1024, True)  # a second call site counts again, whatever jit has cached
+    assert again == at_1k
+    full = traced(1024, False)
+    assert share(full) == 1.0 and full["diagonal"] == 0 and full["skipped"] == 0, full
+    at_2k = traced(2048, True)
+    assert at_2k["interior"] > 0 and at_2k["skipped"] > 0 and share(at_2k) <= 0.75, at_2k
+
+
+_SCHEDULES = [(256, 1024, 1024, 1024), (512, 1024, 1024, 1024), (1024, 256, 1024, 1024), (256, 256, 1024, 1024),
+              (512, 512, 1024, 1024), (256, 1024, 2048, 2048), (1024, 512, 2048, 2048), (256, 512, 1024, 1536),
+              (256, 128, 1024, 1152), (256, 512, 1024, 1152 + 128 * 3), (128, 512, 512, 1024), (256, 512, 1024, 512)]
+
+
+@pytest.mark.parametrize("sweep", ["kv", "q"])
+@pytest.mark.parametrize("bq,bk,t,tk", _SCHEDULES)
+def test_the_causal_schedule_computes_every_score_the_mask_keeps(bq, bk, t, tk, sweep):
+    """The schedule as the kernels decide it (skipped / interior / a tile
+    crossing the diagonal trimmed to one of its static parts), replayed on
+    the host against the mask itself: no score the mask keeps is left out,
+    a wide or tall tile whose crossing is aligned computes no granule that
+    is all mask, and `_tile_classes` (what flash_tiles_total counts) is
+    the area computed. `sweep`: the forward and dq sweep kv tiles, dkv q
+    tiles; a sweep of several steps keeps a tile of more than two parts
+    whole."""
+    import sys
+
+    fa = sys.modules["paddle_tpu.ops.pallas.flash_attention"]
+    nq, nk, offset, g = t // bq, tk // bk, tk - t, min(bq, bk)
+    keep = np.tril(np.ones((t, tk), bool), offset)
+    computed = np.zeros((t, tk), bool)
+    trims = fa._trims(bq, bk, offset, nk if sweep == "kv" else nq)
+    assert len(trims) in (1, max(bq, bk) // g)
+    n_interior = n_diagonal = 0
+    for iq in range(nq):
+        for ik in range(nk):
+            cross = iq * bq + offset - ik * bk
+            run, full = cross + bq - 1 >= 0, cross >= bk - 1
+            if not run:
+                continue
+            r0, r1, c0, c1 = (0, bq, 0, bk) if full or len(trims) == 1 else trims[abs(cross) // g]
+            tile = computed[iq * bq:(iq + 1) * bq, ik * bk:(ik + 1) * bk]
+            assert not tile.any()
+            tile[r0:r1, c0:c1] = True
+            if full:
+                assert keep[iq * bq:(iq + 1) * bq, ik * bk:(ik + 1) * bk].all()
+                n_interior += (bq // g) * (bk // g)
+            else:
+                n_diagonal += (r1 - r0) * (c1 - c0) // g ** 2
+    assert not (keep & ~computed).any(), "a score the mask keeps is never computed"
+    skipped, interior, diagonal = fa._tile_classes(nq, nk, bq, bk, offset, True, trims)
+    assert (interior, diagonal) == (n_interior, n_diagonal)
+    assert (interior + diagonal) * g * g == computed.sum() and skipped * g * g == (~computed).sum()
+    if len(trims) > 1:  # aligned: every computed granule holds a score the mask keeps
+        blocks = (keep & computed).reshape(t // g, g, tk // g, g).any(axis=(1, 3))
+        assert blocks.sum() == interior + diagonal
+    assert fa._tile_classes(nq, nk, bq, bk, offset, False, trims) == (0, t * tk // g ** 2, 0)
+
+
+@pytest.mark.parametrize("bq,bk,t,tk", [(256, 256, 1024, 1024), (128, 256, 1024, 1024), (256, 128, 1024, 1152),
+                                        (256, 256, 1024, 1536), (512, 256, 2048, 2048), (256, 512, 1024, 512)])
+def test_a_skipped_step_names_the_block_its_neighbour_holds(bq, bk, t, tk):
+    """The sequential sweep's block index under the causal mask: a tile
+    that runs fetches its own block; a skipped one names the block of the
+    nearest tile of its row (column) that runs, so consecutive skipped
+    steps change no index and cost no copy. Tk < T leaves rows with
+    nothing to attend: their steps all name block 0."""
+    import sys
+
+    fa = sys.modules["paddle_tpu.ops.pallas.flash_attention"]  # the package attribute is the function
+    nq, nk, offset = t // bq, tk // bk, tk - t
+    run = lambda iq, ik: iq * bq + bq - 1 + offset >= ik * bk  # noqa: E731
+    qi, ki = fa._tile_index(True, False, bq, bk, nq, nk, offset)
+    for iq in range(nq):
+        runs = [ik for ik in range(nk) if run(iq, ik)]
+        for ik in range(nk):
+            assert int(qi(iq, ik)) == iq
+            assert int(ki(iq, ik)) == (ik if run(iq, ik) else max(runs, default=0)), (iq, ik)
+    qi, ki = fa._tile_index(True, True, bq, bk, nq, nk, offset)
+    for ik in range(nk):
+        runs = [iq for iq in range(nq) if run(iq, ik)]
+        for iq in range(nq):
+            assert int(ki(ik, iq)) == ik
+            assert int(qi(ik, iq)) == (iq if run(iq, ik) else min(runs, default=nq - 1)), (iq, ik)
+    # non-causal: the bare indices, as before
+    qi, ki = fa._tile_index(False, False, bq, bk, nq, nk, offset)
+    assert (qi(2, 3), ki(2, 3)) == (2, 3)
+    qi, ki = fa._tile_index(False, True, bq, bk, nq, nk, offset)
+    assert (qi(2, 3), ki(2, 3)) == (3, 2)
+
+
+def test_flash_tiles_total_reaches_the_report_and_the_scrape():
+    from paddle_tpu import monitor
+    from tools import obs_report
+
+    a = jax.ShapeDtypeStruct((1, 1024, 2, 64), jnp.bfloat16)
+    f = lambda q, k, v: flash_attention(q, k, v, causal=True, block_q=256, block_k=256,  # noqa: E731
+                                        layout="BTHD")
+    jax.make_jaxpr(lambda q, k, v: jax.vjp(f, q, k, v)[1](q))(a, a, a)
+    section = obs_report._executor_section(monitor.snapshot())["flash_tiles"]
+    assert set(section) == {"fwd", "dq", "dkv"}
+    now = _tiles_total()
+    for kernel, tiles in section.items():
+        for cls in ("skipped", "interior", "diagonal"):
+            assert tiles[cls] == now[kernel, cls]
+        assert 0.0 < tiles["computed_share"] <= 1.0
+    prom = monitor.to_prometheus()
+    assert 'flash_tiles_total{kernel="fwd",cls="diagonal"}' in prom or \
+        'flash_tiles_total{cls="diagonal",kernel="fwd"}' in prom

@@ -55,8 +55,13 @@ def test_synthetic_fallback_tracks_live_arrays():
     """On CPU the fallback must SEE allocations: a 4MB array raises
     bytes_in_use by at least its size, and the peak is sticky after
     the array dies."""
+    import gc
+
     import jax.numpy as jnp
 
+    # an earlier test file's arrays held in reference cycles must die
+    # now, not between the two readings (seen once under xdist, PR 35)
+    gc.collect()
     before = device.memory_stats()
     big = jnp.zeros((1024, 1024), jnp.float32)  # 4MiB
     big.block_until_ready()

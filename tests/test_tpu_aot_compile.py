@@ -147,6 +147,28 @@ def test_flash_kernels_carry_their_names_at_gpt2s_widths(tpu_arg):
     assert sum(bool(fwd_rx.search(n)) for n in names) == 1
 
 
+@pytest.mark.parametrize("layout", ["BTHD", "BHTD"])
+def test_the_dispatchers_tiles_compile_at_gpt2s_widths(tpu_arg, monkeypatch, layout):
+    """Mosaic takes the three kernels at the tiles the dispatcher picks for
+    a causal call of T 1024 and 12 heads of 64: the one-step forward and dq
+    with their trimmed parts, the clamped index maps, dkv's tall tiles."""
+    from paddle_tpu.ops import attention
+
+    for knob in ("PADDLE_TPU_FLASH_BLOCKS", "PADDLE_TPU_FLASH_BWD_BLOCKS"):
+        monkeypatch.delenv(knob, raising=False)
+    bq, bk, bwd = attention._flash_tiles(1024, 1024, layout, True)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk, bwd_blocks=bwd,
+                               layout=layout, interpret=False).astype(jnp.float32).sum()
+
+    qkv = [tpu_arg((2, 1024, 12, 64) if layout == "BTHD" else (2, 12, 1024, 64), jnp.bfloat16)] * 3
+    names = _kernel_names(_compiled_text(jax.grad(loss, argnums=(0, 1, 2)), *qkv))
+    assert _own_names(names) == ["flash_dkv", "flash_dq", "flash_fwd"], names
+    rx = _metric_pattern("flash_kernels_roofline")
+    assert all(rx.search(n) for n in names), names
+
+
 def test_lmhead_ce_kernels_carry_their_names_at_gpt2s_widths(tpu_arg):
     def loss(x, w, labels):
         return lmhead_ce(x, w, labels, interpret=False).sum()
@@ -410,6 +432,127 @@ def test_train_step_runs_every_pallas_forward_once_and_backward_under_its_grad_o
     for name, op_name in calls:
         (kernel,) = _own_names([name])
         assert op_name.startswith("jit(train_step)/" + scope_of[kernel]), (name, op_name)
+
+
+def _pallas_eqns(jaxpr):
+    """Every pallas_call equation of a jaxpr, sub-jaxprs (jit, custom_vjp,
+    cond, scan bodies) included."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append(eqn)
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    out += _pallas_eqns(sub)
+    return out
+
+
+def _pallas_calls(jaxpr):
+    """[(kernel name, grid)] of every pallas_call in a jaxpr."""
+    return [(str(getattr(eqn.params.get("name_and_src_info"), "name", None) or eqn.params.get("name")),
+             tuple(eqn.params["grid_mapping"].grid)) for eqn in _pallas_eqns(jaxpr)]
+
+
+def test_the_cells_train_step_holds_113_kernels_on_the_tables_grids(monkeypatch):
+    """gpt2s-train-1k's own program (12 layers, batch 32, seq 1024, Adam),
+    traced as the chip traces it and not compiled: 113 Mosaic calls = 74
+    fused Adam + 3 of the CE + 12 each of the three flash kernels, on the
+    grids the dispatcher's table gives at T 1024: the forward 256 x 1024
+    and dq 128 x 1024 in ONE kv step, dkv 512 x 256 in two q steps; and
+    what flash_tiles_total counts for the 12 layers' calls: no kernel
+    computes more than 10 of 16 parts of the score square."""
+    import sys
+    from collections import Counter
+
+    import numpy as np
+
+    import paddle_tpu as paddle
+    from paddle_tpu.framework import Executor, Scope, program_guard
+    from paddle_tpu.models.gpt import GPTConfig, build_train_program
+    from paddle_tpu.optimizer import Adam
+
+    for mod in ("flash_attention", "fused_lmhead_ce", "backend"):
+        monkeypatch.setattr(sys.modules[f"paddle_tpu.ops.pallas.{mod}"], "on_tpu", lambda: True)
+    for knob in ("PADDLE_TPU_FLASH_BLOCKS", "PADDLE_TPU_FLASH_BWD_BLOCKS", "PADDLE_TPU_FLASH_MIN_SEQ"):
+        monkeypatch.delenv(knob, raising=False)
+    n_layer, B, T = 12, 32, 1024
+    fa = sys.modules["paddle_tpu.ops.pallas.flash_attention"]
+    read = lambda: {(k, c): n for k, by_cls in fa.tile_counts().items()  # noqa: E731
+                    for c, n in by_cls.items()}
+    paddle.enable_static()
+    try:
+        cfg = GPTConfig(vocab_size=50304, n_layer=n_layer, n_head=12, d_model=768, max_seq_len=T,
+                        dropout=0.0, dtype="bfloat16")
+        main, startup, io = build_train_program(cfg, batch=B, seq=T)
+        with program_guard(main, startup):
+            Adam(learning_rate=1e-4).minimize(io["loss"])
+        scope, exe = Scope(), Executor()
+        exe.run(startup, scope=scope)
+        feeds = {"tokens": jnp.zeros((B, T), jnp.int32), "labels": jnp.zeros((B, T), jnp.int32)}
+        for n, fn in (getattr(main, "_extra_feeds", None) or {}).items():
+            feeds[n] = jnp.asarray(fn())
+        compiled = exe._get_compiled(main, feeds, [io["loss"].name], scope)
+    finally:
+        paddle.disable_static()
+    spec = lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype)  # noqa: E731
+    before = read()
+    jaxpr = jax.make_jaxpr(compiled.fn)(
+        {k: spec(v) for k, v in feeds.items()},
+        {n: spec(scope.get(n)) for n in compiled.mutable_names},
+        {n: spec(scope.get(n)) for n in compiled.const_names},
+        jax.ShapeDtypeStruct((2,), jnp.uint32))
+    counted = {key: n - before[key] for key, n in read().items()}
+    calls = _pallas_calls(jaxpr.jaxpr)
+    names = Counter(name for name, _ in calls)
+    assert len(calls) == 113 and names == {
+        "fused_adam": 74, "lmhead_ce_stats": 1, "lmhead_ce_dx": 1, "lmhead_ce_dw": 1,
+        "flash_fwd": n_layer, "flash_dq": n_layer, "flash_dkv": n_layer}, names
+    grids = {name: {g for n, g in calls if n == name} for name in ("flash_fwd", "flash_dq", "flash_dkv")}
+    assert grids == {"flash_fwd": {(B, 4, 1)}, "flash_dq": {(B, 8, 1)}, "flash_dkv": {(B, 4, 2)}}
+    per_plane = {"fwd": (6, 0, 10), "dq": (28, 0, 36), "dkv": (6, 4, 6)}  # squares of 256, 128, 256
+    for kernel, want in per_plane.items():
+        got = [counted[kernel, c] for c in ("skipped", "interior", "diagonal")]
+        assert got == [n_layer * B * n for n in want], (kernel, counted)
+        assert (got[1] + got[2]) / sum(got) <= 0.625
+
+
+# sha256 (first 16 hex digits) of the three kernels (each pallas_call's own
+# jaxpr and grid mapping) of a NON-causal flash call, forward and backward,
+# on the parent of PR 35 (72361c6) at the tiles the dispatcher gave such a
+# call then and gives it now: nothing to skip or trim, so the index maps
+# stay bare and the kernels trace as they did. (A BTHD call whose kv sweep
+# is ONE step, T 1024 here, takes the kernels' one-step path, causal or
+# not, and is not among these.)
+_PARENT_FLASH_FULL = {
+    ("BTHD", 2048, 2048, 12, 64): "4b5986486b63f121", ("BHTD", 1024, 1024, 12, 64): "8a2d5f2b02b5f461",
+    ("BTHD", 1024, 2048, 4, 128): "9809582472e40813"}
+
+
+def _kernel_jaxprs(jaxpr):
+    return [str(eqn.params["jaxpr"]) + str(eqn.params["grid_mapping"]) for eqn in _pallas_eqns(jaxpr)]
+
+
+@pytest.mark.parametrize("layout,t,tk,h,d", sorted(_PARENT_FLASH_FULL))
+def test_a_non_causal_flash_call_runs_the_parents_kernels(monkeypatch, layout, t, tk, h, d):
+    from paddle_tpu.ops import attention
+
+    for knob in ("PADDLE_TPU_FLASH_BLOCKS", "PADDLE_TPU_FLASH_BWD_BLOCKS"):
+        monkeypatch.delenv(knob, raising=False)
+    bq, bk, bwd = attention._flash_tiles(t, tk, layout, False)
+
+    def call(q, k, v):
+        out, vjp = jax.vjp(lambda q, k, v: flash_attention(
+            q, k, v, causal=False, block_q=bq, block_k=bk, layout=layout, bwd_blocks=bwd,
+            interpret=False), q, k, v)
+        return vjp(out)
+
+    shape = lambda n: (2, n, h, d) if layout == "BTHD" else (2, h, n, d)  # noqa: E731
+    q, kv = (jax.ShapeDtypeStruct(shape(n), jnp.bfloat16) for n in (t, tk))
+    kernels = _kernel_jaxprs(jax.make_jaxpr(call)(q, kv, kv).jaxpr)
+    assert len(kernels) == 3
+    assert _sha("\n".join(kernels)) == _PARENT_FLASH_FULL[layout, t, tk, h, d]
 
 
 # The same programs for a block of another kind: OLMoE's (RMSNorm, RoPE, q/k

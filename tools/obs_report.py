@@ -118,6 +118,22 @@ def _hist_entry(snapshot, name,
 # ---------------------------------------------------------------------------
 
 
+def _flash_tiles(snap) -> Dict[str, Any]:
+    """Per flash kernel (fwd, dq, dkv): the score tiles of the calls traced
+    so far by what the causal schedule does with them, and the share of the
+    score square that is computed (1.0: nothing skipped; a non-causal call,
+    or tiles as wide as the sequence)."""
+    out: Dict[str, Any] = {}
+    for s in _series(snap, "flash_tiles_total"):
+        labels = s.get("labels") or {}
+        out.setdefault(labels.get("kernel", ""), {})[labels.get("cls", "")] = float(s.get("value", 0))
+    for tiles in out.values():
+        total = sum(tiles.values())
+        tiles["computed_share"] = (round((total - tiles.get("skipped", 0.0)) / total, 4)
+                                   if total else None)
+    return out
+
+
 def _executor_section(snap) -> Dict[str, Any]:
     hits = _scalar(snap, "executor_cache_lookups_total", {"result": "hit"})
     misses = _scalar(snap, "executor_cache_lookups_total", {"result": "miss"})
@@ -134,6 +150,7 @@ def _executor_section(snap) -> Dict[str, Any]:
         # the forward rule a second time (a Mosaic kernel then runs twice)
         "grad_paired": _scalar(snap, "executor_grad_paired_total"),
         "grad_retraced": _scalar(snap, "executor_grad_retraced_total"),
+        "flash_tiles": _flash_tiles(snap),
         "compile_seconds": hist_summary(
             _hist_entry(snap, "executor_compile_seconds")),
         "run_seconds": hist_summary(_hist_entry(snap, "executor_run_seconds")),
