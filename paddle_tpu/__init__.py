@@ -11,6 +11,10 @@ graph building.
 """
 __version__ = "0.1.0"
 
+import time as _time
+
+_IMPORT_T0 = _time.perf_counter()
+
 from .framework import (
     CPUPlace,
     CUDAPlace,
@@ -187,3 +191,12 @@ def summary(net, input_size=None, dtypes="float32"):
             sizes = _clean(sizes)
     dt = dtypes[0] if isinstance(dtypes, (list, tuple)) else dtypes
     return Model(net).summary(input_size=sizes, dtype=dt)
+
+
+# what `import paddle_tpu` cost this process (jax and its backends' Python
+# included where nothing imported them before): a part of every set-up
+# that no jit event sees (framework/xla_insight.build_log has those)
+monitor.gauge(
+    "paddle_tpu_import_seconds",
+    "wall seconds `import paddle_tpu` took in this process").set(
+        _time.perf_counter() - _IMPORT_T0)

@@ -51,7 +51,9 @@ _M_COMPILE = _monitor.counter(
     "executor_compile_total", "program block compiles (cache misses)")
 _M_COMPILE_T = _monitor.histogram(
     "executor_compile_seconds",
-    "first-run latency of a freshly compiled block (trace + XLA compile)",
+    "the WHOLE first run of a freshly built block (build, trace, lower, "
+    "compile or cache load, and the run itself); the stages alone are "
+    "program_build_seconds_total{stage}",
     buckets=(0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0))
 _M_RUN = _monitor.counter("executor_run_total", "Executor.run calls")
 _M_PROG_RUN = _monitor.counter(
@@ -349,6 +351,9 @@ class Executor:
             out = self._run_impl(
                 program, feed, fetch_list, scope, return_numpy, use_prune
             )
+            if self._last_run_compiled:
+                # "which step recompiled", by step number, in any trace
+                sp.set(compiled=True)
         dt = sp.seconds
         _monitor.note_progress()  # hang-watchdog heartbeat
         _M_RUN.inc()
@@ -429,6 +434,13 @@ class Executor:
         self._dispatch_s = sp.seconds
         with _profiler.span("executor/commit", cat="executor"):
             self._commit(program, compiled, probes, new_params, scope)
+        with _profiler.span("executor/release", cat="executor"):
+            # the call consumed the donated state (every parameter and
+            # optimizer moment) and `_commit` replaced it in the scope, so
+            # this frame holds the last reference to each old array
+            # object: they are freed here, under a name, and not when the
+            # frame dies, inside executor/run and outside every child span
+            del call_args, new_params, probes
         if not return_numpy:
             return list(fetches)
         with _profiler.span("executor/fetch", cat="executor"):
@@ -585,7 +597,6 @@ class Executor:
         fetch_names: List[str],
         scope: Scope,
     ) -> _CompiledBlock:
-        block = program.global_block()
         feed_spec = tuple(
             (k, tuple(v.shape), str(jnp.result_type(v))) for k, v in sorted(feed_vals.items())
         )
@@ -606,7 +617,22 @@ class Executor:
                 return cached
         _M_CACHE_MISS.inc()
         self._last_run_compiled = True
+        with _profiler.span("executor/build", cat="build") as sp:
+            compiled = self._build(program, feed_vals, fetch_names, scope,
+                                   feed_spec, check_nan, check_numerics)
+            sp.set(program=compiled.module_name, key=compiled.key_hash)
+        self._cache[key] = compiled
+        self._note_cache_size()
+        return compiled
 
+    def _build(self, program, feed_vals, fetch_names, scope, feed_spec,
+               check_nan, check_numerics) -> _CompiledBlock:
+        """The miss path up to the jit wrapper: block analysis, the
+        recipe's placement of the scope, the function the block lowers
+        through. Nothing is traced or compiled here: `_prepare` hands the
+        wrapper to `xla_insight.capture` once the arguments exist."""
+        block = program.global_block()
+        mesh = getattr(program, "_mesh", None)
         feed_names = sorted(feed_vals)
         param_names, updated_names = self._analyze_block(block, feed_names, scope)
         updated_set = set(updated_names)
@@ -614,7 +640,6 @@ class Executor:
         # inputs (learning rate, frozen params) must survive the call
         mutable_names = [n for n in param_names if n in updated_set]
         const_names = [n for n in param_names if n not in updated_set]
-        mesh = getattr(program, "_mesh", None)
         recipe = getattr(program, "_sharding_recipe", None)
         if mesh is not None and recipe is not None:
             # recipe programs shard their own scope (params + optimizer
@@ -779,8 +804,6 @@ class Executor:
             feed_spec, tuple(fetch_names), check_nan, check_numerics,
         ))
         compiled.jittable = not has_host
-        self._cache[key] = compiled
-        self._note_cache_size()
         return compiled
 
     @staticmethod

@@ -44,8 +44,11 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+import jax.monitoring as _jax_monitoring
+
 from .. import flags as _flags
 from .. import monitor as _monitor
+from .. import profiler as _profiler
 from . import shard_insight as _shard
 
 __all__ = [
@@ -53,7 +56,8 @@ __all__ = [
     "aot_call", "memory_analysis_bytes", "dump_artifacts",
     "load_dump_dir", "recent", "clear_recent", "program_footprint",
     "value_bytes", "new_footprint_row", "footprint_report",
-    "failure_counts", "COST_SCHEMA", "FOOTPRINT_SCHEMA",
+    "failure_counts", "build_log", "program_of", "COST_SCHEMA",
+    "FOOTPRINT_SCHEMA",
 ]
 
 COST_SCHEMA = "paddle_tpu.xla_cost/1"
@@ -81,6 +85,18 @@ _M_AOT_FALLBACK = _monitor.counter(
     "AOT executables abandoned for plain jit after a call-time "
     "signature mismatch (each one is a second compile of that program)")
 
+_M_BUILD_S = _monitor.counter(
+    "program_build_seconds_total",
+    "seconds this process spent building jitted programs, by stage: trace "
+    "(Python -> jaxpr), lower (jaxpr -> StableHLO, Mosaic kernels "
+    "included), compile (XLA, or the load from the persistent cache). "
+    "Every jit of the process, named or not; time nested inside another "
+    "build is counted once, where it was spent", labelnames=("stage",))
+_M_BUILD_N = _monitor.counter(
+    "program_build_total",
+    "build stages this process went through (see "
+    "program_build_seconds_total)", labelnames=("stage",))
+
 _log = logging.getLogger(__name__)
 
 
@@ -107,6 +123,7 @@ class ProgramInsight:
 
     key_hash: str
     label: str = ""
+    program: str = ""  # the module's name in a device trace: jit_<fn>
     fetch_names: Tuple[str, ...] = ()
     flops: Optional[float] = None
     bytes_accessed: Optional[float] = None
@@ -124,6 +141,10 @@ class ProgramInsight:
     # comms-plane summary parsed from the post-optimization HLO
     # (shard_insight.comms_summary): collective counts/bytes per kind
     collectives: Optional[dict] = None
+    # the build/* spans' seconds ({"trace", "lower", "compile", "analyze"})
+    # and what the persistent cache did for the compile: hit | miss | off
+    build_s: Dict[str, float] = field(default_factory=dict)
+    cache: str = ""
 
     def to_dict(self) -> dict:
         d = asdict(self)
@@ -149,6 +170,186 @@ def clear_recent() -> None:
 
 
 # ---------------------------------------------------------------------------
+# build log (every jit of the process, through jax.monitoring)
+# ---------------------------------------------------------------------------
+
+BUILD_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+_BUILD_LOG_MAX = 2048
+_SMALL_TRACE_S = 1e-3
+_MERGE_BELOW_S = 0.05
+_OPEN_MAX = 1 << 16
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/compile_requests_use_cache": "requests",
+    "/jax/compilation_cache/cache_hits": "hits",
+    "/jax/compilation_cache/cache_misses": "misses",
+}
+# per stage, the two counters' children: one dict lookup an event
+_M_BUILD = {st: (_M_BUILD_S.labels(stage=st), _M_BUILD_N.labels(stage=st))
+            for st in BUILD_STAGES.values()}
+
+
+def program_of(fun_name: str) -> str:
+    """A build record's name without its jit wrapper: ``train_step`` for
+    ``train_step``, ``jit(train_step)`` and ``jit_train_step``."""
+    if fun_name.startswith("jit(") and fun_name.endswith(")"):
+        return fun_name[4:-1]
+    return fun_name[4:] if fun_name.startswith("jit_") else fun_name
+
+
+class _BuildLog:
+    """What JAX reports of every jit's three stages, as it reports it.
+
+    JAX calls the listeners on the thread that builds, when a stage ENDS,
+    with the stage's duration and a name: the trace event carries the
+    Python function's (``train_step``), the other two the module's
+    (``jit(train_step)``; ``jit_train_step`` in a device trace), so a
+    record also has ``program``, the name without its jit wrapper, to
+    join them by. A record is ``{fun_name, program, stage, t_end,
+    seconds, self_s, count, thread}`` (and ``cache`` on a compile):
+    ``t_end`` on ``time.perf_counter``'s clock, ``self_s`` the seconds
+    less the records of the same thread that lie inside this one (a
+    jitted layer traced inside its program, a constant compiled while a
+    program is traced), so ``self_s`` summed over any records is wall
+    time counted once.
+
+    JAX reports a trace for every ``jnp`` function a program's trace
+    calls and for every op whose shapes the program builder infers
+    (``registry``'s ``eval_shape``): 2,500 for a two-layer GPT. So a
+    trace under a millisecond loses its name (``<small>``), and a record
+    under ``_MERGE_BELOW_S`` is added to the last one of its thread,
+    stage and name if that ended less than a second before (``count`` > 1,
+    ``t_end`` the newest). Nothing here runs unless something is built.
+    """
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._tls = threading.local()
+        self.records: List[dict] = []
+        stages = tuple(BUILD_STAGES.values())
+        self.totals = {st: {"seconds": 0.0, "count": 0} for st in stages}
+        self.dropped = {st: {"seconds": 0.0, "count": 0} for st in stages}
+        self.cache = {"requests": 0, "hits": 0, "misses": 0,
+                      "retrieval_s": 0.0}
+
+    def _self_seconds(self, t_end: float, seconds: float) -> float:
+        """``seconds`` less what this thread built inside [t_end -
+        seconds, t_end]. A record is claimed by the first one that closes
+        over it, so a grandchild is taken off its parent only."""
+        mine = self._tls.__dict__.setdefault("open", [])
+        start = t_end - seconds
+        inside = 0.0
+        # 2e-5: JAX times a stage on time.time() and calls the listener a
+        # few microseconds after its end, later for one record than for
+        # the next (at worst a neighbour that short is taken for a child)
+        while mine and mine[-1][0] >= start - 2e-5:
+            inside += mine.pop()[1]
+        mine.append((start, seconds))
+        del mine[:-_OPEN_MAX]
+        return max(0.0, seconds - inside)
+
+    def on_duration(self, event: str, seconds: float, **kw) -> None:
+        stage = BUILD_STAGES.get(event)
+        if stage is None:
+            if event == "/jax/compilation_cache/cache_retrieval_time_sec":
+                with self._lock:
+                    self.cache["retrieval_s"] += float(seconds)
+            return
+        t_end, seconds = time.perf_counter(), float(seconds)
+        self_s = self._self_seconds(t_end, seconds)
+        seconds_total, stages_total = _M_BUILD[stage]
+        seconds_total.inc(self_s)
+        stages_total.inc()
+        name = str(kw.get("fun_name", ""))
+        if stage == "trace" and seconds < _SMALL_TRACE_S:
+            name = "<small>"
+        cache = None
+        if stage == "compile":
+            cache = getattr(self._tls, "pending", None) or "off"
+            self._tls.pending = None
+            self._tls.compiled = cache
+        merge = self._tls.__dict__.setdefault("merge", {})
+        with self._lock:
+            tot = self.totals[stage]
+            tot["seconds"] += self_s
+            tot["count"] += 1
+            last = merge.get((stage, name))
+            if (last is not None and seconds < _MERGE_BELOW_S
+                    and t_end - last["t_end"] < 1.0
+                    and last.get("cache") == cache):
+                last["t_end"] = t_end
+                last["seconds"] += seconds
+                last["self_s"] += self_s
+                last["count"] += 1
+                return
+            rec = {"fun_name": name, "program": program_of(name),
+                   "stage": stage, "t_end": t_end, "seconds": seconds,
+                   "self_s": self_s, "count": 1,
+                   "thread": threading.get_ident()}
+            if cache is not None:
+                rec["cache"] = cache
+            if seconds < _MERGE_BELOW_S:
+                if len(merge) >= 256:
+                    merge.clear()
+                merge[(stage, name)] = rec
+            self.records.append(rec)
+            for old in self.records[:-_BUILD_LOG_MAX]:
+                gone = self.dropped[old["stage"]]
+                gone["seconds"] += old["self_s"]
+                gone["count"] += old["count"]
+            del self.records[:-_BUILD_LOG_MAX]
+
+    def on_event(self, event: str, **_kw) -> None:
+        what = _CACHE_EVENTS.get(event)
+        if what is None:
+            return
+        with self._lock:
+            self.cache[what] += 1
+        # a request that uses the cache is a miss until the hit is seen
+        # (JAX reports a miss only where it then WRITES the entry)
+        if what == "hits":
+            self._tls.pending = "hit"
+        elif what == "requests":
+            self._tls.pending = "miss"
+
+    def take_compiled(self) -> Optional[str]:
+        """The ``cache`` (hit | miss | off) of the newest compile this
+        thread made since the last call, None where it made none."""
+        out = getattr(self._tls, "compiled", None)
+        self._tls.compiled = None
+        return out
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            return {
+                "records": [dict(r) for r in self.records],
+                "dropped": {st: dict(t) for st, t in self.dropped.items()},
+                "totals": {st: dict(t) for st, t in self.totals.items()},
+                "cache": dict(self.cache),
+            }
+
+
+_BUILD_LOG = _BuildLog()
+_jax_monitoring.register_event_duration_secs_listener(_BUILD_LOG.on_duration)
+_jax_monitoring.register_event_listener(_BUILD_LOG.on_event)
+
+
+def build_log() -> dict:
+    """Every jit this process built, named by the program or not:
+    ``records`` (the newest 2,048, oldest first; what fell off the end
+    is summed by stage in ``dropped``), per-stage ``totals`` of ``self_s``
+    and of stages gone through, which never drop (also
+    ``program_build_seconds_total{stage}`` and
+    ``program_build_total{stage}`` on ``/metrics``), and the persistent
+    cache's ``requests``, ``hits``, ``misses`` and ``retrieval_s``. The
+    same ``program`` with two ``compile`` records was compiled twice."""
+    return _BUILD_LOG.snapshot()
+
+
+# ---------------------------------------------------------------------------
 # capture (the executor cache-miss hook)
 # ---------------------------------------------------------------------------
 
@@ -169,11 +370,21 @@ def capture(jit_fn, example_args: Sequence[Any], *, key_hash: str,
     """
     if not enabled() or not hasattr(jit_fn, "trace"):
         return None, None
+    # one span a stage, named as the module is in a device trace; their
+    # seconds are the insight's build_s
+    who = {"program": "jit_" + getattr(jit_fn, "__name__", "program"),
+           "key": key_hash}
     try:
-        traced = jit_fn.trace(*example_args)
-        jaxpr = traced.jaxpr
-        lowered = traced.lower()
-        executable = lowered.compile()
+        with _profiler.span("build/trace", cat="build", **who) as sp_trace:
+            traced = jit_fn.trace(*example_args)
+            jaxpr = traced.jaxpr
+        with _profiler.span("build/lower", cat="build", **who) as sp_lower:
+            lowered = traced.lower()
+        with _profiler.span("build/compile", cat="build", **who) as sp_comp:
+            _BUILD_LOG.take_compiled()
+            executable = lowered.compile()
+            cache = _BUILD_LOG.take_compiled() or "off"
+            sp_comp.set(cache=cache)
     except Exception as e:
         _M_CAPTURE.labels(result="error").inc()
         _log.warning("xla_insight capture of %s (%s) failed, the caller "
@@ -182,8 +393,27 @@ def capture(jit_fn, example_args: Sequence[Any], *, key_hash: str,
         return None, None
 
     insight = ProgramInsight(
-        key_hash=key_hash, label=label, fetch_names=tuple(fetch_names),
-        time_unix=time.time())
+        key_hash=key_hash, label=label, program=who["program"],
+        fetch_names=tuple(fetch_names), time_unix=time.time(), cache=cache,
+        build_s={"trace": sp_trace.seconds, "lower": sp_lower.seconds,
+                 "compile": sp_comp.seconds})
+    with _profiler.span("build/analyze", cat="build", **who) as sp_analyze:
+        _analyze(insight, jaxpr, lowered, executable, dump_to)
+    insight.build_s["analyze"] = sp_analyze.seconds
+    _M_CAPTURE.labels(result="ok").inc()
+    with _RECENT_LOCK:
+        _RECENT.append(insight)
+        del _RECENT[:-_RECENT_MAX]
+    return insight, executable
+
+
+def _analyze(insight: ProgramInsight, jaxpr, lowered, executable,
+             dump_to: Optional[str]) -> None:
+    """What `capture` does beside jit's own path once the executable
+    exists: cost and memory analysis into the insight and the gauges, the
+    HLO text where someone reads it, the comms plan, the dump."""
+    started = time.perf_counter()
+    key_hash = insight.key_hash
     try:
         insight.n_jaxpr_eqns = len(jaxpr.jaxpr.eqns)
     except Exception:
@@ -242,17 +472,13 @@ def capture(jit_fn, example_args: Sequence[Any], *, key_hash: str,
         # shard_insight.reconcile); rides the cost.json dump below
         insight.collectives = _shard.attach(insight, hlo_text)
     if out_dir:
+        # the dump's cost.json holds the analysis up to its own writing
+        insight.build_s["analyze"] = time.perf_counter() - started
         try:
             dump_artifacts(insight, out_dir, jaxpr_text=str(jaxpr),
                            hlo_text=hlo_text)
         except OSError:
             pass
-
-    _M_CAPTURE.labels(result="ok").inc()
-    with _RECENT_LOCK:
-        _RECENT.append(insight)
-        del _RECENT[:-_RECENT_MAX]
-    return insight, executable
 
 
 def _device_count() -> int:

@@ -67,6 +67,7 @@ others — the continuous-batching correctness property.
 """
 from __future__ import annotations
 
+import contextlib
 import math
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -74,11 +75,20 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from .. import flags as _flags
+from .. import monitor as _monitor
 from .. import profiler as _profiler
 from ..models.gpt import GPTConfig
 from .kv_cache import blocks_for_tokens
 
 __all__ = ["GPTConfig", "DecodeModel", "init_params", "calibrate"]
+
+_M_BOOT = _monitor.gauge(
+    "serve_boot_seconds",
+    "seconds this process spent bringing serving models up: load (the "
+    "serve/load spans: weights placed, KV and state pools allocated) and "
+    "warm (the serve/warm spans: DecodeModel.warm building programs ahead "
+    "of traffic; their stages are program_build_seconds_total)",
+    labelnames=("phase",))
 
 _NEG = -1e30  # finite mask value: garbage behind it stays non-NaN
 _SUBLANES = 8  # rows of one (8, 128) tile, the unit the TPU lays arrays out in
@@ -289,9 +299,6 @@ class DecodeModel:
                  block_size: Optional[int] = None,
                  prefill_buckets: Optional[Sequence[int]] = None,
                  seed: int = 0):
-        import jax
-        import jax.numpy as jnp
-
         self.cfg = cfg
         # the kinds of layer this model has, in order of first appearance
         # (one traced body each), and which layers own a share of a pool
@@ -324,9 +331,40 @@ class DecodeModel:
         self.rules: List[Tuple[str, Tuple]] = []
         self.sharding_mismatches: List[dict] = []
         host_params = params if params is not None else init_params(cfg, seed)
-        if self.recipe is not None and self.recipe.n_devices > 1:
-            import jax
+        with self._load_span("params") as sp:
+            self._place_params(host_params)
+            sp.set(param_bytes=sum(int(a.nbytes)
+                                   for a in self.params.values()))
 
+        self.insights: Dict[str, Any] = {}
+        self._decode_fn = None
+        self._prefill_fns: Dict[int, Any] = {}
+        self._score_fns: Dict[int, Any] = {}
+        # what a decode tick takes as `prev` when no tick ran before it:
+        # the shape of its own second output (behind the tokens of a model
+        # with experts ride the three ops/moe.py::routing_counts)
+        self._no_prev = np.zeros(
+            (self.max_batch + (3 if self.routes else 0),), np.int32)
+
+    # -- placement ------------------------------------------------------
+
+    @contextlib.contextmanager
+    def _load_span(self, what: str):
+        """A ``serve/load`` span (``what``: params | kv_pool | state_pool;
+        ``pool_bytes``: what both pools hold once allocated), its seconds
+        added to ``serve_boot_seconds{phase="load"}``."""
+        with _profiler.span("serve/load", cat="build", what=what,
+                            pool_bytes=self.pool_bytes()) as sp:
+            yield sp
+        _M_BOOT.labels(phase="load").inc(sp.seconds)
+
+    def _place_params(self, host_params) -> None:
+        """``self.params``: the weights on the device, or on the recipe's
+        mesh as its rules lay them out."""
+        import jax
+        import jax.numpy as jnp
+
+        if self.recipe is not None and self.recipe.n_devices > 1:
             if self.routes:
                 raise NotImplementedError(
                     f"recipe {self.recipe.name!r} places the model on "
@@ -358,18 +396,6 @@ class DecodeModel:
         else:
             self.params = {name: jnp.asarray(arr)
                            for name, arr in host_params.items()}
-
-        self.insights: Dict[str, Any] = {}
-        self._decode_fn = None
-        self._prefill_fns: Dict[int, Any] = {}
-        self._score_fns: Dict[int, Any] = {}
-        # what a decode tick takes as `prev` when no tick ran before it:
-        # the shape of its own second output (behind the tokens of a model
-        # with experts ride the three ops/moe.py::routing_counts)
-        self._no_prev = np.zeros(
-            (self.max_batch + (3 if self.routes else 0),), np.int32)
-
-    # -- placement ------------------------------------------------------
 
     @staticmethod
     def _resolve_recipe(recipe):
@@ -435,6 +461,14 @@ class DecodeModel:
         return (len(self.conv_layers), self.cfg.conv_kernel - 1,
                 self.max_batch, self.cfg.d_model)
 
+    def pool_bytes(self) -> int:
+        """Bytes of the KV pool and the state pool together."""
+        import jax.numpy as jnp
+
+        elems = math.prod(self.pool_shape()) + math.prod(
+            self.state_shape() or (0,))
+        return elems * jnp.dtype(self.cfg.dtype).itemsize
+
     def attention_path(self) -> Tuple[str, str]:
         """How the decode program attends, and why: ``("kernel", "")`` is
         ``ops/pallas/paged_attention`` over the pool as it lies;
@@ -474,8 +508,9 @@ class DecodeModel:
         they are given and return its successor: hold only the newest."""
         import jax.numpy as jnp
 
-        return jnp.zeros(self.pool_shape(), self.cfg.dtype,
-                         device=self._pages_sharding())
+        with self._load_span("kv_pool"):
+            return jnp.zeros(self.pool_shape(), self.cfg.dtype,
+                             device=self._pages_sharding())
 
     def init_state(self):
         """A zeroed state pool (:meth:`state_shape`), or None where the
@@ -483,7 +518,10 @@ class DecodeModel:
         import jax.numpy as jnp
 
         shape = self.state_shape()
-        return None if shape is None else jnp.zeros(shape, self.cfg.dtype)
+        if shape is None:
+            return None
+        with self._load_span("state_pool"):
+            return jnp.zeros(shape, self.cfg.dtype)
 
     # -- shared forward pieces -----------------------------------------
 
@@ -1156,13 +1194,16 @@ class DecodeModel:
         path, where a mid-traffic bucket compile would masquerade as a
         multi-second p99 tail (and a warm RESTART should pay the XLA
         persistent-cache hit, not a fresh compile)."""
-        if self._decode_fn is None:
-            self._decode_fn = self._build_decode()
         buckets = (self.prefill_buckets if full
                    else self.prefill_buckets[:1])
-        for L in buckets:
-            if L not in self._prefill_fns:
+        with _profiler.span("serve/warm", cat="build") as sp:
+            todo = [L for L in buckets if L not in self._prefill_fns]
+            sp.set(programs=len(todo) + (self._decode_fn is None))
+            if self._decode_fn is None:
+                self._decode_fn = self._build_decode()
+            for L in todo:
                 self._prefill_fns[L] = self._build_prefill(L)
+        _M_BOOT.labels(phase="warm").inc(sp.seconds)
 
     # -- reference path (tests) ----------------------------------------
 

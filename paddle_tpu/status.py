@@ -109,6 +109,7 @@ class _StatusHandler(BaseHTTPRequestHandler):
                 doc["dynamics"] = _dynamics.status()
                 doc["comms"] = _commswatch.status()
                 doc["serving"] = _serving_ledger.status()
+                doc["boot"] = boot()
                 self._send_json(200, doc)
             else:
                 self._send_json(404, {"error": f"unknown path {path!r}",
@@ -209,6 +210,30 @@ class _StatusHandler(BaseHTTPRequestHandler):
         self._send_json(200, {"draining": True,
                               "drained": engine.drained(),
                               **engine.healthz_info()})
+
+
+def boot() -> dict:
+    """The /status ``boot`` section, "why did this start take so long":
+    the import, every program built so far by stage with what the
+    persistent cache did (framework/xla_insight.build_log), and a serving
+    replica's load and warm phases."""
+    from .framework import xla_insight
+
+    def gauge(name, **labels):
+        family = _monitor.default_registry().get(name)
+        if family is None:
+            return None
+        return float((family.labels(**labels) if labels else family).value)
+
+    log = xla_insight.build_log()
+    return {
+        "import_seconds": gauge("paddle_tpu_import_seconds"),
+        "build_seconds": {st: t["seconds"] for st, t in log["totals"].items()},
+        "builds": {st: t["count"] for st, t in log["totals"].items()},
+        "compile_cache": log["cache"],
+        "serve_load_seconds": gauge("serve_boot_seconds", phase="load"),
+        "serve_warm_seconds": gauge("serve_boot_seconds", phase="warm"),
+    }
 
 
 def _replica_engine():

@@ -11,7 +11,7 @@ import pytest
 from benchmark import common, flops, manifest
 from benchmark.readers import (idle_in_spans, kernel_events_per_step, kernel_roofline,
                                ledger_itl_ms, ledger_tick_host_ms, module_device_ms,
-                               monitor_hist_mean_ms)
+                               monitor_hist_mean_ms, setup_build_s)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NEW = ("executor_host_ms", "executor_dispatch_ms", "fwd_passes_per_step", "flash_kernels_roofline",
@@ -194,3 +194,91 @@ def test_the_executor_registers_the_families_the_metrics_name():
 
     for metric in ("executor_host_ms", "executor_dispatch_ms"):
         assert monitor.default_registry().get(_args(metric)["family"]) is not None
+
+
+# -- setup_s split by the program's build log (PR 36) ------------------------
+
+SETUP = ("setup_trace_s", "setup_lower_s", "setup_compile_s", "setup_rest_s")
+
+
+def _rec(fun_name, stage, t_end, self_s, **more):
+    from paddle_tpu.framework import xla_insight
+
+    return {"fun_name": fun_name, "program": xla_insight.program_of(fun_name), "stage": stage,
+            "t_end": t_end, "seconds": self_s, "self_s": self_s, "count": 1, **more}
+
+
+def _hand_made_log():
+    # a process that starts at t = 100 on perf_counter's clock and opens its
+    # window at 130; what is built after that is the reference check's
+    records = [_rec("train_step", "trace", 110.0, 4.0), _rec("jit(train_step)", "lower", 112.0, 2.0),
+               _rec("jit(train_step)", "compile", 115.0, 3.0, cache="hit"),
+               _rec("jit(copy)", "compile", 116.0, 0.5, cache="miss", count=5),
+               _rec("jit_train_step", "compile", 117.0, 1.0, cache="miss"),  # a fallback to plain jit
+               _rec("mean_nll", "trace", 131.0, 7.0), _rec("jit(mean_nll)", "compile", 140.0, 9.0, cache="miss")]
+    return {"records": records, "cache": {"requests": 8, "hits": 1, "misses": 7, "retrieval_s": 0.2},
+            "dropped": {"trace": {"seconds": 0.25, "count": 3}, "lower": {"seconds": 0.0, "count": 0},
+                        "compile": {"seconds": 0.0, "count": 0}}}
+
+
+def test_manifest_takes_the_setup_metrics_in_every_cell_without_a_list():
+    man = manifest.load()
+    assert manifest.problems(man) == []
+    assert [m["name"] for m in man["per_layer"]][-4:] == list(SETUP)
+    for m in man["per_layer"][-4:]:
+        spec = manifest.layer_metric(m["name"])
+        assert "workloads" not in m and "workloads" not in spec
+        assert (m["moves"], m["unit"], m["source"]) == ("setup_s", "s", "program_counter")
+        assert spec["reader"] == "setup_build_s" and spec["args"]["stage"] == m["name"][6:-2]
+    for w in man["workloads"]:
+        assert [m["name"] for m in manifest.cell(man, w["name"])["per_layer"]][-4:] == list(SETUP)
+    # the only per-layer metrics that move the one metric every cell reports
+    assert {m["name"] for m in man["per_layer"] if m["moves"] == "setup_s"} == set(SETUP)
+
+
+@pytest.mark.parametrize("metric,want", [("setup_trace_s", 4.25), ("setup_lower_s", 2.0),
+                                         ("setup_compile_s", 4.5), ("setup_rest_s", 19.25)])
+def test_setup_build_reader_cuts_at_the_windows_opening(monkeypatch, metric, want):
+    from paddle_tpu.framework import xla_insight
+
+    monkeypatch.setattr(xla_insight, "build_log", _hand_made_log)
+    monkeypatch.setattr(xla_insight, "recent", lambda: [
+        xla_insight.ProgramInsight(key_hash="k", program="jit_train_step", cache="hit",
+                                   build_s={"analyze": 0.75})])
+    ctx = _ctx()
+    ctx.t0 = 100.0
+    ctx.results["setup_s"] = 30.0
+    assert setup_build_s.read(ctx, _args(metric)) == pytest.approx(want)
+    tl = ctx.results["setup_timeline"]
+    # the three and the rest are setup_s; what came after the opening is out
+    assert sum(tl["stage_s"].values()) + tl["rest_s"] == pytest.approx(30.0) and not tl["clamped"]
+    assert (tl["records_before_opening"], tl["records_after"]) == (5, 2) and "mean_nll" not in tl["by_name"]
+    # names joined across the jit wrapper, whichever way JAX spells it
+    row = tl["by_name"]["train_step"]
+    assert (row["trace"], row["lower"]) == ({"s": 4.0, "n": 1}, {"s": 2.0, "n": 1})
+    assert row["compile"] == {"s": 4.0, "n": 2, "cache": {"hit": 1, "miss": 1}}
+    # a named program with two compile records is said; a helper compiled once a shape is not
+    assert tl["compiled_twice"] == ["train_step"] and tl["by_name"]["copy"]["compile"]["n"] == 5
+    assert tl["cache_before_opening"] == {"hit": 1, "miss": 6, "off": 0, "hit_s": 3.0, "miss_s": 1.5, "off_s": 0.0}
+    assert tl["programs"] == {"jit_train_step": {"cache": "hit", "analyze_s": 0.75}}
+    assert tl["rest_unowned_s"] == pytest.approx(19.25 - 0.75 - (tl["import_s"] or 0.0)
+                                                 - (tl["serve_boot_s"]["load"] or 0.0))
+
+
+def test_setup_build_reader_clamps_the_rest_and_says_so():
+    log = _hand_made_log()
+    tl = setup_build_s.split(log, t_cut=130.0, setup_s=8.0)  # two threads built at once
+    assert tl["rest_s"] == 0.0 and tl["clamped"] and tl["rest_unowned_s"] == 0.0
+    assert sum(tl["stage_s"].values()) == pytest.approx(10.75)
+
+
+def test_setup_build_reader_finds_nothing_in_the_parent(monkeypatch):
+    from paddle_tpu.framework import xla_insight
+
+    ctx = _ctx()
+    assert setup_build_s.read(ctx, {"stage": "trace"}) is None  # no setup_s yet
+    ctx.results["setup_s"] = 30.0
+    monkeypatch.delattr(xla_insight, "build_log")  # a program from before PR 36
+    for metric in SETUP:
+        assert setup_build_s.read(ctx, _args(metric)) is None
+    assert "setup_timeline" not in ctx.results

@@ -22,6 +22,7 @@ from paddle_tpu.serving import ledger
 EXECUTOR_SPANS = {
     "executor/run": None, "executor/prepare": "executor/run",
     "executor/dispatch": "executor/run", "executor/commit": "executor/run",
+    "executor/release": "executor/run",
     "executor/fetch": "executor/run"}
 ENGINE_SPANS = {
     "engine/step": None, "engine/idle": None, "engine/admit": "engine/step",
@@ -37,6 +38,9 @@ ENGINE_SPANS = {
                          "engine/admit"),
     "tick/bookkeeping": ("engine/decode_tick", "engine/admit")}
 N_STEPS = 3  # the first compiles: two steady-state runs
+# what a run that builds its program holds under executor/prepare, in order
+BUILD_SPANS = ("executor/build", "build/trace", "build/lower",
+               "build/compile", "build/analyze")
 
 
 def _hist(name):
@@ -94,16 +98,18 @@ def traced(tmp_path_factory):
     profiler.clear_events()
     cfg = serving.GPTConfig(vocab_size=128, n_layer=2, n_head=2, d_model=32,
                             max_seq_len=64)
-    model = serving.DecodeModel(cfg, max_batch=4, n_blocks=16, block_size=8,
-                                prefill_buckets=[16, 32], seed=1)
-    model.warm(full=True)
-    ledger.reset()
     hist0 = {n: _hist(n) for n in ("executor_run_seconds",
                                    "executor_dispatch_seconds",
                                    "executor_host_seconds")}
     d = str(tmp_path_factory.mktemp("xplane"))
     jax.profiler.start_trace(d)
     try:
+        # a replica's boot is inside the session: serve/load, serve/warm
+        model = serving.DecodeModel(cfg, max_batch=4, n_blocks=16,
+                                    block_size=8, prefill_buckets=[16, 32],
+                                    seed=1)
+        model.warm(full=True)
+        ledger.reset()
         modules = _train_steps()
         answers = _serve(model)
     finally:
@@ -119,7 +125,8 @@ def traced(tmp_path_factory):
         for line in plane.lines:
             for e in line.events:
                 name = e.name.partition("#")[0]
-                if name.split("/")[0] in ("executor", "engine", "tick"):
+                if name.split("/")[0] in ("executor", "engine", "tick",
+                                          "build", "serve"):
                     events.append({"name": name, "t0": e.start_ns,
                                    "t1": e.start_ns + e.duration_ns,
                                    "thread": line.name,
@@ -193,6 +200,75 @@ def test_span_attributes(traced):
     assert sum(e["attrs"]["admitted"] for e in admits) == 3
     assert all("queued" in e["attrs"] for e in admits)
     assert all(e["attrs"]["queued"] == 0 for e in _named(traced, "engine/idle"))
+
+
+def _inside(traced, outer):
+    return sorted((e for e in traced["events"] if e is not outer
+                   and e["thread"] == outer["thread"]
+                   and outer["t0"] <= e["t0"] and e["t1"] <= outer["t1"]),
+                  key=lambda e: e["t0"])
+
+
+@pytest.mark.parametrize("step", range(1 + N_STEPS))
+def test_a_run_that_builds_says_so_and_a_hit_holds_no_build_span(traced, step):
+    run = next(e for e in _named(traced, "executor/run")
+               if e["attrs"]["step"] == step)
+    inside = _inside(traced, run)
+    built = [e for e in inside if e["name"] in BUILD_SPANS]
+    if step >= 2:  # a hit in the executor's cache
+        assert built == [] and "compiled" not in run["attrs"]
+        return
+    # startup (step 0) and the first train step: one of each, in order,
+    # all under executor/prepare, capture's after executor/build closed
+    assert str(run["attrs"]["compiled"]) in ("True", "1")
+    assert tuple(e["name"] for e in built) == BUILD_SPANS
+    prepare = next(e for e in inside if e["name"] == "executor/prepare")
+    assert all(prepare["t0"] <= e["t0"] and e["t1"] <= prepare["t1"]
+               for e in built)
+    assert all(a["t1"] <= b["t0"] for a, b in zip(built, built[1:]))
+    program = ("jit_startup", "jit_train_step")[step]
+    assert {e["attrs"]["program"] for e in built} == {program}
+    assert len({e["attrs"]["key"] for e in built}) == 1
+    assert built[3]["attrs"]["cache"] in ("hit", "miss", "off")
+
+
+def test_serving_boot_spans_and_status(traced):
+    import json
+    import urllib.request
+
+    from paddle_tpu import status
+
+    model = traced["model"]
+    loads = {e["attrs"]["what"]: e for e in _named(traced, "serve/load")}
+    assert set(loads) == {"params", "kv_pool"}  # no conv layer: no state pool
+    assert loads["params"]["attrs"]["param_bytes"] == sum(
+        int(a.nbytes) for a in model.params.values())
+    assert {e["attrs"]["pool_bytes"] for e in loads.values()} == {
+        model.pool_bytes()} == {int(np.prod(model.pool_shape())) * 4}
+    warm, = _named(traced, "serve/warm")
+    assert warm["attrs"]["programs"] == 3
+    built = [e for e in _inside(traced, warm) if e["name"] in BUILD_SPANS]
+    assert [e["name"] for e in built] == list(BUILD_SPANS[1:]) * 3
+    assert [e["attrs"]["program"] for e in built[::4]] == [
+        "jit_decode_tick", "jit_prefill_16", "jit_prefill_32"]
+    assert all(model.insights[k].build_s["compile"] > 0 and model.insights[k].cache
+               for k in ("decode", "prefill@16", "prefill@32"))
+    boot = status.boot()
+    assert boot["serve_warm_seconds"] >= (warm["t1"] - warm["t0"]) / 1e9 * 0.9
+    # set once when the package was imported (a test that resets the
+    # registry since has zeroed it: the key is what /status promises)
+    assert boot["serve_load_seconds"] > 0 and boot["import_seconds"] >= 0
+    assert boot["builds"]["compile"] >= 5 and boot["build_seconds"]["trace"] > 0
+    srv = status.start_status_server(port=0)
+    try:
+        with urllib.request.urlopen(
+                f"http://127.0.0.1:{srv.server_port}/status", timeout=10) as r:
+            doc = json.load(r)
+    finally:
+        status.stop_status_server()
+    assert doc["boot"]["serve_warm_seconds"] == boot["serve_warm_seconds"]
+    assert set(doc["boot"]["compile_cache"]) == {
+        "requests", "hits", "misses", "retrieval_s"}
 
 
 def test_nothing_recorded_with_tracing_off(traced):

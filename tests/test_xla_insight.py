@@ -372,3 +372,199 @@ def test_obs_report_compile_section(tmp_path):
     assert row["flops"] == 1000.0 and row["peak_bytes"] == 2048.0
     assert row["label"] == "loss" and row["n_jaxpr_eqns"] == 7
     assert "compile" in obs_report.REQUIRED_KEYS
+
+
+# ---------------------------------------------------------------------------
+# the build log (every jit of the process) and a program's own stages
+# ---------------------------------------------------------------------------
+
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_COMPILE = "/jax/core/compile/backend_compile_duration"
+
+
+def _static_steps(steps=3):
+    paddle.enable_static()
+    try:
+        main, startup, loss = _build_train_program()
+        return _run_steps(main, startup, loss, Scope(), steps=steps)[0]
+    finally:
+        paddle.disable_static()
+
+
+def _slow_to_trace(name):
+    """A jit under a name of its own whose trace takes well over the
+    millisecond under which a trace record loses its name."""
+    import jax
+    import jax.numpy as jnp
+
+    def fn(x):
+        for i in range(150):
+            x = jnp.sin(x) * (1.0 + i)
+        return x
+
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn)
+
+
+def _records(name, since=0):
+    return [r for r in xla_insight.build_log()["records"][since:]
+            if r["program"] == name]
+
+
+@pytest.mark.parametrize("stage", ["trace", "lower", "compile"])
+def test_fresh_jit_leaves_one_record_a_stage_and_a_second_call_none(stage):
+    import time
+
+    import jax.numpy as jnp
+
+    name = f"build_log_probe_{stage}"
+    f = _slow_to_trace(name)
+    t0 = time.perf_counter()
+    f(jnp.ones((4,))).block_until_ready()
+    t1 = time.perf_counter()
+    mine = _records(name)
+    assert [r["stage"] for r in mine] == ["trace", "lower", "compile"]
+    # JAX names the trace by the function and the others by the module
+    assert mine[0]["fun_name"] == name and mine[1]["fun_name"] == f"jit({name})"
+    assert xla_insight.program_of(f"jit_{name}") == name
+    rec = next(r for r in mine if r["stage"] == stage)
+    assert rec["count"] == 1 and 0 < rec["self_s"] <= rec["seconds"] < t1 - t0
+    ends = [r["t_end"] for r in mine]
+    assert t0 < ends[0] < ends[1] < ends[2] < t1  # perf_counter's clock
+    assert mine[2]["cache"] in ("hit", "miss", "off")
+    totals = xla_insight.build_log()["totals"]
+    f(jnp.ones((4,))).block_until_ready()
+    assert len(_records(name)) == 3
+    assert xla_insight.build_log()["totals"] == totals
+
+
+def test_build_log_is_bounded_and_its_totals_are_not():
+    log = xla_insight._BuildLog()
+    n = xla_insight._BUILD_LOG_MAX + 500
+    for i in range(n):
+        log.on_duration(_COMPILE, 0.01, fun_name=f"jit(p{i})")
+    doc = log.snapshot()
+    assert len(doc["records"]) == xla_insight._BUILD_LOG_MAX
+    assert doc["records"][-1]["program"] == f"p{n - 1}"
+    assert doc["totals"]["compile"]["count"] == n
+    assert doc["dropped"]["compile"]["count"] == 500
+    kept = sum(r["self_s"] for r in doc["records"])
+    assert kept + doc["dropped"]["compile"]["seconds"] == pytest.approx(
+        doc["totals"]["compile"]["seconds"])
+    log.on_duration("/jax/some/other/event", 1.0)
+    assert log.snapshot()["totals"] == doc["totals"]
+
+
+def test_build_log_counts_nested_time_once_and_merges_short_runs():
+    import time
+
+    log = xla_insight._BuildLog()
+    # what JAX reports while a program is traced: every jnp function it
+    # calls (sub-millisecond), a jitted layer, then the program itself
+    t0 = time.perf_counter()
+    for _ in range(40):
+        time.sleep(2e-4)
+        log.on_duration(_TRACE, 2e-5, fun_name="add")
+    time.sleep(0.02)
+    log.on_duration(_TRACE, 0.015, fun_name="layer")
+    time.sleep(0.02)
+    outer = time.perf_counter() - t0
+    log.on_duration(_TRACE, outer, fun_name="decode_tick")
+    log.on_duration(_LOWER, 0.0, fun_name="jit(decode_tick)")
+    recs = {r["fun_name"]: r for r in log.snapshot()["records"]}
+    assert set(recs) == {"<small>", "layer", "decode_tick", "jit(decode_tick)"}
+    assert recs["<small>"]["count"] == 40
+    assert recs["<small>"]["self_s"] == pytest.approx(40 * 2e-5)
+    assert recs["layer"]["self_s"] == pytest.approx(0.015)
+    # the program's own share: its seconds less what lies inside it
+    assert recs["decode_tick"]["self_s"] == pytest.approx(
+        outer - 0.015 - 40 * 2e-5, abs=1e-9)
+    totals = log.snapshot()["totals"]
+    assert totals["trace"]["count"] == 42
+    assert totals["trace"]["seconds"] == pytest.approx(outer, abs=1e-9)
+
+
+def test_build_log_reads_the_cache_events_of_its_thread():
+    log = xla_insight._BuildLog()
+    log.on_duration(_COMPILE, 0.2, fun_name="jit(a)")  # no request: cache off
+    log.on_event("/jax/compilation_cache/compile_requests_use_cache")
+    log.on_duration(_COMPILE, 0.2, fun_name="jit(b)")  # asked, not found
+    log.on_event("/jax/compilation_cache/compile_requests_use_cache")
+    log.on_event("/jax/compilation_cache/cache_hits")
+    log.on_duration("/jax/compilation_cache/cache_retrieval_time_sec", 0.03)
+    log.on_duration(_COMPILE, 0.2, fun_name="jit(c)")
+    assert log.take_compiled() == "hit" and log.take_compiled() is None
+    doc = log.snapshot()
+    assert [r["cache"] for r in doc["records"]] == ["off", "miss", "hit"]
+    assert doc["cache"] == {"requests": 2, "hits": 1, "misses": 0,
+                            "retrieval_s": 0.03}
+
+
+def test_program_insight_has_four_build_stages_and_flows_where_insights_flow(
+        tmp_path, monkeypatch):
+    monkeypatch.setenv("PADDLE_TPU_XLA_DUMP_DIR", str(tmp_path))
+    docs = _static_steps().compiled_insights()
+    assert len(docs) == 2
+    for doc in docs:
+        assert sorted(doc["build_s"]) == ["analyze", "compile", "lower", "trace"]
+        assert all(v > 0 for v in doc["build_s"].values())
+        assert doc["cache"] in ("hit", "miss", "off")
+        assert doc["program"] in ("jit_startup", "jit_train_step")
+        with open(tmp_path / f"program.{doc['key_hash']}.cost.json") as f:
+            dumped = json.load(f)
+        assert dumped["cache"] == doc["cache"]
+        assert sorted(dumped["build_s"]) == sorted(doc["build_s"])
+    # the registry's totals are the log's
+    totals = xla_insight.build_log()["totals"]
+    fam = monitor.default_registry().get("program_build_total")
+    assert fam.labels(stage="compile").value >= 2
+    assert totals["compile"]["count"] >= fam.labels(stage="compile").value
+    sys.path.insert(0, _TOOLS)
+    try:
+        import obs_report
+    finally:
+        sys.path.pop(0)
+    report = obs_report.build_report(
+        monitor.snapshot(),
+        xla_dump_records=xla_insight.load_dump_dir(str(tmp_path)))
+    section = report["compile"]
+    assert section["builds"]["compile"] >= 2
+    assert set(section["build_seconds"]) == {"trace", "lower", "compile"}
+    row = section["programs"][docs[0]["key_hash"]]
+    assert row["cache"] == docs[0]["cache"] and "analyze" in row["build_s"]
+    # the text prints the first ten programs: this worker may have built more
+    section["programs"] = {docs[0]["key_hash"]: row}
+    text = obs_report.render_text(report)
+    assert "build (every jit): " in text and f"cache={row['cache']}" in text
+
+
+def test_second_build_of_a_program_reads_cache_hit(tmp_path):
+    """A process that finds its program in the persistent cache says so:
+    the insight of the second build of the same program."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    saved = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir", "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    cc.reset_cache()
+    paddle.enable_static()
+    try:
+        programs = _build_train_program()
+        seen = []
+        for _ in range(2):  # the same programs, nothing kept in memory
+            jax.clear_caches()
+            exe, _ = _run_steps(*programs, Scope(), steps=1)
+            seen.append({d["program"]: d["cache"]
+                         for d in exe.compiled_insights()})
+    finally:
+        paddle.disable_static()
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+    assert seen[0] == {"jit_startup": "miss", "jit_train_step": "miss"}
+    assert seen[1] == {"jit_startup": "hit", "jit_train_step": "hit"}

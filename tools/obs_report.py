@@ -358,10 +358,18 @@ def _compile_section(snap, dump_records: Optional[Dict[str, dict]] = None
     for h, rec in (dump_records or {}).items():
         row = programs.setdefault(h, {})
         for key in ("flops", "bytes_accessed", "peak_bytes", "label",
-                    "fetch_names", "n_jaxpr_eqns"):
+                    "fetch_names", "n_jaxpr_eqns", "build_s", "cache"):
             if rec.get(key) is not None:
                 row[key] = rec[key]
+    stages = _by_label(snap, "program_build_seconds_total", "stage")
+    counts = _by_label(snap, "program_build_total", "stage")
     return {
+        # every jit of the process by stage (xla_insight.build_log), the
+        # named programs' own stages in each row's build_s
+        "build_seconds": {st: float(v.get("value", 0))
+                          for st, v in sorted(stages.items())},
+        "builds": {st: float(v.get("value", 0))
+                   for st, v in sorted(counts.items())},
         "n_programs": len(programs),
         "total_flops": sum(p.get("flops") or 0 for p in programs.values()),
         "max_peak_bytes": max(
@@ -1146,9 +1154,17 @@ def render_text(report: Dict[str, Any]) -> str:
             f"total_flops={comp['total_flops']:.3g} "
             f"max_peak={comp['max_peak_bytes'] / 1e6:.2f}MB")
         for h, p in list(comp["programs"].items())[:10]:
-            lines.append(
-                f"  program {h}: flops={p.get('flops') or 0:.3g} "
-                f"peak={(p.get('peak_bytes') or 0) / 1e6:.2f}MB")
+            line = (f"  program {h}: flops={p.get('flops') or 0:.3g} "
+                    f"peak={(p.get('peak_bytes') or 0) / 1e6:.2f}MB")
+            if p.get("build_s"):
+                line += " build " + " ".join(
+                    f"{st}={sec:.2f}s" for st, sec in p["build_s"].items()
+                ) + f" cache={p.get('cache')}"
+            lines.append(line)
+    if comp.get("build_seconds"):
+        lines.append("build (every jit): " + " ".join(
+            f"{st}={sec:.2f}s/{comp['builds'].get(st, 0):.0f}"
+            for st, sec in comp["build_seconds"].items()))
     dl = report["dataloader"]
     lines.append(
         f"dataloader: batches={dl['batches_total']:.0f} "
