@@ -389,12 +389,17 @@ def _fused_lm_head_ce(ctx, ins, attrs):
     tensor. Two implementations behind ``attrs["impl"]``:
 
     - ``"pallas"`` (the default training loss path since the raw-speed
-      round): one flash-style online-softmax kernel sweeping vocab
-      tiles in VMEM — the logits tile never reaches HBM in either
-      direction (ops/pallas/fused_lmhead_ce.py; interpret-mode on
-      non-TPU backends). Under a sharding recipe the kernel runs as a
-      manual-SPMD region: per-vocab-shard partial stats all-reduced
-      over tp, gather-at-use over fsdp, token rows over the batch axes.
+      round): two kernels, each sweeping vocab tiles in VMEM — the
+      logits tile never reaches HBM in either direction. The forward
+      (``lmhead_ce_stats``) keeps an online max and sum-exp a row; the
+      backward (``lmhead_ce_dw``) rematerializes each tile ONCE from the
+      saved logsumexp and feeds both dx and dW from it
+      (ops/pallas/fused_lmhead_ce.py; interpret-mode on non-TPU
+      backends). Tiles follow the call's (tokens, width);
+      ``block_n`` / ``block_v`` override them. Under a sharding recipe
+      the kernels run as a manual-SPMD region: per-vocab-shard partial
+      stats all-reduced over tp, gather-at-use over fsdp, token rows
+      over the batch axes.
     - ``"chunked"``: X (B, T, D) @ W (V, D)^T chunked over tokens, fp32
       streaming logsumexp per chunk, backward rematerializes each chunk
       (a lax-loop — holds one [C, V] tile in HBM per step). Kept as the

@@ -8,26 +8,43 @@ logits at B*T=16384, V=32768 are 1GB written by the matmul and read
 straight back by the softmax, twice more in the backward). The chunked
 ``fused_lm_head_ce`` lax-loop (ops/fused_ops.py) already avoids holding
 every chunk at once but still materializes one [C, V] tile per step of a
-*sequential* scan — the MXU stalls on every chunk's HBM traffic.
+*sequential* scan, at 1.1 GB more peak HBM on the benchmark's GPT-2
+small cell (PR 40's A/B: PERF.md section 6).
 
-Here the whole loss is one flash-style kernel family:
+Here the whole loss is one flash-style kernel family of two kernels
+(PR 40; three until then: the backward ran as a dx and a dw kernel that
+each formed the logits tile again):
 
-- forward: a blocked online-softmax sweep over vocab tiles. For each
-  token block the kernel walks the vocab tiles, keeps running
-  (max, sum-exp, picked-logit) accumulators in VMEM, and writes only
-  three f32 row stats per token — the (block_n, block_v) logits tile
-  lives in VMEM only, *never* in HBM;
-- backward (custom VJP): two kernels rematerialize the logits tile
-  blockwise from the saved per-row logsumexp (exactly the flash
-  backward pattern in flash_attention.py): the dx pass keeps a
-  (block_n, D) accumulator and sweeps vocab tiles; the dw pass keeps a
-  (block_v, D) accumulator and sweeps token blocks. ``dW``/``dx`` are
-  accumulated in f32 and cast once at the end.
+- forward (``lmhead_ce_stats``): a blocked online-softmax sweep over
+  vocab tiles. For each token block the kernel walks the vocab tiles
+  and keeps a running (max, sum-exp) PER LANE in VMEM scratch: a tile's
+  128-column chunks fold into it elementwise, and the one cross-lane
+  reduction a row needs runs at the last tile. It writes two f32 row
+  stats per token; the (block_n, block_v) logits tile lives in VMEM
+  only, *never* in HBM. The logit at the label is not picked out of the
+  tiles: it is the f32 row dot ``x . w[label]`` beside the kernel;
+- backward (custom VJP, ``lmhead_ce_dw``: the name the benchmark's
+  pattern admits; it gives dx AND dW): ONE kernel rematerializes each
+  logits tile from the saved per-row logsumexp (the flash backward
+  pattern of flash_attention.py), forms ``dl = (softmax - onehot) * g``
+  once and feeds both products from it: three matmuls a tile. The grid
+  is (token blocks, vocab tiles), both sequential. dx accumulates in a
+  (block_n, D) f32 VMEM scratch across the vocab sweep; the f32 dW
+  accumulator (156 MB at GPT-2 small's vocabulary) cannot stay in VMEM,
+  so it lives in HBM and the kernel copies a (block_v, D) block of it
+  in, adds and copies it back around each tile's matmuls
+  (``make_async_copy``, two staging buffers). ``dW``/``dx`` are
+  accumulated in f32 and cast once at the end;
+- the vocab is padded up to a tile multiple, and only the LAST vocab
+  tile holds padded columns: both kernels carry the mask's compare and
+  select in a last-tile branch only;
+- tiles follow the call's (tokens, width) and a VMEM budget
+  (``tiles``); ``block_n`` / ``block_v`` are explicit overrides.
 
 Memory math (the README "Raw speed" section walks this): the naive path
 holds tokens*vocab logits (+ the same again as the backward's d_logits);
-the pallas path holds 3*tokens f32 of row stats — at the bench shapes
-that is 1GB+ vs 192KB, and the AOT ``memory_analysis`` peak of the
+the pallas path holds 2*tokens f32 of row stats — at the bench shapes
+that is 1GB+ vs 128KB, and the AOT ``memory_analysis`` peak of the
 ``lmhead_ce_fused_pallas`` OPBENCH row proves it.
 
 Tensor-parallel composition: under the recipe table's tp axis the
@@ -46,8 +63,7 @@ On non-TPU backends the kernels run under the pallas interpreter
 from __future__ import annotations
 
 import functools
-import math
-from typing import Optional, Sequence, Tuple
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -60,16 +76,45 @@ from .backend import compiler_params, on_tpu
 
 _NEG_INF = -1e30  # finite stand-in for -inf (inf-inf = nan in rescaling)
 
-# default tiles: (256, 512) keeps the fwd working set (x tile 384KB +
-# w tile 768KB + f32 score tile 512KB + stats) and the dw pass's
-# (block_v, D) f32 accumulator comfortably inside the 16MB scoped-vmem
-# budget at D=768 while feeding the MXU full 128-lane tiles
-DEFAULT_BLOCK_N = 256
-DEFAULT_BLOCK_V = 512
+# Tiles, preferred first, and what `_vmem_bytes` of a call's tiles may
+# reach: swept on a TPU v5e by PR 40 with tools/ce_sweep.py (PERF.md
+# section 6). Well inside the 64 MB a kernel may use (backend.VMEM_LIMIT)
+# there is a cliff: at D 768 the backward takes 42.8 ms at (1024, 768)
+# [29 MB by this count] and 66-67 at (1024, 1536) or (2048, 512) [49,
+# 40]; at D 1600 (1,024 tokens) 4.2 ms at (1024, 256) [29], 4.4 at
+# (512, 512) [24] and 5.5-5.9 at (1024, 512) [38]. The two widths
+# measured pad GPT-2's 50,304 rows to the same 50,688.
+_VMEM_BUDGET = 32 * 1024 * 1024
+_TILES = ((1024, 768), (1024, 256), (512, 512), (256, 512), (256, 256),
+          (256, 128))
 
 
 def _round_up(n: int, m: int) -> int:
     return ((n + m - 1) // m) * m
+
+
+def _vmem_bytes(bn: int, bv: int, d: int, itemsize: int) -> int:
+    """What the backward kernel, the larger of the two, keeps in VMEM at
+    a tile: the x, dx and w blocks double-buffered, the f32 dx
+    accumulator, two f32 staging buffers of a dW block, and the score
+    tile's f32 temporaries (s, p, dl and one to spare)."""
+    return (2 * 2 * bn * d * itemsize + 2 * bv * d * itemsize
+            + 4 * bn * d + 2 * 4 * bv * d + 4 * 4 * bn * bv)
+
+
+def tiles(n: int, d: int, v: int, itemsize: int = 2):
+    """(block_n, block_v) for a call of ``n`` tokens, width ``d``, vocab
+    ``v``: the first of ``_TILES`` under the VMEM budget at this width
+    (every token block streams all of w again, and the backward's HBM
+    accumulator once more, so tall blocks first), never larger than the
+    call, and no token block that pads the call by more than 1/16."""
+    bn, bv = next((t for t in _TILES
+                   if _vmem_bytes(*t, d, itemsize) <= _VMEM_BUDGET),
+                  _TILES[-1])
+    n8 = _round_up(max(n, 1), 8)
+    bns = [min(b, n8) for b in (1024, 512, 256) if b <= bn]
+    bn = next((b for b in bns if (_round_up(n, b) - n) * 16 <= n), bns[-1])
+    return bn, min(bv, _round_up(v, 128))
 
 
 def _cost_kwargs(flops: int, bytes_accessed: int, transcendentals: int = 0):
@@ -82,213 +127,280 @@ def _cost_kwargs(flops: int, bytes_accessed: int, transcendentals: int = 0):
         bytes_accessed=int(bytes_accessed))}
 
 
+def _scores(x_ref, w_ref):
+    """The (BN, BV) f32 logits tile — VMEM only, never HBM."""
+    return jax.lax.dot_general(
+        x_ref[...], w_ref[...], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+def _columns(shape, iv, block_v):
+    return iv * block_v + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+
+
+def _mask_last_tile(tile, iv, nv, ragged):
+    """Run ``tile(masked)``. The vocab is padded up to a tile multiple
+    and the padded columns must not reach the softmax, but only the LAST
+    vocab tile holds any: every other tile runs the copy of the body
+    without the compare and select."""
+    if not ragged:
+        tile(False)
+        return
+    pl.when(iv < nv - 1)(lambda: tile(False))
+    pl.when(iv == nv - 1)(lambda: tile(True))
+
+
 # ---------------------------------------------------------------- forward
 
 
-def _stats_kernel(x_ref, w_ref, lbl_ref, m_ref, l_ref, pk_ref,
-                  m_scr, l_scr, pk_scr, *, block_v, v_total):
-    """One token block x one vocab tile: online (max, sum-exp, picked)
-    update. Row stats live one lane each in (block_n, 128) VMEM scratch
-    (the flash_attention row-stat convention); outputs are (1, block_n)
-    row vectors written at the last vocab tile."""
+def _stats_kernel(x_ref, w_ref, m_ref, l_ref, m_scr, l_scr,
+                  *, block_v, v_total):
+    """One token block x one vocab tile: online (max, sum-exp) update.
+    The running statistics are kept PER LANE in (block_n, 128) scratch:
+    a tile folds its 128-column chunks into them elementwise, and the
+    one cross-lane reduction a row needs runs at the last vocab tile,
+    not on every tile. Outputs are (1, block_n) row vectors."""
     iv = pl.program_id(1)
     nv = pl.num_programs(1)
 
     @pl.when(iv == 0)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        pk_scr[:] = jnp.zeros_like(pk_scr)
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
 
-    x = x_ref[...]                       # (BN, D)
-    w = w_ref[...]                       # (BV, D)
-    s = jax.lax.dot_general(
-        x, w, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )                                    # (BN, BV) — VMEM only, never HBM
-    col = iv * block_v + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    lbl = lbl_ref[0]                     # (BN,) int32
-    hit = col == lbl[:, None]
-    if v_total % block_v:
-        # vocab padded up to a tile multiple: padded columns must not
-        # contribute to the softmax stats — NOR to picked (an
-        # out-of-shard label under tp can numerically land inside the
-        # padded range and must not pick up the mask value)
-        s = jnp.where(col < v_total, s, _NEG_INF)
-        hit = hit & (col < v_total)
-    pk_scr[:, :1] += jnp.sum(jnp.where(hit, s, 0.0), axis=-1, keepdims=True)
+    def tile(masked):
+        s = _scores(x_ref, w_ref)
+        if masked:
+            s = jnp.where(_columns(s.shape, iv, block_v) < v_total, s,
+                          _NEG_INF)
+        chunks = [s[:, c:c + 128] for c in range(0, block_v, 128)]
+        m_prev = m_scr[...]
+        m_new = functools.reduce(jnp.maximum, chunks, m_prev)
+        l_scr[...] = l_scr[...] * jnp.exp(m_prev - m_new) + sum(
+            jnp.exp(c - m_new) for c in chunks)
+        m_scr[...] = m_new
 
-    m_prev = m_scr[:, :1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    l_new = alpha * l_scr[:, :1] + jnp.sum(jnp.exp(s - m_new), axis=-1,
-                                           keepdims=True)
-    m_scr[:, :1] = m_new
-    l_scr[:, :1] = l_new
+    _mask_last_tile(tile, iv, nv, v_total % block_v)
 
     @pl.when(iv == nv - 1)
     def _finish():
-        m_ref[...] = jnp.swapaxes(m_scr[:, :1], 0, 1)     # (1, BN)
-        l_ref[...] = jnp.swapaxes(l_scr[:, :1], 0, 1)
-        pk_ref[...] = jnp.swapaxes(pk_scr[:, :1], 0, 1)
+        # a lane that only ever saw padding holds (_NEG_INF, count): its
+        # weight exp(_NEG_INF - m) is exactly 0
+        m_lane = m_scr[...]
+        m = jnp.max(m_lane, axis=-1, keepdims=True)
+        l = jnp.sum(l_scr[...] * jnp.exp(m_lane - m), axis=-1,
+                    keepdims=True)
+        m_ref[...] = jnp.swapaxes(m, 0, 1)                # (1, BN)
+        l_ref[...] = jnp.swapaxes(l, 0, 1)
 
 
-def _specs(bn, bv, d, swap_grid=False):
-    """(x tile, w tile, row-stat tile) BlockSpecs. The forward/dx grid is
-    (n-blocks, v-tiles); swap_grid flips it for the dw pass (v-tiles in
-    parallel, token blocks sequential)."""
-    if swap_grid:
-        ni = lambda iv, i_n: i_n
-        vi = lambda iv, i_n: iv
-    else:
-        ni = lambda i_n, iv: i_n
-        vi = lambda i_n, iv: iv
-    xspec = pl.BlockSpec((bn, d), lambda i, j: (ni(i, j), 0))
-    wspec = pl.BlockSpec((bv, d), lambda i, j: (vi(i, j), 0))
-    rspec = pl.BlockSpec((1, bn), lambda i, j: (0, ni(i, j)))
-    return xspec, wspec, rspec
-
-
-def _stats_call(x2d, w, lbl_row, block_n, block_v, v_total, interpret):
+def _stats_call(x2d, w, bn, bv, v_total, interpret):
     n, d = x2d.shape
     vp = w.shape[0]
-    bn, bv = min(block_n, n), min(block_v, vp)
-    grid = (n // bn, vp // bv)
-    xspec, wspec, rspec = _specs(bn, bv, d)
+    rspec = pl.BlockSpec((1, bn), lambda i, j: (0, i))
     stat = jax.ShapeDtypeStruct((1, n), jnp.float32)
-    m, l, pk = pl.pallas_call(
+    m, l = pl.pallas_call(
         functools.partial(_stats_kernel, block_v=bv, v_total=v_total),
-        grid=grid,
-        in_specs=[xspec, wspec, rspec],
-        out_specs=[rspec, rspec, rspec],
-        out_shape=[stat, stat, stat],
-        scratch_shapes=[pltpu.VMEM((bn, 128), jnp.float32)] * 3,
+        grid=(n // bn, vp // bv),
+        in_specs=[pl.BlockSpec((bn, d), lambda i, j: (i, 0)),
+                  pl.BlockSpec((bv, d), lambda i, j: (j, 0))],
+        out_specs=[rspec, rspec],
+        out_shape=[stat, stat],
+        scratch_shapes=[pltpu.VMEM((bn, 128), jnp.float32)] * 2,
         compiler_params=compiler_params(("parallel", "arbitrary")),
         interpret=interpret,
         name="lmhead_ce_stats",
         **_cost_kwargs(2 * n * vp * d,
-                       x2d.nbytes + w.nbytes + 3 * 4 * n,
+                       x2d.nbytes + (n // bn) * w.nbytes + 2 * 4 * n,
                        transcendentals=n * vp),
-    )(x2d, w, lbl_row)
-    return m[0], l[0], pk[0]
+    )(x2d, w)
+    return m[0], l[0]
+
+
+def _picked(x2d, w, lbl):
+    """The logit at each row's label as the f32 row dot x . w[label]
+    (bf16 products are exact in f32, as out of the MXU): N*D work beside
+    the kernel instead of a compare, select and row sum over every
+    (block_n, block_v) tile. A label outside [0, V) — another shard's
+    under vocab sharding — picks 0."""
+    v = w.shape[0]
+    ok = (lbl >= 0) & (lbl < v)
+    rows = jnp.take(w, jnp.where(ok, lbl, 0), axis=0)
+    pk = jnp.sum(x2d.astype(jnp.float32) * rows.astype(jnp.float32), axis=-1)
+    return jnp.where(ok, pk, 0.0)
 
 
 # ---------------------------------------------------------------- backward
 
 
-def _dx_kernel(x_ref, w_ref, lbl_ref, g_ref, lse_ref, dx_ref, dx_scr,
-               *, block_v, v_total):
-    """dx = (softmax - onehot) * g @ W, vocab tiles rematerialized from
-    the saved per-row logsumexp; (BN, D) f32 accumulator across the
-    vocab sweep."""
-    iv = pl.program_id(1)
-    nv = pl.num_programs(1)
+def _bwd_kernel(x_ref, w_ref, lbl_ref, g_ref, lse_ref, dx_ref, dw_ref,
+                *scratch, block_v, v_total, nn, nv):
+    """dx = dl @ W and dW = dl^T @ X from ONE rematerialised tile
+    dl = (softmax - onehot) * g, on a sequential (token blocks, vocab
+    tiles) grid. dx accumulates in VMEM scratch across the vocab sweep.
+    The f32 dW accumulator cannot stay in VMEM: it lives in HBM, and the
+    kernel copies a (block_v, D) block of it in, adds to it and copies it
+    back around each tile's matmuls, through two staging buffers. The
+    first token block writes and does not add (no zeros to make), and a
+    block's write-back is waited for two steps later, so it has landed
+    before the block is read again ``nv`` >= 2 steps on. With one vocab
+    tile the block never changes and stays a resident output block; with
+    one token block nothing accumulates and dW is written as it comes."""
+    i_n, iv = pl.program_id(0), pl.program_id(1)
+    by_hand = nn > 1 and nv > 1
+    if by_hand:
+        *scratch, stage0, stage1, rd_sem, wr_sem = scratch
+    step = i_n * nv + iv
 
-    @pl.when(iv == 0)
-    def _init():
-        dx_scr[:] = jnp.zeros_like(dx_scr)
+    def on_stage(fn, of_step=step):
+        """fn(staging buffer, read, write) with the copies HBM block ->
+        buffer and back, for the buffer of a step. The two buffers are
+        separate scratch arrays chosen by branch: Mosaic slices no
+        buffer whose rows are not whole lane tiles (D 1600)."""
+        block = dw_ref.at[pl.ds(iv * block_v, block_v)]
+        for s, stage in enumerate((stage0, stage1)):
+            pl.when(of_step % 2 == s)(functools.partial(
+                fn, stage,
+                lambda stage=stage, s=s: pltpu.make_async_copy(
+                    block, stage, rd_sem.at[s]),
+                lambda stage=stage, s=s: pltpu.make_async_copy(
+                    stage, block, wr_sem.at[s])))
 
-    x = x_ref[...]
-    w = w_ref[...]
-    s = jax.lax.dot_general(
-        x, w, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    col = iv * block_v + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    if v_total % block_v:
-        s = jnp.where(col < v_total, s, _NEG_INF)
-    lse_col = jnp.swapaxes(lse_ref[...], 0, 1)           # (BN, 1)
-    p = jnp.exp(s - lse_col)
-    hit = (col == lbl_ref[0][:, None]).astype(jnp.float32)
-    g_col = jnp.swapaxes(g_ref[...], 0, 1)               # (BN, 1)
-    dl = ((p - hit) * g_col).astype(w.dtype)             # (BN, BV) bf16
-    dx_scr[:] += jax.lax.dot_general(
-        dl, w, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    if by_hand:
+        def before(stage, read, write):
+            @pl.when(step >= 2)
+            def _():
+                write().wait()  # the write-back of two steps ago
 
-    @pl.when(iv == nv - 1)
-    def _finish():
-        dx_ref[...] = dx_scr[:].astype(dx_ref.dtype)
+            @pl.when(i_n > 0)
+            def _():
+                read().start()
+
+        on_stage(before)
+
+    def tile(masked):
+        w = w_ref[...]
+        s = _scores(x_ref, w_ref)
+        col = _columns(s.shape, iv, block_v)
+        if masked:
+            s = jnp.where(col < v_total, s, _NEG_INF)
+        p = jnp.exp(s - jnp.swapaxes(lse_ref[...], 0, 1))
+        hit = (col == lbl_ref[0][:, None]).astype(jnp.float32)
+        g_col = jnp.swapaxes(g_ref[...], 0, 1)               # (BN, 1)
+        dl = ((p - hit) * g_col).astype(w.dtype)             # (BN, BV)
+        dxc = jax.lax.dot_general(
+            dl, w, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)              # (BN, D)
+        # the transposed product as a contraction over dim 0 of both:
+        # Mosaic's transpose of the bf16 dl tile hides under the matmuls
+        dwc = jax.lax.dot_general(
+            dl, x_ref[...], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)              # (BV, D)
+
+        if nv == 1:
+            dx_ref[...] = dxc.astype(dx_ref.dtype)
+        else:
+            (dx_scr,) = scratch
+
+            @pl.when(iv == 0)
+            def _():
+                dx_scr[...] = dxc
+
+            @pl.when(iv > 0)
+            def _():
+                dx_scr[...] += dxc
+
+            @pl.when(iv == nv - 1)
+            def _():
+                dx_ref[...] = dx_scr[...].astype(dx_ref.dtype)
+
+        if nn == 1:
+            dw_ref[...] = dwc.astype(dw_ref.dtype)
+        elif nv == 1:
+            @pl.when(i_n == 0)
+            def _():
+                dw_ref[...] = dwc
+
+            @pl.when(i_n > 0)
+            def _():
+                dw_ref[...] += dwc
+        else:
+            def accumulate(stage, read, write):
+                # the buffers are as wide as the HBM accumulator, whose
+                # rows are padded up to whole lane tiles
+                @pl.when(i_n == 0)
+                def _():
+                    stage[:, :dwc.shape[1]] = dwc
+
+                @pl.when(i_n > 0)
+                def _():
+                    read().wait()
+                    stage[:, :dwc.shape[1]] += dwc
+
+                write().start()
+
+            on_stage(accumulate)
+
+    _mask_last_tile(tile, iv, nv, v_total % block_v)
+
+    if by_hand:
+        @pl.when(step == nn * nv - 1)
+        def _():
+            on_stage(lambda stage, read, write: write().wait())
+            on_stage(lambda stage, read, write: write().wait(), step - 1)
 
 
-def _dw_kernel(x_ref, w_ref, lbl_ref, g_ref, lse_ref, dw_ref, dw_scr,
-               *, block_v, v_total):
-    """dW = ((softmax - onehot) * g)^T @ X. k-major orientation (the
-    flash dkv trick): the score tile is built transposed as (BV, BN) so
-    every product is a standard (M,K)x(K,N) matmul, and the (1, BN) row
-    stats broadcast over the vocab rows with no transpose."""
-    iv, i_n = pl.program_id(0), pl.program_id(1)
-    nn = pl.num_programs(1)
-
-    @pl.when(i_n == 0)
-    def _init():
-        dw_scr[:] = jnp.zeros_like(dw_scr)
-
-    x = x_ref[...]                       # (BN, D)
-    w = w_ref[...]                       # (BV, D)
-    st = jax.lax.dot_general(
-        w, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )                                    # (BV, BN)
-    colr = iv * block_v + jax.lax.broadcasted_iota(jnp.int32, st.shape, 0)
-    if v_total % block_v:
-        st = jnp.where(colr < v_total, st, _NEG_INF)
-    pt = jnp.exp(st - lse_ref[...])      # (1, BN) broadcasts over rows
-    hit_t = (colr == lbl_ref[...]).astype(jnp.float32)
-    dlt = ((pt - hit_t) * g_ref[...]).astype(x.dtype)    # (BV, BN)
-    dw_scr[:] += jax.lax.dot_general(
-        dlt, x, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-    @pl.when(i_n == nn - 1)
-    def _finish():
-        dw_ref[...] = dw_scr[:].astype(dw_ref.dtype)
-
-
-def _dx_call(x2d, w, lbl_row, g_row, lse_row, block_n, block_v, v_total,
-             interpret):
+def _bwd_call(x2d, w, lbl_row, g_row, lse_row, bn, bv, v_total, interpret):
+    """(dx, dw) at the padded shapes. dx: x2d's dtype; dw: w's dtype."""
     n, d = x2d.shape
     vp = w.shape[0]
-    bn, bv = min(block_n, n), min(block_v, vp)
-    xspec, wspec, rspec = _specs(bn, bv, d)
-    return pl.pallas_call(
-        functools.partial(_dx_kernel, block_v=bv, v_total=v_total),
-        grid=(n // bn, vp // bv),
+    nn, nv = n // bn, vp // bv
+    xspec = pl.BlockSpec((bn, d), lambda i, j: (i, 0))
+    wspec = pl.BlockSpec((bv, d), lambda i, j: (j, 0))
+    rspec = pl.BlockSpec((1, bn), lambda i, j: (0, i))
+    # dW: cast in the kernel where one token block is all there is, else
+    # an f32 accumulator: a resident block (one vocab tile), or in HBM
+    # with rows of whole lane tiles, which is all Mosaic copies by hand
+    dw_spec = wspec
+    dw_shape = jax.ShapeDtypeStruct(
+        (vp, d), w.dtype if nn == 1 else jnp.float32)
+    scratch = [pltpu.VMEM((bn, d), jnp.float32)] if nv > 1 else []
+    if nn > 1 and nv > 1:
+        dp = _round_up(d, 128)
+        dw_spec = pl.BlockSpec(memory_space=pl.ANY)
+        dw_shape = jax.ShapeDtypeStruct((vp, dp), jnp.float32)
+        scratch += [pltpu.VMEM((bv, dp), jnp.float32)] * 2
+        scratch += [pltpu.SemaphoreType.DMA((2,))] * 2
+    dx, dw = pl.pallas_call(
+        functools.partial(_bwd_kernel, block_v=bv, v_total=v_total,
+                          nn=nn, nv=nv),
+        grid=(nn, nv),
         in_specs=[xspec, wspec, rspec, rspec, rspec],
-        out_specs=[xspec],
-        out_shape=[jax.ShapeDtypeStruct(x2d.shape, x2d.dtype)],
-        scratch_shapes=[pltpu.VMEM((bn, d), jnp.float32)],
-        compiler_params=compiler_params(("parallel", "arbitrary")),
-        interpret=interpret,
-        name="lmhead_ce_dx",
-        **_cost_kwargs(4 * n * vp * d, 2 * x2d.nbytes + w.nbytes,
-                       transcendentals=n * vp),
-    )(x2d, w, lbl_row, g_row, lse_row)[0]
-
-
-def _dw_call(x2d, w, lbl_row, g_row, lse_row, block_n, block_v, v_total,
-             interpret):
-    n, d = x2d.shape
-    vp = w.shape[0]
-    bn, bv = min(block_n, n), min(block_v, vp)
-    xspec, wspec, rspec = _specs(bn, bv, d, swap_grid=True)
-    return pl.pallas_call(
-        functools.partial(_dw_kernel, block_v=bv, v_total=v_total),
-        grid=(vp // bv, n // bn),
-        in_specs=[xspec, wspec, rspec, rspec, rspec],
-        out_specs=[wspec],
-        out_shape=[jax.ShapeDtypeStruct(w.shape, w.dtype)],
-        scratch_shapes=[pltpu.VMEM((bv, d), jnp.float32)],
-        compiler_params=compiler_params(("parallel", "arbitrary")),
+        out_specs=[xspec, dw_spec],
+        out_shape=[jax.ShapeDtypeStruct((n, d), x2d.dtype), dw_shape],
+        scratch_shapes=scratch,
+        compiler_params=compiler_params(("arbitrary", "arbitrary")),
         interpret=interpret,
         name="lmhead_ce_dw",
-        **_cost_kwargs(4 * n * vp * d, x2d.nbytes + 2 * w.nbytes,
+        **_cost_kwargs(6 * n * vp * d,
+                       2 * x2d.nbytes + nn * (w.nbytes + 8 * vp * d),
                        transcendentals=n * vp),
-    )(x2d, w, lbl_row, g_row, lse_row)[0]
+    )(x2d, w, lbl_row, g_row, lse_row)
+    return dx, dw[:, :d].astype(w.dtype)
 
 
 # ---------------------------------------------------------------- custom vjp
 
 
-def _clamp_blocks(n: int, v: int, block_n: int, block_v: int):
-    bn = min(int(block_n), _round_up(max(n, 1), 8))
-    bv = min(int(block_v), _round_up(v, 128))
+def _blocks(x2d, w, block_n, block_v):
+    """The call's tiles: by shape (``tiles``), or the explicit override
+    clamped to the call."""
+    (n, d), v = x2d.shape, w.shape[0]
+    bn, bv = tiles(n, d, v, x2d.dtype.itemsize)
+    if block_n:
+        bn = min(int(block_n), _round_up(max(n, 1), 8))
+    if block_v:
+        bv = min(int(block_v), _round_up(v, 128))
     return bn, bv
 
 
@@ -322,12 +434,13 @@ def _shift_labels(lbl, w, axis_name):
 def _run_fwd(x2d, w, lbl, axis_name, block_n, block_v, interpret):
     """Padded forward sweep (+ cross-shard combine): (nll, lse), both at
     the caller's unpadded token count."""
-    n, _ = x2d.shape
-    bn, bv = _clamp_blocks(n, w.shape[0], block_n, block_v)
+    bn, bv = _blocks(x2d, w, block_n, block_v)
     lbl = _shift_labels(lbl.astype(jnp.int32), w, axis_name)
-    xp, lblp, n = _pad_tokens(x2d, lbl, bn)
+    pk = _picked(x2d, w, lbl)
+    xp, _, n = _pad_tokens(x2d, lbl, bn)
     wp, v_real = _pad_vocab(w, bv)
-    m, l, pk = _stats_call(xp, wp, lblp[None, :], bn, bv, v_real, interpret)
+    m, l = _stats_call(xp, wp, bn, bv, v_real, interpret)
+    m, l = m[:n], l[:n]
     if axis_name:
         # combine the per-shard partial stats across the vocab (tp)
         # axis: one pmax for the running max, one psum for the (rescaled
@@ -338,15 +451,14 @@ def _run_fwd(x2d, w, lbl, axis_name, block_n, block_v, interpret):
         l, pk = lp[0], lp[1]
         m = mg
     lse = m + jnp.log(jnp.where(l > 0.0, l, 1.0))
-    return (lse - pk)[:n], lse[:n]
+    return lse - pk, lse
 
 
 def _run_bwd(x2d, w, lbl, lse, g, axis_name, block_n, block_v, interpret):
-    """Padded backward kernels: (dx, dw) with dx at the caller's token
+    """Padded backward kernel: (dx, dw) with dx at the caller's token
     count and dw covering the local (unpadded) vocab rows. No
     collectives here — the caller owns every cross-shard reduction."""
-    n, _ = x2d.shape
-    bn, bv = _clamp_blocks(n, w.shape[0], block_n, block_v)
+    bn, bv = _blocks(x2d, w, block_n, block_v)
     lbl = _shift_labels(lbl.astype(jnp.int32), w, axis_name)
     xp, lblp, n = _pad_tokens(x2d, lbl, bn)
     wp, v_real = _pad_vocab(w, bv)
@@ -355,10 +467,8 @@ def _run_bwd(x2d, w, lbl, lse, g, axis_name, block_n, block_v, interpret):
     # all-zero x rows contribute nothing to either gradient
     g_row = jnp.pad(g.astype(jnp.float32), (0, np_ - n))[None, :]
     lse_row = jnp.pad(lse, (0, np_ - n))[None, :]
-    dx = _dx_call(xp, wp, lblp[None, :], g_row, lse_row, bn, bv, v_real,
-                  interpret)
-    dw = _dw_call(xp, wp, lblp[None, :], g_row, lse_row, bn, bv, v_real,
-                  interpret)
+    dx, dw = _bwd_call(xp, wp, lblp[None, :], g_row, lse_row, bn, bv,
+                       v_real, interpret)
     return dx[:n], dw[:v_real]
 
 
@@ -386,8 +496,8 @@ def _ce_local_bwd(block_n, block_v, interpret, res, g):
 _ce_local.defvjp(_ce_local_fwd, _ce_local_bwd)
 
 
-def lmhead_ce(x2d, w, labels, block_n: int = DEFAULT_BLOCK_N,
-              block_v: int = DEFAULT_BLOCK_V,
+def lmhead_ce(x2d, w, labels, block_n: Optional[int] = None,
+              block_v: Optional[int] = None,
               interpret: Optional[bool] = None):
     """Per-token NLL of ``softmax(x2d @ w^T)`` at ``labels`` without ever
     materializing the [tokens, vocab] logits. x2d: (N, D); w: (V, D)
@@ -396,8 +506,7 @@ def lmhead_ce(x2d, w, labels, block_n: int = DEFAULT_BLOCK_N,
     vocab may be arbitrary (padded up to tile multiples internally)."""
     if interpret is None:
         interpret = not on_tpu()
-    return _ce_local(x2d, w, labels, int(block_n), int(block_v),
-                     bool(interpret))
+    return _ce_local(x2d, w, labels, block_n, block_v, bool(interpret))
 
 
 # -- mesh entry (manual SPMD region inside a GSPMD program) -----------------
@@ -489,8 +598,8 @@ def lmhead_ce_sharded(x2d, w, labels, mesh,
                       batch_axes: Sequence[str] = (),
                       vocab_axis: Optional[str] = None,
                       gather_axis: Optional[str] = None,
-                      block_n: int = DEFAULT_BLOCK_N,
-                      block_v: int = DEFAULT_BLOCK_V,
+                      block_n: Optional[int] = None,
+                      block_v: Optional[int] = None,
                       interpret: Optional[bool] = None):
     """The mesh-program composition: run the fused CE as a manual-SPMD
     region inside the surrounding GSPMD program (GSPMD cannot partition
@@ -510,5 +619,5 @@ def lmhead_ce_sharded(x2d, w, labels, mesh,
         interpret = not on_tpu()
     cfg = (mesh, tuple(a for a in batch_axes if a),
            vocab_axis or None, gather_axis or None,
-           int(block_n), int(block_v), bool(interpret))
+           block_n, block_v, bool(interpret))
     return _ce_sharded(x2d, w, labels.astype(jnp.int32), cfg)

@@ -32,13 +32,17 @@ from paddle_tpu.ops.pallas.fused_lmhead_ce import (lmhead_ce,
 
 
 def _ref_nll(x, w, lbl):
+    """Materialized logits; a label outside [0, V) picks nothing (what an
+    out-of-shard label does under vocab sharding)."""
     logits = jax.lax.dot_general(
         x, w, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
     lse = jax.nn.logsumexp(logits, axis=-1)
+    lbl = lbl.astype(jnp.int32)
+    ok = (lbl >= 0) & (lbl < w.shape[0])
     picked = jnp.take_along_axis(
-        logits, lbl[:, None].astype(jnp.int32), axis=1)[:, 0]
-    return lse - picked
+        logits, jnp.where(ok, lbl, 0)[:, None], axis=1)[:, 0]
+    return lse - jnp.where(ok, picked, 0.0)
 
 
 def _data(n, d, v, dtype=jnp.float32, seed=0):
@@ -54,50 +58,147 @@ def _data(n, d, v, dtype=jnp.float32, seed=0):
 # kernel vs reference
 # ---------------------------------------------------------------------------
 
+_F32 = dict(rtol=1e-4, atol=1e-5)
+_BF16 = dict(rtol=0.05, atol=0.05)
+_NLL_F32 = dict(rtol=1e-5, atol=1e-5)
+_NLL_BF16 = dict(rtol=2e-3, atol=2e-3)
+_DTYPES = [(jnp.float32, _NLL_F32, _F32), (jnp.bfloat16, _NLL_BF16, _BF16)]
 
-@pytest.mark.parametrize("n,d,v", [(64, 64, 512), (48, 64, 300),
-                                   (33, 32, 130)])
-def test_kernel_matches_reference_fp32(n, d, v):
-    """Forward + both gradients against the materialized-logits path;
-    the (48, 300) and (33, 130) shapes force the token AND vocab padding
-    paths (labels near the padded boundary must not pick mask values)."""
-    x, w, lbl, g = _data(n, d, v)
-    nll = lmhead_ce(x, w, lbl, block_n=16, block_v=128)
+
+def _check_against_reference(x, w, lbl, g, nll_tol, grad_tol, **blocks):
+    nll = lmhead_ce(x, w, lbl, **blocks)
     np.testing.assert_allclose(np.asarray(nll), np.asarray(
-        _ref_nll(x, w, lbl)), rtol=1e-5, atol=1e-5)
-
-    f = lambda x, w: jnp.vdot(lmhead_ce(x, w, lbl, block_n=16,
-                                        block_v=128), g)
+        _ref_nll(x, w, lbl)), **nll_tol)
+    f = lambda x, w: jnp.vdot(lmhead_ce(x, w, lbl, **blocks), g)
     fr = lambda x, w: jnp.vdot(_ref_nll(x, w, lbl), g)
     dx, dw = jax.grad(f, argnums=(0, 1))(x, w)
     dxr, dwr = jax.grad(fr, argnums=(0, 1))(x, w)
-    np.testing.assert_allclose(np.asarray(dx), np.asarray(dxr),
-                               rtol=1e-4, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(dw), np.asarray(dwr),
-                               rtol=1e-4, atol=1e-5)
+    assert dx.dtype == x.dtype and dw.dtype == w.dtype
+    np.testing.assert_allclose(np.asarray(dx, np.float32),
+                               np.asarray(dxr, np.float32), **grad_tol)
+    np.testing.assert_allclose(np.asarray(dw, np.float32),
+                               np.asarray(dwr, np.float32), **grad_tol)
 
 
-def test_kernel_matches_reference_bf16():
+# (n, d, v, block_n, block_v); the grid of the fused backward is (token
+# blocks, vocab tiles), the dW accumulator lives in HBM and is read back
+# once a token block and vocab tile after the first block
+_SHAPES = [
+    (64, 64, 512, 16, 128),    # 4 x 4: every dW block read back 3 times
+    (48, 64, 300, 16, 128),    # tokens AND vocab no tile multiple
+    (33, 32, 130, 16, 128),    # 2 vocab tiles: a block is read back one
+                               # step after the write-back before it
+    (64, 64, 128, 16, 128),    # 1 vocab tile: the block stays resident
+    (64, 64, 256, 16, 128),    # ... and the same at two
+    (24, 64, 300, 64, 128),    # n < block_n (fsdp4: one token block,
+                               # nothing is read back)
+    (24, 64, 100, 64, 128),    # one token block x one vocab tile
+    (16, 1600, 384, 8, 128),   # GPT-2 XL's width: not a lane multiple
+    (40, 64, 300, None, None),  # tiles by shape, clamped to the call
+]
+
+
+@pytest.mark.parametrize("n,d,v,block_n,block_v", _SHAPES)
+def test_kernel_matches_reference_fp32(n, d, v, block_n, block_v):
+    """Forward + both gradients of the fused backward against the
+    materialized-logits path; labels near the padded boundary must not
+    pick mask values."""
+    x, w, lbl, g = _data(n, d, v)
+    _check_against_reference(x, w, lbl, g, _NLL_F32, _F32,
+                             block_n=block_n, block_v=block_v)
+
+
+@pytest.mark.parametrize("n,d,v,block_n,block_v", _SHAPES[:3])
+def test_kernel_matches_reference_bf16(n, d, v, block_n, block_v):
     """bf16 inputs at the dtype-aware tolerance floor: the kernel and
     the reference both matmul in bf16 with f32 accumulation, so the
     loss agrees at f32 resolution while grads (cast back to bf16)
     agree at bf16 resolution."""
-    x, w, lbl, g = _data(64, 64, 512, dtype=jnp.bfloat16)
-    nll = lmhead_ce(x, w, lbl, block_n=16, block_v=128)
-    np.testing.assert_allclose(
-        np.asarray(nll), np.asarray(_ref_nll(x, w, lbl)),
-        rtol=2e-3, atol=2e-3)
+    x, w, lbl, g = _data(n, d, v, dtype=jnp.bfloat16)
+    _check_against_reference(x, w, lbl, g, _NLL_BF16, _BF16,
+                             block_n=block_n, block_v=block_v)
+
+
+@pytest.mark.parametrize("dtype,nll_tol,tol", _DTYPES)
+def test_label_in_the_padded_range_picks_nothing(dtype, nll_tol, tol):
+    """v = 300 pads to 384 columns: a label of 300..383 (what another
+    shard's label can look like under vocab sharding) and a negative one
+    match no column: the loss is the logsumexp alone and d_logits the
+    softmax alone, in the kernels' last-tile branch and outside it."""
+    x, w, lbl, g = _data(48, 64, 300, dtype=dtype, seed=5)
+    lbl = lbl.at[3].set(300).at[17].set(383).at[20].set(-84)
+    _check_against_reference(x, w, lbl, g, nll_tol, tol,
+                             block_n=16, block_v=128)
+
+
+# the loss and gradients the TWO backward kernels of the parent of PR 40
+# (679b9a8: lmhead_ce_dx + lmhead_ce_dw, each forming its own logits
+# tile) gave for _data(48, 64, 300, seed=11) at tiles (16, 128): the first
+# six losses and their sum, six entries of dx[5] and of dw[lbl[5]], and
+# the gradients' absolute sums
+_TWO_KERNELS = {
+    "float32": {
+        "nll": [8.010916709899902, 11.251486778259277, 4.567679405212402,
+                9.408699989318848, 7.758273601531982, 2.9175190925598145],
+        "nll_sum": 360.6402587890625,
+        "dx": [-0.015014749020338058, 0.47870463132858276,
+               0.6962459683418274, -0.12962190806865692,
+               -0.4277999699115753, -0.13631463050842285],
+        "dx_abs": 929.9119873046875,
+        "dw": [-1.222535490989685, 1.540732502937317, -0.10366442799568176,
+               0.8198438882827759, -0.44570398330688477,
+               -0.12794071435928345],
+        "dw_abs": 1263.200927734375},
+    "bfloat16": {
+        "nll": [8.01668643951416, 11.24679946899414, 4.567939281463623,
+                9.403664588928223, 7.753928184509277, 2.91546630859375],
+        "nll_sum": 360.5628662109375,
+        "dx": [-0.01171875, 0.4765625, 0.6953125, -0.12890625,
+               -0.427734375, -0.1337890625],
+        "dx_abs": 929.9776611328125,
+        "dw": [-1.2265625, 1.5390625, -0.103515625, 0.81640625,
+               -0.443359375, -0.1279296875],
+        "dw_abs": 1263.19677734375},
+}
+
+
+@pytest.mark.parametrize("dtype,nll_tol,tol", _DTYPES)
+def test_fused_backward_equals_the_two_kernel_result(dtype, nll_tol, tol):
+    want = _TWO_KERNELS[jnp.dtype(dtype).name]
+    x, w, lbl, g = _data(48, 64, 300, dtype=dtype, seed=11)
+    nll = np.asarray(lmhead_ce(x, w, lbl, block_n=16, block_v=128))
     f = lambda x, w: jnp.vdot(lmhead_ce(x, w, lbl, block_n=16,
                                         block_v=128), g)
-    fr = lambda x, w: jnp.vdot(_ref_nll(x, w, lbl), g)
-    dx, dw = jax.grad(f, argnums=(0, 1))(x, w)
-    dxr, dwr = jax.grad(fr, argnums=(0, 1))(x, w)
-    np.testing.assert_allclose(
-        np.asarray(dx, np.float32), np.asarray(dxr, np.float32),
-        rtol=0.05, atol=0.05)
-    np.testing.assert_allclose(
-        np.asarray(dw, np.float32), np.asarray(dwr, np.float32),
-        rtol=0.05, atol=0.05)
+    dx, dw = (np.asarray(a, np.float32)
+              for a in jax.grad(f, argnums=(0, 1))(x, w))
+    np.testing.assert_allclose(nll[:6], want["nll"], **nll_tol)
+    np.testing.assert_allclose(nll.sum(), want["nll_sum"], rtol=1e-4)
+    np.testing.assert_allclose(dx[5, :6], want["dx"], **tol)
+    np.testing.assert_allclose(dw[int(lbl[5]), :6], want["dw"], **tol)
+    np.testing.assert_allclose(np.abs(dx).sum(), want["dx_abs"], rtol=2e-3)
+    np.testing.assert_allclose(np.abs(dw).sum(), want["dw_abs"], rtol=2e-3)
+
+
+def test_tiles_follow_the_shape():
+    """The dispatcher picks tiles from (tokens, width) and a VMEM budget
+    (PR 40's sweep on a v5e): the tallest token block at GPT-2 small's
+    width, a narrower vocab tile at GPT-2 XL's (where (1024, 512) ran a
+    third slower than (1024, 256)), at GPT-2 small's a vocab tile under
+    which the vocabulary pads to the 50,688 rows the benchmark's
+    shape-based metric looks for in gpt2s-train-1k, no token block that
+    pads a call by more than 1/16."""
+    from paddle_tpu.ops.pallas.fused_lmhead_ce import (_VMEM_BUDGET,
+                                                       _vmem_bytes, tiles)
+
+    assert tiles(32768, 768, 50304) == (1024, 768)
+    assert tiles(1024, 1600, 50304) == (1024, 256)
+    assert -(-50304 // 768) * 768 == 50688
+    for d in (768, 1600, 4096):
+        bn, bv = tiles(32768, d, 50304)
+        assert bn % 128 == 0 and _vmem_bytes(bn, bv, d, 2) <= _VMEM_BUDGET
+    assert tiles(32768, 4096, 50304)[0] < 1024
+    assert tiles(40, 64, 300) == (40, 384)     # clamped to the call
+    assert tiles(1500, 768, 50304)[0] == 512   # 1024 would pad 1500 to 2048
 
 
 def test_kernel_loss_decreases_under_sgd():

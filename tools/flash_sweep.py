@@ -172,9 +172,11 @@ def sweep_step(a):
         print(f"step {spec}: {lines[-1][5:] if lines else 'FAILED ' + out.stderr[-500:]}", flush=True)
 
 
-def one_step(a):
+def one_step(a, prepare=None):
     """GPT-2 small (12 layers, vocab 50304, Adam) at (batch, seq) under
-    whatever PADDLE_TPU_FLASH_* the environment holds: mean step time."""
+    whatever PADDLE_TPU_FLASH_* the environment holds: mean step time.
+    ``prepare(main)`` may edit the forward program before the optimizer
+    appends its backward (tools/ce_sweep.py sets the CE op's tiles)."""
     import jax
 
     import paddle_tpu as paddle
@@ -188,6 +190,8 @@ def one_step(a):
     cfg = GPTConfig(vocab_size=50304, n_layer=12, n_head=a.heads, d_model=a.heads * a.head_dim,
                     max_seq_len=max(a.seq, 1024), dropout=0.0, dtype="bfloat16")
     main, startup, io = build_train_program(cfg, batch=a.batch, seq=a.seq)
+    if prepare is not None:
+        prepare(main)
     with program_guard(main, startup):
         Adam(learning_rate=1e-4).minimize(io["loss"])
     scope, exe = Scope(), Executor()
