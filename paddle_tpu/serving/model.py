@@ -24,7 +24,10 @@ path —
   ``ops/pallas/paged_attention`` reads the pages a context occupies
   where they lie; a mesh program (GSPMD cannot partition a Mosaic call)
   and a pool whose row the runtime would pad gather the whole window
-  instead (``DecodeModel.attention_path``). A tick is two calls,
+  instead (``DecodeModel.attention_path``). Where the embedding table
+  rests with its vocabulary on the lanes the tick takes each slot's row
+  by a slice of its own, not by a gather that would first copy the whole
+  table (``DecodeModel.embed_path``). A tick is two calls,
   ``decode_enqueue`` and ``decode_read``, and takes a slot's last token
   from the host or, unread, from the output of the tick before it
   (``prev``): the engine enqueues tick N+1 before it reads tick N.
@@ -92,7 +95,25 @@ _M_BOOT = _monitor.gauge(
 
 _NEG = -1e30  # finite mask value: garbage behind it stays non-NaN
 _SUBLANES = 8  # rows of one (8, 128) tile, the unit the TPU lays arrays out in
+_LANES = 128  # its columns
 _FFN_ROWS = 512  # positions full_logits puts through a feed-forward at once
+
+
+def rests_lanes_first(shape: Tuple[int, int]) -> bool:
+    """Whether the TPU runtime stores a ``[rows, cols]`` array with its
+    ROWS on the lanes (``{0,1}``): it picks the most compact tiled layout
+    for the shape, each dimension padded to whole (8, 128) tiles, and
+    row-major where neither is smaller. ``[50304, 1600]`` (1600 = 12.5 x
+    128, 50304 = 393 x 128) rests so; ``[65536, 2048]`` and anything whose
+    columns are whole lane tiles rest row-major. A matmul contracts such a
+    table as it lies; a row gather first copies ALL of it to row-major
+    (tests/test_tpu_aot_compile.py holds the rule to the compiler)."""
+    def tiled(sublanes, lanes):
+        return (-(-sublanes // _SUBLANES) * _SUBLANES
+                * -(-lanes // _LANES) * _LANES)
+
+    rows, cols = shape
+    return tiled(cols, rows) < tiled(rows, cols)
 
 
 def param_table(cfg: GPTConfig) -> Dict[str, Tuple[tuple, float]]:
@@ -218,6 +239,20 @@ def _kv_rows(k, v):
 
     kv = jnp.stack([k, v], axis=-2)  # [..., H, 2, hd]
     return kv.reshape(kv.shape[:-3] + (-1,))
+
+
+def _row_slices(table, idx):
+    """``table[idx]`` for ``idx`` ``[B]`` in bounds, as B slices of one
+    row each: a slice reads its row out of the table as it lies, whatever
+    its layout. A loop on purpose: under ``vmap`` the slices are one
+    gather again, and the copy of the table is back."""
+    import jax
+    import jax.numpy as jnp
+
+    return jnp.concatenate([
+        jax.lax.dynamic_slice(table, (idx[b], 0), (1, table.shape[1]),
+                              allow_negative_indices=False)
+        for b in range(idx.shape[0])])
 
 
 _LAYER = "gpt.h"  # a layer's parameters inside the traced layer body
@@ -551,11 +586,33 @@ class DecodeModel:
                         approximate=False)
         return self._linear(p, h, f"{ln}.mlp.fc_out")
 
-    def _embed(self, p, tokens, pos):
+    def embed_path(self) -> Tuple[str, str]:
+        """How the decode program looks up its ``max_batch`` token rows,
+        and why: ``("slices", reason)`` is one ``dynamic_slice`` a slot,
+        for a table that rests with the vocabulary on the lanes
+        (:func:`rests_lanes_first`), which a gather would first copy
+        whole, every tick; ``("gather", reason)`` is one gather over a
+        table that rests row-major. Decided by what the model can see of
+        itself, the same on every backend. Prefill and ``score`` always
+        gather: a bucket's worth of row slices of such a table reads as
+        much as the copy does."""
+        v, d = self.cfg.vocab_size, self.cfg.d_model
+        if self.mesh is not None:
+            return "gather", "a mesh program: the recipe places the table"
+        if rests_lanes_first((v, d)):
+            return "slices", (f"the [{v}, {d}] table rests vocabulary-on-"
+                              f"lanes: {d} is no multiple of {_LANES}")
+        return "gather", f"the [{v}, {d}] table rests row-major"
+
+    def _embed(self, p, tokens, pos, slices: bool = False):
         """Token rows, plus the learned position rows where the block has
-        them (``pos`` broadcasts against ``tokens``)."""
-        x = p["gpt.wte"][tokens]
-        return x + p["gpt.wpe"][pos] if self.cfg.position == "learned" else x
+        them (``pos`` broadcasts against ``tokens``). ``slices``: a row at
+        a time (``tokens`` and ``pos`` ``[B]``), the same bits."""
+        rows = _row_slices if slices else (lambda table, idx: table[idx])
+        x = rows(p["gpt.wte"], tokens)
+        if self.cfg.position == "learned":
+            x = x + rows(p["gpt.wpe"], pos)
+        return x
 
     def _rot(self, pos):
         """What :func:`_rope` turns heads at positions ``pos`` [...] by:
@@ -945,6 +1002,7 @@ class DecodeModel:
 
         kernel = self.attention_path()[0] == "kernel"
         attend = attend_paged if kernel else attend_gathered
+        sliced = self.embed_path()[0] == "slices"
 
         def traced(kind):
             op, mlp = kind
@@ -981,7 +1039,7 @@ class DecodeModel:
             tokens = jnp.where(tokens < 0, prev[:B], tokens)
             pos = context_lens  # [B]: the new token's position
             with jax.named_scope("embed"):
-                x = self._embed(p, tokens, pos)  # [B, D]
+                x = self._embed(p, tokens, pos, sliced)  # [B, D]
             blk = block_tables[barange, pos // BS]  # [B]
             slot = pos % BS
             # the gathered window's mask; the kernel masks by `pos`

@@ -606,6 +606,116 @@ def test_one_tick_in_flight_edges(tiny_model, monkeypatch, case):
     case(tiny_model, monkeypatch)
 
 
+# -- the decode tick's row lookup: a slice a slot where the table rests
+# vocabulary-on-lanes (DecodeModel.embed_path; PERF.md, PR 47) --------------
+
+
+def _lookup_model(position="learned", dtype="float32", gather=False):
+    """d_model 48 (no multiple of 128) under a lane-aligned vocabulary:
+    the decode tick takes its rows by slices. ``gather``: the same model
+    made to keep the one gather, as a table that rests row-major would."""
+    cfg = serving.GPTConfig(vocab_size=256, n_layer=2, n_head=2, d_model=48,
+                            max_seq_len=64, position=position, dtype=dtype)
+    dm = serving.DecodeModel(cfg, max_batch=8, n_blocks=40, block_size=8,
+                             prefill_buckets=[16, 32], seed=5)
+    assert dm.embed_path()[0] == "slices"
+    if gather:
+        dm.embed_path = lambda: ("gather", "the test's")
+    return dm
+
+
+@pytest.mark.parametrize("position,dtype", [
+    ("learned", "float32"), ("learned", "bfloat16"),
+    ("rope", "float32"), ("rope", "bfloat16")])
+def test_row_slices_are_the_gathers_rows_bit_for_bit(position, dtype):
+    """Repeated tokens, token 0 and the last vocabulary row; positions
+    likewise; a ``rope`` model has no ``wpe`` to look up."""
+    import jax
+
+    dm = _lookup_model(position, dtype)
+    p = dm.params
+    assert ("gpt.wpe" in p) == (position == "learned")
+    tokens = np.asarray([0, 255, 7, 7, 255, 0, 3, 200], np.int32)
+    pos = np.asarray([0, 63, 5, 5, 63, 1, 0, 31], np.int32)
+    want = np.asarray(p["gpt.wte"])[tokens]
+    if position == "learned":
+        want = want + np.asarray(p["gpt.wpe"])[pos]
+    for sliced in (True, False):
+        got = jax.jit(lambda p, t, q: dm._embed(p, t, q, sliced))(
+            p, tokens, pos)
+        assert got.dtype == want.dtype and got.shape == (8, 48)
+        assert np.array_equal(np.asarray(got), want), sliced
+
+
+def test_embed_path_follows_from_the_tables_shape():
+    """The same rule on every backend, no knob: lanes that are no whole
+    tiles under a vocabulary that is; a mesh program keeps the gather."""
+    from paddle_tpu.serving.model import rests_lanes_first
+
+    assert rests_lanes_first((50304, 1600)) and rests_lanes_first((1024, 1600))
+    assert not rests_lanes_first((65536, 2048)) and not rests_lanes_first((50304, 2048))
+    assert not rests_lanes_first((512, 256))  # neither tiling is smaller
+    path, why = _lookup_model().embed_path()
+    assert path == "slices" and "vocabulary-on-lanes" in why and "48" in why
+    wide = serving.DecodeModel(
+        serving.GPTConfig(vocab_size=256, n_layer=1, n_head=2, d_model=128,
+                          max_seq_len=64),
+        max_batch=2, n_blocks=8, block_size=16, prefill_buckets=[16])
+    path, why = wide.embed_path()
+    assert path == "gather" and "row-major" in why
+    path, why = serving.DecodeModel(
+        serving.GPTConfig(vocab_size=256, n_layer=1, n_head=2, d_model=48,
+                          max_seq_len=64),
+        max_batch=2, n_blocks=8, block_size=16, prefill_buckets=[16],
+        recipe="tp").embed_path()
+    assert path == "gather" and "mesh" in why
+
+
+@pytest.mark.parametrize("position", ["learned", "rope"])
+def test_decode_tick_returns_the_same_tokens_on_either_path(position):
+    """One tick, slot by slot: a ``-1`` slot takes the tick before's own
+    output (``prev``) in front of the lookup, and the tokens that come
+    back are those of the one gather."""
+    import jax.numpy as jnp
+
+    B = 8
+    tables = np.zeros((B, 8), np.int32)
+    lens = np.asarray([3, 0, 5, 1, 0, 7, 2, 4], np.int32)
+    unread = np.asarray([-1, 5, -1, 0, 255, -1, 9, 9], np.int32)
+    prev = np.asarray([255, 1, 0, 1, 1, 17, 1, 1], np.int32)
+    read = np.where(unread < 0, prev, unread)
+    out = {}
+    for gather in (False, True):
+        dm = _lookup_model(position, gather=gather)
+        for name, toks, pv in (("unread", unread, jnp.asarray(prev)),
+                               ("read", read, None)):
+            _, _, nxt, _ = dm.decode_enqueue(dm.init_pages(), None, tables,
+                                             lens, toks, pv)
+            out[gather, name] = dm.decode_read(nxt)[0]
+    first = out[False, "unread"]
+    assert first.shape == (B,) and len(set(first.tolist())) > 1
+    for key, toks in out.items():
+        assert np.array_equal(toks, first), key
+
+
+def test_engine_answers_are_the_same_on_either_path():
+    """Whole answers through the engine (prefill, then ticks enqueued on
+    unread tokens): slices and gather serve the same tokens, the greedy
+    ones of the non-paged forward."""
+    r = np.random.RandomState(2)
+    prompts = [list(r.randint(0, 256, size=n)) for n in (5, 11, 7, 14)]
+    answers = []
+    for gather in (False, True):
+        dm = _lookup_model(gather=gather)
+        eng = _engine(dm)
+        handles = [eng.submit(p, max_new_tokens=9) for p in prompts]
+        eng.run_until_idle()
+        answers.append([h.result(timeout=5) for h in handles])
+    assert answers[0] == answers[1]
+    for p, got in zip(prompts, answers[0]):
+        assert _greedy_teacher_forced(dm, p, got) == got
+
+
 def test_kv_eviction_under_pressure(tiny_model):
     """Under KV exhaustion a tight-SLO arrival preempts the loosest
     running request: the victim's blocks free and are REUSED by the

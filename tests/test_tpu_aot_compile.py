@@ -276,13 +276,12 @@ def test_fused_adam_kernel_carries_its_name_at_gpt2s_widths(tpu_arg):
     assert _kernel_names(text) == ["fused_adam"]
 
 
-@pytest.fixture(scope="module")
-def serving_programs(tpu_device):
+def _xl_serving_programs(tpu_device, vocab_size):
     """The decode and one prefill program of a DecodeModel at the serving
-    cells' widths (GPT-2 XL's heads, slots and blocks; 2 layers and a
-    small vocabulary), compiled for the described chip through the model's
-    own jit wrapper and pool description. ``.text`` and ``.facts`` by
-    program name (tools/serve_compile_report.py reads the facts)."""
+    cells' widths (GPT-2 XL's heads, slots and blocks; 2 layers, no weight
+    allocated), compiled for the described chip through the model's own
+    jit wrapper and pool description. ``.text`` and ``.facts`` by program
+    name (tools/serve_compile_report.py reads the facts)."""
     import os
     import sys
     import types
@@ -293,16 +292,27 @@ def serving_programs(tpu_device):
         os.path.abspath(__file__))), "tools"))
     import serve_compile_report as report
 
-    cfg = serving.GPTConfig(vocab_size=1024, n_layer=2, n_head=25, d_model=1600, max_seq_len=1024,
+    cfg = serving.GPTConfig(vocab_size=vocab_size, n_layer=2, n_head=25, d_model=1600, max_seq_len=1024,
                             dtype="bfloat16")
-    dm = serving.DecodeModel(cfg, max_batch=12, n_blocks=432, block_size=16, prefill_buckets=[256],
-                             seed=0)
+    dm = report.abstract_model(cfg, max_batch=12, n_blocks=432, block_size=16, prefill_buckets=[256])
     out = types.SimpleNamespace(dm=dm, text={}, facts={})
     for name, (jit_fn, args) in report.serving_programs(dm).items():
         compiled = report.compile_on(jit_fn, args, tpu_device)
         out.text[name] = compiled.as_text()
         out.facts[name] = report.describe(compiled, dm.pool_shape())
     return out
+
+
+@pytest.fixture(scope="module")
+def serving_programs(tpu_device):
+    """At a small vocabulary (1,024): what the pool's tests need."""
+    return _xl_serving_programs(tpu_device, 1024)
+
+
+@pytest.fixture(scope="module")
+def xl_table_programs(tpu_device):
+    """At GPT-2 XL's TRUE table, vocabulary 50,304: a copy of it shows."""
+    return _xl_serving_programs(tpu_device, 50304)
 
 
 _SERVING_PROGRAMS = ["decode_tick", "prefill_256"]
@@ -388,6 +398,81 @@ def test_serving_program_copies_neither_pool_nor_gathered_context(serving_progra
     limit = min(math.prod(dm.pool_shape()), dm.max_batch * dm.gather_len * dm.cfg.d_model)
     big = [c for c in serving_programs.facts[name]["top_level_copies"] if c["elements"] >= limit]
     assert not big, big
+
+
+# The embedding table stays where it is too (PERF.md, PR 47): `[V, D]` with
+# D no multiple of 128 rests with the VOCABULARY on the lanes, the most
+# compact tiling of its shape. The tied head's matmul reads it as it lies;
+# a row gather first copies all of it to row-major, every tick. The fixture
+# above, at vocabulary 1,024, is too small to show it.
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("shape", [(50304, 1600), (50257, 1600), (1024, 1600), (50304, 1608), (128, 32), (4096, 64),
+                                   (50304, 1536), (50304, 2048), (65536, 2048), (512, 256), (200, 100)])
+def test_a_table_rests_in_the_most_compact_tiling_of_its_shape(tpu_arg, shape, dtype):
+    """``serving.model.rests_lanes_first`` is the compiler's own rule: the
+    entry layout of a parameter follows from its shape alone, whatever
+    reads it."""
+    from paddle_tpu.serving.model import rests_lanes_first
+
+    text = _compiled_text(lambda w: w + 1, tpu_arg(shape, jnp.dtype(dtype)))
+    layout = re.search(r"entry_computation_layout=\{\(\w+\[[\d,]+\]\{([\d,]+):", text).group(1)
+    assert layout == ("0,1" if rests_lanes_first(shape) else "1,0"), (shape, layout)
+
+
+def _embed_facts(progs, name):
+    import serve_compile_report as report
+
+    return report.embed_facts(progs.dm, progs.text[name], progs.facts[name]["top_level_copies"])
+
+
+def test_decode_tick_looks_up_its_rows_in_the_table_as_it_lies(xl_table_programs):
+    """A slice a slot: no copy as large as the table, and the table named
+    in ONE layout throughout the program, the one it rests in."""
+    assert xl_table_programs.dm.embed_path()[0] == "slices"
+    emb = _embed_facts(xl_table_programs, "decode_tick")
+    assert emb["table_sized_copies"] == 0 and emb["table_layouts"] == ["0,1:T(8,128)(2,1)"], emb
+    # and the temporaries no longer hold a second table (161 MB)
+    assert xl_table_programs.facts["decode_tick"]["memory"]["temp_size_in_bytes"] < 16 * 2 ** 20
+
+
+def test_prefill_still_copies_the_table_once(xl_table_programs):
+    """Prefill keeps the gather on purpose (256 row slices read a third of
+    what the copy moves, 1,024 more than it): the day someone cures it,
+    this says so."""
+    pre = _embed_facts(xl_table_programs, "prefill_256")
+    assert pre["table_sized_copies"] == 1 and {lay[:3] for lay in pre["table_layouts"]} == {"0,1", "1,0"}, pre
+    copies = xl_table_programs.facts["prefill_256"]["top_level_copies"]
+    assert [c["count"] for c in copies if (c["op"], c["shape"]) == ("copy", "bf16[50304,1600]")] == [1], copies
+
+
+def test_compile_report_says_how_the_decode_tick_looks_up_its_rows(xl_table_programs):
+    """tools/serve_compile_report.py: the path and its reason beside the
+    table's layouts and the table-sized copies, per program."""
+    emb = _embed_facts(xl_table_programs, "decode_tick")
+    assert sorted(emb) == ["decode_path", "table", "table_layouts", "table_sized_copies", "why"]
+    assert emb["decode_path"] == "slices" and "vocabulary-on-lanes" in emb["why"] and "1600" in emb["why"]
+    assert emb["table"] == [50304, 1600]
+    assert _embed_facts(xl_table_programs, "prefill_256")["decode_path"] == "slices"  # the model's, not the program's
+
+
+@pytest.mark.parametrize("programs,table,slots,tied", [("lfm2_programs", (65536, 2048), 64, True),
+                                                       ("olmoe_programs", (50304, 2048), 24, False)])
+@pytest.mark.parametrize("name", _SERVING_PROGRAMS)
+def test_a_2048_wide_table_rests_row_major_and_keeps_the_gather(request, programs, table, slots, tied, name):
+    """LFM2's tied ``[65536, 2048]`` and OLMoE's untied ``[50304, 2048]``:
+    whole lane tiles a row, so the table rests row-major, one gather takes
+    the rows and neither program copies anything as large as the table."""
+    progs = request.getfixturevalue(programs)
+    dm = progs.dm
+    assert (dm.cfg.vocab_size, dm.cfg.d_model) == table and dm.max_batch == slots
+    assert dm.cfg.tie_embeddings == tied
+    path, why = dm.embed_path()
+    assert path == "gather" and "row-major" in why
+    emb = _embed_facts(progs, name)
+    assert emb["decode_path"] == "gather" and emb["table_sized_copies"] == 0, emb
+    assert {lay[:3] for lay in emb["table_layouts"]} == {"1,0"}, emb
 
 
 @pytest.mark.parametrize("name", _SERVING_PROGRAMS)

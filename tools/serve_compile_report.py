@@ -8,7 +8,9 @@ whole-array ``copy`` (and unfused ``reshape`` / ``transpose``: a relayout)
 instructions the compiler put into the program, which Mosaic kernels it
 holds, how the decode tick attends (the ``paged_attention`` kernel, or the
 gathered window and why: a model whose K|V row is not whole 128-lane tiles
-shows here, before a chip run) and ``memory_analysis()``. Sizes and
+shows here, before a chip run), how it looks up its token rows (``embed``:
+a slice a slot or one gather, the embedding table's layouts in the program
+and the copies as large as the table) and ``memory_analysis()``. Sizes and
 instruction names only: nothing runs, so no time comes out of this tool.
 
 The programs are the ones ``DecodeModel`` serves with: its own functions
@@ -159,6 +161,21 @@ def attention_facts(dm, mosaic_kernels: Dict[str, int]) -> Dict[str, Any]:
                 cfg.kv_heads) if calls else 0}
 
 
+def embed_facts(dm, hlo_text: str, top_level_copies: List[Dict[str, Any]]) -> Dict[str, Any]:
+    """How ``dm``'s decode tick looks up its token rows
+    (``DecodeModel.embed_path``) beside what a compiled program holds of
+    the table: every layout it is named in, fused computations included
+    (one, the entry's, when nothing relays it), and how many of
+    ``describe``'s ``top_level_copies`` move ``vocab_size x d_model``
+    elements or more."""
+    path, why = dm.embed_path()
+    v, d = dm.cfg.vocab_size, dm.cfg.d_model
+    layouts = {re.sub(r"S\(\d\)$", "", lay) for lay in
+               re.findall(rf"\w+\[{v},{d}\]\{{([^}}]*)\}}", hlo_text)}
+    return {"decode_path": path, "why": why, "table": [v, d], "table_layouts": sorted(layouts),
+            "table_sized_copies": sum(c["count"] for c in top_level_copies if c["elements"] >= v * d)}
+
+
 def describe(compiled, pool_shape: Tuple[int, ...]) -> Dict[str, Any]:
     """What the compiled program does with a pool of ``pool_shape``."""
     text = compiled.as_text()
@@ -230,12 +247,14 @@ def main(argv=None) -> int:
     device = described_device(a.topology)
     for name, (jit_fn, args) in serving_programs(dm).items():
         compiled = compile_on(jit_fn, args, device)
+        text = compiled.as_text()
         if a.hlo_dir:
             os.makedirs(a.hlo_dir, exist_ok=True)
             with open(os.path.join(a.hlo_dir, f"{name}.hlo"), "w") as f:
-                f.write(compiled.as_text())
+                f.write(text)
         facts = describe(compiled, dm.pool_shape())
         facts["attention"] = attention_facts(dm, facts["mosaic_kernels"])
+        facts["embed"] = embed_facts(dm, text, facts["top_level_copies"])
         if dm.state_shape() is not None:  # the conv layers' second donated pool
             facts["state_pool"] = describe(compiled, dm.state_shape())["pool"]
         print(json.dumps({name: facts}, indent=1))
