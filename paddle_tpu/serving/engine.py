@@ -43,11 +43,14 @@ built from what was DISPATCHED (a context's length and a request's
 budget are known when a tick goes out; only its tokens are not),
 enqueued on tick N's own output still on the device, and only then is N
 read back, so the host's work on a tick runs beside the device's on the
-one before. Wherever the host needs what it has not read (an admission
-with its prefill, an eviction, a failed program, ``stop()``, no request
-left to dispatch)
-the tick in flight is read first (``_drain``) and the order is the
-serial one; the ledger counts both (``ticks_ahead``,
+one before. An admission keeps that pipeline full: its prefill is
+enqueued behind the tick in flight and writes its first token into that
+tick's output on the device, tick N+1 is enqueued on the result, and
+then the host reads N and the prefill's token, in the order the device
+ran them (``_unread``). Wherever the host needs what it has not read (an
+eviction, a failed program, ``stop()``, no request left to dispatch)
+everything in flight is read first (``_drain``) and the order is the
+serial one; the ledger counts both (``ticks_ahead``, ``prefills_ahead``,
 ``pipeline_drains``).
 
 Threading: ``start()`` runs the scheduler on a daemon thread (the
@@ -131,7 +134,8 @@ class ServeRequest:
     # positions whose K/V the programs dispatched so far write (a decode
     # tick counts when it is enqueued, not when it is read)
     context_len: int = 0
-    # tokens of decode ticks dispatched and not read back yet
+    # tokens of programs dispatched (its prefill, decode ticks) and not
+    # read back yet
     unread: int = 0
     prompt_len: int = 0
     slot: int = -1
@@ -162,6 +166,20 @@ class _Tick:
     # perf_counter_ns up to which the ledger's decode_compute bucket has
     # that window already (the ledger's ticks close under it)
     charged: int
+
+
+@dataclass
+class _Prefill:
+    """A prefill between its enqueue and its read."""
+
+    req: ServeRequest
+    slot: int  # as dispatched
+    # the newest token vector on the device when it went out, with the
+    # prompt's first token written at [slot]: the next program's `prev`
+    nxt: Any
+    # perf_counter_ns where its window starts: its own put, or the end of
+    # the read before it where that is later
+    t0: int
 
 
 class RequestHandle:
@@ -292,7 +310,10 @@ class ServingEngine:
         # admitted one-shot executes waiting for a thread to claim them
         self._exec_ready: List[ServeRequest] = []
         self._tick_no = 0
-        self._inflight: Optional[_Tick] = None  # enqueued, not read yet
+        # enqueued and not read yet, in the order the device runs them: at
+        # most one decode tick between steps, behind it an admission's
+        # prefills until the tick built on them is out
+        self._unread: List[Any] = []
         # running totals a step takes the difference of, so that a drain
         # anywhere in it counts: tokens read back, ns inside device_sync
         self._decoded = 0
@@ -445,7 +466,7 @@ class ServingEngine:
         if self._thread is not None:
             self._thread.join(timeout=30)
             self._thread = None
-        if self._inflight is not None:
+        if self._unread:
             # nothing the device was given stays unread
             with self._step_lock, _profiler.span("engine/step", cat="engine"):
                 t0, decoded0 = self._tick_start(), self._decoded
@@ -573,7 +594,7 @@ class ServingEngine:
                     self._run_prefill(req)
                 else:
                     self._exec_ready.append(req)
-            if self._inflight is not None or any(
+            if self._unread or any(
                     r is not None and r.status == RUNNING and
                     r.kind == "generate" for r in self._slots):
                 gen_work = True
@@ -586,14 +607,25 @@ class ServingEngine:
                     self._close_tick(t0, self._decoded - decoded0, active)
         return gen_work or bool(admitted)
 
+    @property
+    def _inflight(self) -> Optional[_Tick]:
+        """The decode tick enqueued and not read yet (the newest, while
+        the one before it is being read)."""
+        return next((u for u in reversed(self._unread)
+                     if isinstance(u, _Tick)), None)
+
+    def _newest(self):
+        """The newest token vector on the device, which the next program
+        takes as its ``prev``; None when nothing is in flight."""
+        return self._unread[-1].nxt if self._unread else None
+
     def _tick_start(self) -> float:
         """Where the ledger's next tick starts (``perf_counter``): now,
         or, under a tick in flight, where its last one closed, so that a
         window that runs across scheduler steps falls into the ledger's
         ticks whole."""
-        if self._inflight is None:
-            return time.perf_counter()
-        return self._inflight.charged / 1e9
+        tick = self._inflight
+        return time.perf_counter() if tick is None else tick.charged / 1e9
 
     def _close_tick(self, t0: float, decoded: int, active: int) -> None:
         """Close the ledger's tick over the wall since ``t0``: ``decoded``
@@ -823,10 +855,6 @@ class ServingEngine:
                         deferred.append(req)
                         break
                 req.blocks = blocks
-                # the prefill's token is needed before the next tick can
-                # be built: the tick in flight is read first, and the wait
-                # is part of this request's admission
-                self._drain("prefill")
             req.t_admit = time.perf_counter_ns()
             req.status = RUNNING
             req.slot = slot
@@ -882,6 +910,7 @@ class ServingEngine:
             req.prompt_len = int(req.prompt.shape[0])
             req.out_tokens = []
         req.context_len = 0
+        req.unread = 0  # what was in flight went with the pool
         req.status = QUEUED
         _ledger.record_request(outcome="evicted")
         self.queue.push(req)
@@ -907,7 +936,7 @@ class ServingEngine:
         if not on_device:
             if not self.pages.is_deleted():
                 return
-            # the tick in flight gave its pool to the program that failed,
+            # what is in flight gave its pool to the program that failed,
             # but its tokens are sound: read them before they are folded
             # into prompts (a read that fails comes back through here)
             if self._drain("error") and not self.pages.is_deleted():
@@ -946,39 +975,38 @@ class ServingEngine:
                          queued=self.queue.depth())
 
     def _run_prefill(self, req: ServeRequest) -> None:
+        """The enqueue half of an admission's prefill: behind whatever is
+        in flight, and waiting for nothing. The slot's state is written
+        behind the tick in flight's pass over it and before the next
+        tick's, by the pools' data dependence; the first token goes into
+        that tick's output on the device (``_Prefill.nxt``), where the
+        next tick finds it (``unread``), and ``_read_prefill`` hands it
+        to the request."""
         with _profiler.span("engine/prefill", cat="engine",
                             bucket=self.model.bucket_for(req.prompt_len),
                             prompt_len=req.prompt_len,
                             request_id=req.request_id):
-            req.t_prefill0 = time.perf_counter_ns()
+            ahead = self._inflight is not None
             try:
-                # returns with pools and token ready (tick/device_sync).
-                # The slot's state is written behind the tick in flight's
-                # read of it: _admit drained that tick before this slot
-                # changed hands
-                pages, state, tok = self.model.prefill(
+                pages, state, nxt, t_put = self.model.prefill_enqueue(
                     self.pages, self.state, req.prompt, req.prompt_len,
-                    req.blocks, req.slot)
+                    req.blocks, req.slot, self._newest())
             except Exception as e:
                 self._drop(req, f"{type(e).__name__}: {e}")
                 self._restore_pages()
                 return
             self.pages, self.state = pages, state
+            _ledger.note_prefill(ahead)
             if state is not None:
                 _ledger.note_state_write(state.nbytes)
-            req.t_prefill1 = time.perf_counter_ns()
-            if not req.t_first_token:  # a re-prefill after eviction is not
-                req.t_first_token = req.t_prefill1  # the user's first token
             req.context_len = req.prompt_len
-            req.out_tokens.append(tok)
-            _ledger.add("prefill_compute",
-                        (req.t_prefill1 - req.t_prefill0) / 1e9)
-            if len(req.out_tokens) >= req.max_new_tokens:
-                req.status = DONE
+            req.unread += 1
+            self._unread.append(_Prefill(req, req.slot, nxt, t_put))
 
     def _decode_tick(self) -> None:
         """One decode dispatch: tick N+1 is enqueued, then tick N, in
-        flight since the last call, is read."""
+        flight since the last call, and the prefills enqueued between the
+        two are read."""
         self._tick_no += 1
         # serving chaos sites, seed-deterministic (paddle_tpu/chaos.py):
         # replica_kill dies NOW with slots full of in-flight state — the
@@ -1027,63 +1055,87 @@ class ServingEngine:
                     for req in ready),
                 B * self.model.max_blocks_per_req,
                 len(self.model.attn_layers))
-        prev = self._inflight
+        ahead = self._inflight is not None
         # tick/put_inputs, tick/enqueue: returns at once, pool and tokens
         # still being computed
         try:
             self.pages, self.state, nxt, t_put = self.model.decode_enqueue(
-                self.pages, self.state, tables, lens, toks,
-                None if prev is None else prev.nxt)
+                self.pages, self.state, tables, lens, toks, self._newest())
         except Exception as e:  # the engine outlives a failed program
             for req in ready:
                 self._drop(req, f"decode program failed: "
                            f"{type(e).__name__}: {e}")
             self._restore_pages()
+            # no tick went out behind what is in flight: it is read now
+            self._drain("error")
             return False, False
         for req in ready:
             req.context_len += 1
             req.unread += 1
-        tick = self._inflight = _Tick(
+        out = _Tick(
             self._tick_no, [(r, r.slot) for r in ready], nxt, t_put, t_put)
-        if prev is not None:
-            t_read = self._read_tick(prev)
-            if t_read is not None:
-                tick.t0 = tick.charged = t_read
-        return True, prev is not None
+        self._unread.append(out)
+        self._read_ahead_of(out)
+        return True, ahead
 
     def _drain(self, cause: str) -> bool:
-        """Read the tick in flight, if there is one, because the host
-        needs what it has not read (``cause``: ``ledger.DRAIN_CAUSES``).
-        Returns whether there was one."""
-        tick, self._inflight = self._inflight, None
-        if tick is None:
+        """Read whatever is in flight, because the host needs what it has
+        not read (``cause``: ``ledger.DRAIN_CAUSES``, counted where a
+        decode tick was in flight). Returns whether anything was."""
+        if not self._unread:
             return False
-        _ledger.note_pipeline_drain(cause)
-        self._read_tick(tick)
+        if self._inflight is not None:
+            _ledger.note_pipeline_drain(cause)
+        self._read_ahead_of(None)
         return True
+
+    def _read_ahead_of(self, upto: Optional[_Tick]) -> None:
+        """Read, in the order the device ran them, the programs enqueued
+        ahead of ``upto`` (None: all of them). Each read closes its
+        program's window and opens the next one's, so that the windows of
+        consecutive ticks, and a prefill's between them, never overlap. A
+        program that turns out to have failed takes what is behind it."""
+        while self._unread and self._unread[0] is not upto:
+            item = self._unread.pop(0)
+            read = (self._read_tick if isinstance(item, _Tick)
+                    else self._read_prefill)
+            t1 = read(item)
+            if t1 is None:
+                return
+            if self._unread:
+                behind = self._unread[0]
+                behind.t0 = max(behind.t0, t1)
+                if isinstance(behind, _Tick):
+                    behind.charged = max(behind.charged, t1)
+
+    def _failed_on_device(self, item, e: BaseException) -> None:
+        """A program turned out, at its read, to have failed on the
+        device. Its requests fail; what was enqueued behind it took its
+        pool and its tokens and is no result either, so those requests
+        are preempted for re-prefill with every other running one, each
+        once, and the pool is rebuilt."""
+        if any(isinstance(u, _Tick) for u in self._unread):
+            _ledger.note_pipeline_drain("error")
+        self._unread = []
+        if isinstance(item, _Tick):
+            what, reqs = "decode program", [r for r, _ in item.slots]
+        else:
+            what, reqs = "prefill", [item.req]
+        for req in reqs:
+            if req.status == RUNNING:
+                self._drop(req, f"{what} failed: {type(e).__name__}: {e}")
+        self._restore_pages(on_device=True)
 
     def _read_tick(self, tick: _Tick) -> Optional[int]:
         """The read half of a decode tick: wait for its tokens, hand each
         to its request, close the tick's window at the end of the read
-        (returned, ``perf_counter_ns``: where the window of a tick
-        enqueued behind this one starts, so that the windows of
-        consecutive ticks, and a prefill's between them, never overlap).
-        None when the program turns out to have failed."""
+        (returned, ``perf_counter_ns``). None when the program turns out
+        to have failed."""
         try:
             with _profiler.span("tick/device_sync", cat="engine") as sync:
                 nxt, routing = self.model.decode_read(tick.nxt)
         except Exception as e:
-            # the program failed on the device, and the tick enqueued
-            # behind it took its tokens and its pool: both go, each
-            # request once
-            behind, self._inflight = self._inflight, None
-            if behind is not None:
-                _ledger.note_pipeline_drain("error")
-            for req, _ in tick.slots + (behind.slots if behind else []):
-                if req.status == RUNNING:
-                    self._drop(req, f"decode program failed: "
-                               f"{type(e).__name__}: {e}")
-            self._restore_pages(on_device=True)
+            self._failed_on_device(tick, e)
             return None
         self._sync_ns += sync.t1_ns - sync.t0_ns
         with _profiler.span("tick/bookkeeping", cat="engine"):
@@ -1103,6 +1155,34 @@ class ServingEngine:
                 if not req.unread and self._spent(req):
                     req.status = DONE
             self._decoded += len(live)
+        return t1
+
+    def _read_prefill(self, pre: _Prefill) -> Optional[int]:
+        """The read half of a prefill: wait for its token and hand it to
+        its request. The window, from the read before it (or its own put)
+        to the end of this read, is the request's ``prefill_compute`` and
+        the ledger's; up to where it opens the request was waiting to be
+        admitted to the device, as it was while the tick in flight was
+        read first. Returns the end of the read, None when the program
+        turns out to have failed."""
+        try:
+            with _profiler.span("tick/device_sync", cat="engine") as sync:
+                tok = self.model.prefill_read(pre.nxt, pre.slot)
+        except Exception as e:
+            self._failed_on_device(pre, e)
+            return None
+        self._sync_ns += sync.t1_ns - sync.t0_ns
+        req, t0, t1 = pre.req, pre.t0, sync.t1_ns
+        _ledger.add("prefill_compute", (t1 - t0) / 1e9)
+        if req.status == RUNNING:  # not reaped or dropped since
+            req.t_admit = req.t_prefill0 = t0
+            req.t_prefill1 = t1
+            if not req.t_first_token:  # a re-prefill after eviction is not
+                req.t_first_token = t1  # the user's first token
+            req.out_tokens.append(tok)
+            req.unread -= 1
+            if not req.unread and self._spent(req):
+                req.status = DONE
         return t1
 
     def _spent(self, req: ServeRequest) -> bool:

@@ -33,9 +33,13 @@ ticks), ``tick_wall_s`` (the ``engine/decode_tick`` spans, summed),
 waiting on the device, so wall - sync is host work a tick),
 ``ticks_ahead`` (decode ticks enqueued while the tick before them was
 still unread: over ``decode_ticks``, the share of ticks whose host work
-ran beside the device's) and ``pipeline_drains`` by cause (the times the
-engine read the tick in flight before going on, because the host needed
-what it had not read: ``serving/engine.py`` has the causes), for a model with
+ran beside the device's), ``prefills`` (prefill programs dispatched, one
+per admission or resume), ``prefills_ahead`` (those enqueued behind a
+decode tick still unread: over ``prefills``, the share of admissions that
+left the device nothing to wait for) and ``pipeline_drains`` by cause (the
+times the engine read what was in flight before going on, because the
+host needed what it had not read: ``serving/engine.py`` has the causes),
+for a model with
 experts the routing its decode ticks read back, summed over ticks and
 layers (``moe_assignments``: (live slot, expert) pairs, all computed;
 ``moe_experts_hit``: distinct experts with at least one; ``moe_max_load``:
@@ -96,7 +100,7 @@ __all__ = [
     "BUCKETS", "PRODUCTIVE_BUCKETS", "ATTRIBUTION_BUCKETS",
     "ServingLedger", "ledger", "reset",
     "add", "mark", "add_slot_seconds", "note_decode_tick",
-    "note_pipeline_drain", "note_token_gaps",
+    "note_pipeline_drain", "note_prefill", "note_token_gaps",
     "end_tick", "record_request",
     "record_attribution", "attribution_summary", "reconcile_attribution",
     "totals", "summary",
@@ -153,16 +157,17 @@ _ITL_SAMPLE = 8192
 MOE_COUNTERS = ("moe_assignments", "moe_experts_hit", "moe_max_load")
 # KV pages of a decode tick: engine.py::_decode_tick_phases
 ATTN_COUNTERS = ("attn_pages_read", "attn_pages_window")
-# summed per decode tick (state_writes: per prefill); in totals(), cleared
-# by reset(), merged as sums
+# summed per decode tick (prefills, prefills_ahead, state_writes: per
+# prefill); in totals(), cleared by reset(), merged as sums
 TICK_COUNTERS = (MOE_COUNTERS + ATTN_COUNTERS
-                 + ("ticks_ahead", "state_writes"))
+                 + ("ticks_ahead", "prefills", "prefills_ahead",
+                    "state_writes"))
 # facts of the model being served, noted with the counts above so that a
 # reset() between warm-up and a window loses nothing: in totals(), cleared
 # by reset(), merged as the largest
 GAUGES = ("attn_layers", "state_pool_bytes")
 # why the engine read the tick in flight early: engine.py::_drain
-DRAIN_CAUSES = ("prefill", "evict", "error", "stop", "empty")
+DRAIN_CAUSES = ("evict", "error", "stop", "empty")
 
 # fixed log-spaced bounds so per-replica histograms merge exactly across
 # restarts and ranks (1ms .. 120s covers CPU-sim ticks through pod SLOs)
@@ -456,6 +461,11 @@ class ServingLedger:
         with self._lock:
             self._count(ATTN_COUNTERS, (pages_read, pages_window))
             self.gauges["attn_layers"] = int(layers)
+
+    def note_prefill(self, ahead: bool) -> None:
+        """One prefill program dispatched; ``ahead``: behind a decode tick
+        still unread, so the device went from one to the other."""
+        self._count(("prefills", "prefills_ahead"), (1, ahead))
 
     def note_state_write(self, pool_bytes: int) -> None:
         """A prefill wrote its request's conv state into its decode slot,
@@ -762,6 +772,12 @@ def note_attention(pages_read: int, pages_window: int,
     _LEDGER.note_attention(pages_read, pages_window, layers)
 
 
+def note_prefill(ahead: bool) -> None:
+    if not _monitor.enabled():
+        return
+    _LEDGER.note_prefill(ahead)
+
+
 def note_state_write(pool_bytes: int) -> None:
     if not _monitor.enabled():
         return
@@ -894,6 +910,9 @@ def status() -> Dict[str, Any]:
             "decode_ticks": doc["decode_ticks"],
             "ticks_ahead": doc["ticks_ahead"],
             "ahead_share": doc["ticks_ahead"] / doc["decode_ticks"],
+            # and how often a prefill went out behind a tick still unread
+            "prefills": doc["prefills"],
+            "prefills_ahead": doc["prefills_ahead"],
             "drains": doc["pipeline_drains"],
         }
     if doc["attn_pages_window"]:

@@ -879,14 +879,19 @@ class DecodeModel:
     def _build_prefill(self, L: int):
         """The bucket-L prefill program: causal pass over [1, L], K/V
         scattered into the request's blocks, the conv layers' final state
-        into decode slot ``slot_id`` (``state`` and ``slot_id`` None for a
-        model that keeps none), argmax token at length-1."""
+        into decode slot ``slot_id`` (``state`` None for a model that
+        keeps none), and the argmax token at length-1 written into
+        ``prev[slot_id]``: ``prev`` is the tick in flight's second output
+        (or ``_no_prev``), so what comes back is what the next decode tick
+        takes as ITS ``prev``, the new slot's first token in it, without
+        the host (routing counts behind ``[:B]`` pass through)."""
         import jax
         import jax.numpy as jnp
 
         BS = self.block_size
 
-        def prefill(p, pages, state, tokens, length, block_ids, slot_id):
+        def prefill(p, pages, state, tokens, length, block_ids, slot_id,
+                    prev):
             pos = jnp.arange(L)
             blk = jnp.where(pos < length, block_ids[pos // BS], 0)
             slot = jnp.where(pos < length, pos % BS, 0)
@@ -897,6 +902,7 @@ class DecodeModel:
                 last = jnp.take(x, length - 1, axis=1)  # [1, D]
                 logits = self._logits(p, last)  # [1, V]
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                nxt = jax.lax.dynamic_update_slice(prev, nxt, (slot_id,))
             return pages, nxt, state  # None: no third result
 
         return self._compile(prefill, "prefill", L)
@@ -1116,20 +1122,19 @@ class DecodeModel:
         pages = jax.ShapeDtypeStruct(self.pool_shape(), self.cfg.dtype,
                                      sharding=self._pages_sharding())
         # None where the model keeps no state: no argument of the program
-        state = slot = None
+        state = None
         if self.state_shape() is not None:
             state = jax.ShapeDtypeStruct(self.state_shape(), self.cfg.dtype)
-            slot = i32()
+        prev = i32(*self._no_prev.shape)
         if kind == "decode":
             B = self.max_batch
             args = (self.params, pages, state,
-                    i32(B, self.max_blocks_per_req), i32(B), i32(B),
-                    i32(*self._no_prev.shape))
+                    i32(B, self.max_blocks_per_req), i32(B), i32(B), prev)
         elif kind == "score":
             args = (self.params, i32(1, bucket), i32())
         else:
             args = (self.params, pages, state, i32(1, bucket), i32(),
-                    i32(self.max_blocks_per_req), slot)
+                    i32(self.max_blocks_per_req), i32(), prev)
         return self._jit_for(fn, kind), args
 
     @staticmethod
@@ -1162,26 +1167,32 @@ class DecodeModel:
                            out_shardings=(repl, repl))
         pages_sh = self._pages_sharding()
         # no state pool on a mesh (__init__ refuses the model), then
-        # (tables, lens, tokens, prev) or (tokens, length, block_ids, None)
-        n_host = 4 if kind == "decode" else 3
+        # (tables, lens, tokens, prev) or (tokens, length, block_ids, slot,
+        # prev)
+        n_host = 4 if kind == "decode" else 5
         in_sh = (param_sh, pages_sh, None) + (repl,) * n_host
-        if kind != "decode":
-            in_sh += (None,)
         return jax.jit(fn, in_shardings=in_sh,
                        out_shardings=(pages_sh, repl, None),
                        donate_argnums=donate)
 
     # -- public API (host-array in, host-scalar-friendly out) ----------
 
-    def prefill(self, pages, state, tokens: np.ndarray, length: int,
-                block_ids: Sequence[int], slot: int = 0):
-        """Run the prompt through the smallest bucket that holds it,
-        leaving its K/V in ``block_ids`` and, where the model keeps a
-        state pool, its conv layers' final state in decode slot ``slot``
-        (``state`` is None otherwise). Returns (pages, state,
-        first_token:int), all ready. Raises InvalidArgument when no
-        bucket fits (the engine fails the request, not the batch)."""
-        import jax
+    def prefill_enqueue(self, pages, state, tokens: np.ndarray, length: int,
+                        block_ids: Sequence[int], slot: int = 0, prev=None):
+        """The first half of a prefill: pad the prompt to the smallest
+        bucket that holds it, put the inputs and enqueue the program;
+        nothing is waited for. The program leaves the prompt's K/V in
+        ``block_ids``, where the model keeps a state pool its conv layers'
+        final state in decode slot ``slot`` (``state`` is None otherwise),
+        and its first token in ``prev[slot]``: ``prev`` is the newest
+        token vector on the device (the tick in flight's, or an earlier
+        prefill's behind it; None: nothing is in flight). Returns (pages,
+        state, next, t0): the pools' successors and ``prev`` with the
+        token in it, all still on the device (:meth:`prefill_read` is the
+        sync; ``next`` is the next decode tick's ``prev``), and the
+        ``perf_counter_ns`` stamp of ``tick/put_inputs``. Raises
+        InvalidArgument when no bucket fits (the engine fails the request,
+        not the batch)."""
         import jax.numpy as jnp
 
         from ..framework import errors as _errors
@@ -1198,17 +1209,19 @@ class DecodeModel:
         ids = np.zeros((self.max_blocks_per_req,), np.int32)
         blocks = list(block_ids)[:self.max_blocks_per_req]
         ids[:len(blocks)] = blocks
-        with _profiler.span("tick/put_inputs", cat="engine"):
+        with _profiler.span("tick/put_inputs", cat="engine") as put:
             args = (jnp.asarray(padded), jnp.int32(int(length)),
-                    jnp.asarray(ids),
-                    None if state is None else jnp.int32(int(slot)))
+                    jnp.asarray(ids), jnp.int32(int(slot)),
+                    jnp.asarray(self._no_prev) if prev is None else prev)
         with _profiler.span("tick/enqueue", cat="engine"):
-            pages, tok, state = self._prefill_fns[L](
+            pages, nxt, state = self._prefill_fns[L](
                 self.params, pages, state, *args)
-        with _profiler.span("tick/device_sync", cat="engine"):
-            first = int(tok[0])
-            jax.block_until_ready((pages, state))
-        return pages, state, first
+        return pages, state, nxt, put.t0_ns
+
+    @staticmethod
+    def prefill_read(nxt, slot: int = 0) -> int:
+        """The second half: wait for a prefill's first token."""
+        return int(np.asarray(nxt)[slot])
 
     def decode_enqueue(self, pages, state, block_tables: np.ndarray,
                        context_lens: np.ndarray, tokens: np.ndarray,

@@ -187,7 +187,8 @@ def _keys(doc):
 # keys a ledger's journal gained since the fixtures were written (PR 27's
 # tree): a file without them is still a base this tree resumes
 _ADDED = {"serving": {"ticks_ahead", "pipeline_drains",
-                      "state_writes", "state_pool_bytes", "attn_layers"}}  # the last three: PR 33
+                      "state_writes", "state_pool_bytes", "attn_layers",  # these three: PR 33
+                      "prefills", "prefills_ahead"}}  # PR 48
 
 
 @_ledgers()

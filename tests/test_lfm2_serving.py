@@ -119,9 +119,11 @@ def test_a_slot_another_request_just_left_answers_as_it_does_alone(model, prompt
 
 
 def test_an_admission_while_a_tick_is_in_flight_reads_the_right_state(model, prompt):
-    """PR 29's pipeline: the tick in flight reads and writes every slot's
-    state, the newcomer's slot too. The drain before a prefill (``_admit``)
-    puts the prefill's write behind it, and the next tick reads that."""
+    """The tick in flight reads and writes every slot's state, the
+    newcomer's slot too. The prefill is enqueued behind it unread (PR 48),
+    and the pools' data dependence puts its write of the slot after that
+    tick's pass and before the next tick's, which also takes the slot's
+    first token from the device."""
     rng = np.random.RandomState(7)
     late = rng.randint(0, V, 19).tolist()
     ledger.reset()
@@ -129,15 +131,25 @@ def test_an_admission_while_a_tick_is_in_flight_reads_the_right_state(model, pro
     first = eng.submit(prompt[:24], max_new_tokens=16)
     for _ in range(4):
         eng.step()
-    assert eng._inflight is not None  # a tick is out, unread
+    before = eng._inflight
+    assert before is not None  # a tick is out, unread
     second = eng.submit(late, max_new_tokens=16)
+    eng.step()
+    doc = ledger.totals()
+    assert (doc["prefills"], doc["prefills_ahead"], doc["state_writes"]) == (2, 1, 2)
+    assert not any(doc["pipeline_drains"].values())
+    req = second._req
+    assert eng._inflight is not before and req.unread == 1 and len(req.out_tokens) == 1
+    # tick | prefill | tick: the windows follow the device's order
+    assert first._req.tick_windows[-1][1] <= req.t_prefill0 < req.t_prefill1 <= eng._inflight.t0
+    assert req.t_first_token == req.t_prefill1
     eng.run_until_idle()
-    drains = ledger.totals()["pipeline_drains"]["prefill"]
     ledger.reset()
-    assert drains >= 1
     assert served_gap(model, prompt[:24], first.result(timeout=5)) <= TOL
     assert served_gap(model, late, second.result(timeout=5)) <= TOL
     assert second.result() == generate(serving.ServingEngine(model), late, 16)
+    for h in (first, second):
+        assert sum(h.attribution.values()) == pytest.approx(h.engine_e2e_s, rel=1e-3, abs=1e-6)
 
 
 def test_preempt_and_resume_give_the_uninterrupted_answer(model, prompt):

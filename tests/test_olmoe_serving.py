@@ -73,6 +73,28 @@ def serve(dm, requests, max_new=12):
     return [r["tokens"] for r in out]
 
 
+def serve_staggered(dm, requests, max_new=12):
+    """The same requests through one engine driven by hand, each after the
+    first admitted while a decode tick is in flight, unread."""
+    eng = serving.ServingEngine(dm)
+    hs = [eng.submit(list(requests[0]), max_new_tokens=max_new)]
+    for _ in range(3):
+        eng.step()
+    for r in requests[1:]:
+        assert eng._inflight is not None
+        hs.append(eng.submit(list(r), max_new_tokens=max_new))
+        eng.step()
+        assert hs[-1]._req.unread == 1 and len(hs[-1]._req.out_tokens) == 1
+    eng.run_until_idle()
+    for h in hs:
+        assert sum(h.attribution.values()) == pytest.approx(h.engine_e2e_s, rel=1e-3, abs=1e-6)
+        req = h._req
+        spans = [(req.t_prefill0, req.t_prefill1)] + [(t0, t1) for t0, t1, _ in req.tick_windows]
+        assert all(a1 <= b0 < b1 for (_, a1), (b0, b1) in zip(spans, spans[1:])), spans
+        assert req.t_first_token == req.t_prefill1
+    return [h.result(timeout=5) for h in hs]
+
+
 def test_full_logits_match_the_reference_and_route_alike(model, prompt):
     got, routing = model.full_logits(prompt, with_routing=True)
     want, ref_routing = ref_logits(model.params, prompt)
@@ -90,17 +112,22 @@ def test_score_matches_the_reference(model, prompt):
     assert abs(total - want.sum()) <= 1e-3
 
 
-@pytest.mark.parametrize("others,max_new", [((), 16), ((9, 30, 17), 36)], ids=["alone", "four_long"])
-def test_prefill_then_decode_through_the_cache_follows_the_references_full_forward(model, prompt, others, max_new):
+@pytest.mark.parametrize("others,max_new,how", [((), 16, serve), ((9, 30, 17), 36, serve),
+                                                ((9, 30, 17), 36, serve_staggered)],
+                         ids=["alone", "four_long", "four_long_staggered"])
+def test_prefill_then_decode_through_the_cache_follows_the_references_full_forward(model, prompt, others, max_new,
+                                                                                   how):
     """Tokens served by the engine, against the reference's teacher-forced
     forward over prompt + answer: at every position the served token is
     the reference's argmax (its logit within TOL of the best). Every tick
-    but the first after an admission is enqueued on the last one's unread
-    tokens, routing counts behind them."""
+    but the first of a run is enqueued on the last one's unread tokens,
+    routing counts behind them; an admission's prefill writes its token
+    among them on the device and leaves the counts as they were
+    (``staggered``: each admission finds a tick in flight)."""
     rng = np.random.RandomState(5)
     prompts = [prompt] + [rng.randint(0, V, n).tolist() for n in others]
     ledger.reset()
-    answers = serve(model, prompts, max_new=max_new)
+    answers = how(model, prompts, max_new=max_new)
     doc = ledger.totals()
     ledger.reset()
     for p, tokens in zip(prompts, answers):
@@ -109,11 +136,14 @@ def test_prefill_then_decode_through_the_cache_follows_the_references_full_forwa
         rows = logits[len(p) - 1:len(seq) - 1]
         gaps = rows.max(-1) - rows[np.arange(len(tokens)), tokens]
         assert len(tokens) == max_new and gaps.max() <= TOL, gaps
-    # a prefill reads the tick in flight first, once per admission that
-    # found one; every other tick goes out ahead
+    # no admission reads the tick in flight first: every tick goes out
+    # ahead but the first after the engine had run dry
     drains = doc["pipeline_drains"]
-    assert drains["prefill"] <= len(others) and drains["empty"] >= 1
-    assert doc["ticks_ahead"] == doc["decode_ticks"] - drains["prefill"] - drains["empty"]
+    assert drains["empty"] >= 1 and not (drains["evict"] or drains["error"])
+    assert doc["ticks_ahead"] == doc["decode_ticks"] - drains["empty"]
+    assert doc["prefills"] == len(prompts) and doc["prefills_ahead"] <= len(others)
+    if how is serve_staggered:
+        assert doc["prefills_ahead"] == len(others) and drains["empty"] == 1
     if others:
         assert doc["ticks_ahead"] / doc["decode_ticks"] > 0.8, doc["pipeline_drains"]
     assert doc["moe_assignments"] == doc["decode_tokens"] * L * K
@@ -128,14 +158,15 @@ def test_a_request_alone_and_in_a_full_batch_bit_for_bit(model, prompt):
     # and the decode program itself: the same row, alone or among others
     pages = model.init_pages()
     B, nb = model.max_batch, model.max_blocks_per_req
-    pages, _, _ = model.prefill(pages, None, np.asarray(prompt), len(prompt), [1, 2])
+    pages, _, _, _ = model.prefill_enqueue(pages, None, np.asarray(prompt), len(prompt), [1, 2])
     tables = np.zeros((B, nb), np.int32)
     tables[0, :2] = [1, 2]
     lens, toks = np.zeros(B, np.int32), np.zeros(B, np.int32)
     lens[0], toks[0] = len(prompt), 7
     full_t, full_l, full_k = tables.copy(), lens.copy(), toks.copy()
     for s, n in ((1, 9), (2, 30), (3, 17)):
-        pages, _, _ = model.prefill(pages, None, np.asarray(others[s - 1]), n, [1 + 2 * s, 2 + 2 * s])
+        pages, _, _, _ = model.prefill_enqueue(pages, None, np.asarray(others[s - 1]), n, [1 + 2 * s, 2 + 2 * s],
+                                               slot=s)
         full_t[s, :2] = [1 + 2 * s, 2 + 2 * s]
         full_l[s], full_k[s] = n, 11
     pages, _, nxt, _ = model.decode_enqueue(pages, None, tables, lens, toks)

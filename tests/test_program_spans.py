@@ -32,10 +32,9 @@ ENGINE_SPANS = {
     "tick/build_inputs": "engine/decode_tick",
     "tick/put_inputs": ("engine/decode_tick", "engine/prefill"),
     "tick/enqueue": ("engine/decode_tick", "engine/prefill"),
-    # the read half of a tick: in the tick that follows it, or where the
-    # tick in flight had to be read first (an admission, an eviction)
-    "tick/device_sync": ("engine/decode_tick", "engine/prefill",
-                         "engine/admit"),
+    # the read half of a tick or a prefill: in the tick enqueued behind it,
+    # or where what is in flight had to be read first (an eviction)
+    "tick/device_sync": ("engine/decode_tick", "engine/admit"),
     "tick/bookkeeping": ("engine/decode_tick", "engine/admit")}
 N_STEPS = 3  # the first compiles: two steady-state runs
 # what a run that builds its program holds under executor/prepare, in order
@@ -168,11 +167,17 @@ def test_span_counts_follow_the_work(traced):
     totals = traced["totals"]
     assert len(ticks) - 1 == totals["decode_ticks"] == 4
     assert totals["ticks_ahead"] == 3
+    assert (totals["prefills"], totals["prefills_ahead"]) == (3, 0)
     assert totals["pipeline_drains"] == {
-        "prefill": 0, "evict": 0, "error": 0, "stop": 0, "empty": 1}
-    for name in ("tick/device_sync", "tick/bookkeeping"):
+        "evict": 0, "error": 0, "stop": 0, "empty": 1}
+    # the first tick reads the three prefills enqueued before it (a sync
+    # each, no bookkeeping), every later span the tick before it
+    for name, n in (("tick/device_sync", 3 + 4), ("tick/bookkeeping", 4)):
         assert len([e for e in _named(traced, name) if any(
-            t["t0"] <= e["t0"] and e["t1"] <= t["t1"] for t in ticks)]) == 4
+            t["t0"] <= e["t0"] and e["t1"] <= t["t1"] for t in ticks)]) == n
+    assert not [e for e in _named(traced, "tick/device_sync") if any(
+        p["t0"] <= e["t0"] and e["t1"] <= p["t1"]
+        for p in _named(traced, "engine/prefill"))]
     # the budget of the issue: at most 12 span entries a decode tick
     # (the last step that ticked: every prefill is behind it)
     step = [s for s in _named(traced, "engine/step")

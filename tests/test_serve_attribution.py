@@ -74,8 +74,10 @@ def test_engine_buckets_sum_to_e2e(tiny_model, staggered):
     With one decode tick in flight ahead of the host a tick's window
     runs from the read before it to its own read: a request's windows
     (its prefill, then its ticks) stay disjoint, also where a later
-    admission read the tick in flight first (`staggered`), and the
-    ledger's two legs and its buckets still close."""
+    admission's prefill went out behind the tick in flight and was read
+    after it (`staggered`): its window opens at that read's end and its
+    own read is the first token's stamp; the ledger's two legs and its
+    buckets still close."""
     eng = serving.ServingEngine(tiny_model)
     hs = [eng.submit([3 + i, 5, 7], max_new_tokens=4 + 4 * staggered)
           for i in range(3)]
@@ -83,6 +85,11 @@ def test_engine_buckets_sum_to_e2e(tiny_model, staggered):
         for _ in range(3):
             eng.step()
         hs.append(eng.submit([9, 5, 7], max_new_tokens=5))
+        eng.step()  # tick | prefill | tick: the first two are read
+        late, tick = hs[-1]._req, eng._inflight
+        assert hs[0]._req.tick_windows[-1][1] == late.t_prefill0 == late.t_admit
+        assert late.t_prefill1 == late.t_first_token == tick.t0
+        assert len(late.out_tokens) == 1 and late.unread == 1
     eng.run_until_idle()
     for h in hs:
         h.result(timeout=10)
@@ -96,8 +103,14 @@ def test_engine_buckets_sum_to_e2e(tiny_model, staggered):
             (t0, t1) for t0, t1, _ in req.tick_windows]
         assert all(a1 <= b0 < b1
                    for (_, a1), (b0, b1) in zip(spans, spans[1:])), spans
+        # queue + prefill is the wall up to the first token's read
+        assert attr["admission_queue"] + attr["prefill_compute"] == \
+            pytest.approx((req.t_first_token - req.t_submit) / 1e9, abs=1e-9)
     doc = serving_ledger.totals()
-    assert doc["pipeline_drains"]["prefill"] == int(staggered)
+    assert (doc["prefills"], doc["prefills_ahead"]) == (
+        3 + staggered, int(staggered))
+    assert doc["pipeline_drains"] == {
+        "evict": 0, "error": 0, "stop": 0, "empty": 1}
     assert serving_ledger.reconcile_spans(doc)["verdict"] == "within_bound"
     assert doc["request_span_seconds"] == pytest.approx(
         doc["decode_slot_seconds"], rel=1e-9)

@@ -305,8 +305,9 @@ def test_continuous_batching_bit_match(request, model_name, lens, budgets):
     assert doc["ticks_ahead"] == doc["decode_ticks"] - 1
     assert doc["ticks_ahead"] / doc["decode_ticks"] > (
         0.8 if max(budgets) >= 32 else 0.0)
+    assert (doc["prefills"], doc["prefills_ahead"]) == (4, 0)
     assert doc["pipeline_drains"] == {
-        "prefill": 0, "evict": 0, "error": 0, "stop": 0, "empty": 1}
+        "evict": 0, "error": 0, "stop": 0, "empty": 1}
     assert serving_ledger.reconcile_spans(doc)["ok"]
 
     eng_seq = _engine(model)
@@ -347,7 +348,8 @@ def test_program_consumes_the_pool_it_is_given(tiny_model, program):
     pages = tiny_model.init_pages()
     assert pages.shape == tiny_model.pool_shape() == (2 * 16, 8, 2 * 2 * 16)
     if program == "prefill":
-        out, _, _ = tiny_model.prefill(pages, None, np.asarray([3, 4, 5]), 3, [1])
+        out, _, _, _ = tiny_model.prefill_enqueue(
+            pages, None, np.asarray([3, 4, 5]), 3, [1])
     else:
         tables = np.zeros((4, tiny_model.max_blocks_per_req), np.int32)
         out, _, _, _ = tiny_model.decode_enqueue(
@@ -445,7 +447,7 @@ def _edge_last_token_in_flight(model, monkeypatch):
     eng = _engine(model)
     prompt = [3, 4, 5, 6, 7, 8]
     h = eng.submit(prompt, max_new_tokens=3)
-    eng.step()  # prefill (token 1), tick 1 out (token 2)
+    eng.step()  # prefill out (token 1), tick 1 out on it (token 2), 1 read
     eng.step()  # tick 2 out on tick 1's unread token (token 3), tick 1 read
     assert len(calls) == 2 and h._req.unread == 1 and not h.done
     assert eng.step() and h.done  # nothing left to dispatch: tick 2 read
@@ -456,7 +458,9 @@ def _edge_last_token_in_flight(model, monkeypatch):
         # the position of the last token it may compute from
         assert lens[0] <= len(prompt) + 3 - 2
         assert tables[0, lens[0] // 8] != 0 and not lens[1:].any()
-    assert [c[2][0] for c in calls] == [h.result()[0], -1]
+    # neither tick had its slot's token from the host: tick 1 went out on
+    # the prefill's, tick 2 on tick 1's, both still on the device
+    assert [c[2][0] for c in calls] == [-1, -1]
 
 
 def _edge_block_boundary(model, monkeypatch):
@@ -469,7 +473,7 @@ def _edge_block_boundary(model, monkeypatch):
     eng.run_until_idle()
     assert h.result(timeout=5) == _greedy_reference(model, prompt, 5)
     (t1, l1, k1), (t2, l2, k2) = calls[:2]
-    assert (l1[0], k1[0] >= 0, t1[0, 1]) == (7, True, 0)
+    assert (l1[0], k1[0], t1[0, 1]) == (7, -1, 0)
     assert (l2[0], k2[0]) == (8, -1) and t2[0, 1] != 0
     assert serving_ledger.totals()["ticks_ahead"] == 3
 
@@ -490,14 +494,19 @@ def _edge_evict_in_flight(model, monkeypatch):
     eng.run_until_idle()
     assert tight.result(timeout=5) == _greedy_reference(model, [9, 8, 7], 2)
     assert loose.result(timeout=5) == _greedy_reference(model, loose_p, 4)
-    assert serving_ledger.totals()["pipeline_drains"]["evict"] == 1
+    doc = serving_ledger.totals()
+    assert doc["pipeline_drains"]["evict"] == 1
+    # every prefill (two admissions, one resume) found the device empty:
+    # the eviction had read the tick in flight before tight's went out
+    assert (doc["prefills"], doc["prefills_ahead"]) == (3, 0)
     assert eng.allocator.used() == 0
 
 
 def _edge_error_behind(model, monkeypatch):
     """A decode program that fails on the device, found at its read with
-    the next tick already behind it: the requests of both go once, the
-    pool is rebuilt, and the engine serves the next request."""
+    the next tick already behind it: its requests go once (the tick behind
+    it, which took its pool, is no result either), the pool is rebuilt,
+    and the engine serves the next request."""
     model.warm()
     reads, real = [], model.decode_read
 
@@ -575,30 +584,165 @@ def _edge_budget(n):
 
 
 def _edge_late_admission(model, monkeypatch):
-    """One `prefill` drain per admission that found a tick in flight."""
+    """An admission that finds a tick in flight reads nothing first: its
+    prefill goes out behind that tick, the next tick behind the prefill
+    with the new slot's token still on the device."""
+    calls = _spy_on_enqueue(model, monkeypatch)
     eng = _engine(model)
-    prompts = [[3, 4, 5], [6, 7], [8, 9, 10], [11, 12]]
+    prompts = [[3, 4, 5], [6, 7], [8, 9, 10]]
     hs = [eng.submit(prompts[0], max_new_tokens=12)]
     for _ in range(3):
         eng.step()
-    hs.append(eng.submit(prompts[1], max_new_tokens=6))  # finds a tick
-    eng.step()
-    assert serving_ledger.totals()["pipeline_drains"]["prefill"] == 1
-    hs += [eng.submit(p, max_new_tokens=5) for p in prompts[2:]]
-    eng.step()  # two admissions in one step: only the first finds one
-    assert serving_ledger.totals()["pipeline_drains"]["prefill"] == 2
+    for i, (p, n) in enumerate(zip(prompts[1:], (6, 5)), start=1):
+        hs.append(eng.submit(p, max_new_tokens=n))  # finds a tick
+        before = eng._inflight
+        eng.step()
+        doc = serving_ledger.totals()
+        assert (doc["prefills"], doc["prefills_ahead"]) == (1 + i, i)
+        assert not any(doc["pipeline_drains"].values())
+        # the tick that was in flight is read, the one built on the
+        # prefill is out, and both slots' tokens came from the device
+        assert eng._inflight is not before and len(eng._unread) == 1
+        assert list(calls[-1][2][:i + 1]) == [-1] * (i + 1)
+        assert len(hs[-1]._req.out_tokens) == 1 and hs[-1]._req.unread == 1
+    assert doc["ticks_ahead"] == doc["decode_ticks"] - 1
     eng.run_until_idle()
-    for p, n, h in zip(prompts, (12, 6, 5, 5), hs):
+    for p, n, h in zip(prompts, (12, 6, 5), hs):
         assert h.result(timeout=5) == _greedy_reference(model, p, n)
+
+
+def _edge_late_budget(n):
+    def case(model, monkeypatch):
+        """An answer of one token admitted behind a tick in flight is the
+        prefill's, and no tick carries its slot; of two, one tick takes
+        the first from the device and nothing is enqueued behind it."""
+        calls = _spy_on_enqueue(model, monkeypatch)
+        eng = _engine(model)
+        a = eng.submit([3, 4, 5], max_new_tokens=8)
+        for _ in range(2):
+            eng.step()
+        b = eng.submit([5, 6, 7, 8], max_new_tokens=n)
+        eng.step()
+        assert b._req.slot in (1, -1) and b.done == (n == 1)
+        assert len(b._req.out_tokens) == 1
+        eng.run_until_idle()
+        assert b.result(timeout=5) == _greedy_reference(model, [5, 6, 7, 8], n)
+        assert a.result(timeout=5) == _greedy_reference(model, [3, 4, 5], 8)
+        # slot 1 is live in n - 1 ticks, each time on the device's token
+        assert [c[2][1] for c in calls if c[1][1]] == [-1] * (n - 1)
+        doc = serving_ledger.totals()
+        assert (doc["prefills"], doc["prefills_ahead"]) == (2, 1)
+        assert doc["pipeline_drains"] == {
+            "evict": 0, "error": 0, "stop": 0, "empty": 1}
+        assert doc["ticks_ahead"] == doc["decode_ticks"] - 1 == 6
+        assert not eng._unread and eng.allocator.used() == 0
+    return case
+
+
+def _edge_two_admissions(model, monkeypatch):
+    """Two admissions in one step behind a tick in flight: the second
+    prefill takes the first one's merged tokens, the tick after them
+    both, and the reads come back in the order the device ran them."""
+    calls = _spy_on_enqueue(model, monkeypatch)
+    eng = _engine(model)
+    prompts = [[3, 4, 5], [8, 9, 10], [11, 12]]
+    hs = [eng.submit(prompts[0], max_new_tokens=9)]
+    for _ in range(2):
+        eng.step()
+    hs += [eng.submit(p, max_new_tokens=5) for p in prompts[1:]]
+    eng.step()
+    doc = serving_ledger.totals()
+    assert (doc["prefills"], doc["prefills_ahead"]) == (3, 2)
+    assert not any(doc["pipeline_drains"].values())
+    assert list(calls[-1][2][:3]) == [-1, -1, -1]
+    first, b, c = (h._req for h in hs)
+    assert [len(r.out_tokens) for r in (b, c)] == [1, 1]
+    # tick | prefill b | prefill c | tick: disjoint windows, in that order
+    assert first.tick_windows[-1][1] <= b.t_prefill0 < b.t_prefill1 \
+        <= c.t_prefill0 < c.t_prefill1 <= eng._inflight.t0
+    assert (b.t_first_token, c.t_first_token) == (b.t_prefill1, c.t_prefill1)
+    eng.run_until_idle()
+    for p, n, h in zip(prompts, (9, 5, 5), hs):
+        assert h.result(timeout=5) == _greedy_reference(model, p, n)
+
+
+def _edge_prefill_error_behind(model, monkeypatch):
+    """A prefill that fails on the device, found at its read with a tick
+    enqueued behind it: its request fails, the tick that took its pool is
+    no result, the running request is re-prefilled once, the pool is
+    rebuilt once, and the engine serves on."""
+    model.warm()
+    real = model.prefill_read
+
+    def flaky(nxt, slot=0):
+        if slot == 1:
+            raise RuntimeError("injected: failed on the device")
+        return real(nxt, slot)
+
+    monkeypatch.setattr(model, "prefill_read", flaky)
+    rebuilt = []
+    monkeypatch.setattr(
+        model, "init_pages",
+        lambda real=model.init_pages: rebuilt.append(1) or real())
+    eng = _engine(model)
+    a = eng.submit([3, 4, 5, 6], max_new_tokens=6)
+    for _ in range(2):
+        eng.step()
+    del rebuilt[:]
+    b = eng.submit([7, 8], max_new_tokens=3)
+    eng.step()  # tick | prefill b | tick: the first read is sound
+    assert b.done and "prefill failed: RuntimeError: injected" in b._req.error
+    assert not eng._unread and rebuilt == [1] and not eng.pages.is_deleted()
+    assert a._req.evictions == 1 and a._req.unread == 0
+    assert len(a._req.generated_prefix) == 3  # the prefill's + two ticks'
+    doc = serving_ledger.totals()
+    assert doc["pipeline_drains"] == {
+        "evict": 0, "error": 1, "stop": 0, "empty": 0}
+    eng.run_until_idle()
+    assert a.result(timeout=5) == _greedy_reference(model, [3, 4, 5, 6], 6)
+    doc = serving_ledger.totals()
+    assert doc["requests"]["failed"] == 1 and rebuilt == [1]
+    assert eng.allocator.used() == 0 and not eng.active()
+    monkeypatch.setattr(model, "prefill_read", real)
+    nxt = eng.submit([11, 12, 13], max_new_tokens=4)
+    eng.run_until_idle()
+    assert nxt.result(timeout=5) == _greedy_reference(model, [11, 12, 13], 4)
+
+
+def _edge_stop_prefill_unread(model, monkeypatch):
+    """stop() with a prefill's token unread behind the tick in flight:
+    both are read, in order, and the engine can go on from there."""
+    eng = _engine(model)
+    a = eng.submit([3, 4, 5], max_new_tokens=5)
+    for _ in range(2):
+        eng.step()
+    b = eng.submit([6, 7, 8, 9], max_new_tokens=3)
+    with eng._step_lock:  # an admission, cut short before its tick
+        (req,) = eng._admit()
+        eng._run_prefill(req)
+    assert len(eng._unread) == 2 and req.unread == 1 and not req.out_tokens
+    eng.stop(flush=False)
+    assert not eng._unread and req.unread == 0 and len(req.out_tokens) == 1
+    assert a._req.tick_windows[-1][1] <= req.t_prefill0 < req.t_prefill1
+    doc = serving_ledger.totals()
+    assert doc["pipeline_drains"]["stop"] == 1
+    assert abs(sum(doc["buckets"].values()) - doc["wall_seconds"]) < 1e-6
+    eng.run_until_idle()
+    assert a.result(timeout=5) == _greedy_reference(model, [3, 4, 5], 5)
+    assert b.result(timeout=5) == _greedy_reference(model, [6, 7, 8, 9], 3)
 
 
 @pytest.mark.parametrize("case", [
     _edge_last_token_in_flight, _edge_block_boundary, _edge_evict_in_flight,
     _edge_error_behind, _edge_stop_in_flight, _edge_drain_in_flight,
     _edge_budget(1), _edge_budget(2), _edge_late_admission,
+    _edge_late_budget(1), _edge_late_budget(2), _edge_two_admissions,
+    _edge_prefill_error_behind, _edge_stop_prefill_unread,
 ], ids=["last_token_in_flight", "block_boundary", "evict_in_flight",
         "error_behind", "stop_in_flight", "drain_in_flight",
-        "max_new_tokens_1", "max_new_tokens_2", "late_admission"])
+        "max_new_tokens_1", "max_new_tokens_2", "late_admission",
+        "late_max_new_tokens_1", "late_max_new_tokens_2",
+        "two_admissions", "prefill_error_behind", "stop_prefill_unread"])
 def test_one_tick_in_flight_edges(tiny_model, monkeypatch, case):
     """The corners of the one-tick lookahead (serving/engine.py): what
     the host has not read yet is never needed unread, never computed
@@ -887,8 +1031,9 @@ def test_serving_ledger_journal_resume_and_merge(tiny_model, tmp_path):
     # 3 tokens: two ticks, the second ahead, the last one read alone
     for doc in (doc0, loaded, resumed):
         assert (doc["decode_ticks"], doc["ticks_ahead"]) == (2, 1)
+        assert (doc["prefills"], doc["prefills_ahead"]) == (1, 0)
         assert doc["pipeline_drains"] == {
-            "prefill": 0, "evict": 0, "error": 0, "stop": 0, "empty": 1}
+            "evict": 0, "error": 0, "stop": 0, "empty": 1}
     serving_ledger.disable_persistence()
 
     # merge two replicas: counts add, histograms add exactly
@@ -992,8 +1137,8 @@ def test_status_serving_section(tiny_model):
     # how often a tick went out before the last one was read, and why not
     assert s["pipeline"] == {
         "decode_ticks": 2, "ticks_ahead": 1, "ahead_share": 0.5,
-        "drains": {"prefill": 0, "evict": 0, "error": 0, "stop": 0,
-                   "empty": 1}}
+        "prefills": 1, "prefills_ahead": 0,
+        "drains": {"evict": 0, "error": 0, "stop": 0, "empty": 1}}
 
 
 def test_disabled_mode_inert(tmp_path):

@@ -943,10 +943,16 @@ def test_lfm2_program_moves_neither_pool_nor_expert_weights(lfm2_programs, name)
 # for the described chip on the PARENT of the PR that gave layers kinds and
 # the kernel grouped queries (ba29c55), with the Mosaic kernel's serialised
 # body cut out (it carries source line numbers; the kernel's own jaxpr is
-# held below instead).
+# held below instead). The LFM2 description's decode tick: on the parent of
+# PR 48 (2eb2b6b). The prefills are PR 48's own: it gave each one more
+# argument, the newest token vector on the device, and writes the prompt's
+# first token into it (the decode ticks did not change for that; before it
+# gpt2 / olmoe / lfm2 read 7b7f087b9f441263 / d4e27627cb07bdd5 /
+# 9bcd9b55f481105f).
 _PARENT_PROGRAMS = {
-    ("gpt2", "decode_tick"): "cf354808014e7cc1", ("gpt2", "prefill_32"): "7b7f087b9f441263",
-    ("olmoe", "decode_tick"): "653b0a7cdc6d0601", ("olmoe", "prefill_32"): "d4e27627cb07bdd5"}
+    ("gpt2", "decode_tick"): "cf354808014e7cc1", ("gpt2", "prefill_32"): "461e4d36e1e9524d",
+    ("olmoe", "decode_tick"): "653b0a7cdc6d0601", ("olmoe", "prefill_32"): "2aa9785cfd6b45c9",
+    ("lfm2", "decode_tick"): "a712444de7e22495", ("lfm2", "prefill_32"): "dc6ba1086558decc"}
 _PARENT_KERNEL = {(4, 4, 64, 64, 8): "90ef1bd43a930eb2", (12, 25, 64, 864, 64): "9394cc5543d1440f",
                   (24, 16, 128, 1920, 64): "9c0520193a38207e"}
 
@@ -959,8 +965,9 @@ def _sha(text):
 
 @pytest.fixture(scope="module")
 def lowered_small(tpu_device):
-    """{(block, program): StableHLO text} of a small GPT-2 and a small
-    OLMoE description, lowered (not compiled) for the described chip."""
+    """{(block, program): StableHLO text} of a small GPT-2, a small OLMoE
+    and a small LFM2 description, lowered (not compiled) for the described
+    chip."""
     import os
     import sys
 
@@ -974,7 +981,13 @@ def lowered_small(tpu_device):
     blocks = {"gpt2": serving.GPTConfig(n_head=4, **sizes),
               "olmoe": serving.GPTConfig(n_head=2, d_ff=64, tie_embeddings=False, norm="rmsnorm",
                                          position="rope", qk_norm=True, bias=False, mlp="moe",
-                                         n_experts=8, experts_per_token=2, **sizes)}
+                                         n_experts=8, experts_per_token=2, **sizes),
+              "lfm2": serving.GPTConfig(n_head=16, n_kv_head=8, d_ff=64, d_ff_dense=96, tie_embeddings=True,
+                                        norm="rmsnorm", position="rope", rope_theta=1e6, qk_norm="head",
+                                        bias=False, mlp="moe", n_experts=8, experts_per_token=2,
+                                        layer_ops=("conv", "attn", "conv"), layer_mlps=("swiglu", "moe", "moe"),
+                                        router_score="sigmoid", router_bias=True, norm_topk=True,
+                                        **dict(sizes, n_layer=3, d_model=1024))}
     out = {}
     for tag, cfg in blocks.items():
         dm = report.abstract_model(cfg, max_batch=4, n_blocks=32, block_size=16, prefill_buckets=[32])
@@ -986,10 +999,15 @@ def lowered_small(tpu_device):
 @pytest.mark.parametrize("block,program", sorted(_PARENT_PROGRAMS))
 def test_programs_of_one_kind_of_layer_lower_as_on_the_parent(lowered_small, block, program):
     """A model of one kind of layer, one K|V head a query head and no conv
-    state gets the program it got before any of that existed: the same
-    arguments, the same inner ``layer``, op for op."""
+    state gets the decode tick it got before any of that existed: the same
+    arguments, the same inner ``layer``, op for op; and no decode tick
+    changed when an admission stopped emptying the device (PR 48): the
+    first token is merged into `prev` by the PREFILL, one
+    ``dynamic_update_slice`` at its end."""
     text = lowered_small[block, program]
     assert ("tpu_custom_call" in text) == (program == "decode_tick")
+    merges = len(re.findall(r"dynamic_update_slice[^\n]*tensor<1xi32>", text))
+    assert merges == (program != "decode_tick"), merges
     body_cut = re.sub(r'(\\22body\\22: \\22)[A-Za-z0-9+/=]+', r"\1", text)
     assert _sha(body_cut) == _PARENT_PROGRAMS[block, program]
 
