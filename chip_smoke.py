@@ -433,15 +433,14 @@ def _serve(router, prompts, new_tokens: int, tag: str, concurrent: bool):
 def phase_server(prompt_lens=(12, 24, 40, 90, 120, 200, 300, 500),
                  new_tokens: int = 32, max_seq_len: int = 1024,
                  recipe=None, model: dict = GPT2S) -> dict:
-    from paddle_tpu import flags, serving
+    from paddle_tpu import serving
     from paddle_tpu.serving import ledger
-    from paddle_tpu.serving.kv_cache import blocks_for_tokens
+    from paddle_tpu.serving.kv_cache import BLOCK_SIZE, blocks_for_tokens
 
     cfg = serving.GPTConfig(max_seq_len=max_seq_len, dtype="bfloat16",
                             **model)
-    block_size = int(flags.env_flag("PADDLE_TPU_SERVE_BLOCK_SIZE"))
     # the whole batch resident at its final length, plus scratch block 0
-    n_blocks = 1 + sum(blocks_for_tokens(n + new_tokens + 1, block_size)
+    n_blocks = 1 + sum(blocks_for_tokens(n + new_tokens + 1, BLOCK_SIZE)
                        for n in prompt_lens)
     t0 = time.perf_counter()
     dm = serving.DecodeModel(cfg, recipe=recipe, max_batch=len(prompt_lens),
@@ -480,7 +479,7 @@ def phase_server(prompt_lens=(12, 24, 40, 90, 120, 200, 300, 500),
     return {"model": dm, "engine": engine, "facts": {
         "requests": len(prompts), "new_tokens": new_tokens,
         "prefill_buckets": buckets, "kv_blocks": n_blocks,
-        "block_size": block_size, "bit_identical_alone": True,
+        "block_size": dm.block_size, "bit_identical_alone": True,
         "compile_seconds": round(warm_s, 2)}}
 
 

@@ -364,24 +364,6 @@ define_env_flag(
     "(tools/mesh_bench.py run_validation: plan, measure pick + "
     "runners-up, record the gated planner_regret); 0 skips the leg")
 define_env_flag(
-    "PADDLE_TPU_SERVE_MAX_BATCH", 8,
-    "continuous-batching decode slots per serving engine: up to this "
-    "many requests share one decode tick (paddle_tpu/serving)")
-define_env_flag(
-    "PADDLE_TPU_SERVE_KV_BLOCKS", 64,
-    "paged KV-cache blocks per serving engine (block 0 is the reserved "
-    "scratch block); a request that cannot get blocks waits in the "
-    "admission queue or triggers an eviction")
-define_env_flag(
-    "PADDLE_TPU_SERVE_BLOCK_SIZE", 16,
-    "tokens per KV-cache block: requests hold ceil(context/block_size) "
-    "blocks and grow one block at a time while decoding")
-define_env_flag(
-    "PADDLE_TPU_SERVE_PREFILL_BUCKETS", "32,128,512",
-    "padded prompt lengths the prefill program compiles for "
-    "(comma-separated, ascending): a prompt runs at the smallest bucket "
-    "that holds it, bounding compile count")
-define_env_flag(
     "PADDLE_TPU_SERVE_RECIPE", "",
     "sharding recipe for the serving decode/prefill programs ('tp' or a "
     "hybrid from parallel/recipes.py): parameters and the KV pages "
@@ -566,14 +548,6 @@ define_env_flag(
     "dispatches reach this, each SLO class keeps admitting only inside "
     "its weight-proportional share (typed Unavailable bounce beyond "
     "it) so one tenant's burst cannot starve another's p99; 0 disables")
-define_env_flag(
-    "PADDLE_TPU_FUSED_LMHEAD", "auto",
-    "GPT training loss path (models/gpt.py): 'auto' (default) lowers "
-    "the tied lm-head + cross-entropy as the pallas flash-style fused "
-    "kernel that never materializes the [tokens, vocab] logits; "
-    "'pallas' forces it, 'on'/'chunked' selects the legacy chunked "
-    "lax-loop fused path (the A/B baseline), 'off' the materialized-"
-    "logits softmax_with_cross_entropy path")
 define_env_flag(
     "PADDLE_TPU_ASYNC_LOSS", True,
     "pipelined fit-loop loss readback: the per-step host float() of the "

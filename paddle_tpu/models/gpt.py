@@ -48,16 +48,11 @@ class GPTConfig:
     # attention tensor layout override: "" = auto (BTHD single-chip,
     # BHTD under sequence parallelism)
     attention_layout: str = ""
-    # fused lm-head cross-entropy (fused_lm_head_ce): never materializes
-    # the [B, T, V] logits for the backward. None = read the
-    # PADDLE_TPU_FUSED_LMHEAD flag (default "auto" = the pallas
-    # flash-style kernel whenever the head is tied and unpipelined — the
-    # raw-speed round's default loss path). Explicit values: "pallas",
-    # "on"/"chunked" (the legacy lax-loop, the A/B baseline — measured
-    # on v5e r5 it only won at B*T <= 8192), "off" (materialized
-    # logits + softmax_with_cross_entropy). Booleans keep their
-    # historical meaning: True = chunked, False = off.
-    fused_lm_head: Optional[object] = None
+    # the loss without the logits (fused_lm_head_ce: the [B, T, V] logits
+    # are never materialized, forward or backward), wherever the head is
+    # tied and the graph unpipelined. False: materialized logits +
+    # softmax_with_cross_entropy, for a caller that needs the logits.
+    fused_lm_head: bool = True
     # -- the block's description (defaults: GPT-2). The serving plane's
     # one layer body (serving/model.py) reads these; the training graph
     # below builds the GPT-2 block only and refuses anything else.
@@ -283,36 +278,17 @@ def build_forward(cfg: GPTConfig, tokens, batch: int, seq: int,
 
 def resolve_lm_head_impl(cfg: GPTConfig) -> str:
     """The training loss path for this config: "pallas" (the fused
-    flash-style kernel — the default), "chunked" (the legacy lax-loop
-    fused path) or "off" (materialized logits). Resolution order:
-    ``cfg.fused_lm_head`` when set (bools keep their historical chunked/
-    off meaning), else the ``PADDLE_TPU_FUSED_LMHEAD`` env flag
-    (auto/on/off/pallas/chunked). Either fused path requires tied
-    embeddings and an unpipelined graph; "auto" degrades to "off" there,
-    an explicit request falls back with the same rule (the chunked op
-    itself guards nothing — the builder is the one gate)."""
-    from .. import flags as _flags
-
-    mode = cfg.fused_lm_head
-    if mode is None:
-        mode = str(_flags.env_flag("PADDLE_TPU_FUSED_LMHEAD") or "auto")
-    if mode is True:
-        mode = "chunked"
-    elif mode is False:
-        mode = "off"
-    mode = str(mode).strip().lower()
-    if mode == "on":
-        mode = "chunked"
-    if mode not in ("auto", "pallas", "chunked", "off"):
-        raise ValueError(
-            f"PADDLE_TPU_FUSED_LMHEAD/fused_lm_head must be one of "
-            f"auto/on/off/pallas/chunked, got {mode!r}")
+    flash-style kernels: PR 40 measured them ahead of the chunked loop
+    and of the materialized logits on gpt2s-train-1k, in time and in
+    memory) where the config wants the loss without the logits, the head
+    is tied and the graph unpipelined; "off" (materialized logits)
+    otherwise. The op still picks its chunked loop itself where a mesh
+    program leaves the kernels no region
+    (ops/fused_ops.py::_pallas_shard_plan)."""
+    if not isinstance(cfg.fused_lm_head, bool):
+        raise ValueError(f"fused_lm_head must be True or False, got {cfg.fused_lm_head!r}")
     eligible = cfg.tie_embeddings and max(1, cfg.pp_stages) == 1
-    if mode == "auto":
-        mode = "pallas" if eligible else "off"
-    elif mode in ("pallas", "chunked") and not eligible:
-        mode = "off"
-    return mode
+    return "pallas" if cfg.fused_lm_head and eligible else "off"
 
 
 def build_train_program(
@@ -321,17 +297,16 @@ def build_train_program(
     """Full LM training graph: tokens/labels feeds -> mean NLL loss.
     Returns (main, startup, io) where io holds tokens/labels/loss/
     checkpoints plus "logits" — which is None when the fused lm-head CE
-    is active (io["fused_lm_head"] says which; the fused path never
+    is active (io["lm_head_impl"] says which; the fused path never
     materializes logits, that being its point). Callers needing logits
     must pass fused_lm_head=False."""
     main, startup = Program(), Program()
     ckpts: list = []
     impl = resolve_lm_head_impl(cfg)
-    use_fused = impl in ("pallas", "chunked")
     with program_guard(main, startup):
         tokens = snn.data("tokens", shape=[batch, seq], dtype="int64")
         labels = snn.data("labels", shape=[batch, seq], dtype="int64")
-        if use_fused:
+        if impl == "pallas":
             hidden, wte = build_forward(
                 cfg, tokens, batch, seq, checkpoints_out=ckpts, lm_head=False)
             block = main.current_block()
@@ -355,7 +330,6 @@ def build_train_program(
         "logits": logits,
         "loss": avg_loss,
         "checkpoints": ckpts,
-        "fused_lm_head": use_fused,
         "lm_head_impl": impl,
     }
 

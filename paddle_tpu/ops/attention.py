@@ -102,9 +102,9 @@ def scaled_dot_product_attention(q, k, v, attn_mask=None, dropout_p=0.0, is_caus
 # are what such a call falls back to. It won the train step (253.5 ms; with
 # q tiles outside and dk / dv accumulated 254.3; two q steps of 512 256.4;
 # the two kernels 266.4). Through the op only T 1024 reaches it (the XLA
-# path runs below PADDLE_TPU_FLASH_MIN_SEQ = 1024); the shorter lengths that
-# knob lets in were measured as kernels alone (ms a call against the two
-# kernels': T 768 2.33 | 4.54, T 512 2.17 | 3.06, T 256 1.87 | 2.64).
+# path runs below FLASH_MIN_SEQ); the shorter lengths were measured as
+# kernels alone (ms a call against the two kernels': T 768 2.33 | 4.54,
+# T 512 2.17 | 3.06, T 256 1.87 | 2.64).
 _WIDE = (1024, 512, 256, 128)
 _FLASH_TILES = {
     # (layout, causal, lengths <= 1024): {kernel: (bq candidates, bk candidates)}
@@ -147,33 +147,12 @@ def _flash_tiles(tq, tk, layout, causal, heads=1, head_dim=128):
     return pick("fwd") + (None if None in bwd else bwd,)
 
 
-def _swept_tiles(tq, tk, layout, causal, heads=1, head_dim=128):
-    """The same under tools/flash_sweep.py's knobs, where the environment
-    holds them: PADDLE_TPU_FLASH_BLOCKS ("bq,..;bk,.." or one shared list)
-    replaces the forward candidates and ties the backward to them unless
-    PADDLE_TPU_FLASH_BWD_BLOCKS ("bq_dq,bk_dq;bq_dkv,bk_dkv", or
-    "fused,bk" for the ONE fused kernel) names its own."""
-    import os
-
-    env_blocks = os.environ.get("PADDLE_TPU_FLASH_BLOCKS")
-    env_bwd = os.environ.get("PADDLE_TPU_FLASH_BWD_BLOCKS")
-    bq, bk, bwd_blocks = _flash_tiles(tq, tk, layout, causal, heads, head_dim)
-    if env_blocks:
-        qs, _, ks = env_blocks.partition(";")
-        bq = _first_dividing([int(b) for b in qs.split(",")], tq)
-        bk = _first_dividing([int(b) for b in (ks or qs).split(",")], tk)
-        bwd_blocks = None
-    if env_bwd:
-        fused = env_bwd.startswith("fused,")  # the ONE fused kernel: "fused,bk"
-        bwd_blocks = tuple(int(x) for pair in env_bwd.removeprefix("fused,").split(";")
-                           for x in pair.split(","))
-        if len(bwd_blocks) != (1 if fused else 4):
-            raise ValueError(
-                f"PADDLE_TPU_FLASH_BWD_BLOCKS={env_bwd!r}: expected "
-                f"'bq_dq,bk_dq;bq_dkv,bk_dkv' or 'fused,bk'")
-        if fused:
-            bwd_blocks = ("fused",) + bwd_blocks
-    return bq, bk, bwd_blocks
+# The flash kernels take sequences from here up (the shape the benchmark
+# cell gpt2s-train-1k trains at and PR 35 swept); below it XLA's fused
+# attention runs. The crossover itself was measured before PR 1 on another
+# installation (XLA won at T=512, where the flash grid overhead dominates)
+# and not since.
+FLASH_MIN_SEQ = 1024
 
 
 @register_op("fused_attention_tpu", no_grad_inputs=("Mask",), uses_rng=True)
@@ -184,11 +163,7 @@ def _fused_attention_tpu(ctx, ins, attrs):
     layout = attrs.get("layout", "BHTD")  # BTHD: heads stay in place, no
     # explicit transpose ops around the attention (profiled ~10% of the
     # GPT step); the head batch dim rides inside the dot_generals
-    import os
-
-    use_flash = attrs.get("use_flash", True) and not os.environ.get(
-        "PADDLE_TPU_DISABLE_FLASH"
-    )
+    use_flash = attrs.get("use_flash", True)
     seq_ax = 1 if layout == "BTHD" else 2
 
     # context parallelism: with a mesh carrying the sequence axis, run the
@@ -220,21 +195,23 @@ def _fused_attention_tpu(ctx, ins, attrs):
         )
         if layout == "BTHD":
             out = out.transpose(0, 2, 1, 3)
-    # The flash kernels take sequences from 1024 up (the shape the
-    # benchmark cell gpt2s-train-1k trains at and PR 35 swept); below that
-    # XLA's fused attention runs. The crossover itself was measured before
-    # PR 1 on another installation (XLA won at T=512, where the flash grid
-    # overhead dominates) and not since: PADDLE_TPU_FLASH_MIN_SEQ overrides
-    # it for re-measurement.
-    min_seq = int(os.environ.get("PADDLE_TPU_FLASH_MIN_SEQ", 1024))
     # GSPMD cannot partition a Mosaic call, and the flash kernel has no
     # shard_map region of its own yet: a mesh program that did not take
     # the ring path above runs the XLA einsum, which GSPMD partitions
     single_device = mesh is None or mesh.size == 1
-    if out is None and use_flash and single_device and mask is None and q.shape[seq_ax] >= min_seq and q.shape[-1] in (64, 128, 256):
+    if out is None and use_flash and single_device and mask is None and q.shape[seq_ax] >= FLASH_MIN_SEQ and q.shape[-1] in (64, 128, 256):
         tq, tk = q.shape[seq_ax], k.shape[seq_ax]
-        bq, bk, bwd_blocks = _swept_tiles(tq, tk, layout, is_causal,
+        bq, bk, bwd_blocks = _flash_tiles(tq, tk, layout, is_causal,
                                           heads=q.shape[3 - seq_ax], head_dim=q.shape[-1])
+        # a sweep's own tiles before the table's (tools/flash_sweep.py step
+        # sets them on the op): the forward's, which the backward then takes
+        # too unless `bwd_blocks` names its own, one kv tile for the ONE fused
+        # kernel or dq's and dkv's four
+        if attrs.get("block_q"):
+            bq, bk, bwd_blocks = int(attrs["block_q"]), int(attrs["block_k"]), None
+        if attrs.get("bwd_blocks"):
+            own = tuple(int(b) for b in attrs["bwd_blocks"])
+            bwd_blocks = ("fused",) + own if len(own) == 1 else own
         if bq is None or bk is None:
             _warn_xla_path(f"seq lengths ({tq},{tk}) not divisible by 128")
         else:

@@ -75,7 +75,7 @@ from .. import flags as _flags
 from .. import monitor as _monitor
 from .. import profiler as _profiler
 from . import ledger as _ledger
-from .kv_cache import BlockAllocator, blocks_for_tokens
+from .kv_cache import BLOCK_SIZE, BlockAllocator, blocks_for_tokens
 
 __all__ = ["ServeRequest", "RequestHandle", "AdmissionQueue",
            "ServingEngine"]
@@ -274,12 +274,14 @@ class AdmissionQueue:
 
 
 class ServingEngine:
-    """The continuous-batching scheduler over one DecodeModel."""
+    """The continuous-batching scheduler over one DecodeModel, whose
+    geometry it takes; ``max_batch``, ``n_blocks`` and ``block_size`` are
+    the model-less (execute-only) form's own."""
 
     def __init__(self, model=None,
-                 max_batch: Optional[int] = None,
-                 n_blocks: Optional[int] = None,
-                 block_size: Optional[int] = None,
+                 max_batch: int = 8,
+                 n_blocks: int = 64,
+                 block_size: int = BLOCK_SIZE,
                  default_slo_s: Optional[float] = None):
         self.model = model
         if model is not None:
@@ -287,14 +289,9 @@ class ServingEngine:
             self.block_size = model.block_size
             n_kv = model.n_blocks
         else:
-            self.max_batch = int(
-                max_batch if max_batch is not None
-                else _flags.env_flag("PADDLE_TPU_SERVE_MAX_BATCH"))
-            self.block_size = int(
-                block_size if block_size is not None
-                else _flags.env_flag("PADDLE_TPU_SERVE_BLOCK_SIZE"))
-            n_kv = int(n_blocks if n_blocks is not None
-                       else _flags.env_flag("PADDLE_TPU_SERVE_KV_BLOCKS"))
+            self.max_batch = int(max_batch)
+            self.block_size = int(block_size)
+            n_kv = int(n_blocks)
         self.default_slo_s = float(
             default_slo_s if default_slo_s is not None
             else _flags.env_flag("PADDLE_TPU_SERVE_SLO_S"))

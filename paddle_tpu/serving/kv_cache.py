@@ -48,6 +48,11 @@ from typing import Dict, List, Optional
 __all__ = ["BlockAllocator", "blocks_for_tokens"]
 
 
+# tokens a KV-cache block holds where a deployment does not say: requests
+# hold ceil(context / block_size) blocks and grow one at a time while decoding
+BLOCK_SIZE = 16
+
+
 def blocks_for_tokens(n_tokens: int, block_size: int) -> int:
     """Blocks a context of n_tokens occupies (ceil division)."""
     if n_tokens <= 0:

@@ -81,7 +81,7 @@ from .. import flags as _flags
 from .. import monitor as _monitor
 from .. import profiler as _profiler
 from ..models.gpt import GPTConfig
-from .kv_cache import blocks_for_tokens
+from .kv_cache import BLOCK_SIZE, blocks_for_tokens
 
 __all__ = ["GPTConfig", "DecodeModel", "init_params", "calibrate"]
 
@@ -324,15 +324,20 @@ def calibrate(n: int = 384, copy_mb: int = 16) -> Dict[str, float]:
 class DecodeModel:
     """The engine's compute plane: compiled prefill/decode callables +
     their xla_insight cost records, over a fixed (max_batch, kv layout,
-    recipe) envelope."""
+    recipe) envelope. The geometry is a deployment's setting: decode
+    slots that share one tick (``max_batch``), paged KV-cache blocks
+    (``n_blocks``; block 0 is the reserved scratch block) of
+    ``block_size`` tokens, and the padded prompt lengths the prefill
+    compiles for (``prefill_buckets``: a prompt runs at the smallest
+    that holds it, bounding compile count)."""
 
     def __init__(self, cfg: GPTConfig,
                  params: Optional[Dict[str, np.ndarray]] = None,
                  recipe: Optional[Any] = None,
-                 max_batch: Optional[int] = None,
-                 n_blocks: Optional[int] = None,
-                 block_size: Optional[int] = None,
-                 prefill_buckets: Optional[Sequence[int]] = None,
+                 max_batch: int = 8,
+                 n_blocks: int = 64,
+                 block_size: int = BLOCK_SIZE,
+                 prefill_buckets: Sequence[int] = (32, 128, 512),
                  seed: int = 0):
         self.cfg = cfg
         # the kinds of layer this model has, in order of first appearance
@@ -342,16 +347,9 @@ class DecodeModel:
         self.attn_layers = cfg.layers_of("attn")
         self.conv_layers = cfg.layers_of("conv")
         self.routes = any(mlp == "moe" for _, mlp in self.kinds)
-        self.max_batch = int(max_batch if max_batch is not None
-                             else _flags.env_flag("PADDLE_TPU_SERVE_MAX_BATCH"))
-        self.n_blocks = int(n_blocks if n_blocks is not None
-                            else _flags.env_flag("PADDLE_TPU_SERVE_KV_BLOCKS"))
-        self.block_size = int(
-            block_size if block_size is not None
-            else _flags.env_flag("PADDLE_TPU_SERVE_BLOCK_SIZE"))
-        if prefill_buckets is None:
-            raw = str(_flags.env_flag("PADDLE_TPU_SERVE_PREFILL_BUCKETS"))
-            prefill_buckets = [int(x) for x in raw.split(",") if x.strip()]
+        self.max_batch = int(max_batch)
+        self.n_blocks = int(n_blocks)
+        self.block_size = int(block_size)
         self.prefill_buckets = sorted(
             min(int(b), cfg.max_seq_len) for b in prefill_buckets)
         # every request's window: the whole (block-padded) context. The

@@ -279,8 +279,9 @@ def _tiles_of(fn, *args):
     return {key: after[key] - before[key] for key in after}
 
 
-def _attend(layout, causal):
-    """The op as the dispatcher lowers it: its own default tiles."""
+def _attend(layout, causal, **attrs):
+    """The op as the dispatcher lowers it: its own default tiles, unless
+    `attrs` holds a sweep's."""
     from paddle_tpu.framework.registry import LoweringContext, get_op_def
 
     opdef = get_op_def("fused_attention_tpu")
@@ -288,7 +289,7 @@ def _attend(layout, causal):
     def f(q, k, v):
         return opdef.lower(
             LoweringContext(rng_key=jax.random.key(0)), {"Q": [q], "K": [k], "V": [v]},
-            {"is_causal": causal, "is_test": True, "layout": layout})["Out"]
+            {"is_causal": causal, "is_test": True, "layout": layout, **attrs})["Out"]
     return f
 
 
@@ -316,16 +317,14 @@ def test_dispatchers_tiles_match_xla(monkeypatch, layout, case):
     forward and dq / dk / dv against the XLA reference; with Tk > T the
     mask is bottom-right aligned (offset 512: whole tiles; 128: the kv
     tile falls to 128 and the backward takes the forward's tiles)."""
-    for knob in ("PADDLE_TPU_FLASH_BLOCKS", "PADDLE_TPU_FLASH_BWD_BLOCKS", "PADDLE_TPU_FLASH_MIN_SEQ"):
-        monkeypatch.delenv(knob, raising=False)
+    from paddle_tpu.ops import attention
+
     t, tk, causal, *shape = _DISPATCH_CASES[case]
-    if t < 1024:  # the kernels' own crossover knob: the XLA path runs below 1024 otherwise
-        monkeypatch.setenv("PADDLE_TPU_FLASH_MIN_SEQ", str(t))
+    if t < 1024:  # the XLA path runs below the op's crossover otherwise
+        monkeypatch.setattr(attention, "FLASH_MIN_SEQ", t)
     q, k, v = _qkv(layout, t, tk, seed=11, **(shape[0] if shape else {}))
     flash = _attend(layout, causal)
     ref = lambda q, k, v: _sdpa_xla(q, k, v, is_causal=causal, layout=layout)  # noqa: E731
-    from paddle_tpu.ops import attention
-
     n0, tiles0 = attention.FLASH_DISPATCH_COUNT, _tiles_total()
     out, vjp = jax.vjp(flash, q, k, v)
     assert attention.FLASH_DISPATCH_COUNT == n0 + 1, "dispatcher fell back to the XLA path"
@@ -363,26 +362,51 @@ _FUSED, _TWO = ("fused", 256), (128, 1024, 512, 256)
     ((1024, 1536, "BTHD", True, 12, 64), (512, 512, 512, 512)), ((2048, 2048, "BTHD", True, 12, 64), (512, 512, 512, 512)),
     ((1024, 1024, "BTHD", False, 12, 64), (512, 512, 512, 512)), ((1024, 1024, "BHTD", True, 12, 64), (512, 1024, 512, 1024)),
 ])
-def test_the_table_takes_the_fused_backward_where_the_call_allows_it(monkeypatch, call, want):
+def test_the_table_takes_the_fused_backward_where_the_call_allows_it(call, want):
     from paddle_tpu.ops import attention
 
-    for knob in ("PADDLE_TPU_FLASH_BLOCKS", "PADDLE_TPU_FLASH_BWD_BLOCKS"):
-        monkeypatch.delenv(knob, raising=False)
-    assert attention._swept_tiles(*call)[2] == want
-    # the sweep tool's knob names either form, whatever the table says
-    monkeypatch.setenv("PADDLE_TPU_FLASH_BWD_BLOCKS", "fused,512")
-    assert attention._swept_tiles(*call)[2] == ("fused", 512)
-    monkeypatch.setenv("PADDLE_TPU_FLASH_BWD_BLOCKS", "128,1024;512,256")
-    assert attention._swept_tiles(*call)[2] == (128, 1024, 512, 256)
-    for bad in ("fused,1024,256", "128,1024"):
-        monkeypatch.setenv("PADDLE_TPU_FLASH_BWD_BLOCKS", bad)
-        with pytest.raises(ValueError, match="PADDLE_TPU_FLASH_BWD_BLOCKS"):
-            attention._swept_tiles(*call)
+    assert attention._flash_tiles(*call)[2] == want
+
+
+def test_the_ops_own_tiles_come_before_the_tables_and_the_sweep_refuses_a_malformed_spec():
+    """tools/flash_sweep.py step names a tiling as the op's block_q /
+    block_k / bwd_blocks attributes: read from what flash_tiles_total
+    counts at T 1024 (squares of a tile's smaller side, two planes). The
+    forward's own tiles tie the backward to them; `bwd_blocks` of one kv
+    tile is the ONE fused kernel (counted under dkv, dq nothing), of four
+    dq's and dkv's."""
+    import os
+    import sys
+
+    a = jax.ShapeDtypeStruct((2, 1024, 2, 64), jnp.bfloat16)
+
+    def squares(**attrs):
+        f = _attend("BTHD", True, **attrs)
+        n = _tiles_of(lambda q, k, v: jax.vjp(f, q, k, v)[1](q), a, a, a)
+        return tuple(sum(n[kern, c] for c in ("skipped", "interior", "diagonal")) for kern in ("fwd", "dq", "dkv"))
+
+    assert squares() == (2 * 16, 0, 2 * 16)  # the table: forward 256 x 1024, fused kv tiles of 256
+    assert squares(block_q=512, block_k=512) == (2 * 4, 2 * 4, 2 * 4)
+    assert squares(bwd_blocks=[512]) == (2 * 16, 0, 2 * 4)
+    assert squares(block_q=512, block_k=512, bwd_blocks=[128, 1024, 512, 256]) == (2 * 4, 2 * 64, 2 * 16)
+
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "tools"))
+    import flash_sweep
+
+    assert flash_sweep.step_attrs("-") == {}
+    assert flash_sweep.step_attrs("512,512") == {"block_q": 512, "block_k": 512}
+    assert flash_sweep.step_attrs("256,1024/fused,256") == {"block_q": 256, "block_k": 1024, "bwd_blocks": [256]}
+    assert flash_sweep.step_attrs("/128,1024,512,256") == {"bwd_blocks": [128, 1024, 512, 256]}
+    for bad in ("fused,256", "256,1024/fused,1024,256", "256,1024/128,1024", "256", "256;1024/128,1024;512,256", ""):
+        with pytest.raises(ValueError, match="--tiles"):
+            flash_sweep.step_attrs(bad)
+    with pytest.raises(ValueError, match="--tiles '256,1024/fused'"):  # before a process is spent on it
+        flash_sweep.main(["step", "--tiles", "- 256,1024/fused"])
 
 
 @pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv", "bwd"])
 @pytest.mark.parametrize("layout", ["BTHD", "BHTD"])
-def test_flash_tiles_total_says_what_the_causal_schedule_computes(monkeypatch, layout, kernel):
+def test_flash_tiles_total_says_what_the_causal_schedule_computes(layout, kernel):
     """The counter is bumped where a call is traced into a program, over
     the call's whole grid, in squares of a tile's smaller side. At T 1024
     causal every BTHD kernel computes at most 10 of 16 parts of the score
@@ -390,9 +414,6 @@ def test_flash_tiles_total_says_what_the_causal_schedule_computes(monkeypatch, l
     quarters); the BHTD forward's one square tile computes all of it and
     its backward three quarters; a non-causal call computes all of it,
     every tile interior; at T 2048 whole tiles lie below the diagonal."""
-    for knob in ("PADDLE_TPU_FLASH_BLOCKS", "PADDLE_TPU_FLASH_BWD_BLOCKS", "PADDLE_TPU_FLASH_MIN_SEQ"):
-        monkeypatch.delenv(knob, raising=False)
-
     def traced(t, causal, b=2, h=2, kernel=kernel):
         shape = (b, t, h, 64) if layout == "BTHD" else (b, h, t, 64)
         a = jax.ShapeDtypeStruct(shape, jnp.bfloat16)

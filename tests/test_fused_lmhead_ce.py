@@ -8,7 +8,7 @@ mesh (interpret-mode pallas — the same code path the TPU runs compiled):
 - tp-sharded kernel consistent with the unsharded one on 8 forced-host
   devices (forward, dx and dw), plus the fsdp gather-at-use and pure-dp
   layouts;
-- the flag resolution (PADDLE_TPU_FUSED_LMHEAD auto/on/off/pallas) and
+- the builder's choice of the loss path by eligibility and
   loss-trajectory parity across all three impls on the GPT train
   program;
 - the analytic plan's lmhead_ce_fused_stats term;
@@ -272,7 +272,7 @@ def test_tp_out_of_shard_labels_and_padding():
 
 
 # ---------------------------------------------------------------------------
-# the GPT train program: flag resolution + impl parity
+# the GPT train program: the builder's choice + impl parity
 # ---------------------------------------------------------------------------
 
 
@@ -285,8 +285,11 @@ def _run_gpt(mode, steps=3, vocab=300):
     try:
         np.random.seed(3)
         cfg = GPTConfig(vocab_size=vocab, n_layer=2, n_head=2, d_model=32,
-                        max_seq_len=32, fused_lm_head=mode)
+                        max_seq_len=32, fused_lm_head=mode != "off")
         main, startup, io = build_train_program(cfg, batch=2, seq=16)
+        ce_ops = [op for op in main.global_block().ops if op.type == "fused_lm_head_ce"]
+        if mode == "chunked":  # the op's own loop, asked for as tools/ce_sweep.py asks for its tiles
+            ce_ops[0]._set_attr("impl", "chunked")
         with program_guard(main, startup):
             Adam(learning_rate=1e-3).minimize(io["loss"])
         scope = Scope()
@@ -297,7 +300,7 @@ def _run_gpt(mode, steps=3, vocab=300):
                 "labels": r.randint(0, vocab, (2, 16)).astype(np.int64)}
         losses = [float(exe.run(main, feed=feed, fetch_list=[io["loss"]],
                                 scope=scope)[0]) for _ in range(steps)]
-        return io["lm_head_impl"], losses
+        return [io["lm_head_impl"]] + [op.attr("impl") for op in ce_ops], losses
     finally:
         paddle.disable_static()
 
@@ -308,49 +311,33 @@ def test_train_program_impl_parity():
     impl_p, lp = _run_gpt("pallas")
     impl_c, lc = _run_gpt("chunked")
     impl_o, lo = _run_gpt("off")
-    assert (impl_p, impl_c, impl_o) == ("pallas", "chunked", "off")
+    assert (impl_p, impl_c, impl_o) == (["pallas", "pallas"], ["pallas", "chunked"], ["off"])
     np.testing.assert_allclose(lp, lc, rtol=2e-4)
     np.testing.assert_allclose(lp, lo, rtol=2e-4)
     assert lp[-1] < lp[0]
 
 
-def test_flag_resolution(monkeypatch):
+def test_flag_resolution():
+    """The builder's choice, by what the config says: the kernels where
+    the head is tied and the graph unpipelined, the materialized logits
+    otherwise or where the caller wants them; no environment, no modes."""
     from paddle_tpu.models.gpt import GPTConfig, resolve_lm_head_impl
 
-    cfg = GPTConfig(vocab_size=64, n_layer=1, n_head=1, d_model=16)
-    # default env: auto -> pallas (the raw-speed round's default path)
-    monkeypatch.delenv("PADDLE_TPU_FUSED_LMHEAD", raising=False)
-    assert resolve_lm_head_impl(cfg) == "pallas"
-    monkeypatch.setenv("PADDLE_TPU_FUSED_LMHEAD", "on")
-    assert resolve_lm_head_impl(cfg) == "chunked"
-    monkeypatch.setenv("PADDLE_TPU_FUSED_LMHEAD", "off")
-    assert resolve_lm_head_impl(cfg) == "off"
-    monkeypatch.setenv("PADDLE_TPU_FUSED_LMHEAD", "pallas")
-    assert resolve_lm_head_impl(cfg) == "pallas"
-    # config beats env; legacy bools keep their historical meaning
-    monkeypatch.setenv("PADDLE_TPU_FUSED_LMHEAD", "off")
-    cfg_b = GPTConfig(vocab_size=64, n_layer=1, n_head=1, d_model=16,
-                      fused_lm_head=True)
-    assert resolve_lm_head_impl(cfg_b) == "chunked"
-    # ineligible graphs (untied head / pipelined) degrade to off
-    monkeypatch.delenv("PADDLE_TPU_FUSED_LMHEAD", raising=False)
-    cfg_u = GPTConfig(vocab_size=64, n_layer=1, n_head=1, d_model=16,
-                      tie_embeddings=False)
-    assert resolve_lm_head_impl(cfg_u) == "off"
-    cfg_pp = GPTConfig(vocab_size=64, n_layer=2, n_head=1, d_model=16,
-                       pp_stages=2)
-    assert resolve_lm_head_impl(cfg_pp) == "off"
-    monkeypatch.setenv("PADDLE_TPU_FUSED_LMHEAD", "bogus")
-    with pytest.raises(ValueError):
-        resolve_lm_head_impl(cfg)
+    small = dict(vocab_size=64, n_layer=2, n_head=1, d_model=16)
+    assert resolve_lm_head_impl(GPTConfig(**small)) == "pallas"
+    assert resolve_lm_head_impl(GPTConfig(**small, fused_lm_head=False)) == "off"
+    assert resolve_lm_head_impl(GPTConfig(**small, tie_embeddings=False)) == "off"
+    assert resolve_lm_head_impl(GPTConfig(**small, pp_stages=2)) == "off"
+    for legacy in ("pallas", "chunked", "auto", None, 1):
+        with pytest.raises(ValueError, match="fused_lm_head must be True or False"):
+            resolve_lm_head_impl(GPTConfig(**small, fused_lm_head=legacy))
 
 
 def test_env_flag_declared_and_documented():
     from paddle_tpu import flags
 
     defs = flags.env_flag_defs()
-    for name in ("PADDLE_TPU_FUSED_LMHEAD", "PADDLE_TPU_ASYNC_LOSS",
-                 "PADDLE_TPU_MEMWATCH_SAMPLE_RUNS"):
+    for name in ("PADDLE_TPU_ASYNC_LOSS", "PADDLE_TPU_MEMWATCH_SAMPLE_RUNS"):
         assert name in defs and defs[name]["help"], name
 
 

@@ -8,7 +8,6 @@ import pytest
 
 from benchmark import arch as arch_modules
 from benchmark.reference import olmoe as reference
-from benchmark.tools import fault_readings
 from paddle_tpu import serving
 from paddle_tpu.serving import ledger
 from paddle_tpu.serving.model import param_table
@@ -200,42 +199,6 @@ def test_every_token_to_one_expert_nothing_dropped_still_exact(prompt):
     t = ledger.totals()
     assert t["moe_experts_hit"] == 2 * L * t["decode_ticks"]
     assert t["moe_max_load"] == L * t["decode_ticks"]  # one slot: each expert holds it once
-
-
-@pytest.fixture(scope="module")
-def cut_cell():
-    """The cell's configuration at its published hidden width (2048, 16
-    heads of 128, experts of 1024) with the cell's OWN seed-made weights
-    (``benchmark/arch/olmoe.py::make_params``: nothing reweighted here),
-    cut to what a CPU test carries: 2 layers, 8 experts of which a token
-    takes 2, 2,048 vocabulary rows, float32."""
-    from benchmark import manifest
-
-    c = dict(manifest.cell(manifest.load(), "olmoe-serve-batch")["config"], n_layer=2, num_experts=8,
-             num_experts_per_tok=2, vocab_size=2048)
-    mod = arch_modules.of(c)
-    cfg = serving.GPTConfig(**mod.gpt_config(c, {"dtype": "float32", "window": 128}))
-    params = mod.make_params(c, 2**31 + 11, "float32")
-    rng = np.random.RandomState(4)
-    requests = [rng.randint(0, 2048, n).tolist() for n in (24, 40)]
-    return mod, c, cfg, params, requests
-
-
-@pytest.mark.parametrize("fault", [None, *fault_readings.FAULTS])
-def test_a_fault_the_tolerance_must_catch_fails_it(fault, cut_cell):
-    """Each of four plausible mistakes, made on purpose in the program
-    (benchmark/tools/fault_readings.py), moves a served token's reference
-    logit gap past the tolerance the benchmark's runner applies to
-    bfloat16 (LOGIT_TOL), read by the runner's own check on the cell's own
-    initialisation; the sound program stays at float32 rounding."""
-    mod, c, cfg, params, requests = cut_cell
-    engine = dict(max_batch=4, n_blocks=64, block_size=16, prefill_buckets=[64])
-    r = fault_readings.reading(fault, mod, c, cfg, params, engine, requests, max_new=12, window=64)
-    assert r["checked_tokens"] == 24
-    if fault is None:
-        assert r["max_logit_gap"] <= 10 * TOL and not r["caught"]
-    else:
-        assert r["max_logit_gap"] > mod.LOGIT_TOL and r["caught"], r
 
 
 def test_bfloat16_stays_within_the_runners_tolerance(prompt):

@@ -28,11 +28,13 @@ Usage: python tools/flash_sweep.py MODE [--batch 32 --heads 12 --seq 1024
   check            dq, dk, dv of each "bwd" tiling against XLA's gradients
                    through materialised scores, at the call's shape
   step             the full GPT-2 small train step at (batch, seq) per
-                   "bq,bk;bq_dq,bk_dq;bq_dkv,bk_dkv" (or "bq,bk/fused,bk")
-                   of --tiles through the PADDLE_TPU_FLASH_* knobs, one
-                   process each ("-" is the dispatcher's own table): the
-                   number that decides, since kernel-local wins can lose
-                   end to end.
+                   "bq,bk" (the backward on the forward's tiles),
+                   "bq,bk/bq_dq,bk_dq,bq_dkv,bk_dkv", "bq,bk/fused,bk" or
+                   "/fused,bk" (the table's forward) of --tiles, set on
+                   every fused_attention_tpu op as its block_q / block_k /
+                   bwd_blocks attributes, one process each ("-" is the
+                   dispatcher's own table): the number that decides,
+                   since kernel-local wins can lose end to end.
 Every mode prints the share of the score square each kernel computes
 (the monitor's flash_tiles_total) beside the time.
 """
@@ -55,7 +57,7 @@ _DEFAULT_TILES = {  # PR 35's candidates at the defaults' shape; the table took 
     # PR 43: the fused kernel against the two it replaces
     "bwd": "fused,256 fused,512 128,1024,512,256",
     "check": "fused,256 128,1024,512,256",
-    "step": "- 256;1024/128,1024;512,256",
+    "step": "- 256,1024/128,1024,512,256",
 }
 _FWD_TILE = (256, 1024)  # the forward beside a fused backward: the table's at the defaults' shape
 
@@ -195,20 +197,35 @@ def sweep_bwd(a, consume):
                _needed_flops(a, {"dq": 2, "dkv": 2, "bwd": 4}[consume]), a.iters)
 
 
+def step_attrs(spec):
+    """One --tiles entry of `step` as the attributes it sets on every
+    fused_attention_tpu op ("-": none, the dispatcher's own table); a
+    malformed entry is refused here, before a process is spent on it."""
+    if spec == "-":
+        return {}
+    fwd, _, bwd = spec.partition("/")
+    fused = bwd.startswith("fused,")
+    try:
+        fwd = [int(x) for x in fwd.split(",") if fwd]
+        bwd = [int(x) for x in bwd.removeprefix("fused,").split(",") if bwd]
+    except ValueError:
+        fwd = bwd = ()
+    if (len(fwd), len(bwd), fused) not in {(2, 0, False), (2, 4, False), (0, 4, False), (2, 1, True), (0, 1, True)}:
+        raise ValueError(f"--tiles {spec!r}: expected '-', 'bq,bk', 'bq,bk/bq_dq,bk_dq,bq_dkv,bk_dkv', "
+                         f"'bq,bk/fused,bk' or '/fused,bk'")
+    attrs = dict(zip(("block_q", "block_k"), fwd))
+    if bwd:
+        attrs["bwd_blocks"] = bwd  # one kv tile: the ONE fused kernel; four: dq's and dkv's
+    return attrs
+
+
 def sweep_step(a):
     """Full train step per config, one process each: the judge of record."""
     for spec in a.tiles:
-        env = {k: v for k, v in os.environ.items()
-               if k not in ("PADDLE_TPU_FLASH_BLOCKS", "PADDLE_TPU_FLASH_BWD_BLOCKS")}
-        if spec != "-":
-            fwd, _, bwd = spec.partition("/")
-            env["PADDLE_TPU_FLASH_BLOCKS"] = fwd
-            if bwd:
-                env["PADDLE_TPU_FLASH_BWD_BLOCKS"] = bwd
-        cmd = [sys.executable, os.path.abspath(__file__), "_one_step", "--batch", str(a.batch),
-               "--heads", str(a.heads), "--seq", str(a.seq), "--head-dim", str(a.head_dim)]
+        cmd = [sys.executable, os.path.abspath(__file__), "_one_step", "--tiles", spec, "--batch", str(a.batch),
+               "--heads", str(a.heads), "--seq", str(a.seq), "--head-dim", str(a.head_dim), "--steps", str(a.steps)]
         try:
-            out = subprocess.run(cmd, env=env, capture_output=True, text=True, timeout=900)
+            out = subprocess.run(cmd, capture_output=True, text=True, timeout=900)
         except subprocess.TimeoutExpired:
             print(f"step {spec}: TIMEOUT", flush=True)
             continue
@@ -216,11 +233,25 @@ def sweep_step(a):
         print(f"step {spec}: {lines[-1][5:] if lines else 'FAILED ' + out.stderr[-500:]}", flush=True)
 
 
+def _one_step(a):
+    """One `step` entry in this process: its attributes on every attention op."""
+    (spec,) = a.tiles
+    attrs = step_attrs(spec)
+
+    def set_tiles(main):
+        for op in main.global_block().ops:
+            if op.type == "fused_attention_tpu":
+                for name, value in attrs.items():
+                    op._set_attr(name, value)
+
+    one_step(a, prepare=set_tiles)
+
+
 def one_step(a, prepare=None):
-    """GPT-2 small (12 layers, vocab 50304, Adam) at (batch, seq) under
-    whatever PADDLE_TPU_FLASH_* the environment holds: mean step time.
-    ``prepare(main)`` may edit the forward program before the optimizer
-    appends its backward (tools/ce_sweep.py sets the CE op's tiles)."""
+    """GPT-2 small (12 layers, vocab 50304, Adam) at (batch, seq): mean
+    step time. ``prepare(main)`` may edit the forward program before the
+    optimizer appends its backward (`step` here sets the attention ops'
+    tiles, tools/ce_sweep.py the CE op's)."""
     import jax
 
     import paddle_tpu as paddle
@@ -274,11 +305,15 @@ def main(argv=None):
     a = ap.parse_args(argv)
     a.kv_seq = a.kv_seq or a.seq
     tiles = (a.tiles or _DEFAULT_TILES.get(a.mode, "")).split()
-    a.tiles = tiles if a.mode in ("step", "_one_step") else [
-        tuple(x if x == "fused" else int(x) for x in t.split(",")) for t in tiles]
+    if a.mode in ("step", "_one_step"):
+        for spec in tiles:
+            step_attrs(spec)
+        a.tiles = tiles
+    else:
+        a.tiles = [tuple(x if x == "fused" else int(x) for x in t.split(",")) for t in tiles]
     {"fwd": sweep_fwd, "dq": lambda a: sweep_bwd(a, "dq"), "dkv": lambda a: sweep_bwd(a, "dkv"),
      "bwd": lambda a: sweep_bwd(a, "bwd"), "check": check_bwd, "step": sweep_step,
-     "_one_step": one_step}[a.mode](a)
+     "_one_step": _one_step}[a.mode](a)
 
 
 if __name__ == "__main__":
