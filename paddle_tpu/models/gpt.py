@@ -27,6 +27,55 @@ from ..framework import initializer as init
 from ..static import nn as snn
 
 
+@dataclass(frozen=True)
+class YarnRope:
+    """YaRN's description of rotary frequencies stretched past the length
+    a model was trained at (a configuration's ``rope_scaling`` of type
+    "yarn"): frequency ``i`` of ``d / 2`` is divided by ``factor`` where
+    it turns less than ``beta_slow`` times over ``original_max_position``
+    positions, kept where it turns more than ``beta_fast`` times, and
+    blended linearly between. ``mscale`` / ``mscale_all_dim`` scale cos
+    and sin by their ratio (:meth:`attention_factor`), and the softmax
+    scale of an attention that says so by ``mscale_all_dim``'s square
+    (:meth:`softmax_gain`)."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @staticmethod
+    def _mscale(factor: float, mscale: float) -> float:
+        return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+    def attention_factor(self) -> float:
+        return (self._mscale(self.factor, self.mscale)
+                / self._mscale(self.factor, self.mscale_all_dim))
+
+    def softmax_gain(self) -> float:
+        return self._mscale(self.factor, self.mscale_all_dim) ** 2
+
+    def ramp_bounds(self, dim: int, base: float) -> Tuple[int, int]:
+        """(low, high): the frequency indices between which the blend
+        runs, for ``dim`` rotated lanes of base ``base``."""
+        def index(turns):
+            return (dim * math.log(self.original_max_position
+                                   / (turns * 2 * math.pi))
+                    / (2 * math.log(base)))
+        return (max(math.floor(index(self.beta_fast)), 0),
+                min(math.ceil(index(self.beta_slow)), dim - 1))
+
+    def inv_freq(self, dim: int, base: float) -> np.ndarray:
+        """The ``dim / 2`` frequencies, float64; they hold at EVERY
+        position, not only past ``original_max_position``."""
+        f = base ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+        low, high = self.ramp_bounds(dim, base)
+        ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                       / max(high - low, 1e-3), 0.0, 1.0)
+        return (f / self.factor) * ramp + f * (1.0 - ramp)
+
+
 @dataclass
 class GPTConfig:
     vocab_size: int = 32000
@@ -76,7 +125,8 @@ class GPTConfig:
     # (grouped-query attention); None: one per query head
     n_kv_head: Optional[int] = None
     # layers of more than one kind, one entry a layer (None: all alike).
-    # layer_ops: "attn", or "conv": the gated short convolution, x W_in
+    # layer_ops: "attn", "latent" (latent attention, described further
+    # down), or "conv": the gated short convolution, x W_in
     # split into B | C | X, causal depthwise taps over B * X, C * that
     # through W_out, whose last conv_kernel - 1 gated inputs are a decode
     # slot's state. layer_mlps: each layer's feed-forward, as `mlp`; a
@@ -94,6 +144,33 @@ class GPTConfig:
     router_bias: bool = False
     norm_topk: bool = False
     routed_scale: float = 1.0
+    # what norm_topk adds to the sum it divides by
+    norm_topk_eps: float = 1e-6
+    # group-limited selection: the experts lie in router_groups equal
+    # groups in order, a group scores the sum of its two largest scores,
+    # and only experts of the router_keep_groups best groups can be chosen
+    router_groups: int = 1
+    router_keep_groups: int = 1
+    # a shared expert beside the routed ones: one SwiGLU this wide on
+    # every token, added unweighted (0: none)
+    d_ff_shared: int = 0
+    # (first, held): the routed experts this model HOLDS of the router's
+    # n_experts, one chip's share of an expert-parallel layer. It routes
+    # over all n_experts and computes its own experts' part of the result;
+    # None: all of them
+    experts_held: Optional[Tuple[int, int]] = None
+    # latent attention (a layer whose operator is "latent"): q through a
+    # normed latent of q_lora_rank, K and V through ONE normed latent of
+    # kv_lora_rank a position beside qk_rope_dim rotated lanes that every
+    # head shares; a head scores qk_nope_dim + qk_rope_dim lanes and
+    # weighs v_head_dim. Pairs (2i, 2i + 1) of the rotated lanes turn
+    # together. rope_yarn: the rotated lanes' frequencies, None: plain
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    rope_yarn: Optional[YarnRope] = None
 
     def __post_init__(self):
         for name in ("layer_ops", "layer_mlps"):
@@ -103,6 +180,18 @@ class GPTConfig:
                     raise ValueError(f"{name} names {len(kinds)} layers, "
                                      f"n_layer is {self.n_layer}")
                 setattr(self, name, tuple(kinds))
+        if self.experts_held is not None:
+            first, held = self.experts_held = tuple(self.experts_held)
+            if not 0 <= first < first + held <= self.n_experts:
+                raise ValueError(f"experts_held {self.experts_held} is no "
+                                 f"share of {self.n_experts} experts")
+        if self.router_groups > 1 and (
+                self.n_experts % self.router_groups
+                or not 0 < self.router_keep_groups <= self.router_groups):
+            raise ValueError(
+                f"{self.n_experts} experts do not lie in "
+                f"{self.router_groups} equal groups of which "
+                f"{self.router_keep_groups} are kept")
 
     @property
     def head_dim(self) -> int:
@@ -115,6 +204,17 @@ class GPTConfig:
     @property
     def ffn_dim(self) -> int:
         return self.d_ff or 4 * self.d_model
+
+    @property
+    def held_experts(self) -> Tuple[int, int]:
+        """(first, held) of the routed experts this model holds."""
+        return self.experts_held or (0, self.n_experts)
+
+    @property
+    def latent_row(self) -> int:
+        """Lanes of a position's row under latent attention as they are
+        used: the K|V latent, then the rotated key lanes."""
+        return self.kv_lora_rank + self.qk_rope_dim
 
     def layer_kind(self, i: int) -> Tuple[str, str]:
         """Layer ``i``'s (operator, feed-forward)."""
@@ -140,7 +240,11 @@ class GPTConfig:
                 self.experts_per_token, self.n_kv_head, self.layer_ops,
                 self.layer_mlps, self.d_ff_dense, self.conv_kernel,
                 self.conv_bias, self.router_score, self.router_bias,
-                self.norm_topk, self.routed_scale, self.tie_embeddings)
+                self.norm_topk, self.routed_scale, self.norm_topk_eps,
+                self.router_groups, self.router_keep_groups,
+                self.d_ff_shared, self.experts_held, self.q_lora_rank,
+                self.kv_lora_rank, self.qk_nope_dim, self.qk_rope_dim,
+                self.v_head_dim, self.rope_yarn, self.tie_embeddings)
 
 
 def _param(helper: LayerHelper, name: str, shape, dtype, std: float = 0.02, zeros=False):

@@ -1,9 +1,12 @@
 """Decode attention over the paged KV pool as it lies: a pallas TPU kernel.
 
 One query token per batch slot against that slot's own context, read
-straight out of the serving pool ``[n_layer * n_blocks, block_size,
-n_head * 2 * head_dim]`` (serving/kv_cache.py: per token and head, K then
-V). The XLA formulation it replaces gathers every slot's whole window
+straight out of the serving pool ``[n_layer * n_blocks, block_size, row]``
+(serving/kv_cache.py). A position's row is one of two things: per K|V
+head, that head's K then its V (``n_kv_head * 2 * head_dim`` lanes:
+:func:`paged_attention`), or ONE latent row that every head shares, whose
+leading lanes are also its V (:func:`paged_latent_attention`, further
+down). The XLA formulation it replaces gathers every slot's whole window
 (``max_seq_len`` positions) into a temporary and scores all of it; this
 kernel walks only the pages a slot's context occupies, ``0 ..
 context_len // block_size``, so a tick moves the live K and V once and
@@ -34,6 +37,19 @@ head ``j``, query head ``j * group + r``. Matrix row ``r * n_kv + j`` is
 that head, its diagonal block the lanes of K|V head ``j``, so each line
 of the output is again a sum over ``n_kv`` aligned rows whose blocks do
 not overlap. With ``group == 1`` this is the kernel above, op for op.
+
+The latent mode (latent attention with the up-projections absorbed into q
+and into the output): the row is ``[latent | rotated key lanes | zeros]``,
+every head's K is the whole row and its V the row's leading ``v_lanes``.
+That is the same two matmuls with nothing to mask: q arrives ``[heads,
+row]`` (absorbed q over the latent lanes, rotated q over the rotated ones,
+zeros over the padding), ``q @ rows^T`` scores every head, ``p @ rows``
+weighs the row for every head, and the output keeps the leading
+``v_lanes``. Same page walk, double buffer and online softmax; the kernel
+is named ``paged_latent_attention`` so that a trace tells the two apart.
+All heads share the bytes of one row, so this mode is bound by the matrix
+unit as much as by the copies (``2 * heads * (row + v_lanes)`` operations
+against ``row * itemsize`` bytes a position).
 """
 from __future__ import annotations
 
@@ -61,18 +77,27 @@ def _sublanes(dtype) -> int:
 
 
 def unsupported(head_dim: int, block_size: int, dtype,
-                n_head: int = 1, n_kv_head: int = 1) -> str:
+                n_head: int = 1, n_kv_head: int = 1, latent=None) -> str:
     """Why a pool of this geometry cannot take the kernel ('' if it
     can): the kernel copies whole pages and multiplies whole rows, so a
     head has to fill whole 128-lane tiles and a page whole sublane tiles
     (a row or a page the runtime would pad is not what the copies
     assume). Grouped queries (``n_head`` over fewer ``n_kv_head``) sum
     each output line over ``n_kv_head`` rows of the float32 accumulator,
-    which have to be whole 8-row tiles."""
-    if n_head != n_kv_head and (n_head % n_kv_head or n_kv_head % 8):
+    which have to be whole 8-row tiles. ``latent = (row_lanes, v_lanes)``
+    asks for the latent mode instead (``head_dim`` and the head counts
+    say nothing then): the shared row and its V prefix have to be whole
+    128-lane tiles."""
+    if latent is not None:
+        row_lanes, v_lanes = latent
+        if row_lanes % _LANES or v_lanes % _LANES or not (
+                0 < v_lanes <= row_lanes):
+            return (f"a latent row of {row_lanes} lanes whose leading "
+                    f"{v_lanes} are V: not whole {_LANES}-lane tiles")
+    elif n_head != n_kv_head and (n_head % n_kv_head or n_kv_head % 8):
         return (f"{n_head} query heads over {n_kv_head} K|V heads: not a "
                 f"whole group over whole 8-row tiles")
-    if (2 * head_dim) % _LANES:
+    if latent is None and (2 * head_dim) % _LANES:
         return (f"a head's K|V is {2 * head_dim} lanes, not a multiple of "
                 f"{_LANES}")
     if block_size % _sublanes(dtype) or _STEP_TOKENS % block_size:
@@ -82,11 +107,12 @@ def unsupported(head_dim: int, block_size: int, dtype,
 
 
 def vmem_scratch_bytes(n_head: int, head_dim: int, block_size: int,
-                       dtype, n_kv_head: int = 0) -> int:
+                       dtype, n_kv_head: int = 0, row_lanes: int = 0) -> int:
     """VMEM the kernel's scratch takes (both page buffers, the
-    accumulator, the two statistics), for the compile report."""
+    accumulator, the two statistics), for the compile report.
+    ``row_lanes``: the latent mode's shared row."""
     hp = _round_up(n_head, _sublanes(dtype))
-    hw = (n_kv_head or n_head) * 2 * head_dim
+    hw = row_lanes or (n_kv_head or n_head) * 2 * head_dim
     return (2 * _STEP_TOKENS * hw * jnp.dtype(dtype).itemsize
             + hp * hw * 4 + 2 * hp * _LANES * 4)
 
@@ -94,7 +120,7 @@ def vmem_scratch_bytes(n_head: int, head_dim: int, block_size: int,
 def _kernel(tables_ref, lens_ref, q_ref, pool_ref, o_ref,
             buf, sem, cur, m_scr, l_scr, acc_scr,
             *, block_size, pages_per_step, max_blocks, head_lanes, scale,
-            n_kv=0, group=1):
+            n_kv=0, group=1, v_lanes=0):
     b, nb = pl.program_id(0), pl.num_programs(0)
     bs, pps, w = block_size, pages_per_step, head_lanes
     step_tokens = pps * bs
@@ -132,9 +158,13 @@ def _kernel(tables_ref, lens_ref, q_ref, pool_ref, o_ref,
     acc_scr[...] = jnp.zeros_like(acc_scr)
 
     # row h of the block-diagonal views owns lanes [h * w, (h + 1) * w)
-    row = jax.lax.broadcasted_iota(jnp.int32, (hp, hw), 0)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (hp, hw), 1)
-    if group == 1:
+    row = lane = diag = None
+    if not v_lanes:
+        row = jax.lax.broadcasted_iota(jnp.int32, (hp, hw), 0)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (hp, hw), 1)
+    if v_lanes:  # the latent mode: every head over the whole shared row
+        qblk = q_ref[...].astype(buf.dtype)
+    elif group == 1:
         diag = (lane >= row * w) & (lane < (row + 1) * w)
         q_rows = q_ref[...]
     else:
@@ -147,7 +177,8 @@ def _kernel(tables_ref, lens_ref, q_ref, pool_ref, o_ref,
              for r in range(group)]
             + [jnp.zeros((hp - group * n_kv, hw), q_ref.dtype)]
             * (hp > group * n_kv))
-    qblk = jnp.where(diag, q_rows, 0.0).astype(buf.dtype)  # [hp, hw]
+    if not v_lanes:
+        qblk = jnp.where(diag, q_rows, 0.0).astype(buf.dtype)  # [hp, hw]
 
     def step(c, slot):
         nxt = 1 - slot
@@ -183,6 +214,9 @@ def _kernel(tables_ref, lens_ref, q_ref, pool_ref, o_ref,
 
     cur[0] = jax.lax.fori_loop(0, n_steps, step, cur[0])
     # position 0 is never masked, so every sum is positive
+    if v_lanes:
+        o_ref[...] = (acc_scr[...] / l_scr[:, :1])[:, :v_lanes]
+        return
     out = jnp.where(diag, acc_scr[...] / l_scr[:, :1], 0.0)
     if group == 1:
         o_ref[...] = jnp.sum(out, axis=0, keepdims=True)
@@ -235,6 +269,68 @@ def _paged_attention(q, pool, tables, context_lens, *, scale, interpret):
     if group > 1:  # back to query-head order
         o = o.reshape(B, group, n_kv, w).transpose(0, 2, 1, 3)
     return o.reshape(B, H, w)[..., hd:].reshape(B, H * hd).astype(q.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "v_lanes", "interpret"))
+def _paged_latent_attention(q, pool, tables, context_lens, *, scale, v_lanes,
+                            interpret):
+    B, H, r = q.shape
+    _, bs, hw = pool.shape
+    max_blocks = tables.shape[1]
+    pps = _STEP_TOKENS // bs
+    hp = _round_up(H, _sublanes(pool.dtype))
+    # whole tiles: zeros over the row's padding lanes and the padding heads
+    qp = jnp.pad(q.astype(pool.dtype), ((0, 0), (0, hp - H), (0, hw - r)))
+    o = pl.pallas_call(
+        functools.partial(_kernel, block_size=bs, pages_per_step=pps,
+                          max_blocks=max_blocks, head_lanes=hw, scale=scale,
+                          v_lanes=v_lanes),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B,),
+            in_specs=[pl.BlockSpec((None, hp, hw), lambda b, *_: (b, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((None, hp, v_lanes),
+                                   lambda b, *_: (b, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, pps * bs, hw), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((hp, _LANES), jnp.float32),
+                pltpu.VMEM((hp, _LANES), jnp.float32),
+                pltpu.VMEM((hp, hw), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, hp, v_lanes), jnp.float32),
+        compiler_params=compiler_params(("arbitrary",)),
+        interpret=interpret,
+        name="paged_latent_attention",
+    )(tables.reshape(-1).astype(jnp.int32), context_lens.astype(jnp.int32),
+      qp, pool)
+    return o[:, :H].astype(q.dtype)
+
+
+def paged_latent_attention(q, pool, tables, context_lens, scale, v_lanes,
+                           interpret=None):
+    """Attention of one new token a slot over that slot's paged context
+    where every head shares ONE row a position (the module's docstring).
+
+    q ``[B, H, r]``: each head's query over the row's leading ``r`` lanes
+    as they lie (the up-projection absorbed, the rotated lanes behind it);
+    ``pool`` ``[rows, block_size, row_lanes]`` with ``row_lanes >= r``
+    whole 128-lane tiles, zeros behind lane ``r``; ``tables`` and
+    ``context_lens`` as :func:`paged_attention` has them. Returns ``[B, H,
+    v_lanes]`` in q's dtype: per head the softmax-weighted sum of the
+    rows' leading ``v_lanes``, which the caller puts through the value
+    up-projection. Scores, statistics and the sum are float32."""
+    why = unsupported(0, pool.shape[1], pool.dtype,
+                      latent=(pool.shape[2], v_lanes))
+    if why:
+        raise ValueError(f"paged_latent_attention: {why}")
+    if interpret is None:
+        interpret = not on_tpu()
+    return _paged_latent_attention(
+        q, pool, tables, context_lens, scale=float(scale),
+        v_lanes=int(v_lanes), interpret=bool(interpret))
 
 
 def paged_attention(q, pool, tables, context_lens, scale, interpret=None):

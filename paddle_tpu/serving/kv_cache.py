@@ -3,7 +3,7 @@
 The vLLM-style design adapted to the repo's functional-XLA runtime: the
 cache is ONE device array of fixed-size blocks
 
-    pages[n_attn_layers * n_blocks, block_size, kv_heads * 2 * head_dim]
+    pages[n_attn_layers * n_blocks, block_size, row]
 
 (``DecodeModel.pool_shape``), and a request owns an ordered *block
 table* — the list of block ids its context occupies, the same ids in
@@ -12,7 +12,10 @@ row-block ``a * n_blocks + b`` (a layer of another kind, such as a gated
 short convolution, owns no share: what it keeps per request lies in the
 state pool, by decode slot and not by block, ``DecodeModel.state_shape``).
 A token's row holds, K|V head by K|V head (one a query head, or one a
-group of them), that head's K then its V. The
+group of them), that head's K then its V (``kv_heads * 2 * head_dim``
+lanes); under latent attention it is ONE row for every head, the normed
+K|V latent and the rotated key lanes, padded to whole 128-lane tiles.
+Nothing below depends on what a row holds. The
 decode program scatters the new token's K/V into the tail slot and reads
 a request's K/V through its table (page by page where it lies, in
 ``ops/pallas/paged_attention``), so the cache never compacts and

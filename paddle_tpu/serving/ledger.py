@@ -43,7 +43,11 @@ for a model with
 experts the routing its decode ticks read back, summed over ticks and
 layers (``moe_assignments``: (live slot, expert) pairs, all computed;
 ``moe_experts_hit``: distinct experts with at least one; ``moe_max_load``:
-the busiest expert's pairs), what the decode attention read
+the busiest expert's pairs; where the model holds one chip's share of its
+router's experts the three count over the experts HELD, and
+``moe_assignments_routed`` counts the same live slots' pairs over ALL the
+router's experts, so that held / routed is the share of the routing that
+lands here; 0 for a model that holds every expert), what the decode attention read
 (``attn_pages_read``: the KV pages its live slots' contexts occupy, summed
 over ticks, layers apart; ``attn_pages_window``: the pages of every slot's
 whole window, which the gathered formulation reads whatever is live: their
@@ -161,7 +165,7 @@ ATTN_COUNTERS = ("attn_pages_read", "attn_pages_window")
 # prefill); in totals(), cleared by reset(), merged as sums
 TICK_COUNTERS = (MOE_COUNTERS + ATTN_COUNTERS
                  + ("ticks_ahead", "prefills", "prefills_ahead",
-                    "state_writes"))
+                    "state_writes", "moe_assignments_routed"))
 # facts of the model being served, noted with the counts above so that a
 # reset() between warm-up and a window loses nothing: in totals(), cleared
 # by reset(), merged as the largest
@@ -449,9 +453,12 @@ class ServingLedger:
             self.pipeline_drains[cause] += 1
 
     def note_routing(self, assignments: int, experts_hit: int,
-                     max_load: int) -> None:
-        """One decode tick's routing counts, each summed over layers."""
-        self._count(MOE_COUNTERS, (assignments, experts_hit, max_load))
+                     max_load: int, routed: int = 0) -> None:
+        """One decode tick's routing counts, each summed over layers;
+        ``routed``: of a model that holds a share of its experts, the
+        assignments over all of them."""
+        self._count(MOE_COUNTERS + ("moe_assignments_routed",),
+                    (assignments, experts_hit, max_load, routed))
 
     def note_attention(self, pages_read: int, pages_window: int,
                        layers: int) -> None:
@@ -759,10 +766,11 @@ def note_pipeline_drain(cause: str) -> None:
     _LEDGER.note_pipeline_drain(cause)
 
 
-def note_routing(assignments: int, experts_hit: int, max_load: int) -> None:
+def note_routing(assignments: int, experts_hit: int, max_load: int,
+                 routed: int = 0) -> None:
     if not _monitor.enabled():
         return
-    _LEDGER.note_routing(assignments, experts_hit, max_load)
+    _LEDGER.note_routing(assignments, experts_hit, max_load, routed)
 
 
 def note_attention(pages_read: int, pages_window: int,

@@ -12,8 +12,15 @@ head; and blocks whose layers are of more than one kind
 (``GPTConfig.layer_ops`` / ``layer_mlps``): attention over fewer K|V
 heads than query heads, gated short convolutions that keep a few gated
 inputs per decode slot instead of K and V, dense SwiGLU layers before
-the expert ones. Pool, donation, programs, names and insight are one
-path —
+the expert ones; latent attention (``"latent"`` layers: q through a
+normed low-rank latent, K and V through ONE normed latent a position
+beside a few rotated lanes every head shares, YaRN frequencies), whose
+pool row is that latent and which attends in two forms, expanded per head
+over a prompt and with the up-projections absorbed into q and into the
+output on a decode tick; a shared expert beside the routed ones, routing
+limited to the best groups of experts, and an expert layer that holds one
+chip's share of the router's experts. Pool, donation, programs, names and
+insight are one path —
 
 - **prefill**: the whole (bucket-padded) prompt in one causal pass,
   writing every position's K/V into the request's cache blocks and
@@ -41,7 +48,8 @@ bench reconciles measured tokens/s against).
 
 The KV pool passes through both as ONE donated array in the layout the
 chip keeps at rest (``DecodeModel.pool_shape``: rows of whole 128-lane
-tiles, the attention layer folded into the block index; serving/
+tiles, per head its K then its V or, under latent attention, the one
+latent row; the attention layer folded into the block index; serving/
 kv_cache.py says why): each program consumes the pool it is given and
 returns the same buffer updated in place, so a caller holds only the
 newest handle. A model with conv layers has a second donated array
@@ -125,8 +133,14 @@ def param_table(cfg: GPTConfig) -> Dict[str, Tuple[tuple, float]]:
     ``[E, D, F]``, ``moe.down.w`` ``[E, F, D]``; an untied head is
     ``gpt.lm_head.w`` ``[D, V]`` (the training graph's name and shape).
     A layer has the weights of its own kind (``cfg.layer_kind``): ``attn.*``
-    (k and v ``kv_heads * head_dim`` wide) or ``conv.*``; ``mlp.fc_*``,
-    ``mlp.gate|up|down`` or ``moe.*``."""
+    (k and v ``kv_heads * head_dim`` wide; a latent layer's seven:
+    ``attn.q_down``, ``q_norm``, ``q_up``, ``kv_down``, ``kv_norm``,
+    ``kv_up`` ``[H, kv_lora_rank, qk_nope_dim + v_head_dim]``, head by head
+    its K up-projection then its V one, so that the absorbed form's two
+    per-head matmuls read it as it lies, and ``proj``) or ``conv.*``;
+    ``mlp.fc_*``, ``mlp.gate|up|down`` or ``moe.*`` (the stacks hold
+    ``cfg.held_experts``, the router is ``n_experts`` wide; a shared expert
+    is ``moe.shared.gate|up|down``)."""
     d, v = cfg.d_model, cfg.vocab_size
     res_std = 0.02 / math.sqrt(2 * cfg.n_layer)
     t: Dict[str, Tuple[tuple, float]] = {"gpt.wte": ((v, d), 0.02)}
@@ -161,6 +175,18 @@ def param_table(cfg: GPTConfig) -> Dict[str, Tuple[tuple, float]]:
                 for part, width in (("in_proj", 3 * d), ("taps", d),
                                     ("out_proj", d)):
                     t[f"{ln}.conv.{part}.b"] = ((width,), 0.0)
+        elif op == "latent":
+            h = cfg.n_head
+            linear(f"{ln}.attn.q_down", d, cfg.q_lora_rank)
+            norm(f"{ln}.attn.q_norm", (cfg.q_lora_rank,))
+            linear(f"{ln}.attn.q_up", cfg.q_lora_rank,
+                   h * (cfg.qk_nope_dim + cfg.qk_rope_dim))
+            linear(f"{ln}.attn.kv_down", d, cfg.latent_row)
+            norm(f"{ln}.attn.kv_norm", (cfg.kv_lora_rank,))
+            t[f"{ln}.attn.kv_up.w"] = ((h, cfg.kv_lora_rank,
+                                        cfg.qk_nope_dim + cfg.v_head_dim),
+                                       0.02)
+            linear(f"{ln}.attn.proj", h * cfg.v_head_dim, d, res_std)
         else:
             linear(f"{ln}.attn.q", d, d)
             linear(f"{ln}.attn.k", d, d_kv)
@@ -173,13 +199,17 @@ def param_table(cfg: GPTConfig) -> Dict[str, Tuple[tuple, float]]:
                 norm(f"{ln}.attn.q_norm")
                 norm(f"{ln}.attn.k_norm", (d_kv,))
         if mlp == "moe":
-            e = cfg.n_experts
-            t[f"{ln}.moe.router.w"] = ((d, e), 0.02)
+            e = cfg.held_experts[1]
+            t[f"{ln}.moe.router.w"] = ((d, cfg.n_experts), 0.02)
             if cfg.router_bias:
-                t[f"{ln}.moe.router.bias"] = ((e,), 0.05)
+                t[f"{ln}.moe.router.bias"] = ((cfg.n_experts,), 0.05)
             t[f"{ln}.moe.gate.w"] = ((e, d, dff), 0.02)
             t[f"{ln}.moe.up.w"] = ((e, d, dff), 0.02)
             t[f"{ln}.moe.down.w"] = ((e, dff, d), res_std)
+            if cfg.d_ff_shared:
+                linear(f"{ln}.moe.shared.gate", d, cfg.d_ff_shared)
+                linear(f"{ln}.moe.shared.up", d, cfg.d_ff_shared)
+                linear(f"{ln}.moe.shared.down", cfg.d_ff_shared, d, res_std)
         elif mlp == "swiglu":
             linear(f"{ln}.mlp.gate", d, dff)
             linear(f"{ln}.mlp.up", d, dff)
@@ -239,6 +269,33 @@ def _kv_rows(k, v):
 
     kv = jnp.stack([k, v], axis=-2)  # [..., H, 2, hd]
     return kv.reshape(kv.shape[:-3] + (-1,))
+
+
+def _rope_pairs(x, rot):
+    """RoPE of ``x`` [..., r] by ``rot`` = (cos, sin), each broadcastable
+    to [..., r/2] float32, where lanes ``(2i, 2i + 1)`` are the real and
+    imaginary parts of pair ``i`` (the interleaved convention of the
+    latent-attention family; :func:`_rope` pairs lane ``i`` with ``i +
+    r/2``)."""
+    import jax.numpy as jnp
+
+    cos, sin = rot
+    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (-1, 2))
+    a, b = pairs[..., 0], pairs[..., 1]
+    return jnp.stack([a * cos - b * sin, b * cos + a * sin],
+                     axis=-1).reshape(x.shape).astype(x.dtype)
+
+
+def _latent_rows(c_kv, k_rope, lanes: int):
+    """The normed K|V latent ``[..., c]`` and the rotated key lanes
+    ``[..., r]`` of some tokens as rows of the latent pool ``[..., lanes]``:
+    the latent, the rotated lanes, zeros up to whole tiles."""
+    import jax.numpy as jnp
+
+    pad = lanes - c_kv.shape[-1] - k_rope.shape[-1]
+    return jnp.concatenate(
+        [c_kv, k_rope, jnp.zeros(c_kv.shape[:-1] + (pad,), c_kv.dtype)],
+        axis=-1)
 
 
 def _row_slices(table, idx):
@@ -344,7 +401,12 @@ class DecodeModel:
         # (one traced body each), and which layers own a share of a pool
         self.kinds = list(dict.fromkeys(
             cfg.layer_kind(i) for i in range(cfg.n_layer)))
-        self.attn_layers = cfg.layers_of("attn")
+        self.latent = bool(cfg.layers_of("latent"))
+        if self.latent and cfg.layers_of("attn"):
+            raise ValueError(
+                "layers of latent attention and of per-head attention in "
+                "one model: the KV pool has one row width")
+        self.attn_layers = cfg.layers_of("latent" if self.latent else "attn")
         self.conv_layers = cfg.layers_of("conv")
         self.routes = any(mlp == "moe" for _, mlp in self.kinds)
         self.max_batch = int(max_batch)
@@ -376,8 +438,10 @@ class DecodeModel:
         # what a decode tick takes as `prev` when no tick ran before it:
         # the shape of its own second output (behind the tokens of a model
         # with experts ride the three ops/moe.py::routing_counts)
+        # (four of a model that holds a share of its experts)
         self._no_prev = np.zeros(
-            (self.max_batch + (3 if self.routes else 0),), np.int32)
+            (self.max_batch + (0 if not self.routes else
+                               4 if cfg.experts_held else 3),), np.int32)
 
     # -- placement ------------------------------------------------------
 
@@ -411,6 +475,13 @@ class DecodeModel:
                     f"conv layers is served on one: parallel/recipes.py has "
                     f"no placement for the conv.* weights or the state pool "
                     f"yet")
+            if self.latent:
+                raise NotImplementedError(
+                    f"recipe {self.recipe.name!r} places the model on "
+                    f"{self.recipe.n_devices} devices, and a model with "
+                    f"latent attention is served on one: its pool row has no "
+                    f"heads to divide, and parallel/recipes.py has no "
+                    f"placement for the latent projections yet")
 
             # a recipe smaller than the host's device pool runs on the
             # leading devices (the CPU-sim tests resolve tp=2 on the
@@ -460,12 +531,15 @@ class DecodeModel:
             _DictScope(self.params), self.mesh, self.rules)
 
     def pool_shape(self) -> Tuple[int, int, int]:
-        """The KV pool ``[n_attn_layers * n_blocks, block_size, kv_heads
-        * 2 * head_dim]``: the ``a``-th attention layer's block ``b`` is
-        row-block ``a * n_blocks + b`` (a layer that does not attend owns
-        nothing here), and a token's row holds, K|V head by K|V head, that
-        head's K then its V (``2 * head_dim`` = 128 lanes a head at
-        GPT-2's 64).
+        """The KV pool ``[n_attn_layers * n_blocks, block_size, row]``: the
+        ``a``-th attention layer's block ``b`` is row-block ``a * n_blocks
+        + b`` (a layer that does not attend owns nothing here). What a
+        token's row holds is the block's: K|V head by K|V head, that
+        head's K then its V (``kv_heads * 2 * head_dim`` lanes, 128 a head
+        at GPT-2's 64); or, under latent attention, ONE row for all heads:
+        the normed K|V latent, the rotated key lanes, zeros up to whole
+        128-lane tiles (512 + 64 + 64 = 640 lanes where per-head K and V
+        would take 64 x 320).
 
         Why this shape: the TPU runtime stores an array in the most
         compact tiled layout FOR ITS SHAPE, and a program whose gather
@@ -478,8 +552,9 @@ class DecodeModel:
         prints it for any widths). Where a model's row is not such a
         multiple the runtime pads it, and nothing here needs to know."""
         cfg = self.cfg
-        return (len(self.attn_layers) * self.n_blocks, self.block_size,
-                cfg.kv_heads * 2 * cfg.head_dim)
+        row = (-(-cfg.latent_row // _LANES) * _LANES if self.latent
+               else cfg.kv_heads * 2 * cfg.head_dim)
+        return (len(self.attn_layers) * self.n_blocks, self.block_size, row)
 
     def state_shape(self) -> Optional[Tuple[int, int, int, int]]:
         """The state pool ``[n_conv_layers, conv_kernel - 1, max_batch,
@@ -513,9 +588,14 @@ class DecodeModel:
         if self.mesh is not None:
             return "gather", ("a mesh program: GSPMD cannot partition a "
                               "Mosaic call")
-        why = pa.unsupported(self.cfg.head_dim, self.block_size,
-                             self.cfg.dtype, self.cfg.n_head,
-                             self.cfg.kv_heads)
+        if self.latent:
+            why = pa.unsupported(
+                0, self.block_size, self.cfg.dtype,
+                latent=(self.pool_shape()[2], self.cfg.kv_lora_rank))
+        else:
+            why = pa.unsupported(self.cfg.head_dim, self.block_size,
+                                 self.cfg.dtype, self.cfg.n_head,
+                                 self.cfg.kv_heads)
         return ("gather", why) if why else ("kernel", "")
 
     def _pages_sharding(self):
@@ -573,13 +653,19 @@ class DecodeModel:
         return ((x - mu) / jnp.sqrt(var + self.cfg.norm_eps)
                 * p[f"{name}.scale"] + p[f"{name}.bias"])
 
+    def _swiglu(self, p, x, name):
+        """``(silu(x W_gate) * (x W_up)) W_down`` of the weights ``name.*``."""
+        import jax
+
+        h = (jax.nn.silu(self._linear(p, x, f"{name}.gate"))
+             * self._linear(p, x, f"{name}.up"))
+        return self._linear(p, h, f"{name}.down")
+
     def _mlp(self, p, x, ln, mlp: str = "gelu"):
         import jax
 
         if mlp == "swiglu":
-            h = (jax.nn.silu(self._linear(p, x, f"{ln}.mlp.gate"))
-                 * self._linear(p, x, f"{ln}.mlp.up"))
-            return self._linear(p, h, f"{ln}.mlp.down")
+            return self._swiglu(p, x, f"{ln}.mlp")
         h = jax.nn.gelu(self._linear(p, x, f"{ln}.mlp.fc_in"),
                         approximate=False)
         return self._linear(p, h, f"{ln}.mlp.fc_out")
@@ -614,11 +700,26 @@ class DecodeModel:
 
     def _rot(self, pos):
         """What :func:`_rope` turns heads at positions ``pos`` [...] by:
-        (cos, sin) [..., 1, hd/2], or None where positions are learned."""
+        (cos, sin) [..., 1, hd/2], or None where positions are learned.
+        Under latent attention only ``qk_rope_dim`` lanes turn
+        (:func:`_rope_pairs`), by the block's YaRN description where it has
+        one: its frequencies at every position, cos and sin scaled by its
+        attention factor."""
         import jax.numpy as jnp
 
         if self.cfg.position != "rope":
             return None
+        if self.latent:
+            cfg = self.cfg
+            yarn, r = cfg.rope_yarn, cfg.qk_rope_dim
+            inv = (yarn.inv_freq(r, cfg.rope_theta) if yarn is not None else
+                   cfg.rope_theta ** (-np.arange(0, r, 2, dtype=np.float64)
+                                      / r))
+            ang = (pos.astype(jnp.float32)[..., None, None]
+                   * jnp.asarray(inv, jnp.float32))
+            gain = yarn.attention_factor() if yarn is not None else 1.0
+            cos, sin = jnp.cos(ang), jnp.sin(ang)
+            return (cos, sin) if gain == 1.0 else (cos * gain, sin * gain)
         half = self.cfg.head_dim // 2
         inv = self.cfg.rope_theta ** (-jnp.arange(half, dtype=jnp.float32)
                                       / half)
@@ -655,6 +756,46 @@ class DecodeModel:
                 q, k = _rope(q, rot), _rope(k, rot)
         return q, k, v
 
+    def _latent_qkv(self, lp, h, rot, lead: tuple):
+        """Latent attention's projections of normed hidden ``h`` ``[*lead,
+        D]``: (q_nope ``[*lead, H, qk_nope_dim]``, q_rope ``[*lead, H,
+        qk_rope_dim]`` rotated, the K|V latent ``[*lead, kv_lora_rank]``
+        AFTER its norm, the rotated key lanes ``[*lead, qk_rope_dim]``, one
+        set a position for every head). The last two are what the pool
+        keeps and what decode scores."""
+        import jax
+
+        cfg, ln = self.cfg, _LAYER
+        with jax.named_scope("attn/q_lora"):
+            c_q = _rms(self._linear(lp, h, f"{ln}.attn.q_down"),
+                       lp[f"{ln}.attn.q_norm.scale"], cfg.norm_eps)
+            q = self._linear(lp, c_q, f"{ln}.attn.q_up").reshape(
+                *lead, cfg.n_head, cfg.qk_nope_dim + cfg.qk_rope_dim)
+            q_nope, q_rope = q[..., :cfg.qk_nope_dim], q[..., cfg.qk_nope_dim:]
+        with jax.named_scope("attn/kv_latent"):
+            ckr = self._linear(lp, h, f"{ln}.attn.kv_down")
+            c_kv = _rms(ckr[..., :cfg.kv_lora_rank],
+                        lp[f"{ln}.attn.kv_norm.scale"], cfg.norm_eps)
+            k_rope = ckr[..., cfg.kv_lora_rank:]
+        with jax.named_scope("attn/rope"):
+            q_rope = _rope_pairs(q_rope, rot)
+            # the key's lanes are one "head": rot broadcasts over that axis
+            k_rope = _rope_pairs(k_rope[..., None, :], rot)[..., 0, :]
+        return q_nope, q_rope, c_kv, k_rope
+
+    def _kv_up(self, lp):
+        """The K|V up-projection by head: (W_uk ``[H, c, qk_nope_dim]``,
+        W_uv ``[H, c, v_head_dim]``), no bias."""
+        w = lp[f"{_LAYER}.attn.kv_up.w"]
+        return w[..., :self.cfg.qk_nope_dim], w[..., self.cfg.qk_nope_dim:]
+
+    def _latent_scale(self) -> float:
+        """Latent attention's softmax scale: over the scored head width,
+        times YaRN's gain where the block has one."""
+        cfg = self.cfg
+        gain = cfg.rope_yarn.softmax_gain() if cfg.rope_yarn else 1.0
+        return gain / math.sqrt(cfg.qk_nope_dim + cfg.qk_rope_dim)
+
     def _ffn(self, lp, x, mlp: str):
         """A layer's second half on the residual stream ``x``: norm, the
         feed-forward of kind ``mlp``, residual add. Returns (x, each row's
@@ -680,13 +821,24 @@ class DecodeModel:
             how["norm_topk"] = True
         if cfg.routed_scale != 1.0:
             how["scale"] = cfg.routed_scale
+        if cfg.norm_topk_eps != 1e-6:
+            how["norm_eps"] = cfg.norm_topk_eps
+        if cfg.router_groups > 1:
+            how.update(groups=cfg.router_groups,
+                       keep_groups=cfg.router_keep_groups)
+        # the stack is a share of the router's experts
+        share = ({"share": cfg.experts_held} if cfg.experts_held else {})
         h = self._ln_p(lp, x, f"{ln}.ln2").reshape(-1, cfg.d_model)
         with jax.named_scope("moe/route"):
             dense, idx = moe.route(h, lp[f"{ln}.moe.router.w"],
                                    cfg.experts_per_token, **how)
         with jax.named_scope("moe/experts"):
             y = moe.experts(h, dense, lp[f"{ln}.moe.gate.w"],
-                            lp[f"{ln}.moe.up.w"], lp[f"{ln}.moe.down.w"])
+                            lp[f"{ln}.moe.up.w"], lp[f"{ln}.moe.down.w"],
+                            **share)
+        if cfg.d_ff_shared:
+            with jax.named_scope("moe/shared"):
+                y = y + self._swiglu(lp, h, f"{ln}.moe.shared")
         return x + y.reshape(x.shape), idx
 
     def _conv_in(self, lp, h):
@@ -820,6 +972,29 @@ class DecodeModel:
         if op == "conv":
             y, state_dest = self._conv_prompt(lp, i, h, L, state_dest)
             x = x + y
+        elif op == "latent":
+            # the expanded form: K and V of every head from the latent
+            q_nope, q_rope, c_kv, k_rope = self._latent_qkv(lp, h, rot,
+                                                            (1, L))
+            if kv_dest is not None:
+                pages, blk, slot = kv_dest
+                with jax.named_scope("attn/kv_write"):
+                    pages = pages.at[i * NB + blk, slot].set(_latent_rows(
+                        c_kv[0], k_rope[0], pages.shape[-1]))
+                kv_dest = (pages, blk, slot)
+            with jax.named_scope("attn/kv_expand"):
+                w_uk, w_uv = self._kv_up(lp)
+                k_nope = jnp.einsum("blc,hcn->blhn", c_kv, w_uk)
+                v = jnp.einsum("blc,hcv->blhv", c_kv, w_uv)
+            with jax.named_scope("attn/scores"):
+                q = jnp.concatenate([q_nope, q_rope], axis=-1)
+                k = jnp.concatenate([k_nope, jnp.broadcast_to(
+                    k_rope[:, :, None, :], q_rope.shape)], axis=-1)
+                s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * self._latent_scale()
+                s = jnp.where(causal[None, None], s, _NEG)
+                a = jax.nn.softmax(s, axis=-1)
+                o = jnp.einsum("bhqk,bkhd->bqhd", a, v).reshape(1, L, -1)
+            x = x + self._linear(lp, o, f"{ln}.attn.proj")
         else:
             q, k, v = self._qkv(lp, h, rot, (1, L))
             if kv_dest is not None:
@@ -970,7 +1145,8 @@ class DecodeModel:
         import jax.numpy as jnp
 
         from ..ops import moe
-        from ..ops.pallas.paged_attention import paged_attention
+        from ..ops.pallas.paged_attention import (paged_attention,
+                                                  paged_latent_attention)
 
         cfg, BS, NB = self.cfg, self.block_size, self.n_blocks
         B, H, hd = self.max_batch, cfg.kv_heads, cfg.head_dim
@@ -1004,8 +1180,27 @@ class DecodeModel:
                 a = jax.nn.softmax(s, axis=-1).reshape(B, H, G, S // T, T)
                 return jnp.einsum("bhgjt,bjhtd->bhgd", a, vv).reshape(B, -1)
 
+        def attend_latent_paged(q, pages, tables, pos, valid):
+            with jax.named_scope("attn/paged"):
+                return paged_latent_attention(
+                    q, pages, tables, pos, self._latent_scale(),
+                    cfg.kv_lora_rank)
+
+        def attend_latent_gathered(q, pages, tables, pos, valid):
+            with jax.named_scope("attn/kv_gather"):
+                ctx = pages[tables].reshape(B, S, -1)[..., :q.shape[-1]]
+            with jax.named_scope("attn/scores"):
+                s = jnp.einsum("bhc,bsc->bhs", q, ctx) * self._latent_scale()
+                s = jnp.where(valid[:, None, :], s, _NEG)
+                a = jax.nn.softmax(s, axis=-1)
+                return jnp.einsum("bhs,bsc->bhc", a,
+                                  ctx[..., :cfg.kv_lora_rank])
+
+        share = {"share": cfg.experts_held} if cfg.experts_held else {}
         kernel = self.attention_path()[0] == "kernel"
         attend = attend_paged if kernel else attend_gathered
+        if self.latent:
+            attend = attend_latent_paged if kernel else attend_latent_gathered
         sliced = self.embed_path()[0] == "slices"
 
         def traced(kind):
@@ -1018,6 +1213,23 @@ class DecodeModel:
                 if op == "conv":
                     y, state = self._conv_step(lp, i, h, state)
                     x = x + y
+                elif op == "latent":
+                    # the absorbed form: scores against the rows as they
+                    # lie, the up-projections moved into q and the output
+                    q_nope, q_rope, c_kv, k_rope = self._latent_qkv(
+                        lp, h, rot, (B,))
+                    with jax.named_scope("attn/kv_write"):
+                        pages = pages.at[i * NB + blk, slot].set(
+                            _latent_rows(c_kv, k_rope, pages.shape[-1]))
+                    w_uk, w_uv = self._kv_up(lp)
+                    with jax.named_scope("attn/absorb_q"):
+                        q = jnp.concatenate(
+                            [jnp.einsum("bhn,hcn->bhc", q_nope, w_uk),
+                             q_rope], axis=-1)
+                    o = attend(q, pages, i * NB + block_tables, pos, valid)
+                    with jax.named_scope("attn/absorb_o"):
+                        o = jnp.einsum("bhc,hcv->bhv", o, w_uv).reshape(B, -1)
+                    x = x + self._linear(lp, o, f"{ln}.attn.proj")
                 else:
                     q, k, v = self._qkv(lp, h, rot, (B,))
                     # the layer is part of the block index: no slice of
@@ -1031,7 +1243,8 @@ class DecodeModel:
                 x, idx = self._ffn(lp, x, mlp)
                 return x, pages, state, (
                     None if idx is None else
-                    moe.routing_counts(idx, live, cfg.n_experts))
+                    moe.routing_counts(idx, live, cfg.n_experts,
+                                       **share))
             return self._layer_fn(layer, kind)
 
         layers = {kind: traced(kind) for kind in self.kinds}
@@ -1249,7 +1462,9 @@ class DecodeModel:
         """The second half: wait for a tick's tokens. Returns (next[B] np,
         routing): for a model with experts the tick's routing counts
         (assignments of live slots, distinct experts hit, the largest
-        expert's load, each summed over the expert layers) come back behind the
+        expert's load, each summed over the expert layers, and over the
+        experts HELD where the model holds a share, with the assignments
+        over all the router's experts as a fourth) come back behind the
         tokens, in the one read; None for any other."""
         nxt = np.asarray(nxt)
         if not self.routes:

@@ -188,7 +188,8 @@ def _keys(doc):
 # tree): a file without them is still a base this tree resumes
 _ADDED = {"serving": {"ticks_ahead", "pipeline_drains",
                       "state_writes", "state_pool_bytes", "attn_layers",  # these three: PR 33
-                      "prefills", "prefills_ahead"}}  # PR 48
+                      "prefills", "prefills_ahead",  # PR 48
+                      "moe_assignments_routed"}}  # PR 50
 
 
 @_ledgers()

@@ -130,7 +130,7 @@ def test_the_cells_metrics_are_in_the_manifest_with_their_readers():
         assert spec["workloads"] == ["lfm2-serve-reason"]
         if n.endswith("_roofline") or n.endswith("_pct"):
             assert spec["unit"] == "%"
-    assert man["workloads"][-1]["name"] == "lfm2-serve-reason" and man["configs"][-1]["name"] == "lfm2-24b-a2b"
+    assert man["workloads"][5]["name"] == "lfm2-serve-reason" and man["configs"][3]["name"] == "lfm2-24b-a2b"
     assert manifest.problems(man) == []
     # the traffic file holds the cell's parameters and no others
     tr = cell["traffic"]

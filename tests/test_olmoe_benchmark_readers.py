@@ -109,4 +109,4 @@ def test_the_cells_metrics_are_in_the_manifest_with_their_readers():
     for n in names:
         assert manifest.layer_metric(n)["workloads"] == ["olmoe-serve-batch"]
     assert manifest.layer_metric("moe_decode_program_ms")["args"] == manifest.layer_metric("decode_program_ms")["args"]
-    assert [w["chips"] for w in man["workloads"]].count(4) == 1 and len(man["workloads"]) == 6
+    assert [w["chips"] for w in man["workloads"]].count(4) == 1 and len(man["workloads"]) == 7

@@ -1,6 +1,6 @@
-"""Compile-only check of the serving programs (GPT-2 XL's, OLMoE's and
-LFM2's decode tick and prefill, the paged-attention kernel, the embedding
-table at rest) against a real TPU target (tests/tpu_aot.py says how)."""
+"""Compile-only check of the serving programs (GPT-2 XL's, OLMoE's, LFM2's
+and A.X-K1's decode tick and prefill, the paged-attention kernel in both its
+modes, the embedding table at rest) against a real TPU target (tests/tpu_aot.py says how)."""
 import functools
 import math
 import os
@@ -414,6 +414,92 @@ def test_lfm2_program_moves_neither_pool_nor_expert_weights(lfm2_programs, name)
     assert not big, big
 
 
+# -- A.X-K1: latent attention over a latent pool (tests/test_axk1_serving.py) --
+
+@pytest.fixture(scope="module")
+def axk1_programs(tpu_device):
+    """The cell's decode tick and its bucket-256 prefill at the published
+    widths, cut to the first two layers (latent attention + dense, latent
+    attention + 12 held experts and the shared one: both kinds), compiled
+    for the described chip."""
+    return _compiled_programs(report.cell_model("axk1-serve-reason", n_layer=2), tpu_device)
+
+
+@pytest.mark.parametrize("name,attends", [("decode_tick", ("attn/absorb_q", "attn/paged", "attn/absorb_o")),
+                                          ("prefill_256", ("attn/kv_expand", "attn/scores"))])
+def test_axk1_programs_carry_their_names_and_each_forms_scopes(axk1_programs, name, attends):
+    from benchmark import manifest
+
+    text = axk1_programs.text[name]
+    assert re.search(rf"HloModule jit_{name}\b", text)
+    metric = "axk1_decode_program_ms" if name == "decode_tick" else "axk1_prefill_program_ms"
+    assert re.search(manifest.layer_metric(metric)["args"]["pattern"], f"jit_{name}")
+    ops = set(re.findall(r'op_name="([^"]*)"', text))
+    latent = ("attn/q_lora", "attn/kv_latent", "attn/rope", "attn/kv_write") + attends
+    for layer, scopes in (("layer_latent_swiglu", latent + ("mlp",)),
+                          ("layer_latent_moe", latent + ("moe/route", "moe/experts", "moe/shared"))):
+        for scope in scopes:
+            assert any(f"jit({name})/jit({layer})/{scope}/" in o for o in ops), (layer, scope)
+    assert not any("/attn/kv_gather/" in o or "/attn/qk_norm/" in o for o in ops)
+    # the absorbed form is the decode tick's alone, the expanded form the prefill's
+    other = ("attn/kv_expand",) if name == "decode_tick" else ("attn/absorb_q", "attn/absorb_o", "attn/paged")
+    assert not any(f"/{scope}/" in o for o in ops for scope in other)
+
+
+def test_axk1_decode_tick_holds_the_latent_kernel_once_a_layer(axk1_programs):
+    """64 heads over ONE row of 640 lanes a position: the kernel's latent
+    mode under its own name, not the gathered window and not the per-head
+    kernel; the report says what the row holds."""
+    dm = axk1_programs.dm
+    assert dm.attention_path() == ("kernel", "") and dm.attn_layers == [0, 1] and dm.latent
+    assert kernel_names(axk1_programs.text["decode_tick"]) == ["paged_latent_attention"] * 2
+    assert kernel_names(axk1_programs.text["prefill_256"]) == []
+    facts = axk1_programs.facts["decode_tick"]
+    assert facts["mosaic_kernels"] == {"paged_latent_attention": 2}
+    att = report.attention_facts(dm, facts["mosaic_kernels"])
+    assert (att["decode_path"], att["kernel"], att["paged_attention_calls"]) == ("kernel", "paged_latent_attention", 2)
+    assert att["row"] == {"lanes": 640, "holds": "latent | rotated key lanes | zeros", "latent": 512,
+                          "rotated": 64, "zeros": 64, "heads_sharing_it": 64}
+    # two buffers of 128 rows of 640 bf16 lanes, the float32 accumulator of 64 heads, two statistics
+    assert att["vmem_scratch_bytes"] == 2 * 128 * 640 * 2 + 64 * 640 * 4 + 2 * 64 * 128 * 4 < 2 ** 20
+    # a per-head model's report names its own kernel and row
+    assert report.attention_facts(report.cell_model("olmoe-serve-batch", n_layer=1), {})["kernel"] == "paged_attention"
+
+
+@pytest.mark.parametrize("name", _SERVING_PROGRAMS)
+def test_axk1_latent_pool_is_donated_rests_row_major_and_is_never_copied(axk1_programs, name):
+    dm, facts = axk1_programs.dm, axk1_programs.facts[name]
+    pool = facts["pool"]
+    assert dm.pool_shape() == (2 * 12032, 16, 640) and dm.state_shape() is None
+    assert pool["parameter"] is not None and pool["aliased_to_output"], pool
+    assert pool["layouts_in_program"] == [pool["layout"]] and pool["layout"].startswith("2,1,0:T(8,128)"), pool
+    assert facts["aliased_parameters"] == [pool["parameter"]]
+    need = 2 * 12032 * 16 * 640 * 2  # unpadded: 640 lanes are five whole tiles
+    assert abs(facts["memory"]["alias_size_in_bytes"] - need) <= 0.01 * need
+    # nothing as large as the pool or as one stacked weight of the held experts is copied or relaid
+    limit = min(math.prod(dm.pool_shape()), 12 * 7168 * 2048)
+    big = [c for c in facts["top_level_copies"] if c["elements"] >= limit]
+    assert not big, big
+    assert facts["memory"]["temp_size_in_bytes"] < 2 ** 30
+
+
+def test_axk1_decode_tick_reads_four_routing_counts_behind_its_tokens(axk1_programs):
+    entry = report.entry_instructions(axk1_programs.text["decode_tick"])
+    ints = sorted(i["dims"] for i in entry if i["op"] == "parameter" and i["dtype"] == "s32")
+    assert ints == sorted([(96,), (96,), (96 + 4,), (96, 128)])
+
+
+def test_the_latent_kernel_compiles_alone_over_the_whole_cells_pool(tpu_arg):
+    from paddle_tpu.ops.pallas.paged_attention import paged_latent_attention
+
+    text = compiled_text(
+        functools.partial(paged_latent_attention, scale=0.13, v_lanes=512, interpret=False),
+        tpu_arg((96, 64, 576), jnp.bfloat16), tpu_arg((8 * 12032, 16, 640), jnp.bfloat16),
+        tpu_arg((96, 128), jnp.int32), tpu_arg((96,), jnp.int32))
+    assert kernel_names(text) == ["paged_latent_attention"]
+    assert not re.search(r"bf16\[96256,16,640\][^\n]* (copy|transpose|reshape)\(", text)
+
+
 # -- the programs of the configurations the benchmark already had ------------
 
 # sha256 (first 16 hex digits) of each program's StableHLO text as lowered
@@ -425,7 +511,9 @@ def test_lfm2_program_moves_neither_pool_nor_expert_weights(lfm2_programs, name)
 # argument, the newest token vector on the device, and writes the prompt's
 # first token into it (the decode ticks did not change for that; before it
 # gpt2 / olmoe / lfm2 read 7b7f087b9f441263 / d4e27627cb07bdd5 /
-# 9bcd9b55f481105f).
+# 9bcd9b55f481105f). PR 50 (latent attention, a shared expert, group-limited
+# routing, an expert share) left all six as they were: every field it added
+# to the block's description defaults to what these three blocks have.
 _PARENT_PROGRAMS = {
     ("gpt2", "decode_tick"): "cf354808014e7cc1", ("gpt2", "prefill_32"): "461e4d36e1e9524d",
     ("olmoe", "decode_tick"): "653b0a7cdc6d0601", ("olmoe", "prefill_32"): "2aa9785cfd6b45c9",

@@ -6,9 +6,11 @@ are answered here in seconds and at no chip time: which layout the KV
 pool has at the program's edge, whether the output aliases it, which
 whole-array ``copy`` (and unfused ``reshape`` / ``transpose``: a relayout)
 instructions the compiler put into the program, which Mosaic kernels it
-holds, how the decode tick attends (the ``paged_attention`` kernel, or the
-gathered window and why: a model whose K|V row is not whole 128-lane tiles
-shows here, before a chip run), how it looks up its token rows (``embed``:
+holds, how the decode tick attends (the ``paged_attention`` kernel, its
+latent mode ``paged_latent_attention`` over a pool whose row is one latent a
+position, or the gathered window and why: a model whose K|V row is not whole
+128-lane tiles shows here, before a chip run; ``row`` says what a position's
+row holds and how many lanes of it are used), how it looks up its token rows (``embed``:
 a slice a slot or one gather, the embedding table's layouts in the program
 and the copies as large as the table) and ``memory_analysis()``. Sizes and
 instruction names only: nothing runs, so no time comes out of this tool.
@@ -150,9 +152,24 @@ def attention_facts(dm, mosaic_kernels: Dict[str, int]) -> Dict[str, Any]:
     from paddle_tpu.ops.pallas import paged_attention as pa
 
     path, why = dm.attention_path()
+    cfg, lanes = dm.cfg, dm.pool_shape()[2]
+    if dm.latent:  # one row a position for every head
+        calls = mosaic_kernels.get("paged_latent_attention", 0)
+        return {"decode_path": path, "attention_layers": len(dm.attn_layers),
+                "kernel": "paged_latent_attention",
+                "row": {"lanes": lanes, "holds": "latent | rotated key lanes | zeros",
+                        "latent": cfg.kv_lora_rank, "rotated": cfg.qk_rope_dim,
+                        "zeros": lanes - cfg.latent_row, "heads_sharing_it": cfg.n_head},
+                "why": why or "one device, a latent row and its V prefix of whole 128-lane tiles, "
+                              "pages of whole tiles",
+                "paged_attention_calls": calls,
+                "vmem_scratch_bytes": pa.vmem_scratch_bytes(
+                    cfg.n_head, 0, dm.block_size, cfg.dtype, row_lanes=lanes) if calls else 0}
     calls = mosaic_kernels.get("paged_attention", 0)
-    cfg = dm.cfg
     return {"decode_path": path, "attention_layers": len(dm.attn_layers),
+            "kernel": "paged_attention",
+            "row": {"lanes": lanes, "holds": "per K|V head, its K then its V",
+                    "kv_heads": cfg.kv_heads, "head_dim": cfg.head_dim},
             "query_heads_a_kv_head": cfg.n_head // cfg.kv_heads,
             "why": why or "one device, heads of whole 128-lane tiles, pages of whole tiles",
             "paged_attention_calls": calls,
