@@ -24,14 +24,22 @@ from ..reference import axk1 as reference
 # the same prefix, scores it within LOGIT_TOL of its own best token. The
 # program computes in bfloat16 and decodes in the absorbed form (scores
 # against the latent row as it lies, where the reference expands K and V per
-# head), so rounding alone moves a logit. The limit lies between two readings
-# (my chip runs, PR 50; PERF.md section 6 has every number): the largest gap
-# the sound program's served tokens showed over its seeds, and the weakest of
-# the nine faults of benchmark/tools/axk1_fault_readings.py; the same
-# reference with its matmul operands rounded to float8 (e4m3), the nearest
-# precision below the configuration's bfloat16, reads beyond it and so comes
-# out NOT correct, as it has to.
-LOGIT_TOL = 0.45
+# head), and with 192 experts under a group choice its top-8 set differs from
+# float32's in 28 % of the (position, layer) pairs (``routing_agreement_share``
+# 0.71-0.74 in every run; LFM2's 64 experts read 0.93), so rounding alone
+# moves a logit. The limit lies between two readings (my chip runs, PR 50;
+# PERF.md section 6 has every number): the largest gap the sound program's
+# served tokens showed, 0.579 over nine runs and 46,399 checked tokens (per
+# run 0.333-0.579), and the weakest of the faults of
+# benchmark/tools/axk1_fault_readings.py that this check can see, the held
+# experts weighed with the next share's routing weights, 1.21 on 1,024 tokens
+# (the others 3.4-14.3); the same reference with its matmul operands rounded
+# to float8 (e4m3), the nearest precision below the configuration's bfloat16,
+# reads 2.72 and so comes out NOT correct, as it has to. Two faults that only
+# change WHICH experts a token takes (no group limit; the router's scores in
+# bfloat16) read 0.43 and 0.38, inside the sound program's own range: this
+# chip holds a sixteenth of what the router decides (that module's docstring).
+LOGIT_TOL = 0.85
 N_CHECKED = 4
 
 

@@ -94,12 +94,13 @@ def unsupported(head_dim: int, block_size: int, dtype,
                 0 < v_lanes <= row_lanes):
             return (f"a latent row of {row_lanes} lanes whose leading "
                     f"{v_lanes} are V: not whole {_LANES}-lane tiles")
-    elif n_head != n_kv_head and (n_head % n_kv_head or n_kv_head % 8):
-        return (f"{n_head} query heads over {n_kv_head} K|V heads: not a "
-                f"whole group over whole 8-row tiles")
-    if latent is None and (2 * head_dim) % _LANES:
-        return (f"a head's K|V is {2 * head_dim} lanes, not a multiple of "
-                f"{_LANES}")
+    else:
+        if n_head != n_kv_head and (n_head % n_kv_head or n_kv_head % 8):
+            return (f"{n_head} query heads over {n_kv_head} K|V heads: not "
+                    f"a whole group over whole 8-row tiles")
+        if (2 * head_dim) % _LANES:
+            return (f"a head's K|V is {2 * head_dim} lanes, not a multiple "
+                    f"of {_LANES}")
     if block_size % _sublanes(dtype) or _STEP_TOKENS % block_size:
         return (f"a page of {block_size} tokens is not a whole number of "
                 f"{_sublanes(dtype)}-row tiles dividing {_STEP_TOKENS}")
@@ -157,27 +158,25 @@ def _kernel(tables_ref, lens_ref, q_ref, pool_ref, o_ref,
     l_scr[...] = jnp.zeros_like(l_scr)
     acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # row h of the block-diagonal views owns lanes [h * w, (h + 1) * w)
-    row = lane = diag = None
-    if not v_lanes:
-        row = jax.lax.broadcasted_iota(jnp.int32, (hp, hw), 0)
-        lane = jax.lax.broadcasted_iota(jnp.int32, (hp, hw), 1)
     if v_lanes:  # the latent mode: every head over the whole shared row
         qblk = q_ref[...].astype(buf.dtype)
-    elif group == 1:
-        diag = (lane >= row * w) & (lane < (row + 1) * w)
-        q_rows = q_ref[...]
     else:
-        # row r * n_kv + j: query head j * group + r, over K|V head j
-        kv = jax.lax.rem(row, n_kv)
-        diag = ((lane >= kv * w) & (lane < (kv + 1) * w)
-                & (row < group * n_kv))
-        q_rows = jnp.concatenate(
-            [jnp.broadcast_to(q_ref[r:r + 1, :], (n_kv, hw))
-             for r in range(group)]
-            + [jnp.zeros((hp - group * n_kv, hw), q_ref.dtype)]
-            * (hp > group * n_kv))
-    if not v_lanes:
+        # row h of the block-diagonal views owns lanes [h * w, (h + 1) * w)
+        row = jax.lax.broadcasted_iota(jnp.int32, (hp, hw), 0)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (hp, hw), 1)
+        if group == 1:
+            diag = (lane >= row * w) & (lane < (row + 1) * w)
+            q_rows = q_ref[...]
+        else:
+            # row r * n_kv + j: query head j * group + r, over K|V head j
+            kv = jax.lax.rem(row, n_kv)
+            diag = ((lane >= kv * w) & (lane < (kv + 1) * w)
+                    & (row < group * n_kv))
+            q_rows = jnp.concatenate(
+                [jnp.broadcast_to(q_ref[r:r + 1, :], (n_kv, hw))
+                 for r in range(group)]
+                + [jnp.zeros((hp - group * n_kv, hw), q_ref.dtype)]
+                * (hp > group * n_kv))
         qblk = jnp.where(diag, q_rows, 0.0).astype(buf.dtype)  # [hp, hw]
 
     def step(c, slot):
@@ -226,6 +225,17 @@ def _kernel(tables_ref, lens_ref, q_ref, pool_ref, o_ref,
                                         axis=0, keepdims=True)
 
 
+def _scratch(step_tokens: int, hw: int, hp: int, dtype):
+    """The kernel's scratch: two page buffers, their semaphores, the buffer
+    in use, then running maximum, sum and weighted rows of ``hp`` heads."""
+    return [pltpu.VMEM((2, step_tokens, hw), dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((hp, _LANES), jnp.float32),
+            pltpu.VMEM((hp, _LANES), jnp.float32),
+            pltpu.VMEM((hp, hw), jnp.float32)]
+
+
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def _paged_attention(q, pool, tables, context_lens, *, scale, interpret):
     B, H, hd = q.shape
@@ -251,14 +261,7 @@ def _paged_attention(q, pool, tables, context_lens, *, scale, interpret):
             grid=(B,),
             in_specs=[row, pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=row,
-            scratch_shapes=[
-                pltpu.VMEM((2, pps * bs, hw), pool.dtype),
-                pltpu.SemaphoreType.DMA((2,)),
-                pltpu.SMEM((1,), jnp.int32),
-                pltpu.VMEM((hp, _LANES), jnp.float32),
-                pltpu.VMEM((hp, _LANES), jnp.float32),
-                pltpu.VMEM((hp, hw), jnp.float32),
-            ]),
+            scratch_shapes=_scratch(pps * bs, hw, hp, pool.dtype)),
         out_shape=jax.ShapeDtypeStruct((B, group, hw), jnp.float32),
         # the buffers and the copy in flight carry over from slot to slot
         compiler_params=compiler_params(("arbitrary",)),
@@ -292,14 +295,7 @@ def _paged_latent_attention(q, pool, tables, context_lens, *, scale, v_lanes,
                       pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((None, hp, v_lanes),
                                    lambda b, *_: (b, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((2, pps * bs, hw), pool.dtype),
-                pltpu.SemaphoreType.DMA((2,)),
-                pltpu.SMEM((1,), jnp.int32),
-                pltpu.VMEM((hp, _LANES), jnp.float32),
-                pltpu.VMEM((hp, _LANES), jnp.float32),
-                pltpu.VMEM((hp, hw), jnp.float32),
-            ]),
+            scratch_shapes=_scratch(pps * bs, hw, hp, pool.dtype)),
         out_shape=jax.ShapeDtypeStruct((B, hp, v_lanes), jnp.float32),
         compiler_params=compiler_params(("arbitrary",)),
         interpret=interpret,

@@ -826,8 +826,6 @@ class DecodeModel:
         if cfg.router_groups > 1:
             how.update(groups=cfg.router_groups,
                        keep_groups=cfg.router_keep_groups)
-        # the stack is a share of the router's experts
-        share = ({"share": cfg.experts_held} if cfg.experts_held else {})
         h = self._ln_p(lp, x, f"{ln}.ln2").reshape(-1, cfg.d_model)
         with jax.named_scope("moe/route"):
             dense, idx = moe.route(h, lp[f"{ln}.moe.router.w"],
@@ -835,7 +833,7 @@ class DecodeModel:
         with jax.named_scope("moe/experts"):
             y = moe.experts(h, dense, lp[f"{ln}.moe.gate.w"],
                             lp[f"{ln}.moe.up.w"], lp[f"{ln}.moe.down.w"],
-                            **share)
+                            share=cfg.experts_held)
         if cfg.d_ff_shared:
             with jax.named_scope("moe/shared"):
                 y = y + self._swiglu(lp, h, f"{ln}.moe.shared")
@@ -1196,7 +1194,6 @@ class DecodeModel:
                 return jnp.einsum("bhs,bsc->bhc", a,
                                   ctx[..., :cfg.kv_lora_rank])
 
-        share = {"share": cfg.experts_held} if cfg.experts_held else {}
         kernel = self.attention_path()[0] == "kernel"
         attend = attend_paged if kernel else attend_gathered
         if self.latent:
@@ -1244,7 +1241,7 @@ class DecodeModel:
                 return x, pages, state, (
                     None if idx is None else
                     moe.routing_counts(idx, live, cfg.n_experts,
-                                       **share))
+                                       cfg.experts_held))
             return self._layer_fn(layer, kind)
 
         layers = {kind: traced(kind) for kind in self.kinds}

@@ -365,7 +365,16 @@ class Router:
                  seed: int = 0,
                  health_interval_s: float = 0.5,
                  health_timeout_s: float = 1.0,
-                 max_workers: int = 64):
+                 max_workers: Optional[int] = None):
+        """``max_workers``: dispatches in flight at once (each holds a
+        thread until its answer is whole). None: 64, or where the
+        replicas are in this process twice their decode slots if that is
+        more, so that every slot can hold a tenant with as many queued
+        behind (a pool smaller than the slots can never fill them)."""
+        if max_workers is None:
+            slots = sum(getattr(getattr(c, "engine", None), "max_batch", 0)
+                        for c in replicas)
+            max_workers = max(64, 2 * slots)
         self._reps: Dict[str, _Rep] = {}
         for client in replicas:
             if client.name in self._reps:

@@ -415,3 +415,19 @@ def test_score_shares_the_expanded_form(model, prompt):
     lp = logits - np.log(np.exp(logits).sum(-1, keepdims=True))
     want = -lp[np.arange(19), prompt[1:20]]
     assert nll.shape == (19,) and np.abs(nll - want).max() <= 1e-3 and total == pytest.approx(want.sum(), abs=1e-2)
+
+
+def test_a_router_over_replicas_in_this_process_can_fill_their_slots():
+    """96 clients against 96 slots: a dispatch holds a thread until its
+    answer is whole, so a pool of 64 never filled the batch (PR 50's first
+    chip run: 'closed loop never filled the batch')."""
+    import types
+
+    def rep(name, slots):
+        return serving.LocalReplica(name, types.SimpleNamespace(max_batch=slots))
+
+    assert serving.Router([rep("a", 96)])._pool._max_workers == 192
+    assert serving.Router([rep("a", 12)])._pool._max_workers == 64          # the other cells: as before
+    assert serving.Router([rep("a", 40), rep("b", 40)])._pool._max_workers == 160
+    assert serving.Router([rep("a", 96)], max_workers=8)._pool._max_workers == 8
+    assert serving.Router([types.SimpleNamespace(name="http")])._pool._max_workers == 64
