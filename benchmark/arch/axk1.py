@@ -29,7 +29,7 @@ from ..reference import axk1 as reference
 # 0.71-0.74 in every run; LFM2's 64 experts read 0.93), so rounding alone
 # moves a logit. The limit lies between two readings (my chip runs, PR 50;
 # PERF.md section 6 has every number): the largest gap the sound program's
-# served tokens showed, 0.579 over nine runs and 46,399 checked tokens (per
+# served tokens showed, 0.579 over eleven runs and 57,715 checked tokens (per
 # run 0.333-0.579), and the weakest of the faults of
 # benchmark/tools/axk1_fault_readings.py that this check can see, the held
 # experts weighed with the next share's routing weights, 1.21 on 1,024 tokens
