@@ -18,7 +18,9 @@ staged or relaid), ``pages_per_step`` pages at a time into one of two
 buffers, and starts the next copy (the slot's next pages, or the NEXT
 slot's first ones) before it computes on the current buffer. Running
 maximum, sum and weighted rows per head live in VMEM scratch (online
-softmax, float32).
+softmax, float32). How long a step is, and how its copies are started
+and waited for, follows from the row's bytes (:func:`step_schedule`): a
+step's fixed work is hidden by the step's own bytes or not at all.
 
 All heads at once, no lane slicing: a head is ``2 * head_dim`` lanes of
 the row (a multiple of 128), its K half then its V half. q arrives
@@ -43,17 +45,22 @@ and into the output): the row is ``[latent | rotated key lanes | zeros]``,
 every head's K is the whole row and its V the row's leading ``v_lanes``.
 That is the same two matmuls with nothing to mask: q arrives ``[heads,
 row]`` (absorbed q over the latent lanes, rotated q over the rotated ones,
-zeros over the padding), ``q @ rows^T`` scores every head, ``p @ rows``
-weighs the row for every head, and the output keeps the leading
-``v_lanes``. Same page walk, double buffer and online softmax; the kernel
-is named ``paged_latent_attention`` so that a trace tells the two apart.
-All heads share the bytes of one row, so this mode is bound by the matrix
-unit as much as by the copies (``2 * heads * (row + v_lanes)`` operations
-against ``row * itemsize`` bytes a position).
+zeros over the padding), ``q @ rows^T`` scores every head, and ``p @
+rows[:, :v_lanes]`` weighs the row's V prefix for every head: the output.
+Same page walk, double buffer and online softmax; the kernel is named
+``paged_latent_attention`` so that a trace tells the two apart.
+All heads share the bytes of one row (``2 * heads * (row + v_lanes)``
+operations against ``row * itemsize`` bytes a position), and on the chip
+neither paces it: at 128 positions a step the arithmetic alone took 70 % of
+the kernel's time and the copies alone 44 %, the two in turn and not
+beside each other, the arithmetic waiting on the matrix unit's round trip
+for 64 streamed rows and the copies on their own scalar work (PERF.md,
+PR 51). Longer steps and batched copies halved both.
 """
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -64,7 +71,43 @@ from .backend import compiler_params, on_tpu
 
 _NEG_INF = -1e30  # finite: a masked score never makes inf - inf
 _LANES = 128
-_STEP_TOKENS = 128  # tokens a step scores: one full contraction of p @ rows
+_STEP_TOKENS = 128  # the shortest step: one full contraction of p @ rows
+_LONG_STEP = 512  # the longest: past it a context's last, part empty step
+# costs more than a step's fixed work saves (PERF.md, PR 51)
+_BUFFER_BYTES = 1 << 20  # what one page buffer may hold
+_GROUP = 4  # page copies started in one loop body of a batched step
+
+
+class Step(NamedTuple):
+    """How the kernel walks a slot's pages (:func:`step_schedule`)."""
+    positions: int  # positions a step copies, scores and weighs
+    buffers: int  # page buffers of that many positions; one step's copies
+    # are in flight while the step before them is computed
+    batched: bool  # a step's copies are started _GROUP to a loop body and
+    # waited for by their bytes (a wait for each power of two in its
+    # pages), not one page a loop body both times
+
+
+def step_schedule(row_bytes: int) -> Step:
+    """The step of a pool whose position is ``row_bytes`` wide: what the
+    kernel can see of its own call, and nothing a caller sets.
+
+    A step has fixed work that only its own bytes hide: its scalar
+    bookkeeping, one pass over the statistics and one rescale of the
+    accumulator, and per page a descriptor (~30 dependent scalar
+    operations, bounds checks among them) and a wait, none of which a
+    matmul overlaps. A row of 6-8 KB carries 0.8-1 MB in 128 positions and
+    sits on its bytes: it keeps 128 positions and a wait a page. A narrower
+    row takes twice the positions for as long as a buffer stays under
+    ``_BUFFER_BYTES`` (512 positions at most), so that its step carries
+    what a wide row's does, and a step longer than the shortest batches its
+    copies. Both modes alike: at a 1,280 B latent row and a 2,048 B
+    grouped-query row the same schedule won the sweep (PERF.md, PR 51)."""
+    positions = _STEP_TOKENS
+    while (positions < _LONG_STEP
+           and 2 * positions * row_bytes <= _BUFFER_BYTES):
+        positions *= 2
+    return Step(positions, 2, positions > _STEP_TOKENS)
 
 
 def _round_up(n: int, m: int) -> int:
@@ -101,48 +144,94 @@ def unsupported(head_dim: int, block_size: int, dtype,
         if (2 * head_dim) % _LANES:
             return (f"a head's K|V is {2 * head_dim} lanes, not a multiple "
                     f"of {_LANES}")
-    if block_size % _sublanes(dtype) or _STEP_TOKENS % block_size:
+        row_lanes = n_kv_head * 2 * head_dim
+    step = step_schedule(row_lanes * jnp.dtype(dtype).itemsize).positions
+    if block_size % _sublanes(dtype) or step % block_size:
         return (f"a page of {block_size} tokens is not a whole number of "
-                f"{_sublanes(dtype)}-row tiles dividing {_STEP_TOKENS}")
+                f"{_sublanes(dtype)}-row tiles dividing {step}")
     return ""
 
 
 def vmem_scratch_bytes(n_head: int, head_dim: int, block_size: int,
-                       dtype, n_kv_head: int = 0, row_lanes: int = 0) -> int:
-    """VMEM the kernel's scratch takes (both page buffers, the
-    accumulator, the two statistics), for the compile report.
-    ``row_lanes``: the latent mode's shared row."""
+                       dtype, n_kv_head: int = 0,
+                       latent: tuple = ()) -> int:
+    """VMEM the kernel's scratch takes (the page buffers of its step, the
+    accumulator, the two statistics), for the compile report. ``latent =
+    (row_lanes, v_lanes)``: the latent mode's shared row and its V prefix,
+    which is all its accumulator holds."""
     hp = _round_up(n_head, _sublanes(dtype))
-    hw = row_lanes or (n_kv_head or n_head) * 2 * head_dim
-    return (2 * _STEP_TOKENS * hw * jnp.dtype(dtype).itemsize
-            + hp * hw * 4 + 2 * hp * _LANES * 4)
+    hw, acc = latent or ((n_kv_head or n_head) * 2 * head_dim,) * 2
+    itemsize = jnp.dtype(dtype).itemsize
+    step = step_schedule(hw * itemsize)
+    return (step.buffers * step.positions * hw * itemsize
+            + hp * acc * 4 + 2 * hp * _LANES * 4)
 
 
 def _kernel(tables_ref, lens_ref, q_ref, pool_ref, o_ref,
             buf, sem, cur, m_scr, l_scr, acc_scr,
             *, block_size, pages_per_step, max_blocks, head_lanes, scale,
-            n_kv=0, group=1, v_lanes=0):
+            n_kv=0, group=1, v_lanes=0, batched=False):
     b, nb = pl.program_id(0), pl.num_programs(0)
     bs, pps, w = block_size, pages_per_step, head_lanes
     step_tokens = pps * bs
-    hp, hw = acc_scr.shape
+    hp, hw = acc_scr.shape[0], buf.shape[2]
 
     def n_pages(i):
         return jnp.minimum(lens_ref[i] // bs + 1, max_blocks)
 
-    def copies(i, c, slot, act):
-        """``act`` on the copy of each page slot ``i`` has in step ``c``
-        (pages past the context are neither started nor waited for)."""
+    def page_copy(i, first, j, slot):
+        """The copy of page ``first + j`` of slot ``i``'s context to its
+        place ``j`` in buffer ``slot``."""
+        return pltpu.make_async_copy(
+            pool_ref.at[tables_ref[i * max_blocks + first + j]],
+            buf.at[slot, pl.ds(pl.multiple_of(j * bs, bs), bs)],
+            sem.at[slot])
+
+    def copies(i, c, slot, act, first_page=0):
+        """``act`` on the copy of each page slot ``i`` has in step ``c``,
+        from its ``first_page`` on (pages past the context are neither
+        started nor waited for)."""
         first = c * pps
 
         def one(j, carry):
-            act(pltpu.make_async_copy(
-                pool_ref.at[tables_ref[i * max_blocks + first + j]],
-                buf.at[slot, pl.ds(pl.multiple_of(j * bs, bs), bs)],
-                sem.at[slot]))
+            act(page_copy(i, first, j, slot))
             return carry
 
-        jax.lax.fori_loop(0, jnp.minimum(pps, n_pages(i) - first), one, 0)
+        jax.lax.fori_loop(first_page,
+                          jnp.minimum(pps, n_pages(i) - first), one, 0)
+
+    def start(i, c, slot):
+        """Starts the copies of step ``c`` of slot ``i``."""
+        if not batched:
+            copies(i, c, slot, lambda dma: dma.start())
+            return
+        # a copy's descriptor is a chain of ~30 dependent scalar operations
+        # (the table entry, two addresses, two bounds checks): _GROUP of
+        # them to a loop body let the scheduler overlap the chains
+        first = c * pps
+        groups = jnp.minimum(pps, n_pages(i) - first) // _GROUP
+
+        def group(g, carry):
+            for u in range(_GROUP):
+                page_copy(i, first, g * _GROUP + u, slot).start()
+            return carry
+
+        jax.lax.fori_loop(0, groups, group, 0)
+        copies(i, c, slot, lambda dma: dma.start(), groups * _GROUP)
+
+    def wait(i, c, slot):
+        """Until the copies of step ``c`` of slot ``i`` have landed."""
+        if not batched:
+            copies(i, c, slot, lambda dma: dma.wait())
+            return
+        # a copy's semaphore counts bytes, so ONE wait for as many rows as
+        # several pages hold stands for all of them: a wait for each power
+        # of two in the step's pages, whichever rows it names
+        n = jnp.minimum(pps, n_pages(i) - c * pps)
+        for k in (1 << j for j in range(pps.bit_length())):
+            @pl.when(n & k != 0)
+            def _(rows=buf.at[slot, pl.ds(0, k * bs)]):
+                pltpu.make_async_copy(rows, rows, sem.at[slot]).wait()
 
     @pl.when(b == 0)
     def _first():
@@ -150,7 +239,7 @@ def _kernel(tables_ref, lens_ref, q_ref, pool_ref, o_ref,
         # they must not hold what VMEM held before (0 * NaN)
         buf[...] = jnp.zeros_like(buf)
         cur[0] = 0
-        copies(0, 0, 0, lambda dma: dma.start())
+        start(0, 0, 0)
 
     pos = lens_ref[b]
     n_steps = pl.cdiv(n_pages(b), pps)
@@ -184,13 +273,13 @@ def _kernel(tables_ref, lens_ref, q_ref, pool_ref, o_ref,
 
         @pl.when(c + 1 < n_steps)
         def _():
-            copies(b, c + 1, nxt, lambda dma: dma.start())
+            start(b, c + 1, nxt)
 
         @pl.when((c + 1 == n_steps) & (b + 1 < nb))
         def _():
-            copies(b + 1, 0, nxt, lambda dma: dma.start())
+            start(b + 1, 0, nxt)
 
-        copies(b, c, slot, lambda dma: dma.wait())
+        wait(b, c, slot)
         rows = buf[slot]  # [step_tokens, hw]: K|V of every head
         s = jax.lax.dot_general(
             qblk, rows, (((1,), (1,)), ((), ())),
@@ -203,9 +292,12 @@ def _kernel(tables_ref, lens_ref, q_ref, pool_ref, o_ref,
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
         l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+        # the latent mode weighs the row's V prefix alone: the lanes behind
+        # it (the rotated keys, the padding) are no part of any output
         pv = jax.lax.dot_general(
-            p.astype(rows.dtype), rows, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)  # [hp, hw]
+            p.astype(rows.dtype), rows[:, :v_lanes] if v_lanes else rows,
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)  # [hp, hw or v_lanes]
         acc_scr[...] = acc_scr[...] * alpha + pv
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
@@ -214,7 +306,7 @@ def _kernel(tables_ref, lens_ref, q_ref, pool_ref, o_ref,
     cur[0] = jax.lax.fori_loop(0, n_steps, step, cur[0])
     # position 0 is never masked, so every sum is positive
     if v_lanes:
-        o_ref[...] = (acc_scr[...] / l_scr[:, :1])[:, :v_lanes]
+        o_ref[...] = acc_scr[...] / l_scr[:, :1]
         return
     out = jnp.where(diag, acc_scr[...] / l_scr[:, :1], 0.0)
     if group == 1:
@@ -225,15 +317,16 @@ def _kernel(tables_ref, lens_ref, q_ref, pool_ref, o_ref,
                                         axis=0, keepdims=True)
 
 
-def _scratch(step_tokens: int, hw: int, hp: int, dtype):
-    """The kernel's scratch: two page buffers, their semaphores, the buffer
-    in use, then running maximum, sum and weighted rows of ``hp`` heads."""
-    return [pltpu.VMEM((2, step_tokens, hw), dtype),
-            pltpu.SemaphoreType.DMA((2,)),
+def _scratch(step: Step, hw: int, hp: int, dtype, v_lanes: int = 0):
+    """The kernel's scratch: the step's page buffers, their semaphores, the
+    buffer in use, then running maximum, sum and weighted rows of ``hp``
+    heads (in the latent mode the rows' leading ``v_lanes`` alone)."""
+    return [pltpu.VMEM((step.buffers, step.positions, hw), dtype),
+            pltpu.SemaphoreType.DMA((step.buffers,)),
             pltpu.SMEM((1,), jnp.int32),
             pltpu.VMEM((hp, _LANES), jnp.float32),
             pltpu.VMEM((hp, _LANES), jnp.float32),
-            pltpu.VMEM((hp, hw), jnp.float32)]
+            pltpu.VMEM((hp, v_lanes or hw), jnp.float32)]
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -244,7 +337,7 @@ def _paged_attention(q, pool, tables, context_lens, *, scale, interpret):
     n_kv = hw // w
     group = H // n_kv
     max_blocks = tables.shape[1]
-    pps = _STEP_TOKENS // bs
+    step = step_schedule(hw * pool.dtype.itemsize)
     hp = _round_up(H, _sublanes(pool.dtype))
     # q over the K lanes of its head, zeros over the V lanes; float32
     # holds the model's values exactly and is what the kernel selects on
@@ -253,15 +346,16 @@ def _paged_attention(q, pool, tables, context_lens, *, scale, interpret):
         qp = qp.reshape(B, n_kv, group, w).transpose(0, 2, 1, 3)
     row = pl.BlockSpec((None, group, hw), lambda b, *_: (b, 0, 0))
     o = pl.pallas_call(
-        functools.partial(_kernel, block_size=bs, pages_per_step=pps,
+        functools.partial(_kernel, block_size=bs,
+                          pages_per_step=step.positions // bs,
                           max_blocks=max_blocks, head_lanes=w, scale=scale,
-                          n_kv=n_kv, group=group),
+                          n_kv=n_kv, group=group, batched=step.batched),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(B,),
             in_specs=[row, pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=row,
-            scratch_shapes=_scratch(pps * bs, hw, hp, pool.dtype)),
+            scratch_shapes=_scratch(step, hw, hp, pool.dtype)),
         out_shape=jax.ShapeDtypeStruct((B, group, hw), jnp.float32),
         # the buffers and the copy in flight carry over from slot to slot
         compiler_params=compiler_params(("arbitrary",)),
@@ -280,14 +374,15 @@ def _paged_latent_attention(q, pool, tables, context_lens, *, scale, v_lanes,
     B, H, r = q.shape
     _, bs, hw = pool.shape
     max_blocks = tables.shape[1]
-    pps = _STEP_TOKENS // bs
+    step = step_schedule(hw * pool.dtype.itemsize)
     hp = _round_up(H, _sublanes(pool.dtype))
     # whole tiles: zeros over the row's padding lanes and the padding heads
     qp = jnp.pad(q.astype(pool.dtype), ((0, 0), (0, hp - H), (0, hw - r)))
     o = pl.pallas_call(
-        functools.partial(_kernel, block_size=bs, pages_per_step=pps,
+        functools.partial(_kernel, block_size=bs,
+                          pages_per_step=step.positions // bs,
                           max_blocks=max_blocks, head_lanes=hw, scale=scale,
-                          v_lanes=v_lanes),
+                          v_lanes=v_lanes, batched=step.batched),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(B,),
@@ -295,7 +390,7 @@ def _paged_latent_attention(q, pool, tables, context_lens, *, scale, v_lanes,
                       pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((None, hp, v_lanes),
                                    lambda b, *_: (b, 0, 0)),
-            scratch_shapes=_scratch(pps * bs, hw, hp, pool.dtype)),
+            scratch_shapes=_scratch(step, hw, hp, pool.dtype, v_lanes)),
         out_shape=jax.ShapeDtypeStruct((B, hp, v_lanes), jnp.float32),
         compiler_params=compiler_params(("arbitrary",)),
         interpret=interpret,

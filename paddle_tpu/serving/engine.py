@@ -288,10 +288,14 @@ class ServingEngine:
             self.max_batch = model.max_batch
             self.block_size = model.block_size
             n_kv = model.n_blocks
+            # pages a step of the decode kernel holds (0: it gathers)
+            step = model.attention_step()
+            self._step_pages = step.positions // self.block_size if step else 0
         else:
             self.max_batch = int(max_batch)
             self.block_size = int(block_size)
             n_kv = int(n_blocks)
+            self._step_pages = 0
         self.default_slo_s = float(
             default_slo_s if default_slo_s is not None
             else _flags.env_flag("PADDLE_TPU_SERVE_SLO_S"))
@@ -1046,12 +1050,15 @@ class ServingEngine:
                 # a last token still unread stays on the device: -1 makes
                 # the program take it from the tick in flight's output
                 toks[req.slot] = -1 if req.unread else req.out_tokens[-1]
-            # what this tick's attention has to read, and its whole window
+            # what this tick's attention has to read, its whole window,
+            # and the kernel steps its live slots take over their pages
+            pages = [blocks_for_tokens(req.context_len + 1, self.block_size)
+                     for req in ready]
             _ledger.note_attention(
-                sum(blocks_for_tokens(req.context_len + 1, self.block_size)
-                    for req in ready),
-                B * self.model.max_blocks_per_req,
-                len(self.model.attn_layers))
+                sum(pages), B * self.model.max_blocks_per_req,
+                len(self.model.attn_layers),
+                sum(-(-n // self._step_pages) for n in pages)
+                if self._step_pages else 0)
         ahead = self._inflight is not None
         # tick/put_inputs, tick/enqueue: returns at once, pool and tokens
         # still being computed

@@ -51,7 +51,11 @@ lands here; 0 for a model that holds every expert), what the decode attention re
 (``attn_pages_read``: the KV pages its live slots' contexts occupy, summed
 over ticks, layers apart; ``attn_pages_window``: the pages of every slot's
 whole window, which the gathered formulation reads whatever is live: their
-ratio is the share of the window that holds anything; ``attn_layers``: how
+ratio is the share of the window that holds anything; ``attn_steps``: the
+steps the paged kernel takes over those pages, by the kernel's own rule
+(``ops/pallas/paged_attention.step_schedule``), layers apart: pages over
+steps against the pages a step holds is how full its steps run, 0 on the
+gathered path; ``attn_layers``: how
 many layers attend, and so read those pages, a gauge), what a model with
 conv layers keeps per slot (``state_writes``: slot states written by
 prefills, one per admission or resume; ``state_pool_bytes``: the state
@@ -160,7 +164,7 @@ _ITL_SAMPLE = 8192
 # routing of a model with experts: serving/model.py::DecodeModel.decode
 MOE_COUNTERS = ("moe_assignments", "moe_experts_hit", "moe_max_load")
 # KV pages of a decode tick: engine.py::_decode_tick_phases
-ATTN_COUNTERS = ("attn_pages_read", "attn_pages_window")
+ATTN_COUNTERS = ("attn_pages_read", "attn_pages_window", "attn_steps")
 # summed per decode tick (prefills, prefills_ahead, state_writes: per
 # prefill); in totals(), cleared by reset(), merged as sums
 TICK_COUNTERS = (MOE_COUNTERS + ATTN_COUNTERS
@@ -461,12 +465,13 @@ class ServingLedger:
                     (assignments, experts_hit, max_load, routed))
 
     def note_attention(self, pages_read: int, pages_window: int,
-                       layers: int) -> None:
+                       layers: int, steps: int = 0) -> None:
         """One decode tick's KV pages: those its live slots' contexts
         occupy (new token included), and those of every slot's window, in
-        each of the ``layers`` that attend."""
+        each of the ``layers`` that attend; ``steps``: the kernel steps
+        those slots take over their pages (0: the gathered window)."""
         with self._lock:
-            self._count(ATTN_COUNTERS, (pages_read, pages_window))
+            self._count(ATTN_COUNTERS, (pages_read, pages_window, steps))
             self.gauges["attn_layers"] = int(layers)
 
     def note_prefill(self, ahead: bool) -> None:
@@ -774,10 +779,10 @@ def note_routing(assignments: int, experts_hit: int, max_load: int,
 
 
 def note_attention(pages_read: int, pages_window: int,
-                   layers: int) -> None:
+                   layers: int, steps: int = 0) -> None:
     if not _monitor.enabled():
         return
-    _LEDGER.note_attention(pages_read, pages_window, layers)
+    _LEDGER.note_attention(pages_read, pages_window, layers, steps)
 
 
 def note_prefill(ahead: bool) -> None:
@@ -930,6 +935,11 @@ def status() -> Dict[str, Any]:
             "pages_window": doc["attn_pages_window"],
             "window_share": doc["attn_pages_read"] / doc["attn_pages_window"],
             "layers": doc["attn_layers"],
+            # the paged kernel's steps over those pages, and how many pages
+            # a step found (against the pages its buffer holds: its fill)
+            "steps": doc["attn_steps"],
+            "pages_a_step": (doc["attn_pages_read"] / doc["attn_steps"]
+                             if doc["attn_steps"] else None),
         }
     if doc["state_pool_bytes"]:
         # what the conv layers keep per slot, and how often a slot's state

@@ -598,6 +598,20 @@ class DecodeModel:
                                  self.cfg.kv_heads)
         return ("gather", why) if why else ("kernel", "")
 
+    def attention_step(self):
+        """How the paged kernel walks a slot's pages at this pool's row
+        (``ops/pallas/paged_attention.step_schedule``, the rule the kernel
+        itself sizes its step by); None where the decode program gathers
+        the window instead and no kernel steps."""
+        import jax.numpy as jnp
+
+        from ..ops.pallas import paged_attention as pa
+
+        if self.attention_path()[0] != "kernel":
+            return None
+        return pa.step_schedule(
+            self.pool_shape()[2] * jnp.dtype(self.cfg.dtype).itemsize)
+
     def _pages_sharding(self):
         """KV pool placement, None off a mesh: the row shards over the
         recipe's tp axis, which gives each device whole heads (K and V

@@ -289,19 +289,35 @@ def test_routing_counts_of_a_share_count_the_held_experts():
     assert moe.routing_counts(idx, live, 12, share=(4, 4)).tolist() == [4, 2, 2, 6]
 
 
-@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5), ("bfloat16", 2e-2)])
-def test_the_kernels_latent_mode_against_the_gathered_formulation(dtype, tol):
-    """Interpret mode: ragged context lengths, a last page part full, an
-    empty slot, 4 heads over one shared row of 160 used lanes in 256."""
-    from paddle_tpu.ops.pallas.paged_attention import paged_latent_attention, unsupported
+# the latent mode alone: 4 heads over one shared row of 160 used lanes in 256,
+# a window of 72 pages (two of the longest steps and a page more)
+_KERNEL_CASE = dict(H=4, r=160, hw=256, v=128, bs=16, nb=6 * 72 + 1, maxb=72)
 
+
+def _latent_step(dtype):
+    """Positions a step of the kernel holds at the case's row, by the
+    kernel's own rule."""
+    from paddle_tpu.ops.pallas.paged_attention import step_schedule
+
+    return step_schedule(_KERNEL_CASE["hw"] * jnp.dtype(dtype).itemsize).positions
+
+
+def _latent_kernel_case(lens, dtype, loud=()):
+    """The kernel's output and the gathered formulation's on one pool:
+    every slot its own scattered pages, ``lens`` the new tokens' positions;
+    the pages of the slots in ``loud`` hold rows of 1e4."""
+    from paddle_tpu.ops.pallas.paged_attention import paged_latent_attention
+
+    H, r, hw, v, bs, nb, maxb = (_KERNEL_CASE[k] for k in ("H", "r", "hw", "v", "bs", "nb", "maxb"))
     rng = np.random.RandomState(0)
-    B, H, r, hw, v, bs, nb, maxb = 4, 4, 160, 256, 128, 16, 40, 9
+    B = len(lens)
     pool = np.zeros((nb, bs, hw), np.float32)
     pool[:, :, :r] = rng.randn(nb, bs, r)
     q = rng.randn(B, H, r).astype(np.float32)
     tables = rng.permutation(np.arange(1, nb))[:B * maxb].reshape(B, maxb).astype(np.int32)
-    lens = np.array([0, 37, 95, 143], np.int32)  # one token; mid page; a page's last row; the window's last
+    for b in loud:
+        pool[tables[b], :, :r] = 1e4
+    lens = np.asarray(lens, np.int32)
     got = paged_latent_attention(jnp.asarray(q, dtype), jnp.asarray(pool, dtype), jnp.asarray(tables),
                                  jnp.asarray(lens), 0.1, v)
     pool, q = (np.asarray(jnp.asarray(a, dtype).astype(jnp.float32)) for a in (pool, q))
@@ -311,7 +327,25 @@ def test_the_kernels_latent_mode_against_the_gathered_formulation(dtype, tol):
     p = np.exp(s - s.max(-1, keepdims=True))
     want = np.einsum("bhs,bsc->bhc", p / p.sum(-1, keepdims=True), ctx[..., :v])
     assert got.shape == (B, H, v) and got.dtype == jnp.dtype(dtype)
-    assert np.abs(np.asarray(got, np.float32) - want).max() <= tol
+    return np.asarray(got, np.float32), want
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5), ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("edge", ["one_token", "a_step_less_one", "a_step", "a_step_and_one", "two_steps_and_a_page",
+                                  "the_windows_last"])
+def test_the_kernels_latent_mode_against_the_gathered_formulation(edge, dtype, tol):
+    """Interpret mode, around the edges of the kernel's step at this row:
+    the edge's slot between an empty neighbour (one token) and ragged ones
+    (a page part full, 6 and 13 pages: every power of two a step's wait is
+    made of)."""
+    from paddle_tpu.ops.pallas.paged_attention import paged_latent_attention, unsupported
+
+    step, bs, maxb = _latent_step(dtype), _KERNEL_CASE["bs"], _KERNEL_CASE["maxb"]
+    assert 2 * step + bs <= maxb * bs
+    n = {"one_token": 0, "a_step_less_one": step - 1, "a_step": step, "a_step_and_one": step + 1,
+         "two_steps_and_a_page": 2 * step + bs - 1, "the_windows_last": maxb * bs - 1}[edge]
+    got, want = _latent_kernel_case([0, n, 37, 95, 0, 200], dtype)
+    assert np.abs(got - want).max() <= tol
     assert unsupported(0, 16, dtype, latent=(640, 512)) == ""
     assert "whole 128-lane tiles" in unsupported(0, 16, dtype, latent=(576, 512))
     assert "whole 128-lane tiles" in unsupported(0, 16, dtype, latent=(640, 96))
@@ -319,6 +353,20 @@ def test_the_kernels_latent_mode_against_the_gathered_formulation(dtype, tol):
     with pytest.raises(ValueError, match="paged_latent_attention: a latent row of 200 lanes"):
         paged_latent_attention(jnp.zeros((1, 2, 160)), jnp.zeros((4, 16, 200)), jnp.zeros((1, 2), jnp.int32),
                                jnp.zeros((1,), jnp.int32), 1.0, 128)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_a_short_slot_behind_a_long_one_reads_none_of_its_rows(dtype):
+    """The short slot's pages land in the buffers the long slot's steps
+    filled: the rows behind them are the long slot's still, and count for
+    nothing (weights of exactly 0 over finite rows), however loud."""
+    step = _latent_step(dtype)
+    lens = [2 * step + 40, 5, 3 * 16 + 2, 0]
+    quiet, want = _latent_kernel_case(lens, dtype)
+    loud, _ = _latent_kernel_case(lens, dtype, loud=(0,))
+    np.testing.assert_array_equal(loud[1:], quiet[1:])
+    assert np.abs(quiet - want).max() <= (1e-5 if dtype == "float32" else 2e-2)
+    assert not np.allclose(loud[0], quiet[0])
 
 
 def test_the_benchmarks_weight_table_names_what_the_program_reads():

@@ -189,7 +189,8 @@ def _keys(doc):
 _ADDED = {"serving": {"ticks_ahead", "pipeline_drains",
                       "state_writes", "state_pool_bytes", "attn_layers",  # these three: PR 33
                       "prefills", "prefills_ahead",  # PR 48
-                      "moe_assignments_routed"}}  # PR 50
+                      "moe_assignments_routed",  # PR 50
+                      "attn_steps"}}  # PR 51
 
 
 @_ledgers()

@@ -33,19 +33,20 @@ def gathered(q, pool, tables, lens, scale):
     return jnp.einsum("bhs,bshd->bhd", a, vv).reshape(B, -1)
 
 
-def make_case(lens, H, hd, dtype, layer=0, seed=0, kv=None):
+def make_case(lens, H, hd, dtype, layer=0, seed=0, kv=None, maxb=MAXB, nb=NB):
     """q, a pool of ``layer + 1`` layers, layer-folded tables and lens:
     each slot owns scattered pages of layer ``layer`` for its context
     (an empty slot none); table entries behind them name scratch block 0;
     every page that no table names holds NaN, those of other layers too.
     ``kv``: K|V heads a row, fewer than the ``H`` query heads (grouped
-    queries); None: one a query head."""
+    queries); None: one a query head. ``maxb``, ``nb``: pages a slot's
+    window and a layer hold."""
     r = np.random.RandomState(seed)
     B, row = len(lens), (kv or H) * 2 * hd
-    pool = np.full(((layer + 1) * NB, BS, row), np.nan, np.float32)
-    base = layer * NB
-    tables = np.zeros((B, MAXB), np.int32)
-    free = list(r.permutation(np.arange(1, NB)))
+    pool = np.full(((layer + 1) * nb, BS, row), np.nan, np.float32)
+    base = layer * nb
+    tables = np.zeros((B, maxb), np.int32)
+    free = list(r.permutation(np.arange(1, nb)))
     pool[base] = r.randn(BS, row)  # whatever idle slots last wrote there
     for b, n in enumerate(lens):
         for j in range(0 if n == 0 else n // BS + 1):
@@ -253,7 +254,8 @@ def test_ledger_counts_the_pages_a_tick_reads_and_the_window(kernel_model, tmp_p
     assert t["attn_pages_window"] == 3 * kernel_model.max_batch * kernel_model.max_blocks_per_req == 48
     att = ledger.status()["attention"]
     assert att == {"pages_read": 7, "pages_window": 48, "window_share": 7 / 48,
-                   "layers": kernel_model.cfg.n_layer}  # every layer of this model attends
+                   "layers": kernel_model.cfg.n_layer,  # every layer of this model attends
+                   "steps": 6, "pages_a_step": 7 / 6}  # a step a slot and tick: no context passes a step's pages
     with open(ledger.flush(str(tmp_path / "serving.rank0.json"))) as f:
         journal = json.load(f)
     assert (journal["attn_pages_read"], journal["attn_pages_window"]) == (7, 48)
@@ -263,3 +265,102 @@ def test_ledger_counts_the_pages_a_tick_reads_and_the_window(kernel_model, tmp_p
     ledger.reset()
     assert ledger.totals()["attn_pages_read"] == ledger.totals()["attn_pages_window"] == 0
     assert "attention" not in ledger.status()
+
+
+# -- the kernel's step: a rule over what the kernel sees, and its counter --
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("edge", ["a_step_less_one", "a_step", "a_step_and_one", "two_steps_and_a_page",
+                                  "the_windows_last"])
+def test_grouped_queries_around_the_edges_of_the_kernels_step(edge, dtype):
+    """32 query heads over 8 K|V heads (LFM2's row), a window of two of
+    the kernel's steps at this row and two pages more: the edge's slot
+    between an empty neighbour and ragged ones, behind a longer slot whose
+    rows its buffers still hold."""
+    H, kv, hd, bs = 32, 8, 64, BS
+    step = pa.step_schedule(kv * 2 * hd * jnp.dtype(dtype).itemsize).positions
+    maxb = 2 * step // bs + 2
+    n = {"a_step_less_one": step - 1, "a_step": step, "a_step_and_one": step + 1,
+         "two_steps_and_a_page": 2 * step + bs - 1, "the_windows_last": maxb * bs - 1}[edge]
+    lens = [2 * step + 3, n, 0, 37, 95]
+    check(*make_case(lens, H, hd, dtype, layer=1, kv=kv, maxb=maxb, nb=len(lens) * maxb + 1))
+
+
+
+@pytest.mark.parametrize("heads,kv,hd,latent,want", [
+    (25, 25, 64, (), (128, 2, False)),  # gpt2-xl: 6,400 B a position in bfloat16
+    (16, 16, 128, (), (128, 2, False)),  # olmoe: 8,192 B
+    (12, 12, 64, (), (256, 2, True)),  # gpt2-small: 3,072 B, between what the sweep measured
+    (32, 8, 64, (), (512, 2, True)),  # lfm2: 2,048 B, four query heads a K|V head: a buffer of 1 MiB
+    (64, 0, 0, (640, 512), (512, 2, True)),  # ax-k1: one latent row of 1,280 B for 64 heads
+    (64, 0, 0, (1280, 1024), (256, 2, True)),  # twice the row: half the positions fill a buffer
+    (64, 0, 0, (3200, 2048), (128, 2, False)),  # a latent row as wide as gpt2-xl's walks as it does
+], ids=["gpt2xl_6400", "olmoe_8192", "gpt2s_3072", "lfm2_2048", "axk1_latent_1280", "latent_2560", "latent_6400"])
+def test_the_step_follows_from_the_rows_bytes_in_both_modes(heads, kv, hd, latent, want):
+    """The rows that sit on their bytes keep 128 positions a step, two
+    buffers and a wait a page (their programs are the parent's); a narrower
+    row, latent or per head, takes the positions that fill a buffer of
+    1 MiB, 512 at most, and batches its copies: the schedule the sweep
+    kept at both rows it measured. ``unsupported`` takes each, and
+    ``vmem_scratch_bytes`` is what ``_scratch`` allocates under the same
+    rule."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    lanes, v_lanes = latent or (kv * 2 * hd, 0)
+    step = pa.step_schedule(lanes * 2)
+    assert tuple(step) == want and step.positions % 128 == 0
+    assert pa.unsupported(hd, 16, jnp.bfloat16, heads, kv, latent=latent or None) == ""
+    hp = -(-heads // 16) * 16  # heads in whole bfloat16 tiles
+    scratch = pa._scratch(step, lanes, hp, jnp.bfloat16, v_lanes)
+    vmem = sum(math.prod(ref.shape) * jnp.dtype(ref.dtype).itemsize for ref in scratch
+               if getattr(ref, "memory_space", None) == pltpu.VMEM)
+    assert vmem == pa.vmem_scratch_bytes(heads, hd, 16, jnp.bfloat16, kv, latent=latent)
+    assert vmem == (step.buffers * step.positions * lanes * 2 + hp * (v_lanes or lanes) * 4
+                    + 2 * hp * 128 * 4)
+
+
+def test_ledger_counts_the_steps_the_kernel_takes(tmp_path):
+    """``attn_steps``: per decode tick, the steps its live slots' pages
+    take at the pages a step of THIS model's kernel holds (8: ten heads of
+    64 in float32 are a row of 5,120 B, which keeps 128 positions a step),
+    by hand on three ticks of a context that crosses a step's edge beside
+    a short one; in totals(), on /status, in the journal, merged as sums,
+    cleared by reset()."""
+    import json
+
+    from paddle_tpu import serving
+    from paddle_tpu.serving import ledger
+
+    def eng_pages(model):  # what an engine over the model counts its steps by
+        return serving.ServingEngine(model)._step_pages
+
+    cfg = serving.GPTConfig(vocab_size=128, n_layer=2, n_head=10, d_model=640, max_seq_len=192)
+    dm = serving.DecodeModel(cfg, max_batch=2, n_blocks=32, block_size=16, prefill_buckets=[16, 128], seed=3)
+    assert dm.attention_path() == ("kernel", "") and eng_pages(dm) == 8
+    ledger.reset()
+    eng = serving.ServingEngine(dm)
+    handles = [eng.submit(list(range(1, n + 1)), max_new_tokens=4) for n in (126, 3)]
+    eng.run_until_idle()
+    assert all(len(h.result(timeout=5)) == 4 for h in handles)
+    t = ledger.totals()
+    # contexts 126, 127, 128 read 8, 8, 9 pages with the new token: 1, 1, 2
+    # steps of 8 pages; contexts 3, 4, 5 one page and one step each
+    assert t["decode_ticks"] == 3 and t["attn_pages_read"] == (8 + 8 + 9) + 3
+    assert t["attn_steps"] == (1 + 1 + 2) + 3
+    att = ledger.status()["attention"]
+    assert (att["steps"], att["pages_a_step"]) == (7, 28 / 7)
+    with open(ledger.flush(str(tmp_path / "serving.rank0.json"))) as f:
+        journal = json.load(f)
+    assert journal["attn_steps"] == 7
+    assert ledger.merge_ledgers([journal, journal])["attn_steps"] == 14
+    ledger.reset()
+    assert ledger.totals()["attn_steps"] == 0
+    # a row of 1,024 B walks 32 pages a step; a model whose heads the kernel
+    # cannot take gathers its window: no step
+    assert eng_pages(serving.DecodeModel(
+        serving.GPTConfig(vocab_size=64, n_layer=1, n_head=2, d_model=128, max_seq_len=64),
+        max_batch=2, n_blocks=8, block_size=16, prefill_buckets=[16])) == 32
+    narrow = serving.DecodeModel(serving.GPTConfig(vocab_size=64, n_layer=1, n_head=4, d_model=128, max_seq_len=64),
+                                 max_batch=2, n_blocks=8, block_size=16, prefill_buckets=[16])
+    assert narrow.attention_path()[0] == "gather" and narrow.attention_step() is None and eng_pages(narrow) == 0

@@ -111,6 +111,7 @@ def test_compile_report_says_how_the_decode_tick_attends(serving_programs):
     att = report.attention_facts(dm, facts["decode_tick"]["mosaic_kernels"])
     assert att["decode_path"] == "kernel" and att["paged_attention_calls"] == 2
     # two buffers of 128 rows of 3,200 bf16 lanes, the float32 accumulator, two statistics
+    assert att["step"] == {"positions": 128, "buffers": 2, "batched": False}
     assert att["vmem_scratch_bytes"] == 2 * 128 * 3200 * 2 + 32 * 3200 * 4 + 2 * 32 * 128 * 4
     assert att["vmem_scratch_bytes"] < 16 * 2 ** 20
     narrow = report.abstract_model(
@@ -460,8 +461,10 @@ def test_axk1_decode_tick_holds_the_latent_kernel_once_a_layer(axk1_programs):
     assert (att["decode_path"], att["kernel"], att["paged_attention_calls"]) == ("kernel", "paged_latent_attention", 2)
     assert att["row"] == {"lanes": 640, "holds": "latent | rotated key lanes | zeros", "latent": 512,
                           "rotated": 64, "zeros": 64, "heads_sharing_it": 64}
-    # two buffers of 128 rows of 640 bf16 lanes, the float32 accumulator of 64 heads, two statistics
-    assert att["vmem_scratch_bytes"] == 2 * 128 * 640 * 2 + 64 * 640 * 4 + 2 * 64 * 128 * 4 < 2 ** 20
+    # the step of a 1,280 B row: two buffers of 512 rows of 640 bf16 lanes; the float32 accumulator
+    # of 64 heads over the row's 512 V lanes, two statistics
+    assert att["step"] == {"positions": 512, "buffers": 2, "batched": True}
+    assert att["vmem_scratch_bytes"] == 2 * 512 * 640 * 2 + 64 * 512 * 4 + 2 * 64 * 128 * 4 < 2 ** 21
     # a per-head model's report names its own kernel and row
     assert report.attention_facts(report.cell_model("olmoe-serve-batch", n_layer=1), {})["kernel"] == "paged_attention"
 
@@ -518,7 +521,13 @@ _PARENT_PROGRAMS = {
     ("gpt2", "decode_tick"): "cf354808014e7cc1", ("gpt2", "prefill_32"): "461e4d36e1e9524d",
     ("olmoe", "decode_tick"): "653b0a7cdc6d0601", ("olmoe", "prefill_32"): "2aa9785cfd6b45c9",
     ("lfm2", "decode_tick"): "a712444de7e22495", ("lfm2", "prefill_32"): "dc6ba1086558decc"}
-_PARENT_KERNEL = {(4, 4, 64, 64, 8): "90ef1bd43a930eb2", (12, 25, 64, 864, 64): "9394cc5543d1440f",
+# The kernel's own jaxpr at rows that keep 128 positions a step (PR 51 gave the
+# step to a rule over the row's bytes, ops/pallas/paged_attention.py::
+# step_schedule: 6,400 and 8,192 B a position, the two cells' rows, and a small
+# one of 5,120 B, whose hash is that of PR 51's parent, 8acd512; the 1,024 B row
+# this table held before takes the longer, batched step and is the parent's no
+# more: 90ef1bd43a930eb2 there).
+_PARENT_KERNEL = {(4, 20, 64, 64, 8): "5fc05325cc7aaa5b", (12, 25, 64, 864, 64): "9394cc5543d1440f",
                   (24, 16, 128, 1920, 64): "9c0520193a38207e"}
 
 
@@ -564,8 +573,10 @@ def test_programs_of_one_kind_of_layer_lower_as_on_the_parent(lowered_small, blo
 
 @pytest.mark.parametrize("B,H,hd,rows,maxb", sorted(_PARENT_KERNEL))
 def test_paged_attention_with_a_kv_head_a_query_head_is_the_parents_call(B, H, hd, rows, maxb):
-    """``n_kv_head == n_head``: the kernel and the call around it trace to
-    the jaxpr they traced to before grouped queries."""
+    """``n_kv_head == n_head`` at a row that sits on its bytes: the kernel
+    and the call around it trace to the jaxpr they traced to before grouped
+    queries, before the latent mode and before the step followed from the
+    row's bytes."""
     from paddle_tpu.ops.pallas import paged_attention as pa
 
     def call(q, pool, tables, lens):

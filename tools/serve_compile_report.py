@@ -10,7 +10,9 @@ holds, how the decode tick attends (the ``paged_attention`` kernel, its
 latent mode ``paged_latent_attention`` over a pool whose row is one latent a
 position, or the gathered window and why: a model whose K|V row is not whole
 128-lane tiles shows here, before a chip run; ``row`` says what a position's
-row holds and how many lanes of it are used), how it looks up its token rows (``embed``:
+row holds and how many lanes of it are used, ``step`` how the kernel walks a
+slot's pages at that row: the positions and buffers its VMEM scratch is sized
+by), how it looks up its token rows (``embed``:
 a slice a slot or one gather, the embedding table's layouts in the program
 and the copies as large as the table) and ``memory_analysis()``. Sizes and
 instruction names only: nothing runs, so no time comes out of this tool.
@@ -153,6 +155,9 @@ def attention_facts(dm, mosaic_kernels: Dict[str, int]) -> Dict[str, Any]:
 
     path, why = dm.attention_path()
     cfg, lanes = dm.cfg, dm.pool_shape()[2]
+    # the kernel's step at this row, which the scratch is sized by
+    step = dm.attention_step()
+    step = step._asdict() if step else None
     if dm.latent:  # one row a position for every head
         calls = mosaic_kernels.get("paged_latent_attention", 0)
         return {"decode_path": path, "attention_layers": len(dm.attn_layers),
@@ -162,9 +167,10 @@ def attention_facts(dm, mosaic_kernels: Dict[str, int]) -> Dict[str, Any]:
                         "zeros": lanes - cfg.latent_row, "heads_sharing_it": cfg.n_head},
                 "why": why or "one device, a latent row and its V prefix of whole 128-lane tiles, "
                               "pages of whole tiles",
-                "paged_attention_calls": calls,
+                "paged_attention_calls": calls, "step": step,
                 "vmem_scratch_bytes": pa.vmem_scratch_bytes(
-                    cfg.n_head, 0, dm.block_size, cfg.dtype, row_lanes=lanes) if calls else 0}
+                    cfg.n_head, 0, dm.block_size, cfg.dtype,
+                    latent=(lanes, cfg.kv_lora_rank)) if calls else 0}
     calls = mosaic_kernels.get("paged_attention", 0)
     return {"decode_path": path, "attention_layers": len(dm.attn_layers),
             "kernel": "paged_attention",
@@ -172,7 +178,7 @@ def attention_facts(dm, mosaic_kernels: Dict[str, int]) -> Dict[str, Any]:
                     "kv_heads": cfg.kv_heads, "head_dim": cfg.head_dim},
             "query_heads_a_kv_head": cfg.n_head // cfg.kv_heads,
             "why": why or "one device, heads of whole 128-lane tiles, pages of whole tiles",
-            "paged_attention_calls": calls,
+            "paged_attention_calls": calls, "step": step,
             "vmem_scratch_bytes": pa.vmem_scratch_bytes(
                 cfg.n_head, cfg.head_dim, dm.block_size, cfg.dtype,
                 cfg.kv_heads) if calls else 0}
