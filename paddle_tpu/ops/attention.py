@@ -92,7 +92,14 @@ def scaled_dot_product_attention(q, k, v, attn_mask=None, dropout_p=0.0, is_caus
 # 1024) the forward and dq take ONE kv step, which the kernels run without
 # the running statistics' round trip and trimmed to what lies under the
 # diagonal (ops/pallas/flash_attention.py: `single`, `_trims`); dkv, which
-# keeps no running statistics, takes two q steps of 512. Longer sequences
+# keeps no running statistics, takes two q steps of 512. Since PR 53 that
+# one-step causal BTHD forward LOOPS over its head groups where the heads
+# fill whole lane tiles (`_fwd_looped_kernel_bthd`) and PR 53 swept its q
+# tile again, the long ones first, which PR 35 could not judge under an
+# unrolled kernel's code size: 256 rows still win the step, because 512
+# and 1024 rows compute 12 and 16 squares of 256 where 256 rows compute 10
+# and the loop leaves a head's cost a score element as it was (PERF.md
+# section 6 has each tile's kernel and step time). Longer sequences
 # and non-causal calls keep the tiles they had (from a T = 2048 sweep before
 # PR 1; re-measured by PR 35 at T 2048, B 16: still the best of those tried).
 # "bwd" (PR 43, the same tool and shape): the ONE fused backward kernel's kv

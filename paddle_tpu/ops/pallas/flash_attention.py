@@ -9,7 +9,11 @@ native), a causal block-skip schedule, and a flash backward driven by the
 saved per-row logsumexp, recomputing P blockwise instead of storing T^2
 probabilities: a dq and a dk/dv kernel, or, for a causal BTHD call of
 equal lengths a few tiles long, ONE kernel that gives all three gradients
-from one rematerialised score tile (_bwd_fused).
+from one rematerialised score tile (_bwd_fused). A BTHD kernel holds all
+heads of a block: the fused backward and the causal forward whose kv sweep
+is one step LOOP over groups of heads that fill 128 lanes
+(_fwd_looped_kernel_bthd; _fwd_loop_refusal and _fused_bwd_refusal say
+when), the others unroll one copy of their body a head.
 
 Layout: q, k, v are (B, H, T, D). The grid walks (batch, head, q-block)
 in parallel and the kv-block dimension sequentially ("arbitrary"), with
@@ -30,6 +34,7 @@ from ... import monitor as _monitor
 from .backend import compiler_params, on_tpu
 
 _NEG_INF = -1e30  # finite stand-in for -inf: avoids inf-inf=nan in rescaling
+_LANES = 128  # a vector register's lanes: a lane tile of the flat (.., H*D) operands
 
 _M_TILES = _monitor.counter(
     "flash_tiles_total",
@@ -44,12 +49,27 @@ _M_TILES = _monitor.counter(
     labelnames=("kernel", "cls"))
 _KERNELS, _CLASSES = ("fwd", "dq", "dkv"), ("skipped", "interior", "diagonal")
 
+_M_FWD_BODY = _monitor.counter(
+    "flash_fwd_calls_total",
+    "BTHD forward calls (every head in one block) by how the kernel walks "
+    "its heads: looped (a loop over groups of heads that fill 128 lanes, "
+    "each finished where it is computed) or unrolled (one copy of the body "
+    "a head, where _fwd_loop_refusal gives a reason). Counted when a call "
+    "is traced, as flash_tiles_total is",
+    labelnames=("body",))
+_BODIES = ("looped", "unrolled")
+
 
 def tile_counts():
     """{kernel: {cls: flash_tiles_total as it stands}}, for the tools and
     tests that read a share of the score square from it."""
     return {k: {c: _M_TILES.labels(kernel=k, cls=c).value for c in _CLASSES}
             for k in _KERNELS}
+
+
+def fwd_body_counts():
+    """{body: flash_fwd_calls_total as it stands}."""
+    return {b: _M_FWD_BODY.labels(body=b).value for b in _BODIES}
 
 
 # ------------------------------------------------- the causal tile schedule
@@ -137,12 +157,13 @@ def _trims(block_q, block_k, offset, steps):
     needs its rows from j*bk on. The rest of such a tile is all mask and
     is not computed. A square tile, or one whose crossing is not aligned,
     has the one part: all of it. Each part is one more copy of the
-    kernel's unrolled body: with more than two, a kernel whose sequential
-    sweep has several `steps` ran three times slower than untrimmed (v5e,
-    T 2048, PR 35), so there the tile stays whole too. (PR 43 found the
-    cause: the time of a kernel whose heads are unrolled grows with the
-    size of its code; the fused backward loops over its heads and takes
-    four parts at no cost. These kernels still unroll theirs.)"""
+    kernel's body: with more than two, a kernel whose sequential sweep has
+    several `steps` ran three times slower than untrimmed (v5e, T 2048,
+    PR 35), so there the tile stays whole too. (PR 43 found the cause: the
+    time of a kernel whose heads are unrolled grows with the size of its
+    code; the fused backward and, since PR 53, the one-step causal forward
+    loop over their heads and take four parts at no cost. The several-step
+    kernels, which this guards, still unroll theirs.)"""
     g = min(block_q, block_k)
     whole = [(0, block_q, 0, block_k)]
     if block_q == block_k or max(block_q, block_k) % g or offset % g:
@@ -238,10 +259,59 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
 # (B, T, H*D) — a free reshape — because a 4D (…, H, D) operand forces a
 # padded (16, 128)-tiled copy of every operand/output around the custom
 # call (2.7x HBM traffic and a scoped-vmem OOM at batch 8), while
-# (T, H*D) tiles dense. Heads live as 64-aligned lane slices; the
-# per-head loop is statically unrolled (this mosaic build rejects batch
-# dims in dot_general). Row stats (lse/delta) are (B, H, T) f32 — dense,
-# vs the 128x lane padding a trailing-1 dim would cost.
+# (T, H*D) tiles dense. Heads live as 64-aligned lane slices and are
+# walked one after another (this mosaic build rejects batch dims in
+# dot_general): by a loop over groups of heads that fill 128 lanes where
+# the slices a loop index places are whole lane tiles (_over_groups: the
+# one-step causal forward, the fused backward), statically unrolled
+# elsewhere. Row stats (lse/delta) are (B, H, T) f32 — dense, vs the 128x
+# lane padding a trailing-1 dim would cost.
+
+
+def _head_group(H, D):
+    """Heads a step of a kernel's loop over heads takes (the one-step
+    forward's, the fused backward's): as many as fill 128 lanes (two of
+    64), so that every slice the loop index places is whole lane tiles."""
+    per_tile = max(1, _LANES // D)
+    return per_tile if H % per_tile == 0 else 1
+
+
+def _lanes_of(g, W):
+    """The lanes [g*W, (g+1)*W) of a flat (.., H*D) operand for group g, a
+    Python int or a loop's index."""
+    return slice(g * W, (g + 1) * W) if isinstance(g, int) else pl.ds(pl.multiple_of(g * W, W), W)
+
+
+def _own_lanes(xs, D):
+    """Head e's lanes from xs[e], each a product against all the group's
+    lanes: the MXU's columns are there anyway, and it keeps every store
+    whole lane tiles."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, xs[0].shape, 1)
+    out = xs[-1]
+    for e in range(len(xs) - 2, -1, -1):
+        out = jnp.where(lane < (e + 1) * D, xs[e], out)
+    return out
+
+
+def _over_groups(group, n):
+    """Run group(g) for the n head groups of a call. A LOOP, not n unrolled
+    copies of the body: unrolled, a kernel's time grew with the size of its
+    code (PR 43, v5e: the fused backward's tile 7.28 ms a call unrolled,
+    2.88 looped; the cause of PR 35's slow tall tiles). Two groups an
+    iteration, where they pair up, let one group's matmuls overlap the
+    other's vector work (2.84)."""
+    per = 2 if n % 2 == 0 else 1
+    if n == per:
+        for g in range(n):
+            group(g)
+        return
+
+    def several(i, carry):
+        for u in range(per):
+            group(i * per + u)
+        return carry
+
+    jax.lax.fori_loop(0, n // per, several, None)
 
 
 def _fwd_kernel_bthd(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
@@ -313,6 +383,93 @@ def _fwd_kernel_bthd(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
             o_ref[0, :, sl] = (acc_scr[:, sl] / l_safe[:, h:h + 1]).astype(o_ref.dtype)
 
 
+def _fwd_loop_refusal(causal, single, H, D):
+    """Why a BTHD forward keeps the body that unrolls its heads, or None.
+    The loop finishes a group of heads where it computes it, so the kv
+    sweep has to be the one step that holds a q row's whole softmax
+    (`single`); it slices lanes at the loop's index, so the heads have to
+    group into whole 128-lane tiles (as _fused_bwd_refusal asks); and only
+    the causal call has been swept with it (a non-causal one traces as it
+    did on PR 35's parent, which tests/test_tpu_aot_training.py holds)."""
+    if not causal:
+        return "a non-causal call"
+    if not single:
+        return "the kv sweep is not one step that reaches every q row"
+    G = _head_group(H, D)
+    if (G * D) % _LANES and G != H:
+        return f"{H} heads of {D} do not group into whole lane tiles"
+    return None
+
+
+def _fwd_looped_kernel_bthd(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
+                            block_q, block_k, offset, trims, H):
+    """_fwd_kernel_bthd's `single` path with its heads looped over, not
+    unrolled (_fwd_loop_refusal says when): the running max, sum and
+    accumulator begin and end inside the one step and a wide tile's parts
+    trim columns only, so a group's output and lse are written from its own
+    values and the kernel keeps no scratch. A head's arithmetic is the
+    unrolled body's, to the bit (v5e, PR 53): its row sums and its p @ v
+    are written out as Mosaic forms them, a chunk of _LANES columns after
+    another, which keeps exp, the sums' partials, the cast and the matmul's
+    pass in registers; the mask is applied where a part crosses the
+    diagonal, its last square, and nowhere else."""
+    iq = pl.program_id(1)
+    D = q_ref.shape[-1] // H
+    G = _head_group(H, D)
+    W = G * D
+
+    def _compute(masked, part):
+        rows, cols = slice(*part[:2]), slice(*part[2:])
+        nr, nc = part[1] - part[0], part[3] - part[2]
+        # a trimmed part (r0 = c0 = 0) ends with the square the diagonal
+        # crosses corner to corner, whichever q tile it belongs to
+        square = masked and len(trims) > 1
+        if square:
+            tri = (jax.lax.broadcasted_iota(jnp.int32, (nr, nr), 1)
+                   <= jax.lax.broadcasted_iota(jnp.int32, (nr, nr), 0))
+        elif masked:
+            keep = _keep(iq, 0, block_q, block_k, offset, part)
+        width = _LANES if nc % _LANES == 0 else nc
+
+        def group(g):
+            """The G heads in the lanes [g*W, (g+1)*W) of every operand."""
+            lanes = _lanes_of(g, W)
+            kv, vv = k_ref[0, cols, lanes], v_ref[0, cols, lanes]
+            qv = (q_ref[0, rows, lanes].astype(jnp.float32) * scale).astype(k_ref.dtype)
+            pvs, ls = [], []
+            for e in range(G):
+                sl = slice(e * D, (e + 1) * D)
+                s = jax.lax.dot_general(
+                    qv[:, sl], kv[:, sl], (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)  # (BQ, BK)
+                if square:
+                    diag = jnp.where(tri, s[:, nc - nr:], _NEG_INF)
+                    s = diag if nc == nr else jnp.concatenate([s[:, :nc - nr], diag], axis=1)
+                elif masked:
+                    s = jnp.where(keep, s, _NEG_INF)
+                m = jnp.max(s, axis=-1, keepdims=True)
+                l_part = pv = None
+                for c in range(0, nc, width):
+                    chunk = slice(c, c + width)
+                    p = jnp.exp(s[:, chunk] - m)
+                    l_part = p if l_part is None else l_part + p
+                    pv_c = jax.lax.dot_general(  # against ALL the group's lanes (_own_lanes)
+                        p.astype(vv.dtype), vv[chunk], (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                    pv = pv_c if pv is None else pv + pv_c
+                l = jnp.sum(l_part, axis=-1, keepdims=True)
+                l_safe = jnp.where(l == 0.0, 1.0, l)
+                pvs.append(pv)
+                ls.append(jnp.broadcast_to(l_safe, pv.shape))
+                lse_ref[0, pl.ds(g * G + e, 1), rows] = jnp.swapaxes(m + jnp.log(l_safe), 0, 1)
+            # ONE division a group, of each head's own lanes by its own sums
+            o_ref[0, rows, lanes] = (_own_lanes(pvs, D) / _own_lanes(ls, D)).astype(o_ref.dtype)
+
+        _over_groups(group, H // G)
+
+    _run_by_class(_compute, iq, 0, block_q, block_k, offset, True, trims)
+
+
 def _specs(bq, bk, D, index):
     """BHTD BlockSpecs for (q-tile, k-tile, row-stat-tile); `index` is
     _tile_index's (qi, ki) over the last two grid axes."""
@@ -343,6 +500,13 @@ def _dims(q, k, bthd):
     return B, H, T, D, k.shape[2]
 
 
+def _single_step(bq, bk, T, Tk):
+    """The forward's kv sweep over (bq, bk) tiles is ONE step that reaches
+    every q row (the kernels' `single`)."""
+    bq, bk = min(bq, T), min(bk, Tk)
+    return bk == Tk and bk >= bq and Tk >= T
+
+
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "block_q", "block_k", "interpret", "bthd"))
 def _fwd(q, k, v, *, causal, scale, block_q, block_k, interpret, bthd=False):
     B, H, T, D, Tk = _dims(q, k, bthd)
@@ -356,26 +520,28 @@ def _fwd(q, k, v, *, causal, scale, block_q, block_k, interpret, bthd=False):
         q = q.reshape(B, T, H * D)
         k = k.reshape(B, Tk, H * D)
         v = v.reshape(B, Tk, H * D)
-        kernel = functools.partial(
-            _fwd_kernel_bthd, scale=scale, causal=causal, block_q=bq,
-            block_k=bk, offset=Tk - T, trims=trims, H=H,
-            single=nk == 1 and bk >= bq and Tk >= T,
-        )
+        common = dict(scale=scale, block_q=bq, block_k=bk, offset=Tk - T, trims=trims, H=H)
         qspec, kspec, rspec = _specs_bthd(bq, bk, H, D, index)
         grid = (B, nq, nk)
         lse_shape = (B, H, T)
         dims = ("parallel", "parallel", "arbitrary")
         if H > 128:
             raise ValueError(f"BTHD flash kernel supports at most 128 heads, got {H}")
-        # row stats live one LANE per head ((bq, 128) f32) — the previous
-        # (bq, H*128) broadcast layout burned 3MB of VMEM and a 128x
-        # redundant write per head per kv block, and pushed the
-        # (256, 1024)-block config 40KB over the 16MB scoped-vmem limit
-        scratch = [
-            pltpu.VMEM((bq, 128), jnp.float32),
-            pltpu.VMEM((bq, 128), jnp.float32),
-            pltpu.VMEM((bq, H * D), jnp.float32),
-        ]
+        single = _single_step(bq, bk, T, Tk)
+        if _fwd_loop_refusal(causal, single, H, D) is None:
+            kernel = functools.partial(_fwd_looped_kernel_bthd, **common)
+            scratch = []
+        else:
+            kernel = functools.partial(_fwd_kernel_bthd, causal=causal, single=single, **common)
+            # row stats live one LANE per head ((bq, 128) f32) — the previous
+            # (bq, H*128) broadcast layout burned 3MB of VMEM and a 128x
+            # redundant write per head per kv block, and pushed the
+            # (256, 1024)-block config 40KB over the 16MB scoped-vmem limit
+            scratch = [
+                pltpu.VMEM((bq, 128), jnp.float32),
+                pltpu.VMEM((bq, 128), jnp.float32),
+                pltpu.VMEM((bq, H * D), jnp.float32),
+            ]
     else:
         kernel = functools.partial(
             _fwd_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
@@ -642,14 +808,6 @@ def _bwd_dkv_kernel_bthd(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 # tiles of several steps: both lost the train step. PERF.md section 6.)
 
 
-def _head_group(H, D):
-    """Heads a step of the fused backward's loop over heads takes: as many
-    as fill 128 lanes (two of 64), so that every slice the loop index
-    places is whole lane tiles."""
-    per_tile = max(1, 128 // D)
-    return per_tile if H % per_tile == 0 else 1
-
-
 # What the fused backward may keep in VMEM, by _fused_bwd_vmem_bytes' count:
 # the widest call PR 43 ran on the chip (v5e, T 1024, 32 heads of 64: 44 MiB
 # of the 64 a kernel may use). Its time stayed linear in the heads up to
@@ -716,7 +874,7 @@ def _bwd_fused_kernel_bthd(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
         def group(g):
             """The G heads in the lanes [g*W, (g+1)*W) of every operand."""
-            lanes = slice(g * W, (g + 1) * W) if isinstance(g, int) else pl.ds(pl.multiple_of(g * W, W), W)
+            lanes = _lanes_of(g, W)
             kv, vv, dov = k_ref[0, cols, lanes], v_ref[0, cols, lanes], do_ref[0, rows, lanes]
             qv = (q_ref[0, rows, lanes].astype(jnp.float32) * scale).astype(k_ref.dtype)
             outs = []
@@ -733,8 +891,7 @@ def _bwd_fused_kernel_bthd(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     vv[:, sl], dov[:, sl], (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32)
                 dst = (pt * (dpt - delta_ref[0, h, rows])).astype(qv.dtype)
-                # the products against ALL G heads' lanes: the MXU's columns
-                # are there anyway, and it keeps every store whole lane tiles
+                # the products against ALL G heads' lanes (_own_lanes)
                 dv = jax.lax.dot_general(  # P^T dO
                     pt.astype(dov.dtype), dov, (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)
@@ -746,34 +903,12 @@ def _bwd_fused_kernel_bthd(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     preferred_element_type=jnp.float32)
                 outs.append((dq, dk, dv))
 
-            def own_lanes(xs):
-                """Head e's product from its own lanes of xs[e]."""
-                lane = jax.lax.broadcasted_iota(jnp.int32, xs[0].shape, 1)
-                out = xs[-1]
-                for e in range(G - 2, -1, -1):
-                    out = jnp.where(lane < (e + 1) * D, xs[e], out)
-                return out
-
-            dq, dk, dv = (own_lanes(xs) for xs in zip(*outs))
+            dq, dk, dv = (_own_lanes(xs, D) for xs in zip(*outs))
             dq_scr[rows, lanes] += dq
             dk_ref[0, cols, lanes] = dk.astype(dk_ref.dtype)
             dv_ref[0, cols, lanes] = dv.astype(dv_ref.dtype)
 
-        # A LOOP over the groups, not twelve unrolled copies of the body:
-        # unrolled, a kernel's time grew with the size of its code (PR 43,
-        # v5e: this tile 7.28 ms a call unrolled, 2.88 looped; the cause of
-        # PR 35's slow tall tiles). Two groups an iteration, where they pair
-        # up, let one group's matmuls overlap the other's vector work (2.84).
-        n, per = H // G, 2 if (H // G) % 2 == 0 else 1
-        if n == per:
-            for g in range(n):
-                group(g)
-        else:
-            def several(i, carry):
-                for u in range(per):
-                    group(i * per + u)
-                return carry
-            jax.lax.fori_loop(0, n // per, several, None)
+        _over_groups(group, H // G)
 
     _run_by_class(_compute, 0, ik, T, block_k, 0, True, trims)
 
@@ -866,7 +1001,7 @@ def _bwd(causal, scale, block_q, block_k, interpret, bthd, bwd_blocks,
         functools.partial(
             dq_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
             offset=Tk - T, trims=tuple(_trims(bq, bk, Tk - T, nk)),
-            **extra(nk == 1 and bk >= bq and Tk >= T),
+            **extra(_single_step(bq, bk, T, Tk)),
         ),
         grid=dq_grid,
         in_specs=[qspec, kspec, kspec, qspec, rspec, rspec],
@@ -940,6 +1075,10 @@ def _flash(q, k, v, causal, scale, block_q, block_k, interpret, bthd,
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret, bthd,
                bwd_blocks):
     _count_tiles("fwd", q, k, bthd, block_q, block_k, causal)
+    if bthd:
+        _, H, T, D, Tk = _dims(q, k, bthd)
+        refusal = _fwd_loop_refusal(causal, _single_step(block_q, block_k, T, Tk), H, D)
+        _M_FWD_BODY.labels(body="unrolled" if refusal else "looped").inc()
     out, lse = _fwd(
         q, k, v, causal=causal, scale=scale,
         block_q=block_q, block_k=block_k, interpret=interpret, bthd=bthd,

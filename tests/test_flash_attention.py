@@ -552,3 +552,128 @@ def test_flash_tiles_total_reaches_the_report_and_the_scrape():
     prom = monitor.to_prometheus()
     assert 'flash_tiles_total{kernel="fwd",cls="diagonal"}' in prom or \
         'flash_tiles_total{cls="diagonal",kernel="fwd"}' in prom
+
+
+# ---- the causal one-step BTHD forward LOOPS over its head groups (PR 53) ----
+#
+# Where the kv sweep is one step and the heads group into whole 128-lane tiles,
+# the forward walks groups of heads with a fori_loop (two groups an iteration
+# where they pair up) and writes a group's output and lse from its own values;
+# everything else keeps the body that unrolls its heads. flash_fwd_calls_total
+# says which a call took.
+
+
+def _fa():
+    import sys
+
+    return sys.modules["paddle_tpu.ops.pallas.flash_attention"]
+
+
+def _scores_reference(q, k, v):
+    """(out, lse) of causal BTHD attention through materialised scores."""
+    from tools.flash_sweep import scores_reference
+
+    return scores_reference(q, k, v)
+
+
+def _bthd_qkv(h, d, t, tk=None, b=1, seed=0):
+    r = np.random.RandomState(seed)
+    return tuple(jnp.asarray(r.randn(b, n, h, d), jnp.float32) for n in (t, tk or t, tk or t))
+
+
+def _forward(q, k, v, bq, bk):
+    fa = _fa()
+    before = fa.fwd_body_counts()
+    out, res = fa._flash_fwd(q, k, v, True, 1.0 / np.sqrt(q.shape[-1]), min(bq, q.shape[1]), min(bk, k.shape[1]),
+                             True, True, None)
+    took = {b: n - before[b] for b, n in fa.fwd_body_counts().items() if n != before[b]}
+    return out, res[4], took
+
+
+_LOOPED = {  # name: (heads, head size, T, q tile rows); the kv tile is the sequence
+    "12x64_paired_groups_256_rows": (12, 64, 1024, 256),  # the cell's: six groups, three iterations, four parts
+    "12x64_512_rows": (12, 64, 1024, 512), "12x64_1024_rows": (12, 64, 1024, 1024),
+    "2x64_no_loop": (2, 64, 1024, 256), "6x64_odd_group_count": (6, 64, 1024, 256),
+    "4x128_one_head_a_group": (4, 128, 1024, 256), "4x64_one_unrolled_pair": (4, 64, 512, 128),
+    "1x64_the_whole_width": (1, 64, 256, 128), "3x128_odd_groups_of_one": (3, 128, 512, 256),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_LOOPED))
+def test_the_looped_forward_gives_the_references_output_and_lse(case):
+    h, d, t, bq = _LOOPED[case]
+    q, k, v = _bthd_qkv(h, d, t)
+    out, lse, took = _forward(q, k, v, bq, t)
+    assert took == {"looped": 1}
+    ref_out, ref_lse = _scores_reference(q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out), rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse), rtol=2e-5, atol=2e-5)
+
+
+_UNROLLED = {  # name: (heads, head size, T, Tk, bq, bk, the refusal's words)
+    "25x64_gpt2_xls": (25, 64, 1024, 1024, 256, 1024, "do not group into whole lane tiles"),
+    "7x64": (7, 64, 1024, 1024, 256, 1024, "do not group into whole lane tiles"),
+    "t2048_two_kv_steps": (12, 64, 2048, 2048, 256, 1024, "one step"),
+    "tk_longer_than_a_tile": (4, 64, 512, 1024, 256, 512, "one step"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_UNROLLED))
+def test_what_the_loop_refuses_takes_the_unrolled_body_and_gives_the_references_numbers(case):
+    h, d, t, tk, bq, bk, why = _UNROLLED[case]
+    fa = _fa()
+    assert why in fa._fwd_loop_refusal(True, fa._single_step(bq, bk, t, tk), h, d)
+    q, k, v = _bthd_qkv(h, d, t, tk)
+    out, lse, took = _forward(q, k, v, bq, bk)
+    assert took == {"unrolled": 1}
+    ref_out, ref_lse = _scores_reference(q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out), rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse), rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("call,want", [
+    ((True, True, 12, 64), None), ((True, True, 16, 64), None), ((True, True, 32, 64), None),
+    ((True, True, 4, 128), None), ((True, True, 1, 64), None), ((True, True, 2, 256), None),
+    ((True, True, 25, 64), "25 heads of 64"), ((True, True, 7, 64), "7 heads of 64"),
+    ((True, False, 12, 64), "one step"), ((False, True, 12, 64), "non-causal"),
+])
+def test_one_predicate_says_which_body_a_forward_takes(call, want):
+    refusal = _fa()._fwd_loop_refusal(*call)
+    assert (refusal is None) if want is None else (want in refusal), refusal
+
+
+@pytest.mark.parametrize("h,d,t,bq,bwd", [(4, 64, 256, 128, ("fused", 128)), (6, 64, 256, 128, ("fused", 256)),
+                                          (2, 128, 256, 256, ("fused", 128)), (4, 64, 256, 128, (128, 256, 128, 128))])
+def test_gradients_through_the_looped_forward_match_xla(h, d, t, bq, bwd):
+    q, k, v = _bthd_qkv(h, d, t, b=2, seed=3)
+    cot = _bthd_qkv(h, d, t, b=2, seed=4)[0]
+    before = _fa().fwd_body_counts()
+    got = jax.vjp(lambda *a: flash_attention(*a, causal=True, block_q=bq, block_k=t, layout="BTHD", bwd_blocks=bwd),
+                  q, k, v)[1](cot)
+    assert _fa().fwd_body_counts()["looped"] == before["looped"] + 1
+    want = jax.vjp(lambda *a: _sdpa_xla(*a, is_causal=True, layout="BTHD"), q, k, v)[1](cot)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("shape,causal,want", [
+    ((32, 1024, 12, 64), True, "looped"),  # gpt2s-train-1k's call
+    ((2, 1024, 16, 64), True, "looped"), ((2, 1024, 8, 128), True, "looped"),
+    ((2, 1024, 25, 64), True, "unrolled"), ((2, 2048, 12, 64), True, "unrolled"),
+    ((2, 1024, 12, 64), False, "unrolled"),
+])
+def test_the_body_counter_says_which_forward_the_dispatchers_call_takes(monkeypatch, shape, causal, want):
+    """Through the op, on the table's tiles, traced and not run; the count
+    reaches the report and the scrape as flash_tiles_total does."""
+    from paddle_tpu import monitor
+    from tools import obs_report
+
+    fa = _fa()
+    before = fa.fwd_body_counts()
+    a = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    jax.make_jaxpr(_attend("BTHD", causal))(a, a, a)
+    after = fa.fwd_body_counts()
+    assert {b: after[b] - before[b] for b in after} == {"looped": float(want == "looped"),
+                                                        "unrolled": float(want == "unrolled")}
+    assert obs_report._executor_section(monitor.snapshot())["flash_fwd_calls"] == after
+    assert f'flash_fwd_calls_total{{body="{want}"}}' in monitor.to_prometheus()

@@ -4,6 +4,7 @@ lm-head CE, fused Adam) and the train step against a real TPU target
 test_tpu_aot_flash_tiles.py and test_tpu_aot_flash_vmem.py)."""
 import functools
 import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -12,7 +13,7 @@ import pytest
 from paddle_tpu.ops.pallas.flash_attention import flash_attention
 from paddle_tpu.ops.pallas.fused_adam import fused_adam
 from paddle_tpu.ops.pallas.fused_lmhead_ce import lmhead_ce
-from tpu_aot import compiled_text, kernel_names, metric_pattern, own_names, sha
+from tpu_aot import compiled_text, kernel_names, metric_pattern, mosaic_bodies, own_names, sha
 from tpu_aot import tpu_arg, tpu_device, tpu_topology  # noqa: F401  (fixtures)
 
 
@@ -76,6 +77,54 @@ def test_flash_kernels_carry_their_names_at_gpt2s_widths(tpu_arg):
     rx, fwd_rx = metric_pattern("flash_kernels_roofline"), metric_pattern("fwd_passes_per_step")
     assert all(rx.search(n) for n in names)
     assert sum(bool(fwd_rx.search(n)) for n in names) == 1
+
+
+# What the forward of gpt2s-train-1k's call handed Mosaic on PR 53's parent
+# (19b1a3b; the same lowering, read there): five copies of a body that
+# unrolls twelve heads and no loop; its math.exp operations cover 11,010,048
+# score elements (12 heads x 256 rows x (256 + 512 + 768 + 1024 + 1024)
+# columns), and the compiled kernel is 52,913 bundles of code.
+_PARENT_CELL_FWD_EXP_ELEMENTS = 11010048
+
+
+def _cell_forward(heads=12, head_dim=64, bq=None, batch=32):
+    from paddle_tpu.ops import attention
+
+    tiles = attention._flash_tiles(1024, 1024, "BTHD", True, heads=heads, head_dim=head_dim)
+    fwd = lambda q, k, v: flash_attention(q, k, v, causal=True, block_q=bq or tiles[0], block_k=tiles[1],  # noqa: E731
+                                          layout="BTHD", interpret=False)
+    return fwd, (batch, 1024, heads, head_dim)
+
+
+def test_the_cells_flash_forward_lowers_with_a_loop_over_its_head_groups(tpu_arg):
+    """[32, 1024, 768] in bfloat16, the dispatcher's tiles: ONE Mosaic module
+    whose every part loops over the head groups, two groups of two heads an
+    iteration, so a third of the parent's score elements stand in its text
+    (the compiled kernel is 16,887 bundles where the parent's is 52,913; the
+    module's OPERATIONS are as many as the parent's, 5,023 | 4,965, since the
+    row sums and p @ v are written out a 128-column chunk at a time), and it
+    compiles for the described v5e under its name."""
+    fwd, shape = _cell_forward()
+    (body,) = mosaic_bodies(fwd, *[jax.ShapeDtypeStruct(shape, jnp.bfloat16)] * 3)
+    parts = len(sys.modules["paddle_tpu.ops.pallas.flash_attention"]._trims(256, 1024, 0, 1)) + 1
+    assert body.count("scf.for") == parts == 5
+    exp_elements = sum(int(r) * int(c) for r, c in re.findall(
+        r'math\.exp"\(%\d+\)[^\n]*?\(vector<(\d+)x(\d+)xf32>\)', body))
+    assert 0 < exp_elements * 3 <= _PARENT_CELL_FWD_EXP_ELEMENTS
+    assert kernel_names(compiled_text(fwd, *[tpu_arg(shape, jnp.bfloat16)] * 3)) == ["flash_fwd"]
+
+
+@pytest.mark.parametrize("heads,head_dim,bq", [(12, 64, 512), (12, 64, 1024), (16, 64, None), (20, 64, None),
+                                               (32, 64, None), (8, 128, None)])
+def test_the_looped_forward_compiles_at_wider_models_and_longer_q_tiles(tpu_arg, heads, head_dim, bq):
+    """Within the VMEM a kernel may use (backend.VMEM_LIMIT): the loop keeps
+    no accumulator and no statistics scratch, so 32 heads of 64 at 256 rows
+    and twelve at 1,024 rows both fit."""
+    fwd, shape = _cell_forward(heads, head_dim, bq, batch=4)
+    fa = sys.modules["paddle_tpu.ops.pallas.flash_attention"]
+    before = fa.fwd_body_counts()["looped"]
+    assert kernel_names(compiled_text(fwd, *[tpu_arg(shape, jnp.bfloat16)] * 3)) == ["flash_fwd"]
+    assert fa.fwd_body_counts()["looped"] == before + 1
 
 
 def test_lmhead_ce_kernels_carry_their_names_at_gpt2s_widths(tpu_arg):
@@ -364,7 +413,10 @@ _PARENT_FLASH_FULL = {
     ("BTHD", 1024, 2048, 4, 128, False): "9809582472e40813",
     ("BTHD", 2048, 2048, 12, 64, True): "b4ad53e89584c5a1", ("BTHD", 1024, 2048, 12, 64, True): "323c667b7e11f2d6",
     ("BTHD", 1024, 1536, 4, 128, True): "3ff759509556c6f9", ("BHTD", 1024, 1024, 12, 64, True): "f99e4361ffac4b45",
-    ("BHTD", 2048, 2048, 4, 128, True): "ab05d7a00a1f8e0b", ("BTHD", 1024, 1024, 12, 64, False): "8812b777696813f0"}
+    ("BHTD", 2048, 2048, 4, 128, True): "ab05d7a00a1f8e0b", ("BTHD", 1024, 1024, 12, 64, False): "8812b777696813f0",
+    # PR 53 (parent 19b1a3b): what its looped forward refuses hashes as on ITS parent: causal calls of ONE kv step
+    # whose heads do not group into whole lane tiles (GPT-2 XL's 25 heads of 64; 7), the unrolled `single` body
+    ("BTHD", 1024, 1024, 25, 64, True): "6f3066afc2743c10", ("BTHD", 1024, 1024, 7, 64, True): "104e870a357cacbe"}
 
 
 def _kernel_jaxprs(jaxpr):
@@ -388,6 +440,26 @@ def test_a_non_causal_flash_call_runs_the_parents_kernels(layout, t, tk, h, d, c
     kernels = _kernel_jaxprs(jax.make_jaxpr(call)(q, kv, kv).jaxpr)
     assert len(kernels) == 3
     assert sha("\n".join(kernels)) == _PARENT_FLASH_FULL[layout, t, tk, h, d, causal]
+
+
+def test_the_cells_flash_call_keeps_the_parents_backward_beside_its_looped_forward():
+    """gpt2s-train-1k's call, two kernels: the fused backward hashes as on PR
+    53's parent (19b1a3b: its loop over head groups now shares _over_groups,
+    _lanes_of and _own_lanes with the forward and traces as it did); the
+    forward no longer does."""
+    from paddle_tpu.ops import attention
+
+    bq, bk, bwd = attention._flash_tiles(1024, 1024, "BTHD", True, heads=12, head_dim=64)
+
+    def call(q, k, v):
+        out, vjp = jax.vjp(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, block_q=bq, block_k=bk, layout="BTHD", bwd_blocks=bwd, interpret=False), q, k, v)
+        return vjp(out)
+
+    a = jax.ShapeDtypeStruct((2, 1024, 12, 64), jnp.bfloat16)
+    fwd, bwd_kernel = _kernel_jaxprs(jax.make_jaxpr(call)(a, a, a).jaxpr)
+    assert sha(bwd_kernel) == "80fc750c59c68ab1"
+    assert sha(fwd) != "9f4dc508dd03b549" and ("scan[" in fwd or "while[" in fwd)
 
 
 # The same programs for a block of another kind: OLMoE's (RMSNorm, RoPE, q/k

@@ -15,8 +15,11 @@ libtpu's lock until it exits: under several workers the files need
 ``ALLOW_MULTIPLE_LIBTPU_LOAD=1``, as the driver's command sets it, or all
 but the first worker's skip.
 """
+import base64
 import hashlib
+import json
 import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -82,6 +85,23 @@ def sha(text):
     return hashlib.sha256(text.encode()).hexdigest()[:16]
 
 
+def mosaic_bodies(fn, *args):
+    """The Mosaic module of every kernel `fn` lowers to for a TPU, as text
+    (generic form, no locations): what Pallas hands the Mosaic compiler.
+    Lowered and not compiled, so it needs no compile-only target."""
+    from jax._src.lib.mlir import ir
+
+    text = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    ctx = ir.Context()
+    ctx.allow_unregistered_dialects = True  # the bytecode's versioned dialect, stable_mosaic
+    out = []
+    with ctx:
+        for config in re.findall(r'backend_config = "(.*?[^\\])"', text, re.S):
+            body = json.loads(config.replace("\\22", '"'))["custom_call_config"]["body"]
+            out.append(ir.Module.parse(base64.b64decode(body)).operation.get_asm(enable_debug_info=False))
+    return out
+
+
 def the_dispatchers_tiles_compile_at_gpt2s_widths(tpu_arg, layout):
     """Mosaic takes the kernels at the tiles the dispatcher picks for a
     causal call of T 1024 and 12 heads of 64: the one-step forward with its
@@ -114,7 +134,13 @@ def the_dispatchers_tiles_compile_at_gpt2s_widths(tpu_arg, layout):
 
     batch = 32 if fused else 2
     qkv = [tpu_arg((batch, 1024, heads, hd) if layout == "BTHD" else (batch, heads, 1024, hd), jnp.bfloat16)] * 3
+    fa = sys.modules["paddle_tpu.ops.pallas.flash_attention"]  # the package attribute is the function
+    before = fa.fwd_body_counts()
     names = kernel_names(compiled_text(jax.grad(loss, argnums=(0, 1, 2)), *qkv))
+    # since PR 53 the one-step BTHD forward LOOPS over its head groups wherever the heads group into whole lane
+    # tiles (every width here but GPT-2 XL's 25 heads of 64); BHTD walks its heads on the grid and is not counted
+    took = {b: n - before[b] for b, n in fa.fwd_body_counts().items() if n != before[b]}
+    assert took == ({} if layout == "BHTD" else {"unrolled": 1} if variant == "1600_wide" else {"looped": 1}), took
     assert own_names(names) == (["flash_dkv", "flash_fwd"] if fused else ["flash_dkv", "flash_dq", "flash_fwd"]), names
     rx = metric_pattern("flash_kernels_roofline")
     assert all(rx.search(n) for n in names), names

@@ -25,8 +25,9 @@ Usage: python tools/flash_sweep.py MODE [--batch 32 --heads 12 --seq 1024
                    kernels per "bq_dq,bk_dq,bq_dkv,bk_dkv", or the ONE
                    fused kernel per "fused,bk" (its kv tile; its q tile is
                    the sequence), so one call ranks them
-  check            dq, dk, dv of each "bwd" tiling against XLA's gradients
-                   through materialised scores, at the call's shape
+  check            the forward's output and lse, then dq, dk, dv of each
+                   "bwd" tiling, against XLA's through materialised scores,
+                   at the call's shape
   step             the full GPT-2 small train step at (batch, seq) per
                    "bq,bk" (the backward on the forward's tiles),
                    "bq,bk/bq_dq,bk_dq,bq_dkv,bk_dkv", "bq,bk/fused,bk" or
@@ -36,7 +37,8 @@ Usage: python tools/flash_sweep.py MODE [--batch 32 --heads 12 --seq 1024
                    dispatcher's own table): the number that decides,
                    since kernel-local wins can lose end to end.
 Every mode prints the share of the score square each kernel computes
-(the monitor's flash_tiles_total) beside the time.
+(the monitor's flash_tiles_total) beside the time, and `fwd` which body the
+forward took (flash_fwd_calls_total: looped over head groups, or unrolled).
 """
 from __future__ import annotations
 
@@ -51,7 +53,10 @@ import numpy as np
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 _DEFAULT_TILES = {  # PR 35's candidates at the defaults' shape; the table took the first of each
-    "fwd": "256,1024 128,1024 512,1024 1024,1024 256,512 512,512 256,256",
+    # PR 53 swept the first four again once the one-step forward looped over its
+    # heads: PR 35's long tiles (512, 1024 rows) had lost to the size of an
+    # unrolled kernel's code before they lost to the area they compute
+    "fwd": "256,1024 512,1024 1024,1024 128,1024 256,512 512,512 256,256",
     "dq": "128,1024 256,1024 512,1024 512,512 256,512 256,256",
     "dkv": "512,256 256,256 512,512 1024,256 1024,512 128,256",
     # PR 43: the fused kernel against the two it replaces
@@ -68,6 +73,12 @@ def computed_share(kernel=None):
     out = {k: round((n["interior"] + n["diagonal"]) / sum(n.values()), 4)
            for k, n in fa.tile_counts().items() if sum(n.values())}
     return out if kernel is None else out.get(kernel)
+
+
+def _fwd_body():
+    """Which body the forward calls traced since the last reset took."""
+    fa = sys.modules["paddle_tpu.ops.pallas.flash_attention"]
+    return {b: int(n) for b, n in fa.fwd_body_counts().items() if n}
 
 
 def _timed(many, args, label, flops, iters):
@@ -124,6 +135,7 @@ def sweep_fwd(a):
                 jax.lax.fori_loop(0, a.iters, body, qq).astype(jnp.float32))
 
         _timed(many, (q, k, v), f"fwd bq={bq} bk={bk}", _needed_flops(a, 2), a.iters)
+        print(f"    body {_fwd_body()}", flush=True)
 
 
 def _attend(a, tiles):
@@ -136,6 +148,50 @@ def _attend(a, tiles):
     return lambda q, k, v: flash_attention(
         q, k, v, causal=a.causal, block_q=fwd[0], block_k=fwd[1], layout=a.layout,
         bwd_blocks=tiles if fused or len(tiles) > 2 else tiles * 2)
+
+
+def scores_reference(q, k, v, causal=True, layout="BTHD"):
+    """(out, lse) of attention through materialised scores in float32 at the
+    highest matmul precision (bottom-right aligned mask, as the kernels');
+    out in the inputs' layout, lse (B, H, T)."""
+    import jax
+    import jax.numpy as jnp
+
+    bthd = layout == "BTHD"
+    with jax.default_matmul_precision("highest"):
+        q, k, v = (t.astype(jnp.float32) if bthd else t.astype(jnp.float32).transpose(0, 2, 1, 3) for t in (q, k, v))
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+        if causal:
+            t, tk = s.shape[-2:]
+            s = jnp.where(jnp.tril(jnp.ones((t, tk), bool), tk - t), s, -jnp.inf)
+        out = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
+        return (out if bthd else out.transpose(0, 2, 1, 3)), jax.nn.logsumexp(s, axis=-1)
+
+
+def check_fwd(a, q, k, v):
+    """Largest |difference| of the forward's output (over the largest
+    |XLA output|) and of its lse from scores_reference's (eight batch
+    elements at a time), on the dispatcher's tiles for the call."""
+    import importlib
+
+    import jax
+
+    from paddle_tpu.ops import attention
+
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")  # the package attribute is the function
+    bthd = a.layout == "BTHD"
+    bq, bk, _ = attention._flash_tiles(a.seq, a.kv_seq, a.layout, a.causal, heads=a.heads, head_dim=a.head_dim)
+    out, lse = fa._fwd(q, k, v, causal=a.causal, scale=1.0 / np.sqrt(a.head_dim), block_q=bq, block_k=bk,
+                       interpret=not fa.on_tpu(), bthd=bthd)
+    lse = np.asarray(lse if bthd else lse[..., 0])
+    ref = jax.jit(lambda *t: scores_reference(*t, causal=a.causal, layout=a.layout))
+    errs = {"out": 0.0, "lse": 0.0}
+    for b in range(0, a.batch, 8):
+        ro, rl = (np.asarray(x) for x in ref(q[b:b + 8], k[b:b + 8], v[b:b + 8]))
+        errs["out"] = max(errs["out"], float(np.abs(np.asarray(out[b:b + 8], np.float32) - ro).max() / np.abs(ro).max()))
+        errs["lse"] = max(errs["lse"], float(np.abs(lse[b:b + 8] - rl).max()))
+    print(f"check fwd ({bq}, {bk}): max |out - xla f32| / max |xla f32|, max |lse - xla f32| {errs}", flush=True)
+    assert all(np.isfinite(e) for e in errs.values())
 
 
 def check_bwd(a):
@@ -160,6 +216,7 @@ def check_bwd(a):
 
     chunks = [ref_grads(*(t[b:b + 8] for t in (q, k, v, cot))) for b in range(0, a.batch, 8)]
     ref = [np.concatenate([np.asarray(c[i]) for c in chunks]) for i in range(3)]
+    check_fwd(a, q, k, v)
     for tiles in a.tiles:
         got = jax.jit(lambda q, k, v, f=_attend(a, tiles): jax.vjp(f, q, k, v)[1](cot))(q, k, v)
         errs = {n: float(np.abs(np.asarray(g, np.float32) - r).max() / np.abs(r).max())

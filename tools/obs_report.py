@@ -151,6 +151,9 @@ def _executor_section(snap) -> Dict[str, Any]:
         "grad_paired": _scalar(snap, "executor_grad_paired_total"),
         "grad_retraced": _scalar(snap, "executor_grad_retraced_total"),
         "flash_tiles": _flash_tiles(snap),
+        # BTHD flash forwards traced so far by how the kernel walks its heads
+        "flash_fwd_calls": {(s.get("labels") or {}).get("body", ""): float(s.get("value", 0))
+                            for s in _series(snap, "flash_fwd_calls_total")},
         "compile_seconds": hist_summary(
             _hist_entry(snap, "executor_compile_seconds")),
         "run_seconds": hist_summary(_hist_entry(snap, "executor_run_seconds")),
